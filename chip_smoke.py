@@ -1,0 +1,485 @@
+"""The quickest proof that the system still starts on the chip.
+
+One process, one TPU v5e chip, no network, weights from a seed. Drives the
+two entry points a user takes, once each, at the full width of models the
+repo lists (depth and all), and compiles every Pallas kernel the default
+configuration can select:
+
+  device   jax.devices() must be TPUs — checked before anything is built
+  train    BERT-large pretraining through TrainStep(amp="bfloat16"), Adam
+           with float32 masters, batch 64 x seq 128, a few steps
+  serve    GPT-2 345M through GenerationEngine(paged=True, bf16 cache) and
+           ContinuousBatcher; greedy tokens checked against a plain
+           re-forward of the same net
+  kernels  flash attention fwd+bwd and paged attention, compiled by Mosaic
+           and compared with their XLA references
+
+Any failure in any phase raises and the process exits non-zero; nothing is
+caught. The last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
+with exactly those keys; what each phase found is on the ``summary:`` line
+before it. This is a smoke run: its seconds say the program ran, they are
+not a benchmark.
+
+    python chip_smoke.py            # one chip, all four phases
+    python chip_smoke.py --chips 4  # device + train under the ZeRO layout
+                                    # over four chips, against one chip
+
+Send it through the chip tool; on a host without a TPU it fails at once.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import importlib.metadata
+import json
+import time
+
+SEED = 0
+
+
+def say(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+# --------------------------------------------------------------------------
+# device
+# --------------------------------------------------------------------------
+def require_tpu(min_devices=1):
+    """The devices jax runs on, after checking that they are TPUs. No probe
+    in a child, no waiting, no retry: one process owns the chip."""
+    import jax
+
+    devices = jax.devices()
+    found = sorted({(d.platform, d.device_kind) for d in devices})
+    if any(d.platform != "tpu" for d in devices):
+        raise RuntimeError(
+            f"chip_smoke needs a TPU; jax.devices() found {len(devices)} "
+            f"device(s) of (platform, kind) {found}")
+    if len(devices) < min_devices:
+        raise RuntimeError(
+            f"chip_smoke --chips {min_devices} needs {min_devices} TPU "
+            f"devices; jax.devices() found {len(devices)}: {devices}")
+    return devices
+
+
+def phase_device(min_devices):
+    import jax
+    import jaxlib
+
+    devices = require_tpu(min_devices)
+    import mxnet_tpu  # noqa: F401  (places the compile cache at import)
+
+    info = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    versions = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                "libtpu": importlib.metadata.version("libtpu")}
+    say(f"device: platform={info['platform']} device_kind={info['kind']} "
+        f"n_devices={info['count']} versions={versions} "
+        f"compile_cache={jax.config.jax_compilation_cache_dir}")
+    return devices, info, versions
+
+
+# --------------------------------------------------------------------------
+# train
+# --------------------------------------------------------------------------
+def build_bert_step(model="bert_large", batch=64, seq=128, masked=20,
+                    layout=None):
+    """BERT pretraining as the docs tell users to run it: float32 masters,
+    ``TrainStep(amp="bfloat16")``, Adam. Returns the step and one batch.
+    Batch 64 x seq 128 without recomputation fits one v5e chip: the device
+    reported a peak of 6.2 GB in use of its 16.9 GB (PR 21's run)."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd, optimizer
+    from mxnet_tpu.models import bert
+    from mxnet_tpu.parallel import TrainStep
+
+    mx.random.seed(SEED)
+    net = bert.get_bert(model, pretrain_head=True)
+    net.initialize()
+    vocab = bert.bert_configs[model]["vocab_size"]
+    rs = np.random.RandomState(SEED)
+    args = (
+        nd.array(rs.randint(0, vocab, (batch, seq)), dtype="int32"),   # ids
+        nd.zeros((batch, seq), dtype="int32"),                         # types
+        nd.full((batch,), seq, dtype="int32"),                         # valid
+        nd.array(rs.randint(0, seq, (batch, masked)), dtype="int32"),  # pos
+        nd.array(rs.randint(0, vocab, (batch, masked)), dtype="int32"),
+        nd.ones((batch, masked)),                                      # weights
+        nd.array(rs.randint(0, 2, (batch,)), dtype="int32"),           # nsp
+    )
+    net(*(a[:2] for a in args[:4]))  # deferred init: shapes from two rows
+
+    def loss_fn(out, labels, weights, nsp_labels):
+        return bert.pretrain_loss(*out, labels, weights, nsp_labels)
+
+    ts = TrainStep(net, loss_fn, optimizer.Adam(learning_rate=1e-4),
+                   n_model_inputs=4, amp="bfloat16", layout=layout)
+    return ts, args
+
+
+def phase_train(devices, layout=None, steps=10, **size):
+    """BERT-large pretraining steps on a repeated batch: one compile, then
+    ``steps`` more."""
+    import jax
+    import numpy as np
+
+    ts, args = build_bert_step(layout=layout, **size)
+
+    losses, seconds = [], []
+    for _ in range(1 + steps):  # the first call traces and compiles
+        t0 = time.perf_counter()
+        losses.append(ts(*args))
+        jax.block_until_ready(losses[-1])
+        seconds.append(time.perf_counter() - t0)
+    losses = [float(x) for x in jax.device_get(losses)]
+    compile_s, step_s = seconds[0], float(np.median(seconds[1:]))
+
+    if not np.isfinite(losses).all():
+        raise AssertionError(f"train: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"train: loss did not fall: {losses}")
+    if max(seconds[1:]) > 10 * step_s:  # one compile, then steps
+        raise AssertionError(f"train: a later step compiled again or "
+                             f"stalled: {seconds}")
+
+    # where the state lives: every parameter and optimizer moment on the
+    # checked devices, float32 masters, and (under a layout) sharded
+    # leaves spread over every device at 1/n of their bytes each
+    state = jax.tree_util.tree_leaves((ts.params, ts.opt_state))
+    want = set(devices) if layout is not None else {devices[0]}
+    n_sharded = 0
+    per_device = dict.fromkeys(devices, 0)
+    for name, leaf in ts.params.items():
+        if leaf.dtype != np.float32:
+            raise AssertionError(f"train: master {name} is {leaf.dtype}")
+    for leaf in state:
+        if not leaf.sharding.device_set <= want:
+            raise AssertionError(
+                f"train: state on {leaf.sharding.device_set}, want {want}")
+        shards = leaf.addressable_shards
+        for sh in shards:
+            per_device[sh.device] += sh.data.nbytes
+        if shards[0].data.nbytes < leaf.nbytes:
+            n_sharded += 1
+            if (leaf.sharding.device_set != set(devices)
+                    or shards[0].data.nbytes * len(devices) != leaf.nbytes):
+                raise AssertionError(
+                    f"train: sharded leaf {leaf.shape} not split evenly "
+                    f"over {len(devices)} devices: {leaf.sharding}")
+    total = sum(leaf.nbytes for leaf in state)
+    mem = devices[0].memory_stats()
+    out = {
+        "model": size.get("model", "bert_large"),
+        "batch_x_seq": list(args[0].shape),
+        "amp": "bfloat16", "optimizer": "adam, float32 masters",
+        "n_params": int(sum(p.size for p in ts.params.values())),
+        "compile_plus_first_step_s": round(compile_s, 2),
+        "step_s": round(step_s, 4), "steps": steps,
+        "slowest_later_step_s": round(max(seconds[1:]), 4),
+        "loss": [round(x, 5) for x in losses],
+        "state_bytes": total,
+        "state_bytes_per_device": [per_device[d] for d in devices],
+        "sharded_leaves": n_sharded,
+        "peak_bytes_in_use": mem["peak_bytes_in_use"],
+        "bytes_limit": mem.get("bytes_limit"),
+    }
+    say(f"train: {out}")
+    return out
+
+
+def phase_train_four_chips(devices):
+    """The same steps on one chip and under the ZeRO layout of
+    docs/PARALLELISM.md over all four: state spread at a quarter per chip
+    and the losses in agreement."""
+    import numpy as np
+
+    from mxnet_tpu.parallel import Layout
+
+    one = phase_train(devices)
+    gc.collect()  # the one-chip state must leave chip 0 first
+    # pure ZeRO: no tensor-parallel rules (on a tp=1 mesh a matching tp
+    # rule shards nothing and shadows the fsdp fallback — see PERF.md)
+    layout = Layout(fsdp=4, fsdp_axis="fsdp")
+    mesh_devices = set(layout.mesh().devices.flat)
+    if mesh_devices != set(devices):
+        raise AssertionError(f"layout mesh holds {mesh_devices}, "
+                             f"not the {len(devices)} visible devices")
+    four = phase_train(devices, layout=layout)
+    if not four["sharded_leaves"]:
+        raise AssertionError("four chips: no state leaf was sharded")
+    share = max(four["state_bytes_per_device"]) / four["state_bytes"]
+    if not 0.25 <= share < 0.27:
+        raise AssertionError(f"four chips: a device holds {share:.3f} of "
+                             "the state, want about a quarter")
+    # bf16 matmuls reduced in another order, then Adam: a percent
+    got, ref = np.array(four["loss"]), np.array(one["loss"])
+    np.testing.assert_allclose(got, ref, rtol=2e-2)
+    return {"one_chip": one, "four_chips": four,
+            "max_loss_rel_diff": round(float(np.max(np.abs(got - ref)
+                                                    / np.abs(ref))), 5),
+            "state_share_per_device": round(share, 4)}
+
+
+# --------------------------------------------------------------------------
+# serve
+# --------------------------------------------------------------------------
+def _reforward_greedy(net, prompt, n_new, length):
+    """The oracle of tools/genbench.py's cached-vs-naive section: greedy
+    tokens from a plain full forward of the hybridized net over the
+    growing sequence. The sequence sits in a fixed ``length`` buffer
+    (causal attention keeps position i blind to the padding behind it), so
+    the forward compiles once. Also returns the least top-2 logit margin
+    met — how close the argmax came to a tie."""
+    import numpy as np
+
+    from mxnet_tpu import nd
+
+    buf = np.zeros((1, length), np.int32)
+    buf[0, :len(prompt)] = prompt
+    cur, margin, out = len(prompt), float("inf"), []
+    for _ in range(n_new):
+        row = net(nd.array(buf, dtype="int32")).asnumpy()[0, cur - 1]
+        top2 = np.partition(row, -2)[-2:]
+        margin = min(margin, float(top2[1] - top2[0]))
+        out.append(int(np.argmax(row)))
+        buf[0, cur] = out[-1]
+        cur += 1
+    return out, margin
+
+
+def phase_serve(devices, model="gpt2_345m", slots=4,
+                prompt_lens=(12, 40, 9, 50, 14, 36), n_new=12):
+    """Requests of several prompt lengths through the paged engine and the
+    batcher: more requests than slots, two prefill buckets (16 and 64)."""
+    import jax
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu.inference import ContinuousBatcher, GenerationEngine
+    from mxnet_tpu.models import gpt2
+
+    mx.random.seed(SEED)
+    net = gpt2.get_gpt2(model, dropout=0.0)
+    net.initialize()
+    net(nd.array(np.zeros((1, 4)), dtype="int32"))  # deferred init
+    vocab = gpt2.gpt2_configs[model]["vocab_size"]
+
+    engine = GenerationEngine(net, batch_size=slots, paged=True,
+                              cache_dtype="bfloat16")
+    say(f"serve: paged decode read path: {engine.read_path}")
+    for k_pool, _ in engine.pools:
+        if k_pool.sharding.device_set != {devices[0]}:
+            raise AssertionError(f"serve: pool on {k_pool.sharding}")
+    rs = np.random.RandomState(SEED + 1)
+    prompts = [rs.randint(1, vocab, n).tolist() for n in prompt_lens]
+    buckets = sorted({engine.bucket_for(n) for n in prompt_lens})
+    if len(buckets) < 2:
+        raise AssertionError(f"serve: prompts fill one bucket {buckets}")
+
+    def wave():
+        batcher = ContinuousBatcher(engine)
+        reqs = [batcher.submit(p, max_new_tokens=n_new) for p in prompts]
+        t0 = time.perf_counter()
+        batcher.run_until_idle()
+        dt = time.perf_counter() - t0
+        for r in reqs:
+            if r.finish_reason != "length" or len(r.output) != n_new:
+                raise AssertionError(
+                    f"serve: request {r.id} ended {r.finish_reason!r} "
+                    f"with {len(r.output)} tokens")
+        return reqs, dt
+
+    cold, cold_s = wave()   # compiles every program
+    warm, warm_s = wave()   # the same traffic again: nothing compiles
+    programs = engine.compiled_programs
+    if programs != len(buckets) + 1:
+        raise AssertionError(f"serve: {programs} programs compiled, want "
+                             f"{len(buckets)} prefill buckets + 1 decode")
+    if [r.output for r in warm] != [r.output for r in cold]:
+        raise AssertionError("serve: second wave's greedy tokens differ")
+
+    # outside any timing: the first request against the re-forward oracle
+    net.hybridize()
+    want, margin = _reforward_greedy(net, prompts[0], n_new, length=64)
+    if cold[0].output != want:
+        raise AssertionError(f"serve: engine tokens {cold[0].output} != "
+                             f"re-forward tokens {want}")
+
+    # which read path the decode program was built with
+    decode_text = engine.lower_decode().compile().as_text()
+    out = {
+        "model": model, "slots": slots, "cache_dtype": "bfloat16",
+        "requests": len(prompts), "prompt_lens": list(prompt_lens),
+        "new_tokens_each": n_new, "prefill_buckets_used": buckets,
+        "compiled_programs": programs,
+        "read_path": engine.read_path,
+        "decode_tpu_custom_calls": decode_text.count("tpu_custom_call"),
+        "tokens_equal_reforward": True,
+        "reforward_min_top2_margin": round(margin, 5),
+        "cold_wave_s": round(cold_s, 2), "warm_wave_s": round(warm_s, 3),
+        "ttft_warm_s": [round(r.ttft, 4) for r in warm],
+        "ttft_cold_s": [round(r.ttft, 2) for r in cold],
+        "bytes_in_use": devices[0].memory_stats()["bytes_in_use"],
+    }
+    say(f"serve: {out}")
+    return out
+
+
+# --------------------------------------------------------------------------
+# kernels
+# --------------------------------------------------------------------------
+def _custom_calls(fn, *args):
+    """``tpu_custom_call`` ops in the program ``fn`` lowers to."""
+    import jax
+
+    return jax.jit(fn).lower(*args).as_text().count("tpu_custom_call")
+
+
+def _rel_err(a, b):
+    import jax.numpy as jnp
+
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def check_flash(causal, b=4, h=16, t=2048, d=64, interpret=None):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    rs = np.random.RandomState(SEED)
+    q, k, v, w = (jnp.asarray(rs.randn(b, h, t, d), jnp.bfloat16)
+                  for _ in range(4))
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal,
+                                  interpret=interpret)
+
+    def ref(q, k, v):
+        return fa._ref_attention(q, k, v, causal)
+
+    def grads(f):
+        return jax.grad(lambda q, k, v, w: jnp.sum(
+            f(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)),
+            argnums=(0, 1, 2))
+
+    calls = {"fwd": _custom_calls(flash, q, k, v),
+             "bwd": _custom_calls(grads(flash), q, k, v, w)}
+    if calls["fwd"] < 1 or calls["bwd"] < 3:  # fwd; fwd + dkv + dq
+        raise AssertionError(f"flash causal={causal}: lowered without its "
+                             f"Mosaic kernels: {calls}")
+    errs = {"out": _rel_err(jax.jit(flash)(q, k, v), jax.jit(ref)(q, k, v))}
+    got = jax.jit(grads(flash))(q, k, v, w)
+    want = jax.jit(grads(ref))(q, k, v, w)
+    errs.update({n: _rel_err(g, r) for n, g, r in zip(("dq", "dk", "dv"),
+                                                      got, want)})
+    if not all(e < 2e-2 for e in errs.values()):  # bf16 in, f32 softmax
+        raise AssertionError(f"flash causal={causal}: off its einsum "
+                             f"reference: {errs}")
+    return {"shape": [b, h, t, d], "causal": causal,
+            "tpu_custom_calls": calls,
+            "rel_err": {n: round(e, 5) for n, e in errs.items()}}
+
+
+def check_paged(rows=8, h=8, ch=128, ps=16, n_pages=64, interpret=None):
+    """A geometry the kernel's own gate admits: the engine's decode layout
+    (float32 activations over a bf16 pool), one query token a row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rs = np.random.RandomState(SEED)
+    pool_pages = rows * n_pages
+    k_pool, v_pool = (jnp.asarray(rs.randn(pool_pages + 1, h, ps, ch),
+                                  jnp.bfloat16) for _ in range(2))
+    table = jnp.asarray(rs.permutation(pool_pages).reshape(rows, n_pages)
+                        + 1, jnp.int32)
+    position = jnp.asarray(rs.randint(0, n_pages * ps - 1, rows), jnp.int32)
+    q, k_new, v_new = (jnp.asarray(rs.randn(rows, h, 1, ch), jnp.float32)
+                       for _ in range(3))
+    why = ppa.paged_attention_refusal(q, k_pool, table)
+    if why is not None:
+        raise AssertionError(f"paged: the kernel's gate refuses: {why}")
+
+    def kernel(*a):
+        return ppa.paged_attention(*a, interpret=interpret)
+
+    a = (q, k_new, v_new, k_pool, v_pool, table, position)
+    calls = _custom_calls(kernel, *a)
+    if calls < 1:
+        raise AssertionError("paged: lowered without its Mosaic kernel")
+    got = jax.jit(kernel)(*a)
+    want = jax.jit(att._paged_gather_mha)(*a)
+    for g, r in zip(got[1:], want[1:]):  # the scatter is XLA on both sides
+        if not bool(jnp.array_equal(g, r)):
+            raise AssertionError("paged: pools differ after the scatter")
+    err = _rel_err(got[0], want[0])
+    if not err < 2e-2:
+        raise AssertionError(f"paged: relative error {err} against the "
+                             "XLA gather path")
+    return {"rows": rows, "heads": h, "head": ch, "page": ps,
+            "pages_per_row": n_pages, "pool": "bfloat16",
+            "tpu_custom_calls": calls, "rel_err": round(err, 6),
+            "bit_identical_to_gather": bool(jnp.array_equal(got[0],
+                                                            want[0]))}
+
+
+def phase_kernels():
+    """Every Pallas kernel the default configuration can select on a TPU,
+    called directly, compiled by Mosaic (a compile error fails the
+    phase) and compared with its XLA reference."""
+    out = {"flash_attention": [check_flash(causal=False),
+                               check_flash(causal=True)],
+           "paged_attention": check_paged()}
+    say(f"kernels: {out}")
+    return out
+
+
+# --------------------------------------------------------------------------
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: the train phase under the ZeRO layout over "
+                         "four chips, checked against one chip")
+    args = ap.parse_args()
+
+    t_start = time.perf_counter()
+    devices, device, versions = phase_device(args.chips)
+    phases = {}
+
+    def run(name, fn, *a):
+        t0 = time.perf_counter()
+        result = fn(*a)
+        gc.collect()  # the phase's device state goes before the next one
+        phases[name] = {"status": "ok",
+                        "seconds": round(time.perf_counter() - t0, 1),
+                        **result}
+
+    if args.chips == 4:
+        run("train_four_chips", phase_train_four_chips, devices)
+    else:
+        run("train", phase_train, devices)
+        run("serve", phase_serve, devices)
+        run("kernels", phase_kernels)
+    say("summary: " + json.dumps({
+        "versions": versions, "chips": args.chips,
+        "seconds": round(time.perf_counter() - t_start, 1),
+        "phases": phases}))
+    # the last line, to the driver's contract: these keys and no others
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -6,8 +6,8 @@ Two composable mechanisms, demonstrated end-to-end on a small decoder:
 
 1. **Single chip, long sequence**: `multi_head_attention` routes to the
    Pallas flash kernel (O(L) memory, FlashAttention-2 backward) once
-   seq >= 2048 — the measured v5e crossover (KERNELBENCH_r03.jsonl) — so
-   one chip trains sequence
+   seq >= 2048 (the kernel's gate; its crossover against einsum under the
+   installed jax is not measured) — so one chip trains sequence
    lengths whose [B, H, T, T] score tensor could never materialize.
 2. **Across chips**: the sequence axis itself is sharded over an `sp` mesh
    and K/V blocks rotate via `lax.ppermute` ring attention, with

@@ -47,7 +47,7 @@ from . import contrib  # noqa: F401
 from . import image  # noqa: F401
 from . import config  # noqa: F401
 
-config.apply_compile_cache()  # MXNET_TPU_COMPILE_CACHE: persistent XLA cache
+config.apply_compile_cache()  # persistent XLA compile cache placement
 
 from . import observability  # noqa: F401
 from . import inference  # noqa: F401

@@ -34,30 +34,53 @@ _KNOBS: Dict[str, tuple] = {
                                "kept for API compat"),
     "use_fusion": (bool, True, ("MXNET_USE_FUSION",),
                    "pointwise fusion — always on via XLA"),
+    # -- Pallas kernel selection. "v5e, PR 21" = chip_smoke.py /
+    # tools/kernelbench.py on one TPU v5e under jax 0.9.0, Mosaic-compiled
+    # (interpret=False) and compared with the XLA composition; speed
+    # against XLA is not measured for any of them yet ----------------------
     "fused_layernorm": (bool, False, ("MXNET_TPU_FUSED_LAYERNORM",),
                         "route LayerNorm through the Pallas kernel on TPU "
-                        "(off until hardware-validated; interpret-mode "
-                        "tested)"),
+                        "(opt-in. v5e, PR 21: compiles and agrees to one "
+                        "bf16 step at 8192-32768 rows x 1024-4096 wide)"),
     "flash_attention": (bool, True, ("MXNET_TPU_FLASH_ATTENTION",),
-                        "use the Pallas flash kernel when shapes allow"),
+                        "use the Pallas flash kernel when shapes allow "
+                        "(no mask, seq >= 2048. v5e, PR 21: forward and "
+                        "backward compile and agree with the einsum "
+                        "reference within 0.4% at seq 2048, causal and "
+                        "not, and at seq 4096; head 64)"),
     "flash_pallas_bwd": (bool, True, ("MXNET_TPU_FLASH_PALLAS_BWD",),
                          "FlashAttention-2 Pallas backward kernels (dq + "
                          "dkv); off = XLA chunked-recompute backward "
-                         "(~2.5x slower on v5e but kernel-free)"),
+                         "(kernel-free)"),
     "paged_attention_kernel": (bool, True, ("MXNET_TPU_PAGED_ATTENTION_KERNEL",),
                                "paged decode/verify read path through the "
                                "Pallas page-table kernel (in-kernel page "
                                "gather, no pool-wide materialization); off "
-                               "= XLA pool[page_table] gather fallback"),
+                               "= XLA pool[page_table] gather. On a TPU the "
+                               "kernel's gate admits head sizes of 128n "
+                               "only, so every listed GPT-2 (head 64) "
+                               "decodes through the gather either way. "
+                               "v5e, PR 21: compiles and agrees within "
+                               "0.3% (not bit-identically) at head 128, "
+                               "pages of 8 and 16, bf16 pool, f32 query; "
+                               "Mosaic refuses a bf16 query ('tpu.matmul' "
+                               "op Expected matmul acc to be 32-bit) and "
+                               "the gate now does too"),
     "fused_adam": (bool, False, ("MXNET_TPU_FUSED_ADAM",),
                    "route Adam/AdamW updates through the fused Pallas "
-                   "kernel on TPU (one pass over grad/m/v/master; off "
-                   "until hardware-validated; interpret-mode tested)"),
+                   "kernel on TPU (one pass over grad/m/v/master; opt-in. "
+                   "v5e, PR 21: compiles and equals the XLA chain exactly "
+                   "at 2^20 and 2^24 elements)"),
     "fused_softmax_xent": (bool, False, ("MXNET_TPU_FUSED_SOFTMAX_XENT",),
                            "fused softmax-cross-entropy Pallas kernel "
                            "(custom VJP) for sparse-label gluon loss on "
-                           "TPU (off until hardware-validated; "
-                           "interpret-mode tested)"),
+                           "TPU (opt-in. v5e, PR 21: at 128 rows a block "
+                           "Mosaic refused it — 'Ran out of memory in "
+                           "memory space vmem ... Scoped allocation with "
+                           "size 33.00M and limit 16.00M' — and with the "
+                           "row block scaled to the class count it "
+                           "compiles and agrees within 1e-5 at 8192 x "
+                           "32768 and 16384 x 50304)"),
     "default_dtype": (str, "float32", ("MXNET_DEFAULT_DTYPE",), "creation dtype"),
     "storage_fallback_warn": (bool, True, ("MXNET_STORAGE_FALLBACK_WARN",),
                               "warn when a sparse input densifies at an op "
@@ -208,13 +231,6 @@ _KNOBS: Dict[str, tuple] = {
                           "comma-separated burn-rate window lengths in "
                           "seconds, anchored at the newest finish "
                           "timestamp the aggregator sees"),
-    # -- compilation (docs/PERFORMANCE.md) -----------------------------------
-    "compile_cache": (str, "", ("MXNET_TPU_COMPILE_CACHE",),
-                      "persistent XLA compilation-cache directory "
-                      "(jax_compilation_cache_dir), honored at import: "
-                      "re-runs skip lowering+compile for every already-seen "
-                      "program signature, including the k-step window "
-                      "programs; empty = disabled"),
     # -- observability subsystem (docs/OBSERVABILITY.md) ---------------------
     "telemetry": (bool, False, ("MXNET_TPU_TELEMETRY",),
                   "arm hot-path telemetry at first use: step/comm/data/ckpt "
@@ -312,32 +328,23 @@ def describe(name: str) -> str:
     return f"{name} ({typ.__name__}, default={default!r}, env={'/'.join(envs)}): {doc}"
 
 
-def apply_compile_cache():
-    """Honor ``MXNET_TPU_COMPILE_CACHE`` at init: point jax's persistent
-    compilation cache at the directory so a restarted run pays zero XLA
-    compile time for every program signature it has seen before (the
-    single-step programs AND the per-(window, shapes) scan windows).
-    Called from package import; returns the applied directory or None."""
-    d = get("compile_cache")
-    if not d:
-        return None
-    import warnings
+#: where the persistent XLA compilation cache lives unless the environment
+#: places it: one fixed directory inside the checkout (git-ignored). The
+#: path is part of the cache key, so it never carries a temporary name, a
+#: pid or a time.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
+
+def apply_compile_cache():
+    """Place jax's persistent compilation cache, so a restarted run skips
+    XLA compilation of every program it has compiled before. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and nothing
+    is set here; otherwise the cache goes to :data:`COMPILE_CACHE_DIR`.
+    jax's own thresholds decide what is worth an entry. Called from
+    package import; returns the directory in effect."""
     import jax
 
-    d = os.path.abspath(d)
-    try:
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-    except (OSError, AttributeError) as e:
-        warnings.warn(f"MXNET_TPU_COMPILE_CACHE={d!r} not applied: {e}")
-        return None
-    # cache tiny/fast programs too — the CI dry-runs and unit meshes are
-    # exactly the programs worth skipping on the next run
-    for knob, v in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, v)
-        except Exception:  # older jax: knob absent
-            pass
-    return d
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir

@@ -68,6 +68,7 @@ first copy-on-write dispatch) — counted through the observability registry
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from collections import deque
 from typing import List, Optional, Sequence, Tuple
@@ -85,6 +86,8 @@ from ..resilience import retry as _retry
 from .prefix_cache import RadixPrefixCache
 
 __all__ = ["GenerationEngine", "SamplingConfig"]
+
+logger = logging.getLogger("mxnet_tpu.inference")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,6 +275,27 @@ class GenerationEngine:
             self.prefix_cache = (RadixPrefixCache(self.page_size)
                                  if prefix_cache else None)
             self._page_gauges()
+            # the kernel-or-gather choice is made per shape at trace time
+            # (ops.attention._paged_cached_mha); say here, once, what the
+            # decode program of this engine will be built with
+            from ..ops.pallas_common import on_tpu
+            from ..ops.pallas_paged_attention import paged_attention_refusal
+
+            k_pool = self.pools[0][0]
+            q = jax.ShapeDtypeStruct(
+                (self.batch_size, k_pool.shape[1], 1, k_pool.shape[3]),
+                self._plist[0]._nd._data.dtype)
+            why = paged_attention_refusal(q, k_pool, self.page_table)
+            #: read path of the paged decode program: the Pallas page-table
+            #: kernel, or the XLA ``pool[page_table]`` gather and why
+            if why is not None:
+                self.read_path = f"xla_gather ({why})"
+            elif on_tpu():
+                self.read_path = "pallas_paged_kernel"
+            else:
+                self.read_path = ("pallas_paged_kernel (interpreted: the "
+                                  "backend is not a TPU)")
+            logger.info("paged decode read path: %s", self.read_path)
         else:
             #: device state: per-layer (k_buf, v_buf), the donated carry
             self.cache = net.init_cache(self.batch_size, self.max_length,
@@ -1337,6 +1361,30 @@ class GenerationEngine:
                            float(active_in.sum()) / self.batch_size)
         return out, m, done
 
+    def lower_decode(self):
+        """Lower (don't run) the single-token decode program this engine
+        dispatches, through the engine's own jit function — the serving
+        counterpart of ``TrainStep.lower_hlo``. ``.as_text()`` /
+        ``.compile().as_text()`` of the result show what the program was
+        built with (e.g. a ``tpu_custom_call`` per Pallas kernel)."""
+        if self.speculative:
+            raise RuntimeError("a speculative engine decodes through its "
+                               "draft/verify pair; see audit()")
+        # constant dummy key: lowering never runs the program, and drawing
+        # from _next_key() would advance the stochastic-sampling stream
+        key = jax.random.key(0)
+        toks = jnp.asarray(self.last_tokens)
+        pos = jnp.asarray(self.positions)
+        done = jnp.asarray(self.done)
+        if not self.paged:
+            return self._decode_jit.lower(self._params(), self.cache, toks,
+                                          pos, done, key)
+        upd = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
+        clear = jnp.zeros((self.batch_size,), bool)
+        return self._decode_jit.lower(
+            self._params(), (self.page_table, self.pools), toks, pos, done,
+            upd, upd, clear, key)
+
     def audit(self, bucket: Optional[int] = None, compile: bool = True,
               program: str = "decode"):
         """Structural :class:`~mxnet_tpu.analysis.ProgramAudit` of a
@@ -1376,8 +1424,7 @@ class GenerationEngine:
         if not self.paged:
             carry = self.cache
             if bucket is None:
-                lowered = self._decode_jit.lower(params, carry, toks, pos,
-                                                 done, key)
+                lowered = self.lower_decode()
             else:
                 bucket = self.bucket_for(bucket)
                 tokens = jnp.full((1, bucket), self.pad_id, jnp.int32)
@@ -1450,9 +1497,7 @@ class GenerationEngine:
                                                 key)
             else:
                 carry = (self.page_table, self.pools)
-                lowered = self._decode_jit.lower(params, carry, toks, pos,
-                                                 done, upd_s, upd_p, clear,
-                                                 key)
+                lowered = self.lower_decode()
         n_carry = len(jax.tree_util.tree_leaves(carry))
         # flat arg order: (params [+ draft params]) leaves, then the cache
         # leaves (the donated carry)

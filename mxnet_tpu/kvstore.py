@@ -291,8 +291,8 @@ def _transfer_dtype(dt):
 def _instrumented_collective(op, arrays, call):
     """Run ``call()`` (the retried DCN collective) with telemetry: latency
     histogram, bytes-moved and call counters, per-transfer-dtype bucket
-    counts — the numbers XLA-side fusion makes invisible (SNIPPETS: DCN
-    psum cost dominates multi-host step time; without explicit timing it is
+    counts — the numbers XLA-side fusion makes invisible (DCN psum cost
+    can dominate multi-host step time; without explicit timing it is
     indistinguishable from compute)."""
     import numpy as np
 

@@ -153,12 +153,7 @@ def timed_region(metric_name: str, help: str, name: str, **labels):
     Exception-safe — the sample records even when the body raises."""
     import jax
 
-    step = events.current_step()
-    try:
-        ann = jax.profiler.TraceAnnotation(name, step=step)
-    except TypeError:  # older jax: no metadata kwargs
-        ann = jax.profiler.TraceAnnotation(name)
-    with ann:
+    with jax.profiler.TraceAnnotation(name, step=events.current_step()):
         t0 = time.perf_counter()
         try:
             yield

@@ -861,12 +861,8 @@ def capture(fn, *args, steps: int = 2, warmup: int = 1,
         jax.profiler.start_trace(trace_dir)
         try:
             for i in range(max(1, steps)):
-                try:
-                    ann = jax.profiler.TraceAnnotation(
-                        PROF_STEP_SPAN, step=step_offset + i)
-                except TypeError:  # older jax: no metadata kwargs
-                    ann = jax.profiler.TraceAnnotation(PROF_STEP_SPAN)
-                with ann:
+                with jax.profiler.TraceAnnotation(
+                        PROF_STEP_SPAN, step=step_offset + i):
                     _block(fn(*args, **kwargs))
         finally:
             jax.profiler.stop_trace()
@@ -1174,10 +1170,7 @@ class CaptureController:
     def _annotation(step: int):
         import jax
 
-        try:
-            ann = jax.profiler.TraceAnnotation(PROF_STEP_SPAN, step=step)
-        except TypeError:
-            ann = jax.profiler.TraceAnnotation(PROF_STEP_SPAN)
+        ann = jax.profiler.TraceAnnotation(PROF_STEP_SPAN, step=step)
         ann.__enter__()
         return ann
 

@@ -198,6 +198,14 @@ def _paged_cached_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
     if ppa.paged_attention_supported(q, k_pool, page_table):
         return ppa.paged_attention(q, k_new, v_new, k_pool, v_pool,
                                    page_table, position)
+    return _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table,
+                             position)
+
+
+def _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
+    """The XLA read path of :func:`_paged_cached_mha` (same contract), and
+    the reference the paged kernel is checked against: scatter the new
+    K/V, gather every row's history out of the pool, attend."""
     b, h, tq, ch = q.shape
     ps = k_pool.shape[2]
     n_pages = page_table.shape[1]

@@ -15,12 +15,10 @@ scratch) and ``dq`` grids over q blocks with k innermost — 5 block matmuls
 per (q,k) tile total, O(L) memory, vs the O(L^2) scores buffer of the einsum
 VJP. A ``lax.scan`` chunked recompute backward (`_chunked_attention`) is kept
 as the escape hatch (`config flash_pallas_bwd=False`) and as the long-seq
-correctness oracle; hardware timing (KERNELBENCH_r03.jsonl, v5e) shows the
-chunked path 1.3-4.7x slower than the flash kernels across seq 1024-8192.
-With the Pallas backward and 512x512 blocks the flash path is a measured
-net training win (same artifact): 1.13-1.33x vs the einsum VJP at seq 2048
-rising to 1.33-1.93x at seq 8192 (b*h=32..8, d 64/128, causal and not), at
-O(L) memory.
+correctness oracle. Forward and backward compile under Mosaic and agree
+with the einsum reference on a TPU v5e (``chip_smoke.py`` kernels phase:
+batch 4, 16 heads, seq 2048, head 64, causal and not). Their speed against
+the einsum and chunked paths under the installed jax: not measured.
 
 On non-TPU backends the kernels run in interpret mode (tests) or callers fall
 back to the einsum path via ``flash_supported``.
@@ -33,18 +31,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import HAS_PLTPU as _HAS_PLTPU
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
-from .pallas_common import pltpu
+from .pallas_common import resolve_interpret as _resolve_interpret
 
 
-_FLASH_MIN_SEQ = 2048  # measured crossover, v5e (KERNELBENCH_r03.jsonl,
-# fwd+bwd with the Pallas backward, 512x512 blocks): seq 1024 parity
-# (0.99-1.05x vs XLA einsum), seq 2048 1.13-1.33x faster, seq 4096 1.25-1.6x,
-# seq 8192 1.33-1.93x — and O(L) memory where einsum's [b,h,t,t] scores
-# buffer stops fitting HBM
+_FLASH_MIN_SEQ = 2048  # where a v5e run under jaxlib 0.4.36 (July 2026,
+# record no longer in the tree) saw flash fwd+bwd pull ahead of the XLA
+# einsum; the crossover under the installed jax is not measured. Past it
+# the kernel also keeps O(L) memory where einsum's [b,h,t,t] scores buffer
+# stops fitting HBM
 
 _FLASH_MEM_BYTES = 2 << 30  # engage below _FLASH_MIN_SEQ too when the einsum
 # path's f32 scores buffer alone would exceed this (huge batch*heads at
@@ -55,7 +53,7 @@ def flash_supported(q, k, v, mask=None) -> bool:
     """Kernel eligibility: TPU backend, no arbitrary mask, tile-able lengths,
     and either past the measured speed crossover or under einsum-memory
     pressure."""
-    if mask is not None or not _HAS_PLTPU or not _on_tpu():
+    if mask is not None or not _on_tpu():
         return False
     b, h, tq, d = q.shape
     tk = k.shape[2]
@@ -153,10 +151,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, bq, bk, scale,
 def _pick_block(t, prefer=512):
     """Largest MXU-friendly block (<= prefer) that divides the seq length.
     Bigger tiles keep the MXU pipeline full and cut grid-iteration
-    overhead; an interactive round-3 sweep saw 512x512 ~20-30% faster than
-    128x128 on v5e, but no committed artifact holds those rows — the
-    committed KERNELBENCH_r03 timings were all taken at this 512
-    default."""
+    overhead; 512x512 against smaller tiles on the chip: not measured."""
     for cand in (prefer, 256, 128):
         if cand <= t and t % cand == 0:
             return cand
@@ -197,8 +192,6 @@ def _flash_fwd(q, k, v, causal, block_q=None, block_k=None, interpret=False,
         pltpu.VMEM((bq, _LANES), jnp.float32),
         pltpu.VMEM((bq, _LANES), jnp.float32),
         pltpu.VMEM((bq, d), jnp.float32),
-    ] if _HAS_PLTPU else [
-        pl.MemorySpace.ANY  # pragma: no cover
     ]
     # the lse output exists only on the grad path (return_lse): Pallas can't
     # DCE an unused kernel output, and at padded d=64 it would be as large
@@ -222,9 +215,8 @@ def _flash_fwd(q, k, v, causal, block_q=None, block_k=None, interpret=False,
         out_specs=out_specs,
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if _HAS_PLTPU and not interpret else None,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr)
     out = res[0].reshape(b, h, tq, d)
     if d_orig != d:
@@ -328,9 +320,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q=None, block_k=None,
     dor = do.reshape(b * h, tq, d)
     off = tk - tq
     common = dict(causal=causal, bq=bq, bk=bk, scale=scale, off=off)
-    cparams = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    ) if _HAS_PLTPU and not interpret else None
+    cparams = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     q_spec_kmaj = pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0))
     lse_spec_kmaj = pl.BlockSpec((1, bq, _LANES),
@@ -345,8 +336,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q=None, block_k=None,
                   kv_spec_kmaj, kv_spec_kmaj],
         out_specs=[kv_spec_kmaj, kv_spec_kmaj],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)] if _HAS_PLTPU else
-        [pl.MemorySpace.ANY] * 2,  # pragma: no cover
+                        pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
         compiler_params=cparams,
     )(qr, dor, lse, di, kr, vr)
@@ -362,8 +352,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q=None, block_k=None,
         in_specs=[q_spec_qmaj, q_spec_qmaj, lse_spec_qmaj, lse_spec_qmaj,
                   kv_spec_qmaj, kv_spec_qmaj],
         out_specs=q_spec_qmaj,
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] if _HAS_PLTPU else
-        [pl.MemorySpace.ANY],  # pragma: no cover
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         compiler_params=cparams,
     )(qr, dor, lse, di, kr, vr)
@@ -446,9 +435,8 @@ def _flash_vjp_bwd(causal, interpret, res, g):
     if _config.get("flash_pallas_bwd"):
         return _flash_bwd_pallas(q, k, v, o, lse, g, causal,
                                  interpret=interpret)
-    # escape hatch: XLA chunked-recompute backward (latency-bound on TPU —
-    # 1.3-4.7x slower than the kernels on v5e, KERNELBENCH_r03.jsonl —
-    # but kernel-free)
+    # escape hatch: XLA chunked-recompute backward (kernel-free; its cost
+    # against the Pallas backward on the chip: not measured)
     _, vjp = jax.vjp(lambda q, k, v: _chunked_attention(q, k, v, causal),
                      q, k, v)
     return vjp(g)
@@ -463,6 +451,4 @@ def flash_attention(q, k, v, mask=None, causal=False, interpret=None):
     if mask is not None:
         raise ValueError("flash_attention kernel does not take arbitrary masks; "
                          "use multi_head_attention which falls back to the einsum path")
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _flash(q, k, v, bool(causal), bool(interpret))
+    return _flash(q, k, v, bool(causal), _resolve_interpret(interpret))

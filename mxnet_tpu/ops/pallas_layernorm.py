@@ -22,9 +22,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .pallas_common import HAS_PLTPU as _HAS_PLTPU
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
+from .pallas_common import resolve_interpret as _resolve_interpret
 
 _BLOCK_ROWS = 256
 # feature-dim cap: a (rows, d) f32 block must fit VMEM with room for the
@@ -33,19 +33,17 @@ _MAX_D = 8192
 
 
 def ln_kernel_supported(x, axis=-1) -> bool:
-    # opt-in on hardware (MXNET_TPU_FUSED_LAYERNORM=1). Interactive round-3
-    # runs (v5e, tools/kernelbench.py) saw oracle-exact results and
-    # 1.00-1.03x vs the XLA-fused jnp composition at (8k-32k rows,
-    # d 1024-4096), but NO committed artifact contains ln rows — treat as
-    # pending hardware. Either way XLA already fuses this pattern well, so
-    # the default stays the composition and the kernel remains an opt-in
-    # (useful as a fusion-regression guard)
+    # opt-in on hardware (MXNET_TPU_FUSED_LAYERNORM=1). On a v5e under jax
+    # 0.9.0 (tools/kernelbench.py, PR 21) the kernel compiles and agrees
+    # with the jnp composition to one bf16 step at (8k-32k rows, d
+    # 1024-4096); its speed against XLA's own fusion of this pattern is not
+    # measured, so the default stays the composition
     from .. import config as _config
 
     if not _config.get("fused_layernorm"):
         return False
     ax = axis % x.ndim
-    return (_HAS_PLTPU and _on_tpu() and ax == x.ndim - 1
+    return (_on_tpu() and ax == x.ndim - 1
             and x.shape[-1] % _LANES == 0 and x.shape[-1] <= _MAX_D
             and x.dtype in (jnp.float32, jnp.bfloat16))
 
@@ -119,9 +117,7 @@ _ln.defvjp(_ln_fwd, _ln_bwd)
 
 def layer_norm_fused(data, gamma, beta, eps=1e-5, interpret=None):
     """Fused LN over the last axis; any leading shape (flattened to rows)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     d = data.shape[-1]
     x2 = data.reshape(-1, d)
-    out = _ln(x2, gamma, beta, float(eps), bool(interpret))
+    out = _ln(x2, gamma, beta, float(eps), _resolve_interpret(interpret))
     return out.reshape(data.shape)

@@ -31,11 +31,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import HAS_PLTPU as _HAS_PLTPU
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
-from .pallas_common import pltpu
+from .pallas_common import resolve_interpret as _resolve_interpret
 
 _BLOCK_ROWS = 256  # (rows, 128) f32 blocks: 5 operands in + 4 out ≈ 1.2MB
 
@@ -53,7 +53,7 @@ def fused_adam_supported(w, g, mean) -> bool:
 
     if not _config.get("fused_adam"):
         return False
-    if not (_HAS_PLTPU and _on_tpu()):
+    if not _on_tpu():
         return False
     return (w.dtype == jnp.float32 and mean.dtype == jnp.float32
             and g.dtype in (jnp.float32, jnp.bfloat16)
@@ -100,8 +100,7 @@ def adam_update_fused(w, g, mean, var, lr_t, *, beta1, beta2, epsilon,
     scalars (they ride in SMEM), so hyperparameter schedules never
     retrigger compilation.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret(interpret)
     shape, dtype = w.shape, w.dtype
     n = w.size
     rows = max(8, min(_BLOCK_ROWS, -(-n // _LANES)))
@@ -126,9 +125,8 @@ def adam_update_fused(w, g, mean, var, lr_t, *, beta1, beta2, epsilon,
         grid=(nrows // rows,),
         in_specs=[scalar_spec, scalar_spec] + [block() for _ in range(4)],
         out_specs=[block() for _ in out_shapes],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ) if (_HAS_PLTPU and not interpret) else None,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(lr_t, jnp.float32).reshape(1, 1),
       jnp.asarray(wd, jnp.float32).reshape(1, 1), *ops2d)

@@ -36,44 +36,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import HAS_PLTPU as _HAS_PLTPU
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
-from .pallas_common import pltpu
+from .pallas_common import resolve_interpret as _resolve_interpret
 
 # VMEM budget for the two (H, cap, Ch) scratch histories plus the f32
 # score block — half the ~16MB/core so the q/out blocks and DMA staging fit
 _MAX_SCRATCH_BYTES = 8 * 1024 * 1024
 
 
-def paged_attention_supported(q, k_pool, page_table) -> bool:
-    """True when the paged kernel should replace the XLA pool gather.
+def paged_attention_refusal(q, k_pool, page_table):
+    """Why the paged kernel does NOT replace the XLA pool gather for these
+    operands (anything with ``.shape``/``.dtype``), or None when it does.
 
-    Interpret mode (CPU CI) has no tiling constraints, so the only gates
-    are the config knob and pallas availability — this is what keeps the
-    compiled decode/verify programs gather-free in the committed memory
-    goldens. On hardware the scratch history must be tile-aligned
-    (``Ch % 128``, ``page_size % 8``) and fit the VMEM budget; callers
-    fall back to the gather path otherwise.
+    Interpret mode (CPU CI) has no tiling constraints, so the only gate
+    there is the config knob — this is what keeps the compiled
+    decode/verify programs gather-free in the committed memory goldens.
+    On a TPU the scratch history must be tile-aligned (``Ch % 128``,
+    ``page_size % 8``) and fit the VMEM budget, and the query must be
+    float32: Mosaic refuses the kernel's bf16 x bf16 einsums ("'tpu.matmul'
+    op Expected matmul acc to be 32-bit"). Callers fall back to the gather
+    path otherwise.
     """
     from .. import config as _config
 
     if not _config.get("paged_attention_kernel"):
-        return False
-    if not _HAS_PLTPU:
-        return False
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return None
     b, h, tq, ch = q.shape
     ps = k_pool.shape[2]
     cap = page_table.shape[1] * ps
-    if not _on_tpu():
-        return True
+    if ch % _LANES:
+        return f"head size {ch} is not a multiple of {_LANES} lanes"
+    if ps % 8:
+        return f"page size {ps} is not a multiple of 8 sublanes"
+    if q.dtype != jnp.float32:
+        return f"query dtype {jnp.dtype(q.dtype).name} is not float32"
+    if k_pool.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"pool dtype {jnp.dtype(k_pool.dtype).name} is not f32/bf16"
     itemsize = jnp.dtype(k_pool.dtype).itemsize
     scratch = 2 * h * cap * ch * itemsize + 4 * h * tq * cap
-    return (ch % _LANES == 0 and ps % 8 == 0
-            and scratch <= _MAX_SCRATCH_BYTES
-            and q.dtype in (jnp.float32, jnp.bfloat16)
-            and k_pool.dtype in (jnp.float32, jnp.bfloat16))
+    if scratch > _MAX_SCRATCH_BYTES:
+        return (f"row history needs {scratch} bytes of VMEM scratch "
+                f"(budget {_MAX_SCRATCH_BYTES})")
+    return None
+
+
+def paged_attention_supported(q, k_pool, page_table) -> bool:
+    """True when the paged kernel should replace the XLA pool gather
+    (see :func:`paged_attention_refusal` for the rules)."""
+    return paged_attention_refusal(q, k_pool, page_table) is None
 
 
 def _paged_kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
@@ -122,8 +137,7 @@ def paged_attention(q, k_new, v_new, k_pool, v_pool, page_table, position,
     and aliases the donated decode carry); only the read path — where the
     pool-wide gather used to materialize — runs in the kernel.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret(interpret)
     b, h, tq, ch = q.shape
     ps = k_pool.shape[2]
     n_pages = page_table.shape[1]
@@ -146,8 +160,8 @@ def paged_attention(q, k_new, v_new, k_pool, v_pool, page_table, position,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, tq, ch), lambda b_, t, p: (b_, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, tq, ch), lambda b_, t, p: (b_, 0, 0, 0)),
         scratch_shapes=[
@@ -161,9 +175,8 @@ def paged_attention(q, k_new, v_new, k_pool, v_pool, page_table, position,
                           tq=tq, cap=cap),
         out_shape=jax.ShapeDtypeStruct((b, h, tq, ch), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ) if (_HAS_PLTPU and not interpret) else None,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(position, jnp.int32),
       q, k_pool, v_pool)

@@ -26,9 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..registry import register
-from .pallas_common import HAS_PLTPU as _HAS_PLTPU
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
+from .pallas_common import resolve_interpret as _resolve_interpret
 
 _BLOCK_ROWS = 128
 # class-dim cap: one (rows, C) f32 block + its exp copy must sit in VMEM
@@ -45,7 +45,7 @@ def xent_kernel_supported(pred, axis=-1) -> bool:
     if not _config.get("fused_softmax_xent"):
         return False
     ax = axis % pred.ndim if pred.ndim else 0
-    return (_HAS_PLTPU and _on_tpu() and pred.ndim >= 2
+    return (_on_tpu() and pred.ndim >= 2
             and ax == pred.ndim - 1
             and pred.shape[-1] % _LANES == 0 and pred.shape[-1] <= _MAX_C
             and pred.dtype in (jnp.float32, jnp.bfloat16))
@@ -63,7 +63,10 @@ def _xent_kernel(x_ref, l_ref, o_ref):
 
 def _xent_forward(x2, labels, interpret=False):
     n, c = x2.shape
-    rows = max(8, min(_BLOCK_ROWS, n))
+    # scale the row block down as C grows: the block's f32 copy, its exp
+    # and the iota/select temporaries share Mosaic's 16 MB of scoped VMEM
+    # (v5e: (32, 32768) compiles, (64, 32768) is refused)
+    rows = max(8, min(_BLOCK_ROWS, (2 ** 20) // c // 8 * 8, n))
     n_pad = -(-n // rows) * rows
     if n_pad != n:
         # padded rows pick class 0 of zero logits -> finite garbage, sliced off
@@ -111,10 +114,8 @@ def softmax_cross_entropy_fused(pred, label, interpret=None):
     """Per-row sparse-label cross entropy ``logsumexp(pred) - pred[label]``
     over the last axis; leading shape preserved (f32 output, the dtype the
     unfused f32 ``log_softmax`` path produces)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     c = pred.shape[-1]
     lead = pred.shape[:-1]
     x2 = pred.reshape(-1, c)
     lbl = jnp.asarray(label, jnp.int32).reshape(-1)
-    return _xent(x2, lbl, bool(interpret)).reshape(lead)
+    return _xent(x2, lbl, _resolve_interpret(interpret)).reshape(lead)

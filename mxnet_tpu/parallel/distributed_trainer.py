@@ -24,16 +24,6 @@ __all__ = ["DistributedTrainer", "init", "shutdown", "rank", "size",
 _initialized = False
 
 
-def _already_bootstrapped() -> bool:
-    # is_initialized() only exists in newer jax; older versions expose the
-    # bootstrap state as jax._src.distributed.global_state.client
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src import distributed as _dist
-
-    return _dist.global_state.client is not None
-
-
 def init(coordinator_address: Optional[str] = None, num_processes: Optional[int] = None,
          process_id: Optional[int] = None, timeout: Optional[float] = None,
          retries: Optional[int] = None):
@@ -57,19 +47,16 @@ def init(coordinator_address: Optional[str] = None, num_processes: Optional[int]
     if coordinator_address is None:
         _initialized = True  # single process
         return
-    if _already_bootstrapped():
+    if jax.distributed.is_initialized():
         _initialized = True  # someone (pod runtime, user) already bootstrapped
         return
     plats = (jax.config.jax_platforms or "").split(",")
     if "cpu" in plats:
-        try:
-            # multi-process on the CPU backend (the N-local-process CI shape)
-            # needs an actual cross-process collectives impl; the default
-            # 'none' makes every psum fail with "Multiprocess computations
-            # aren't implemented". Must be set before the backend initializes.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older/newer jax without the option: keep prior behavior
+        # multi-process on the CPU backend (the N-local-process CI shape)
+        # needs an actual cross-process collectives impl; 'none' makes
+        # every psum fail with "Multiprocess computations aren't
+        # implemented". Must be set before the backend initializes.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     from .. import config
     from ..resilience import faults, retry
@@ -91,16 +78,9 @@ def init(coordinator_address: Optional[str] = None, num_processes: Optional[int]
     def _bootstrap():
         faults.fire("dist.init")
         try:
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=nproc, process_id=pid, **kwargs)
-            except TypeError:  # older jax without initialization_timeout
-                if not kwargs:
-                    raise
-                jax.distributed.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=nproc, process_id=pid)
+            jax.distributed.initialize(
+                coordinator_address=coordinator_address,
+                num_processes=nproc, process_id=pid, **kwargs)
         except Exception:
             _clear_half_bootstrap()
             raise
@@ -124,8 +104,8 @@ def _clear_half_bootstrap() -> None:
     jax's ``State.initialize`` registers ``global_state.client`` (and rank
     0's coordinator service) BEFORE ``client.connect()`` — a timed-out dial
     leaves them set, every later attempt dies on "should only be called
-    once", and ``_already_bootstrapped()`` would report the failure as
-    success. Clear the fields first (so the state is clean even when the
+    once", and ``jax.distributed.is_initialized()`` would report the failure
+    as success. Clear the fields first (so the state is clean even when the
     handles refuse to shut down), then best-effort release the handles."""
     try:
         from jax._src import distributed as _jdist
@@ -153,7 +133,7 @@ def shutdown() -> None:
     when never initialized; single-process "initialized" state is also
     cleared."""
     global _initialized
-    if _already_bootstrapped():
+    if jax.distributed.is_initialized():
         jax.distributed.shutdown()
     _initialized = False
     from ..observability import events as _ev

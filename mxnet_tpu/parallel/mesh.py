@@ -8,6 +8,7 @@ names the axes it uses; unused axes have size 1 and cost nothing.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -16,6 +17,8 @@ import numpy as np
 from jax.sharding import Mesh
 
 __all__ = ["AXES", "MeshConfig", "make_mesh", "local_mesh", "refit_config"]
+
+logger = logging.getLogger("mxnet_tpu.parallel")
 
 # the axis vocabulary is owned by the declarative layout spec
 # (parallel.layout.AXES — docs/PARALLELISM.md); re-exported here for the
@@ -50,6 +53,10 @@ def make_mesh(config: Optional[MeshConfig] = None, devices=None) -> Mesh:
     devices = list(devices if devices is not None else jax.devices())
     config = config or MeshConfig(dp=len(devices))
     if config.total < len(devices):
+        # a mesh smaller than the host takes the FIRST devices in jax's
+        # order; say so — the rest of the host's chips sit idle
+        logger.info("mesh %s uses %d of %d visible devices: %s", config,
+                    config.total, len(devices), devices[: config.total])
         devices = devices[: config.total]
     if config.total != len(devices):
         raise ValueError(f"mesh {config} needs {config.total} devices, "

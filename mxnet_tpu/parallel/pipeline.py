@@ -29,22 +29,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-agnostic wrapper: new jax.shard_map uses check_vma, the
-    experimental one check_rep; disable the replication check either way
-    (per-device branches on axis_index are intentionally device-varying)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: per-device
+    branches on axis_index are intentionally device-varying."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 __all__ = ["pipeline_apply", "stack_stage_params", "stage_sharding"]
 

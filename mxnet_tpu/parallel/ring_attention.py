@@ -18,11 +18,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
 __all__ = ["ring_attention"]
 
 
@@ -106,10 +101,6 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = False):
         return (acc / l[..., None]).astype(q_blk.dtype)
 
     spec = P(None, None, axis, None)
-    try:
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # jax < 0.6 spells the replication check 'check_rep'
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return fn(q, k, v)

@@ -153,6 +153,8 @@ class TrainStep:
                 axes = [ax for ax in ("dp", "fsdp") if ax in mesh.shape and mesh.shape[ax] > 1]
                 batch_spec = P(tuple(axes) if len(axes) > 1 else (axes[0] if axes else None))
             self.batch_sharding = NamedSharding(mesh, batch_spec or P())
+            self.step_count, self.amp_state = self._on_mesh(
+                (self.step_count, self.amp_state))
         else:
             self.param_sharding = None
             self.batch_sharding = None
@@ -193,6 +195,17 @@ class TrainStep:
         # window-program dispatch count (one host sync per dispatch when
         # telemetry is on) — tests assert one dispatch per window
         self._window_dispatches = 0
+
+    def _on_mesh(self, tree):
+        """Place the scalar carry (step count, amp state) replicated on the
+        mesh, as the step program returns it. jax types an array by the
+        mesh it lives on: a carry created off the mesh makes the SECOND
+        call a new signature — a full retrace and compile (17 s of a
+        BERT-large four-chip run even with the persistent cache warm)."""
+        if self.mesh is None:
+            return tree
+        rep = NamedSharding(self.mesh, P())
+        return jax.tree_util.tree_map(lambda x: jax.device_put(x, rep), tree)
 
     # -- functional loss -----------------------------------------------------
     def _loss_of(self, params: Dict[str, jax.Array], batch, key):
@@ -1113,14 +1126,15 @@ class TrainStep:
             pass  # pre-extra checkpoints: fall back to step for everything
         self.params = {k: jnp.asarray(v) for k, v in params.items()}
         self.opt_state = jax.tree_util.tree_map(jnp.asarray, opt_state)
-        self.step_count = jnp.asarray(int(meta.get("applied_step", step)),
-                                      jnp.int32)
+        self.step_count = self._on_mesh(jnp.asarray(
+            int(meta.get("applied_step", step)), jnp.int32))
         self.optimizer.num_update = step
         if self.amp_state is not None and "amp_state" in meta:
             a = meta["amp_state"]
-            self.amp_state = {"scale": jnp.float32(a["scale"]),
-                              "good": jnp.int32(a["good"]),
-                              "skipped": jnp.int32(a["skipped"])}
+            self.amp_state = self._on_mesh(
+                {"scale": jnp.float32(a["scale"]),
+                 "good": jnp.int32(a["good"]),
+                 "skipped": jnp.int32(a["skipped"])})
             self._amp_skipped_seen = int(a["skipped"])
         if self.param_sharding is not None:
             # reshard-on-restore (docs/RESILIENCE.md "Elastic training"):

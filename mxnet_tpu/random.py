@@ -29,7 +29,7 @@ __all__ = ["seed", "next_key", "trace_key_scope", "uniform", "normal", "randint"
 
 class _KeyState(threading.local):
     """Key creation is lazy: materialising a PRNG key initialises the jax
-    backend, and importing the library must not grab the TPU lease (host-side
+    backend, and importing the library must not claim the chip (host-side
     tools like im2rec import mxnet_tpu without ever touching the device)."""
 
     def __init__(self):

@@ -233,7 +233,7 @@ def test_dist_init_retries_with_backoff(monkeypatch, _fast_retry):
     calls = []
     monkeypatch.setattr(dt.jax.distributed, "initialize",
                         lambda **kw: calls.append(kw))
-    monkeypatch.setattr(dt, "_already_bootstrapped", lambda: False)
+    monkeypatch.setattr(dt.jax.distributed, "is_initialized", lambda: False)
     monkeypatch.setattr(dt, "_initialized", False)
     faults.arm("dist.init", on=1)  # first dial: coordinator not up yet
     dt.init("127.0.0.1:9", num_processes=2, process_id=1, retries=3)
@@ -249,7 +249,7 @@ def test_dist_init_failed_attempt_does_not_poison_retry(monkeypatch,
     """jax's State.initialize registers global_state.client BEFORE
     client.connect(): a failed dial that *raises* must not leave the
     half-built client behind, or attempt 2 dies on "should only be called
-    once" (and _already_bootstrapped() reports the failure as success)."""
+    once" (and is_initialized() reports the failure as success)."""
     from jax._src import distributed as jdist
 
     from mxnet_tpu.parallel import distributed_trainer as dt
@@ -266,7 +266,7 @@ def test_dist_init_failed_attempt_does_not_poison_retry(monkeypatch,
             raise IOError("connect: coordinator not up")  # ...then the dial
 
     monkeypatch.setattr(dt.jax.distributed, "initialize", _initialize)
-    monkeypatch.setattr(dt, "_already_bootstrapped", lambda: False)
+    monkeypatch.setattr(dt.jax.distributed, "is_initialized", lambda: False)
     monkeypatch.setattr(dt, "_initialized", False)
     monkeypatch.setattr(jdist.global_state, "client", None)
     monkeypatch.setattr(jdist.global_state, "service", None)
@@ -283,7 +283,7 @@ def test_dist_init_exhausted_retries_fail(monkeypatch, _fast_retry):
 
     monkeypatch.setattr(dt.jax.distributed, "initialize",
                         lambda **kw: None)
-    monkeypatch.setattr(dt, "_already_bootstrapped", lambda: False)
+    monkeypatch.setattr(dt.jax.distributed, "is_initialized", lambda: False)
     monkeypatch.setattr(dt, "_initialized", False)
     faults.arm("dist.init", every=1)  # coordinator never comes up
     with pytest.raises(retry.RetryError):

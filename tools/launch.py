@@ -11,6 +11,15 @@ Local mode forks N processes on this host (the reference's ``--launcher
 local`` CI topology, SURVEY §4 fixture #5); ssh mode prints per-host
 commands (zero-egress environments can't ssh out, so it stops at the plan).
 
+A TPU chip belongs to one process at a time, and workers started here see
+whatever this process sees. So on a host with TPU chips ``-n`` > 1 is
+refused, unless the workers are pinned off the TPU (``JAX_PLATFORMS=cpu``,
+the CI shape) or the caller has taken charge of chip visibility
+(``TPU_VISIBLE_CHIPS`` set, e.g. narrowed per ``MXNET_TPU_LOCAL_RANK`` by a
+wrapper command). One host's four chips are driven by ONE process and a
+four-device mesh (``parallel.Layout``), not by four workers; ``-n`` counts
+hosts' worth of processes.
+
 Elastic mode (``--elastic``, docs/RESILIENCE.md "Elastic training") wraps
 local mode in a *supervising* loop: when a worker dies (crash, SIGKILL,
 preemption) or exits with the re-formation code (75, EX_TEMPFAIL — see
@@ -25,6 +34,7 @@ spend before the supervisor gives up and propagates the failure.
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import shutil
 import socket
@@ -37,6 +47,24 @@ import time
 #: with mxnet_tpu.resilience.elastic.ELASTIC_RESTART_EXIT without importing
 #: the package — the launcher must run from a bare checkout/venv)
 ELASTIC_RESTART_EXIT = 75
+
+
+def shared_chip_refusal(n: int, env, chips) -> str | None:
+    """Why ``n`` local workers may not start on a host whose TPU device
+    nodes are ``chips``, or None when they may (see the module docstring).
+    The launcher never imports jax — it would hold the chip itself."""
+    if n <= 1 or not chips:
+        return None
+    platforms = [p for p in env.get("JAX_PLATFORMS", "").split(",") if p]
+    if platforms and "tpu" not in platforms:
+        return None
+    if env.get("TPU_VISIBLE_CHIPS"):
+        return None
+    return (f"this host has TPU chips ({', '.join(chips)}) and each of the "
+            f"{n} workers would claim all of them. Drive one host's chips "
+            "from ONE process and a mesh (parallel.Layout); or pin the "
+            "workers off the TPU (JAX_PLATFORMS=cpu); or set "
+            "TPU_VISIBLE_CHIPS and narrow it per MXNET_TPU_LOCAL_RANK.")
 
 
 def free_port() -> int:
@@ -340,6 +368,12 @@ def main():
     if not args.command:
         ap.error("no command given")
     if args.launcher == "local":
+        why = shared_chip_refusal(
+            args.num_workers, os.environ,
+            sorted(glob.glob("/dev/accel[0-9]*")
+                   + glob.glob("/dev/vfio/[0-9]*")))
+        if why:
+            ap.error(why)
         if args.elastic:
             sup = ElasticSupervisor(
                 args.num_workers, args.command,
