@@ -104,6 +104,7 @@ class _TraceState(threading.local):
         self.updates = []  # list[(Parameter, raw)]
         self.force_eager = False  # deferred-init pass: children must not jit
         self.symbolic = False  # export pass: hybrid_forward sees the sym namespace
+        self.block_prefix = ""  # prefix of the block whose forward is being staged
 
 
 _TRACE = _TraceState()
@@ -322,10 +323,35 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        if _TRACE.active and not _TRACE.symbolic:
+            # staged (TrainStep's loss or a hybridized jit): name the device
+            # operations of this block after it, so a trace tells attention
+            # from feed-forward from the MLM head with no model edited
+            # (docs/OBSERVABILITY.md "Named scopes"). Metadata only.
+            outer = _TRACE.block_prefix
+            _TRACE.block_prefix = self._prefix
+            try:
+                with jax.named_scope(self._scope_label(outer)):
+                    out = self.forward(*args, **kwargs)
+            finally:
+                _TRACE.block_prefix = outer
+        else:
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
+
+    def _scope_label(self, outer):
+        """This block's name for a trace scope: its own name less the
+        prefix of the block it is called from (``attn`` inside
+        ``..._layer3_``, not the whole chain again); a container that
+        shares its parent's prefix goes by its class."""
+        name = self.name
+        if outer and self._prefix == outer:
+            return self._alias()
+        if outer and name.startswith(outer) and len(name) > len(outer):
+            return name[len(outer):]
+        return name or self._alias()
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError

@@ -9,7 +9,12 @@ Three pieces, one switch:
   - ``span``     — times a region into the ``span_seconds`` histogram AND
                    forwards the name (+ current step) to
                    ``jax.profiler.TraceAnnotation`` so wall-clock metrics
-                   and XPlane trace rows correlate by step id.
+                   and XPlane trace rows correlate by step id;
+  - ``step_record`` — the always-on record of one hot-path call
+                   (``TrainStep.__call__``): the spans opened inside it
+                   write their end times into one tuple in a bounded
+                   process-wide ring (:func:`step_records`), telemetry on
+                   or off, without ever touching the device.
 
 The switch: hot-path instrumentation (TrainStep, KVStore collectives, the
 DataLoader) is gated on :func:`enabled` — a single module-global bool read,
@@ -30,10 +35,13 @@ programmatically::
 from __future__ import annotations
 
 import atexit
+import collections
 import os
+import threading
 import time
+import weakref
 from contextlib import contextmanager
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 from . import events  # noqa: F401
 from . import goodput  # noqa: F401
@@ -47,7 +55,8 @@ from . import tracing  # noqa: F401  (imports metrics above)
 __all__ = ["metrics", "events", "REGISTRY", "counter", "gauge", "histogram",
            "emit", "set_step", "read_events", "enabled", "enable", "disable",
            "shutdown", "span", "timed_region", "telemetry_dir",
-           "throughput_delta", "fleet", "goodput", "profiling", "tracing"]
+           "throughput_delta", "fleet", "goodput", "profiling", "tracing",
+           "StepRecord", "step_record", "step_records", "on_flush", "flush"]
 
 
 def throughput_delta(prev):
@@ -123,8 +132,38 @@ def disable() -> None:
     """Turn the hot-path gate off and close the event log (registry content
     is kept — counters survive an enable/disable cycle)."""
     global _enabled
+    flush()
     _enabled = False
     events.LOG.close()
+
+
+# telemetry that lags the hot path (TrainStep reads step n's loss some
+# dispatches later, so that reading never stalls the device's queue) hands
+# its "publish what is still held" method here; weak, so a registered
+# object dies when its owner drops it
+_flushers: list = []
+_flushers_lock = threading.Lock()
+
+
+def on_flush(method) -> None:
+    """Register a bound method that :func:`flush` (and so
+    :func:`shutdown` / :func:`disable`) calls to publish lagging
+    telemetry. Held weakly."""
+    with _flushers_lock:
+        _flushers.append(weakref.WeakMethod(method))
+
+
+def flush() -> None:
+    """Publish every reading the hot path still holds back (docs/
+    OBSERVABILITY.md "The lag of telemetry-on readings"): after it the
+    registry and the event log are level with the steps dispatched. Blocks
+    until those steps have run."""
+    with _flushers_lock:
+        live = [(ref, ref()) for ref in _flushers]
+        _flushers[:] = [ref for ref, method in live if method is not None]
+    for _, method in live:
+        if method is not None:
+            method()
 
 
 def shutdown() -> None:
@@ -132,6 +171,7 @@ def shutdown() -> None:
     Idempotent; registered atexit by :func:`enable`."""
     if _dir is None:
         return
+    flush()
     # final fleet snapshot BEFORE the event log closes (the snapshot
     # copies the event files; a clean exit must land its tail)
     fleet.shutdown_snapshotter()
@@ -162,15 +202,129 @@ def timed_region(metric_name: str, help: str, name: str, **labels):
                       unit="s").observe(time.perf_counter() - t0, **labels)
 
 
-@contextmanager
-def span(name: str, **labels):
-    """Time a region into ``span_seconds{span=name,...}`` and annotate the
-    XPlane trace with the same name + current step id, so a slow span found
-    in metrics can be located in the TensorBoard/Perfetto timeline (and
-    vice versa). No-op (one bool check) when telemetry is off."""
-    if not enabled():
-        yield
-        return
-    with timed_region("span_seconds", "obs.span region wall-clock", name,
-                      span=name, **labels):
-        yield
+# -- the step record ----------------------------------------------------------
+class StepRecord(NamedTuple):
+    """One hot-path call as the host saw it. Times are
+    ``time.perf_counter_ns()``: ``t0_ns`` at the call's entry, and in
+    ``marks`` the end of each span opened inside it, in order. The spans
+    are contiguous by construction (one's end is the next one's start), so
+    their durations sum to the call's."""
+
+    loop: str                            # "train_step" / "run_window"
+    step: int                            # optimizer.num_update once it ran
+    t0_ns: int
+    marks: Tuple[Tuple[str, int], ...]   # (span name, end ns)
+    compiled: bool                       # this call lowered or compiled
+
+    @property
+    def duration_ns(self) -> int:
+        return (self.marks[-1][1] if self.marks else self.t0_ns) - self.t0_ns
+
+    def phase_ns(self) -> dict:
+        """{span name: nanoseconds}, each measured from the mark before."""
+        out, last = {}, self.t0_ns
+        for name, end in self.marks:
+            out[name] = out.get(name, 0) + end - last
+            last = end
+        return out
+
+
+#: how many calls the ring remembers (a 50 s benchmark window of BERT-large
+#: is some 400 steps; at 1 ms a step this is still the last four seconds)
+STEP_RECORDS_KEPT = 4096
+_records: "collections.deque[StepRecord]" = collections.deque(
+    maxlen=STEP_RECORDS_KEPT)
+_open = threading.local()  # .rec: the step_record this thread is inside
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        import jax
+
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def step_records(loop: Optional[str] = None) -> list:
+    """The ring's records, oldest first (those of ``loop`` if given). It
+    belongs to the process, not to the object that wrote it: it is read
+    after a ``TrainStep`` is gone."""
+    return [r for r in list(_records) if loop is None or r.loop == loop]
+
+
+class step_record:
+    """Context manager around ONE hot-path call: opens the root span
+    ``name`` (a ``TraceAnnotation`` carrying ``step``, free when no
+    profiler session is open), collects the end time of every
+    :func:`span` opened inside it, and appends a :class:`StepRecord` to
+    the ring on exit, telemetry on or off. It reads clocks and nothing
+    else: no device value is touched. ``rec.compiled = True`` marks a call
+    that lowered or compiled a program."""
+
+    __slots__ = ("loop", "step", "name", "t0", "marks", "compiled", "_ann",
+                 "_outer")
+
+    def __init__(self, loop: str, step: int, name: str = "mx.train.step"):
+        self.loop, self.step, self.name = loop, int(step), name
+        self.marks, self.compiled = [], False
+
+    def __enter__(self):
+        self._ann = _trace_annotation()(self.name, step=self.step)
+        self._ann.__enter__()
+        self._outer = getattr(_open, "rec", None)
+        _open.rec = self
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        _open.rec = self._outer
+        self._ann.__exit__(*exc)
+        _records.append(StepRecord(self.loop, self.step, self.t0,
+                                   tuple(self.marks), self.compiled))
+        return False
+
+
+class span:
+    """Time a region. Inside a :class:`step_record` (the train step's hot
+    path) it is always on: a ``TraceAnnotation`` with the record's step id,
+    so the region lies on the profiler's clock beside the device
+    operations, and its end time in the record. Elsewhere it is a no-op
+    (one bool check) when telemetry is off. With telemetry on, either way,
+    it also times the region into ``span_seconds{span=name,...}``, so a slow
+    span found in metrics can be located in the TensorBoard/Perfetto
+    timeline (and vice versa)."""
+
+    __slots__ = ("name", "labels", "_rec", "_ann", "_t0")
+
+    def __init__(self, name: str, **labels):
+        self.name, self.labels = name, labels
+
+    def __enter__(self):
+        rec = self._rec = getattr(_open, "rec", None)
+        if rec is None and not enabled():
+            self._ann = None
+            return self
+        if rec is None:
+            step, self._t0 = events.current_step(), time.perf_counter_ns()
+        else:
+            # contiguous: what lies between two spans counts to the later
+            step = rec.step
+            self._t0 = rec.marks[-1][1] if rec.marks else rec.t0
+        self._ann = _trace_annotation()(self.name, step=step)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is None:
+            return False
+        end = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        if self._rec is not None:
+            self._rec.marks.append((self.name, end))
+        if enabled():
+            histogram("span_seconds", "obs.span region wall-clock",
+                      unit="s").observe((end - self._t0) * 1e-9,
+                                        span=self.name, **self.labels)
+        return False

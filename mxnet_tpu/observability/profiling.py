@@ -688,6 +688,23 @@ class MeasuredReport:
             out[cls] = out.get(cls, 0.0) + sns / 1e9
         return out
 
+    def scope_seconds(self, op_scopes: Dict[str, str],
+                      depth: Optional[int] = 1) -> Dict[str, float]:
+        """Seconds of own time by named scope, at ``depth`` components of
+        the scope path (1: ``forward`` / ``backward`` / ``optimizer`` ...;
+        None: the whole path), summed over the devices. ``op_scopes`` is
+        {HLO instruction name: scope path}, as ``TrainStep.op_scopes()``
+        gives it for the program that was traced; a row it does not name
+        counts as ``unscoped`` (docs/OBSERVABILITY.md "Named scopes")."""
+        from .scopes import UNSCOPED, at_depth, instruction_name
+
+        out: Dict[str, float] = {}
+        for r, sns in zip(self.op_rows, self._self_times()):
+            path = op_scopes.get(instruction_name(r.hlo_op or r.name))
+            key = at_depth(path, depth) if path else UNSCOPED
+            out[key] = out.get(key, 0.0) + sns / 1e9
+        return out
+
     def devices(self) -> List[str]:
         return sorted({r.device for r in self.op_rows})
 

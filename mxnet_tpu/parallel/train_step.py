@@ -11,7 +11,7 @@ machinery, and updated parameters are written back on request (``sync``).
 """
 from __future__ import annotations
 
-import functools
+import collections
 import math
 import time
 from typing import Callable, Dict, Optional
@@ -28,6 +28,23 @@ from ..ndarray import NDArray
 from .sharding import ShardingRules
 
 __all__ = ["TrainStep"]
+
+#: With telemetry on, step n's loss, gradient norm and loss scale are read
+#: from the device this many dispatches later (or at ``obs.flush()``), so the
+#: reading never waits for the step just dispatched. Of the order of the
+#: runtime's own queue (the TPU runtime lets about 8 steps be queued).
+TELEMETRY_LAG = 8
+
+# one dispatch whose readings telemetry still holds as device futures
+_Held = collections.namedtuple(
+    "_Held", "loop step t_entry values window accum samples tokens flops")
+
+
+def _programs_held(jitted) -> int:
+    """Executables in a jitted function's own cache: one more after a call
+    than before it means that call lowered or compiled."""
+    size = getattr(jitted, "_cache_size", None)
+    return size() if size is not None else 0
 
 
 class TrainStep:
@@ -192,9 +209,14 @@ class TrainStep:
         # device-resident + sharded, so __call__/run skip the per-call
         # device_put on the caller thread
         self._prefetcher = None
-        # window-program dispatch count (one host sync per dispatch when
-        # telemetry is on) — tests assert one dispatch per window
+        # window-program dispatch count — tests assert one dispatch per
+        # window
         self._window_dispatches = 0
+        # telemetry-on readings not yet published (_hold/_publish), and
+        # when the host last saw a dispatch done
+        self._held: collections.deque = collections.deque()
+        self._seen_done = 0.0
+        _obs.on_flush(self.flush_telemetry)
 
     def _on_mesh(self, tree):
         """Place the scalar carry (step count, amp state) replicated on the
@@ -217,10 +239,12 @@ class TrainStep:
         # pin layouts at known dp→tp transition points (MLM head)
         with active_mesh(self.mesh), _HybridTrace(self._plist, raws, True, key):
             nd_batch = [NDArray(b) for b in batch]
-            out = self.net(*nd_batch[:n])
-            loss = self.loss_fn(out, *nd_batch[n:])
-        raw = loss._data if isinstance(loss, NDArray) else loss
-        return jnp.mean(raw.astype(jnp.float32))
+            with jax.named_scope("forward"):
+                out = self.net(*nd_batch[:n])
+            with jax.named_scope("loss"):
+                loss = self.loss_fn(out, *nd_batch[n:])
+                raw = loss._data if isinstance(loss, NDArray) else loss
+                return jnp.mean(raw.astype(jnp.float32))
 
     def _resolve_mults(self):
         """Static per-name lr/wd multipliers, resolving the same channels as
@@ -249,13 +273,14 @@ class TrainStep:
         if pol is None:
             return params, batch
         cd = pol.jnp_compute_dtype
-        params = {k: (v.astype(cd) if v.dtype == jnp.float32 else v)
-                  for k, v in params.items()}
-        n = self.n_model_inputs
-        batch = tuple(
-            b.astype(cd) if (i < n and hasattr(b, "dtype")
-                             and b.dtype == jnp.float32) else b
-            for i, b in enumerate(batch))
+        with jax.named_scope("amp"):
+            params = {k: (v.astype(cd) if v.dtype == jnp.float32 else v)
+                      for k, v in params.items()}
+            n = self.n_model_inputs
+            batch = tuple(
+                b.astype(cd) if (i < n and hasattr(b, "dtype")
+                                 and b.dtype == jnp.float32) else b
+                for i, b in enumerate(batch))
         return params, batch
 
     def _grad_fn(self):
@@ -282,7 +307,8 @@ class TrainStep:
             cp, batch = self._amp_cast(cp, batch)
             loss = self._loss_of(cp, batch, key)
             if scale is not None:
-                loss = loss * scale
+                with jax.named_scope("amp"):
+                    loss = loss * scale
             return loss
 
         return jax.value_and_grad(lossf)
@@ -323,15 +349,26 @@ class TrainStep:
         grads = self._overlap_grads(grads)
         opt = self.optimizer
         new_params, new_state = dict(params), {}
-        for name in params:
-            if name not in opt_state:
-                continue
-            nw, ns = opt.update_raw(params[name], grads[name], opt_state[name],
-                                    lr * lr_mult.get(name, 1.0),
-                                    wd * wd_mult.get(name, 1.0), t)
-            new_params[name] = nw
-            new_state[name] = ns
+        with jax.named_scope("optimizer"):
+            for name in params:
+                if name not in opt_state:
+                    continue
+                nw, ns = opt.update_raw(
+                    params[name], grads[name], opt_state[name],
+                    lr * lr_mult.get(name, 1.0),
+                    wd * wd_mult.get(name, 1.0), t)
+                new_params[name] = nw
+                new_state[name] = ns
         return new_params, new_state
+
+    @staticmethod
+    def _grad_norm(grads, names):
+        """Global gradient norm for telemetry: a handful of fused reduces,
+        compiled into the program only when telemetry is on."""
+        with jax.named_scope("grad_norm"):
+            return jnp.sqrt(sum(
+                jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
+                for n in names))
 
     def _opt_shardings(self):
         return {
@@ -370,10 +407,11 @@ class TrainStep:
         """Unscale grads, gate the optimizer update on finiteness via
         ``lax.cond`` (skip = identity carry, Adam's t frozen), advance the
         amp carry. Shared by the single-step and window programs."""
-        inv = 1.0 / amp_state["scale"]
-        grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
-        loss = sloss * inv
-        finite = self._finite_all(grads, list(opt_state))
+        with jax.named_scope("amp"):
+            inv = 1.0 / amp_state["scale"]
+            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+            loss = sloss * inv
+            finite = self._finite_all(grads, list(opt_state))
         t2 = step_count + 1
 
         def _apply(_):
@@ -386,8 +424,9 @@ class TrainStep:
 
         new_params, new_state, new_t = jax.lax.cond(finite, _apply, _skip,
                                                     None)
-        return (new_params, new_state, new_t,
-                self._next_amp_state(amp_state, finite), grads, loss)
+        with jax.named_scope("amp"):
+            new_amp = self._next_amp_state(amp_state, finite)
+        return new_params, new_state, new_t, new_amp, grads, loss
 
     def _step_cache_key(self, n_raws, obs_on):
         """Jit-cache key of the single-step program: everything folded into
@@ -418,11 +457,8 @@ class TrainStep:
             new_params, new_state = self._apply_update(
                 params, opt_state, t, grads, lr, wd, lr_mult, wd_mult)
             if with_gnorm:
-                # global grad-norm for telemetry: a handful of fused reduces,
-                # compiled into the same program only when telemetry is on
-                gsq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
-                          for n in opt_state)
-                return new_params, new_state, t, loss, jnp.sqrt(gsq)
+                return (new_params, new_state, t, loss,
+                        self._grad_norm(grads, opt_state))
             return new_params, new_state, t, loss
 
         def step_scaled(params, opt_state, step_count, amp_state, batch, key,
@@ -433,10 +469,8 @@ class TrainStep:
                                          amp_state, grads, sloss, lr, wd,
                                          lr_mult, wd_mult)
             if with_gnorm:
-                gsq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
-                          for n in opt_state)
                 return (new_params, new_state, new_t, new_amp, loss,
-                        jnp.sqrt(gsq))
+                        self._grad_norm(grads, opt_state))
             return new_params, new_state, new_t, new_amp, loss
 
         fn = step_scaled if scaling else step
@@ -510,20 +544,25 @@ class TrainStep:
                             if k in self.param_sharding else v)
                         for k, v in g.items()}
 
+            # the "accumulate" scope names the accumulation's own
+            # arithmetic; each microbatch's forward and backward keep theirs
             def micro(acc, mxs):
                 mb, midx = mxs
                 l, g = grad_fn(p, mb, jax.random.fold_in(key, midx), scale)
-                return (acc[0] + l,
-                        jax.tree_util.tree_map(
-                            jnp.add, acc[1], constrain(g))), None
+                with jax.named_scope("accumulate"):
+                    return (acc[0] + l,
+                            jax.tree_util.tree_map(
+                                jnp.add, acc[1], constrain(g))), None
 
-            zeros = constrain(
-                {k: jnp.zeros(v.shape, v.dtype) for k, v in p.items()})
+            with jax.named_scope("accumulate"):
+                zeros = constrain(
+                    {k: jnp.zeros(v.shape, v.dtype) for k, v in p.items()})
             (lsum, gsum), _ = jax.lax.scan(
                 micro, (jnp.float32(0.0), zeros),
                 (batch, jnp.arange(accum)))
-            return lsum / accum, jax.tree_util.tree_map(
-                lambda x: x / accum, gsum)
+            with jax.named_scope("accumulate"):
+                return lsum / accum, jax.tree_util.tree_map(
+                    lambda x: x / accum, gsum)
 
         def window_fn(params, opt_state, step_count, batches, keys, lrs, wd):
             # lrs is a [window] vector scanned alongside the batches: with
@@ -537,9 +576,7 @@ class TrainStep:
                 np_, ns = self._apply_update(p, s, t2, grads, lr, wd,
                                              lr_mult, wd_mult)
                 if with_gnorm:
-                    gsq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
-                              for n in s)
-                    return (np_, ns, t2), (loss, jnp.sqrt(gsq))
+                    return (np_, ns, t2), (loss, self._grad_norm(grads, s))
                 return (np_, ns, t2), loss
 
             carry, ys = jax.lax.scan(
@@ -561,9 +598,7 @@ class TrainStep:
                  loss) = self._scaled_update(p, s, t, a, grads, sloss, lr,
                                              wd, lr_mult, wd_mult)
                 if with_gnorm:
-                    gsq = sum(jnp.sum(jnp.square(grads[n].astype(jnp.float32)))
-                              for n in s)
-                    return (np_, ns, t2, a2), (loss, jnp.sqrt(gsq))
+                    return (np_, ns, t2, a2), (loss, self._grad_norm(grads, s))
                 return (np_, ns, t2, a2), loss
 
             carry, ys = jax.lax.scan(
@@ -598,77 +633,108 @@ class TrainStep:
 
     # -- public API ----------------------------------------------------------
     def __call__(self, *batch):
-        """Run one step. batch = (x, label, ...) as NDArray/jax arrays."""
+        """Run one step. batch = (x, label, ...) as NDArray/jax arrays.
+
+        The host's side of the call is measured from inside, always: one
+        :class:`~mxnet_tpu.observability.StepRecord` per call (phases
+        ``mx.train.input`` / ``args`` / ``dispatch`` / ``after`` under
+        ``mx.train.step``, each also a profiler annotation; docs/
+        OBSERVABILITY.md "The step record"). Nothing on this path reads
+        the device: with telemetry on, step n's loss is read
+        ``TELEMETRY_LAG`` dispatches later."""
         obs_on = _obs.enabled()
-        t0 = time.perf_counter() if obs_on else 0.0
-        raws = tuple(b._data if isinstance(b, NDArray) else jnp.asarray(b) for b in batch)
-        if self.batch_sharding is not None and self._prefetcher is None:
-            # with a prefetcher attached the batch is already device-resident
-            # in the right sharding — re-placing it on the caller thread is
-            # exactly the hot-path tax the prefetcher exists to remove
-            raws = tuple(jax.device_put(r, self.batch_sharding) for r in raws)
-        # the resolved lr/wd multipliers fold into the compiled program as
-        # constants, so the cache key carries them: opt.set_lr_mult /
-        # param_dict edits after the first step trigger a recompile instead
-        # of being silently frozen (round-3 advisor finding)
-        cache_key = self._step_cache_key(len(raws), obs_on)
-        if obs_on:
-            # signatures seen while telemetry was off DO recompile once it
-            # flips on (the gnorm output changes the program), so counting
-            # only enabled-mode misses stays truthful
-            self._note_recompile(cache_key, raws)
-        step = self._compiled.get(cache_key)
-        if step is None:
-            step = self._compiled[cache_key] = self._make_step(
-                len(raws), with_gnorm=obs_on)
-        key = _rng.next_key()
-        lr = jnp.float32(self.optimizer.learning_rate)
-        wd = jnp.float32(self.optimizer.wd)
-        gnorm = None
+        with _obs.step_record("train_step",
+                              int(self.optimizer.num_update) + 1) as rec:
+            with _obs.span("mx.train.input"):
+                raws = tuple(b._data if isinstance(b, NDArray)
+                             else jnp.asarray(b) for b in batch)
+                if self.batch_sharding is not None and self._prefetcher is None:
+                    # with a prefetcher attached the batch is already
+                    # device-resident in the right sharding — re-placing it
+                    # on the caller thread is exactly the hot-path tax the
+                    # prefetcher exists to remove
+                    raws = tuple(jax.device_put(r, self.batch_sharding)
+                                 for r in raws)
+            with _obs.span("mx.train.args"):
+                # the resolved lr/wd multipliers fold into the compiled
+                # program as constants, so the cache key carries them:
+                # opt.set_lr_mult / param_dict edits after the first step
+                # trigger a recompile instead of being silently frozen
+                # (round-3 advisor finding)
+                cache_key = self._step_cache_key(len(raws), obs_on)
+                step = self._compiled.get(cache_key)
+                missed = step is None
+                if missed:
+                    step = self._compiled[cache_key] = self._make_step(
+                        len(raws), with_gnorm=obs_on)
+                key = _rng.next_key()
+                lr = jnp.float32(self.optimizer.learning_rate)
+                wd = jnp.float32(self.optimizer.wd)
+            loss = self._dispatch_recorded(rec, step, missed, cache_key,
+                                           raws, key, lr, wd, obs_on)
+        return loss
+
+    def _dispatch_recorded(self, rec, fn, missed, cache_key, batch, keys, lr,
+                           wd, obs_on, window=None, accum=1):
+        """The part of a recorded call that the single step and the fused
+        window share: the jitted call under ``mx.train.dispatch``; then,
+        under ``mx.train.after``, the host's step count, the record's
+        compile flag (a ``_compiled`` miss, or ``fn`` holding more programs
+        than before the call), holding the telemetry, the capture hooks,
+        monitors and the preemption check. Returns the loss(es), a future."""
+        programs = _programs_held(fn)
         # measured profiling (docs/OBSERVABILITY.md): a periodic or
-        # straggler-triggered capture traces THIS dispatch; one global
-        # read + call per step while disarmed. Immediately before the
-        # guarded region — everything fallible after begin must reach
-        # the abort handler, or a raise would leak the trace session
-        ptok = _profiling.step_capture_begin(
-            int(self.optimizer.num_update) + 1)
+        # straggler-triggered capture traces THIS dispatch (a whole fused
+        # window); one global read + call per step while disarmed.
+        # Immediately before the guarded region — everything fallible after
+        # begin must reach the abort handler, or a raise would leak the
+        # trace session (it would disable every later capture in the process)
+        ptok = _profiling.step_capture_begin(rec.step)
         try:
-            if self.amp_state is not None:
-                if obs_on:
-                    (self.params, self.opt_state, self.step_count,
-                     self.amp_state, loss, gnorm) = step(
-                        self.params, self.opt_state, self.step_count,
-                        self.amp_state, raws, key, lr, wd)
-                else:
-                    (self.params, self.opt_state, self.step_count,
-                     self.amp_state, loss) = step(
-                        self.params, self.opt_state, self.step_count,
-                        self.amp_state, raws, key, lr, wd)
-            elif obs_on:
-                (self.params, self.opt_state, self.step_count, loss,
-                 gnorm) = step(self.params, self.opt_state, self.step_count,
-                               raws, key, lr, wd)
-            else:
-                self.params, self.opt_state, self.step_count, loss = step(
-                    self.params, self.opt_state, self.step_count, raws, key,
-                    lr, wd)
-            # host-side mirror (no device sync — loss is a future)
-            self.optimizer.num_update += 1
-            if obs_on:
-                self._record_step(t0, raws, loss, gnorm, cache_key)
+            with _obs.span("mx.train.dispatch"):
+                loss, gnorm = self._dispatch(fn, batch, keys, lr, wd, obs_on)
         except BaseException:
-            # a failed traced step must not leak the live trace session
-            # (it would disable every later capture in the process)
             _profiling.step_capture_abort(ptok)
             raise
-        if ptok is not None:
-            # close the traced window AFTER the step was recorded: the
-            # parse/persist/retention overhead never inflates the
-            # train_step_seconds observation of the step it measured
-            _profiling.step_capture_end(ptok, loss)
-        self._run_monitors()
-        self._check_preemption()
+        with _obs.span("mx.train.after"):
+            try:
+                # host-side mirror (no device sync — loss is a future)
+                self.optimizer.num_update += window or 1
+                self._window_dispatches += window is not None
+                rec.compiled = missed or _programs_held(fn) > programs
+                if rec.compiled:
+                    self._note_recompile(
+                        cache_key, batch, kind="window" if window else "step")
+                if obs_on:
+                    self._hold(rec, batch, loss, gnorm, cache_key, window,
+                               accum)
+            except BaseException:
+                _profiling.step_capture_abort(ptok)
+                raise
+            if ptok is not None:
+                # the capture waits for this dispatch anyway: publish what
+                # telemetry holds first, so that the parse/persist/retention
+                # overhead of closing the traced window never counts into a
+                # train_step_seconds observation
+                self.flush_telemetry()
+                _profiling.step_capture_end(ptok, loss)
+            self._run_monitors()
+            self._check_preemption()
         return loss
+
+    def _dispatch(self, fn, batch, keys, lr, wd, with_gnorm):
+        """Call the jitted step or window program on the carry and take the
+        carry back: ``(loss or losses, gradient norm(s) or None)``. The
+        outputs are futures; nothing here waits for the device."""
+        carry = (self.params, self.opt_state, self.step_count)
+        if self.amp_state is not None:
+            carry += (self.amp_state,)
+        out = fn(*carry, batch, keys, lr, wd)
+        self.params, self.opt_state, self.step_count = out[:3]
+        out = out[3:]
+        if self.amp_state is not None:
+            self.amp_state, out = out[0], out[1:]
+        return out[0], (out[1] if with_gnorm else None)
 
     # -- fused multi-step window (docs/PERFORMANCE.md) -----------------------
     def attach_prefetcher(self, prefetcher):
@@ -759,75 +825,46 @@ class TrainStep:
 
     def _run_window(self, batches, window, accum):
         """Dispatch one compiled k-step window (batches already stacked +
-        device-resident). One program, one dispatch, and — with telemetry
-        on — one host sync for the whole window."""
+        device-resident). One program, one dispatch, one step record
+        (``loop="run_window"``), and no host sync: with telemetry on the
+        window's losses are read ``TELEMETRY_LAG`` dispatches later."""
         obs_on = _obs.enabled()
-        t0 = time.perf_counter() if obs_on else 0.0
-        cache_key = self._window_cache_key(window, accum, len(batches),
-                                           obs_on)
-        if obs_on:
-            self._note_recompile(cache_key, batches, kind="window")
-        fn = self._compiled.get(cache_key)
-        if fn is None:
-            fn = self._compiled[cache_key] = self._make_window(
-                len(batches), window, accum, with_gnorm=obs_on)
-        # draw the window's keys from the same host-side stream k sequential
-        # __call__s would consume — the fused path is bit-compatible with
-        # the single-step path for a fixed seed
-        keys = jnp.stack([_rng.next_key() for _ in range(window)])
-        # per-step lr vector: window step i reads the scheduler at
-        # num_update + i, exactly what i sequential __call__s would see
         opt = self.optimizer
-        if getattr(opt, "lr_scheduler", None) is not None:
-            base = opt.num_update
-            lrs = jnp.asarray([float(opt.lr_scheduler(base + i))
-                               for i in range(window)], jnp.float32)
-        else:
-            lrs = jnp.full((window,), opt.learning_rate, jnp.float32)
-        wd = jnp.float32(opt.wd)
-        gnorms = None
-        # measured profiling: one capture covers the whole fused window;
-        # placed immediately before the guarded region so any raise after
-        # begin reaches the abort handler (no leaked trace session)
-        ptok = _profiling.step_capture_begin(
-            int(self.optimizer.num_update) + window)
-        try:
-            if self.amp_state is not None:
-                if obs_on:
-                    (self.params, self.opt_state, self.step_count,
-                     self.amp_state, losses, gnorms) = fn(
-                        self.params, self.opt_state, self.step_count,
-                        self.amp_state, batches, keys, lrs, wd)
+        with _obs.step_record("run_window",
+                              int(opt.num_update) + window) as rec:
+            with _obs.span("mx.train.args"):
+                cache_key = self._window_cache_key(window, accum,
+                                                   len(batches), obs_on)
+                fn = self._compiled.get(cache_key)
+                missed = fn is None
+                if missed:
+                    fn = self._compiled[cache_key] = self._make_window(
+                        len(batches), window, accum, with_gnorm=obs_on)
+                # draw the window's keys from the same host-side stream k
+                # sequential __call__s would consume — the fused path is
+                # bit-compatible with the single-step path for a fixed seed
+                keys = jnp.stack([_rng.next_key() for _ in range(window)])
+                # per-step lr vector: window step i reads the scheduler at
+                # num_update + i, exactly what i sequential __call__s would
+                # see
+                if getattr(opt, "lr_scheduler", None) is not None:
+                    base = opt.num_update
+                    lrs = jnp.asarray([float(opt.lr_scheduler(base + i))
+                                       for i in range(window)], jnp.float32)
                 else:
-                    (self.params, self.opt_state, self.step_count,
-                     self.amp_state, losses) = fn(
-                        self.params, self.opt_state, self.step_count,
-                        self.amp_state, batches, keys, lrs, wd)
-            elif obs_on:
-                (self.params, self.opt_state, self.step_count, losses,
-                 gnorms) = fn(self.params, self.opt_state, self.step_count,
-                              batches, keys, lrs, wd)
-            else:
-                self.params, self.opt_state, self.step_count, losses = fn(
-                    self.params, self.opt_state, self.step_count, batches,
-                    keys, lrs, wd)
-            self._window_dispatches += 1
-            self.optimizer.num_update += window
-            if obs_on:
-                self._record_window(t0, batches, losses, gnorms, window,
-                                    accum, cache_key)
-        except BaseException:
-            _profiling.step_capture_abort(ptok)
-            raise
-        if ptok is not None:  # after recording — overhead stays out of it
-            _profiling.step_capture_end(ptok, losses)
-        self._run_monitors()
-        self._check_preemption()
+                    lrs = jnp.full((window,), opt.learning_rate, jnp.float32)
+                wd = jnp.float32(opt.wd)
+            losses = self._dispatch_recorded(rec, fn, missed, cache_key,
+                                             batches, keys, lrs, wd, obs_on,
+                                             window, accum)
         return losses
 
     # -- telemetry (docs/OBSERVABILITY.md) -----------------------------------
     def _note_recompile(self, cache_key, raws, kind="step"):
-        """Count lowered-program cache misses WITH their cause: jax.jit
+        """Count a call that lowered or compiled a program (the step
+        record's always-on ``compiled`` flag: a ``_compiled`` miss, or the
+        jitted function holding more programs after the call than before)
+        WITH its cause, telemetry on or off: jax.jit
         recompiles silently on any new (arity, shape, dtype,
         folded-constant) signature; under fusion that cost is invisible
         without this counter, and without the fingerprint diff the
@@ -845,7 +882,17 @@ class TrainStep:
         # group by program family: a step fingerprint diffed against a
         # window's stacked-batch fingerprint would report a phantom
         # shape change no input ever underwent
-        self._recompile_guard.observe(fp, reason=reason, group=kind)
+        label = self._recompile_guard.observe(fp, reason=reason, group=kind)
+        if label is None:
+            # lowered again though its shapes, dtypes and static arguments
+            # were seen before: telemetry was switched (the gradient-norm
+            # output is another program), or an argument changed its
+            # placement (sharding, mesh) — the kind no fingerprint shows
+            _obs.counter(self._recompile_guard.counter_name).inc(
+                reason="other")
+            _obs.emit("recompile", reason="other", cause="other",
+                      detail="fingerprint seen before: telemetry switched, "
+                      "or an argument's placement changed", **fp.describe())
 
     def model_flops_per_step(self, *batch, window: Optional[int] = None,
                              accum: int = 1) -> Optional[float]:
@@ -905,44 +952,96 @@ class TrainStep:
             return None
         return (self.amp_state["scale"], self.amp_state["skipped"])
 
-    def _record_step(self, t0, raws, loss, gnorm, cache_key=None):
-        # reading loss/gnorm blocks on the device — when telemetry is on,
-        # step time is the real wall-clock of the whole step, not dispatch
-        loss_h, gnorm_h, amp_h = jax.device_get(
-            (loss, gnorm, self._amp_fetchable()))
-        loss_f = float(loss_h)
-        gnorm_f = float(gnorm_h) if gnorm_h is not None else None
-        dt = time.perf_counter() - t0
-        step_no = int(self.optimizer.num_update)
-        _obs.set_step(step_no)
-        samples = int(raws[0].shape[0]) if raws and getattr(raws[0], "ndim", 0) else 1
-        tokens = int(raws[0].size) if raws else 0
-        _obs.histogram("train_step_seconds", "full train-step wall clock",
-                       unit="s").observe(dt, loop="train_step")
-        _obs.counter("train_steps_total").inc(loop="train_step")
-        _obs.counter("train_samples_total").inc(samples, loop="train_step")
-        _obs.counter("train_tokens_total").inc(tokens, loop="train_step")
-        _obs.gauge("train_tokens_per_sec", unit="tokens/s").set(
-            tokens / dt if dt > 0 else 0.0)
-        _obs.gauge("train_loss").set(loss_f)
-        if gnorm_f is not None:
-            _obs.gauge("train_grad_norm").set(gnorm_f)
-        self._record_amp(amp_h)
-        # the caller hands down the jit cache key it just dispatched with,
-        # so the memoized FLOPs lookup never re-resolves the multipliers
-        if cache_key is None:
-            cache_key = self._step_cache_key(len(raws), True)
-        self._record_flops(
-            self._estimate_flops(cache_key, lambda: self.lower_hlo(*raws)),
-            dt)
-        _obs.emit("train_step", loss=loss_f, grad_norm=gnorm_f,
-                  step_seconds=round(dt, 6), samples=samples, tokens=tokens,
-                  tokens_per_sec=round(tokens / dt, 3) if dt > 0 else 0.0)
+    # With telemetry on, a dispatch's readings (loss, gradient norm, loss
+    # scale) are HELD as device futures and published TELEMETRY_LAG
+    # dispatches later, or at obs.flush()/obs.shutdown(): reading them at
+    # once would make the host wait for the step it has just queued, and the
+    # device would then never have a second step to go on with.
+    def _hold(self, rec, batch, loss, gnorm, cache_key, window=None, accum=1):
+        """Hold one dispatch's readings: a step's (``batch`` its arrays), or
+        a fused window's (``batch`` the stacked arrays)."""
+        b0 = batch[0] if batch else None
+        if window is None:
+            samples = (int(b0.shape[0])
+                       if b0 is not None and getattr(b0, "ndim", 0) else 1)
+            lower = lambda: self.lower_hlo(*batch)  # noqa: E731
+        else:
+            nlead = 2 if accum > 1 else 1
+            samples = (int(math.prod(b0.shape[:nlead + 1]))
+                       if b0 is not None and b0.ndim > nlead else window)
+            # the scan body appears once in the window program text, so its
+            # census is one step's dots (one microbatch when accum > 1); the
+            # per-step batch is sliced off the stack only on the memo miss
+            lead = (0, 0) if accum > 1 else (0,)
+            lower = lambda: self.lower_window_hlo(  # noqa: E731
+                *(b[lead] for b in batch), window=window, accum=accum)
+        _obs.set_step(rec.step)
+        self._held.append(_Held(
+            "train_step" if window is None else "run_window", rec.step,
+            rec.t0 * 1e-9, (loss, gnorm, self._amp_fetchable()), window or 1,
+            accum, samples, int(b0.size) if b0 is not None else 0,
+            self._estimate_flops(cache_key, lower, accum)))
+        if len(self._held) > TELEMETRY_LAG:
+            self._publish(len(self._held) - TELEMETRY_LAG)
+
+    def flush_telemetry(self):
+        """Publish every held reading (blocks until those steps have run).
+        ``obs.flush()`` and ``obs.shutdown()`` call it."""
+        self._publish(len(self._held))
+
+    def _publish(self, count):
+        """Read the ``count`` oldest held dispatches (ONE ``device_get``,
+        which waits for the newest of them only) and publish each under its
+        own step number. ``train_step_seconds`` is the gap between
+        successive completions as the host sees them: the time since the
+        dispatch before was seen done (or since this one was submitted, if
+        that was later), shared equally among dispatches seen done at one
+        look. While work is queued that is the device's own step."""
+        if count <= 0:
+            return
+        held = [self._held.popleft() for _ in range(count)]
+        values = jax.device_get([h.values for h in held])
+        now = time.perf_counter_ns() * 1e-9
+        dt = (now - max(self._seen_done, held[0].t_entry)) / count
+        self._seen_done = now
+        for h, (loss_h, gnorm_h, amp_h) in zip(held, values):
+            if h.loop == "run_window":
+                last = {"loss": float(loss_h[-1]),
+                        "grad_norm": None if gnorm_h is None
+                        else float(gnorm_h[-1])}
+            else:
+                last = {"loss": float(loss_h),
+                        "grad_norm": None if gnorm_h is None
+                        else float(gnorm_h)}
+            rate = h.tokens / dt if dt > 0 else 0.0
+            _obs.histogram("train_step_seconds",
+                           "gap between successive step completions as the "
+                           "host sees them", unit="s").observe(dt, loop=h.loop)
+            _obs.counter("train_steps_total").inc(h.window, loop=h.loop)
+            _obs.counter("train_samples_total").inc(h.samples, loop=h.loop)
+            _obs.counter("train_tokens_total").inc(h.tokens, loop=h.loop)
+            _obs.gauge("train_tokens_per_sec", unit="tokens/s").set(rate)
+            _obs.gauge("train_loss").set(last["loss"])
+            if last["grad_norm"] is not None:
+                _obs.gauge("train_grad_norm").set(last["grad_norm"])
+            self._record_amp(amp_h)
+            self._record_flops(h.flops, dt / h.window)
+            common = dict(step=h.step, samples=h.samples, tokens=h.tokens,
+                          tokens_per_sec=round(rate, 3), **last)
+            if h.loop == "run_window":
+                _obs.emit("train_window", window=h.window, accum=h.accum,
+                          loss_mean=float(sum(float(x) for x in loss_h)
+                                          / len(loss_h)),
+                          window_seconds=round(dt, 6),
+                          step_seconds_amortized=round(dt / h.window, 6),
+                          **common)
+            else:
+                _obs.emit("train_step", step_seconds=round(dt, 6), **common)
 
     def _record_amp(self, amp_h):
         """Loss-scale gauge + skipped-step counter from the already-fetched
         ``(scale, skipped)`` host pair (float16 policy only) — part of the
-        step/window's single telemetry sync, never a second device_get."""
+        one telemetry read, never a second device_get."""
         if amp_h is None:
             return
         scale_f, skipped = amp_h
@@ -953,53 +1052,6 @@ class TrainStep:
             _obs.counter("train_amp_skipped_steps_total",
                          "steps dropped by AMP overflow handling").inc(d)
         self._amp_skipped_seen = int(skipped)
-
-    def _record_window(self, t0, batches, losses, gnorms, window, accum,
-                       cache_key=None):
-        # ONE device sync for the whole window: losses+gnorms+amp carry
-        # fetched together, so window time is true wall clock of K fused steps
-        loss_h, gnorm_h, amp_h = jax.device_get(
-            (losses, gnorms, self._amp_fetchable()))
-        dt = time.perf_counter() - t0
-        _obs.set_step(int(self.optimizer.num_update))
-        b0 = batches[0] if batches else None
-        nlead = 2 if accum > 1 else 1
-        samples = (int(math.prod(b0.shape[:nlead + 1]))
-                   if b0 is not None and b0.ndim > nlead else window)
-        tokens = int(b0.size) if b0 is not None else 0
-        _obs.histogram("train_step_seconds", "full train-step wall clock",
-                       unit="s").observe(dt, loop="run_window")
-        _obs.counter("train_steps_total").inc(window, loop="run_window")
-        _obs.counter("train_samples_total").inc(samples, loop="run_window")
-        _obs.counter("train_tokens_total").inc(tokens, loop="run_window")
-        _obs.gauge("train_tokens_per_sec", unit="tokens/s").set(
-            tokens / dt if dt > 0 else 0.0)
-        _obs.gauge("train_loss").set(float(loss_h[-1]))
-        if gnorm_h is not None:
-            _obs.gauge("train_grad_norm").set(float(gnorm_h[-1]))
-        self._record_amp(amp_h)
-        # the scan body appears once in the window program text, so its
-        # census is one step's dots (one microbatch when accum > 1); the
-        # per-step batch is sliced off the stack only on the memo miss
-        lead = (0, 0) if accum > 1 else (0,)
-        if cache_key is None:
-            cache_key = self._window_cache_key(window, accum, len(batches),
-                                               True)
-        self._record_flops(
-            self._estimate_flops(
-                cache_key,
-                lambda: self.lower_window_hlo(*(b[lead] for b in batches),
-                                              window=window, accum=accum),
-                accum),
-            dt / window if window else dt)
-        _obs.emit("train_window", window=window, accum=accum,
-                  loss=float(loss_h[-1]),
-                  loss_mean=float(sum(float(x) for x in loss_h) / len(loss_h)),
-                  grad_norm=None if gnorm_h is None else float(gnorm_h[-1]),
-                  window_seconds=round(dt, 6),
-                  step_seconds_amortized=round(dt / window, 6),
-                  samples=samples, tokens=tokens,
-                  tokens_per_sec=round(tokens / dt, 3) if dt > 0 else 0.0)
 
     def attach_monitor(self, mon):
         """Register a :class:`~mxnet_tpu.monitor.Monitor`: at each step's
@@ -1076,6 +1128,7 @@ class TrainStep:
     def save(self, directory):
         from ..checkpoint import save_train_state
 
+        self.flush_telemetry()  # the save waits for the device anyway
         # the checkpoint step is num_update (ATTEMPTED steps, the schedule
         # clock); the meta extras carry what differs from it under the f16
         # policy: the APPLIED count (Adam's t, held back on skips) and the
@@ -1217,6 +1270,35 @@ class TrainStep:
         return fn.lower(self.params, self.opt_state, self.step_count,
                         stacked, keys, lrs, wd)
 
+    def op_scopes(self, *batch, window: Optional[int] = None,
+                  accum: int = 1) -> Dict[str, str]:
+        """{HLO instruction name: scope path} of the compiled program this
+        batch signature runs: the join key for a device trace, whose rows
+        carry an instruction's text and not its ``op_name``
+        (``MeasuredReport.scope_seconds`` takes it; docs/OBSERVABILITY.md
+        "Named scopes"). Paths start at one of ``SCOPES`` or ``backward``.
+
+        Compiles the lowered program once more, apart from the one that
+        runs: jax leaves metadata out of its compile cache's key, so the
+        running executable may have been cached before the scopes existed,
+        and its text then names none of them (its instruction names are the
+        same). An explicit compiler option, set to its default, keeps this
+        compile from being handed that executable, and the scopes are made
+        part of the key."""
+        from ..observability.scopes import op_scopes_from_hlo
+
+        lowered = (self.lower_window_hlo(*batch, window=window, accum=accum)
+                   if window else self.lower_hlo(*batch))
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        was = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            text = lowered.compile(compiler_options={
+                "xla_embed_ir_in_executable": False}).as_text()
+        finally:
+            jax.config.update(flag, was)
+        return op_scopes_from_hlo(text)
+
     def audit(self, *batch, window: Optional[int] = None, accum: int = 1,
               compile: bool = True, rules: Optional[ShardingRules] = None):
         """Structural :class:`~mxnet_tpu.analysis.ProgramAudit` of the
@@ -1352,9 +1434,18 @@ class TrainStep:
             if self.batch_sharding is not None:
                 ws = self.window_batch_sharding(accum)
                 stacked = tuple(jax.device_put(s, ws) for s in stacked)
-            fn = lambda: self._run_window(stacked, window, accum)  # noqa: E731
+            dispatch = lambda: self._run_window(stacked, window, accum)  # noqa: E731
         else:
-            fn = lambda: self(*batch)  # noqa: E731
+            dispatch = lambda: self(*batch)  # noqa: E731
+
+        def fn():
+            # capture() waits for every traced dispatch anyway: publish its
+            # telemetry there and then, so train_step_seconds holds one
+            # whole step for each of them, as the trace does
+            out = jax.block_until_ready(dispatch())
+            self.flush_telemetry()
+            return out
+
         cap = _profiling.capture(fn, steps=steps, warmup=warmup,
                                  trace_dir=trace_dir)
         if calibrate:

@@ -207,6 +207,7 @@ def test_train_mfu_gauge_from_flops(tmp_path):
         ts, (x, y) = _dense_step(seed=3)
         ts(x, y)
         ts(x, y)
+        obs.flush()  # a step's readings are published some dispatches on
         flops = obs.REGISTRY.get("train_model_flops_per_step").value()
         assert flops == ts.model_flops_per_step(x, y)
         mfu = obs.REGISTRY.get("train_mfu").value()

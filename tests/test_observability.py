@@ -187,6 +187,10 @@ def test_train_step_metrics_and_events(tmp_path):
     before = steps_c.value(loop="train_step")
     step(nd.ones((2, 3)), nd.ones((2, 4)))
     step(nd.ones((2, 3)), nd.ones((2, 4)))
+    # readings lag the dispatch (no step ever waits for the one just
+    # queued): nothing is published before the lag or a flush
+    assert steps_c.value(loop="train_step") == before
+    obs.flush()
     assert steps_c.value(loop="train_step") == before + 2
     assert obs.REGISTRY.get("train_step_seconds").total_count() >= 2
     assert obs.gauge("train_loss").value() is not None

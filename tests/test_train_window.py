@@ -144,7 +144,7 @@ def test_one_program_per_window_signature(tmp_path):
         ts.run(iter(_batches(8)), steps=8, window=4)
         wkeys = [k for k in ts._compiled if k[0] == "window"]
         assert len(wkeys) == 1, "window=4 x2 must lower exactly one program"
-        assert ts._window_dispatches == 2  # one dispatch (+sync) per window
+        assert ts._window_dispatches == 2  # one dispatch per window
         assert rc.value(reason="window") == before + 1
 
         # same (window, shapes) signature again: fully cached
@@ -171,6 +171,7 @@ def test_window_telemetry_records_run_window_loop(tmp_path):
         c_before = obs.counter("train_steps_total").value(loop="run_window")
         ts = _make_step()
         ts.run(iter(_batches(4)), steps=4, window=2)
+        obs.flush()  # a window's readings are published some dispatches on
         assert h.stats(loop="run_window")["count"] == h_before + 2
         assert obs.counter("train_steps_total").value(
             loop="run_window") == c_before + 4

@@ -174,6 +174,7 @@ def main(argv=None):
     # mean the measured (post-warmup) step time is checked against
     ts(*batch)
     ts(*batch)
+    obs.flush()  # telemetry lags the dispatch: level the registry first
     hist = obs.REGISTRY.get("train_step_seconds")
     c0 = hist.total_count() if hist is not None else 0
     s0 = hist.total_sum() if hist is not None else 0.0
