@@ -119,7 +119,7 @@ profcheck:
 kernelcheck:
 	$(PY) -m pytest tests/test_flash_attention.py tests/test_pallas_layernorm.py \
 	    tests/test_pallas_paged_attention.py tests/test_pallas_optimizer.py \
-	    tests/test_pallas_softmax_xent.py -q
+	    tests/test_pallas_softmax_xent.py tests/test_packed_attention.py -q
 
 native:
 	$(MAKE) -C native

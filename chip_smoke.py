@@ -11,8 +11,9 @@ configuration can select:
   serve    GPT-2 345M through GenerationEngine(paged=True, bf16 cache) and
            ContinuousBatcher; greedy tokens checked against a plain
            re-forward of the same net
-  kernels  flash attention fwd+bwd and paged attention, compiled by Mosaic
-           and compared with their XLA references
+  kernels  flash attention fwd+bwd, paged attention and packed attention
+           fwd+bwd (BERT-large's: batch 64 x seq 128 x 3 x 1024, key mask),
+           compiled by Mosaic and compared with their XLA references
 
 Any failure in any phase raises and the process exits non-zero; nothing is
 caught. The last line of stdout is one JSON object,
@@ -436,13 +437,71 @@ def check_paged(rows=8, h=8, ch=128, ps=16, n_pages=64, interpret=None):
                                                             want[0]))}
 
 
+def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
+    """The training cell's attention: the packed projection of BERT-large
+    with the cell's key-padding mask (valid lengths T/2..T), forward and
+    backward, against the unpack + einsum + transpose path it replaces."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_packed_attention as ppa
+
+    c = heads * d
+    rs = np.random.RandomState(SEED)
+    qkv = jnp.asarray(rs.randn(b, t, 3 * c), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(b, t, c), jnp.bfloat16)
+    valid = jnp.asarray(rs.randint(t // 2, t + 1, b), jnp.int32)
+    mask = (jnp.arange(t, dtype=jnp.int32).reshape(1, 1, 1, t)
+            < valid.reshape(b, 1, 1, 1))
+    if interpret is None:
+        why = ppa.packed_attention_refusal(qkv, mask, heads)
+        if why is not None:
+            raise AssertionError(f"packed: the kernel's gate refuses: {why}")
+
+    def packed(qkv):
+        return ppa.packed_attention(qkv, mask, heads, interpret=interpret)
+
+    def ref(qkv):
+        q, k, v = att._unpack_qkv(qkv, heads)
+        return att._merge_heads(att._reference_mha(q, k, v, mask=mask))
+
+    def grad(f):
+        return jax.grad(lambda qkv, w: jnp.sum(
+            f(qkv).astype(jnp.float32) * w.astype(jnp.float32)))
+
+    calls = {"fwd": _custom_calls(packed, qkv),
+             "bwd": _custom_calls(grad(packed), qkv, w)}
+    if not interpret and min(calls.values()) < 1:
+        raise AssertionError(f"packed: lowered without its Mosaic kernels: "
+                             f"{calls}")
+    errs = {"out": _rel_err(jax.jit(packed)(qkv), jax.jit(ref)(qkv))}
+    got, want = jax.jit(grad(packed))(qkv, w), jax.jit(grad(ref))(qkv, w)
+    for i, n in enumerate(("dq", "dk", "dv")):
+        errs[n] = _rel_err(got[..., i * c:(i + 1) * c],
+                           want[..., i * c:(i + 1) * c])
+    if not all(e < 2e-2 for e in errs.values()):  # bf16 in, f32 softmax
+        raise AssertionError(f"packed: off its einsum reference: {errs}")
+    # a masked key takes no gradient at all
+    dead = ~jnp.broadcast_to(mask.reshape(b, t, 1), (b, t, 2 * c))
+    leak = float(jnp.max(jnp.abs(jnp.where(
+        dead, got[..., c:].astype(jnp.float32), 0.0))))
+    if leak != 0.0:
+        raise AssertionError(f"packed: a masked key got gradient {leak}")
+    return {"shape": [b, t, 3 * c], "heads": heads, "mask": "keys, T/2..T",
+            "tpu_custom_calls": calls,
+            "rel_err": {n: round(e, 5) for n, e in errs.items()}}
+
+
 def phase_kernels():
     """Every Pallas kernel the default configuration can select on a TPU,
     called directly, compiled by Mosaic (a compile error fails the
     phase) and compared with its XLA reference."""
     out = {"flash_attention": [check_flash(causal=False),
                                check_flash(causal=True)],
-           "paged_attention": check_paged()}
+           "paged_attention": check_paged(),
+           "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out
 

@@ -37,7 +37,12 @@ _KNOBS: Dict[str, tuple] = {
     # -- Pallas kernel selection. "v5e, PR 21" = chip_smoke.py /
     # tools/kernelbench.py on one TPU v5e under jax 0.9.0, Mosaic-compiled
     # (interpret=False) and compared with the XLA composition; speed
-    # against XLA is not measured for any of them yet ----------------------
+    # against XLA is not measured for any of them yet. The packed attention
+    # kernel (ops/pallas_packed_attention.py) has no knob on purpose: its
+    # gate reads backend, dtype, shapes, mask form and mesh. v5e, PR 25
+    # (chip_smoke.py check_packed): (64, 128, 3 x 1024) bf16 with BERT's key
+    # mask compiles, forward and backward, 0.004% (out, dv) and 0.36% (dq,
+    # dk) from the einsum path, no gradient on a masked key ----------------
     "fused_layernorm": (bool, False, ("MXNET_TPU_FUSED_LAYERNORM",),
                         "route LayerNorm through the Pallas kernel on TPU "
                         "(opt-in. v5e, PR 21: compiles and agrees to one "

@@ -2,10 +2,15 @@
 
 The reference model calls the fused transformer ops
 (``src/operator/contrib/transformer.cc`` interleaved matmuls); here the
-encoder's attention goes through ``multi_head_attention`` which dispatches to
-the Pallas flash kernel on TPU (tile-friendly head dims) and the XLA einsum
-path elsewhere. Parameter names carry the ``qkv_/proj_/ffn1_/ffn2_`` markers
-the TP sharding rules key on (``parallel.sharding.DEFAULT_BERT_RULES``).
+encoder's attention goes through ``self_attention_packed``, which hands the
+``qkv`` projection as the Dense wrote it to a path chosen from what it can
+observe: on one TPU, at sequences up to 512 with the key-padding mask, the
+Pallas packed-attention kernel (``ops.pallas_packed_attention``: softmax and
+both products of a batch row in VMEM, backward recomputed); otherwise
+``multi_head_attention``, which is the XLA einsum path whenever a mask is
+passed (BERT always passes one) and on every non-TPU backend. Parameter
+names carry the ``qkv_/proj_/ffn1_/ffn2_`` markers the TP sharding rules key
+on (``parallel.sharding.DEFAULT_BERT_RULES``).
 
 Pretraining heads follow GluonNLP's ``BERTForPretrain``: masked-LM over
 gathered positions + next-sentence classifier.
@@ -46,14 +51,10 @@ class BERTAttention(HybridBlock):
             self.dropout = nn.Dropout(dropout)
 
     def hybrid_forward(self, F, x, mask=None):
-        # x: (B, T, C)
-        b, t, c = x.shape
-        h = self._heads
-        qkv = self.qkv(x)  # (B, T, 3C)
-        qkv = qkv.reshape((b, t, 3, h, c // h)).transpose((2, 0, 3, 1, 4))
-        q, k, v = qkv[0], qkv[1], qkv[2]  # (B, H, T, Ch)
-        out = F.multi_head_attention(q, k, v, mask=mask)
-        out = out.transpose((0, 2, 1, 3)).reshape((b, t, c))
+        # x: (B, T, C); the packed projection (B, T, 3C) goes to the
+        # attention operator as the Dense wrote it, columns [3][H][D]
+        out = F.self_attention_packed(self.qkv(x), mask=mask,
+                                      heads=self._heads)
         return self.dropout(self.proj(out))
 
 

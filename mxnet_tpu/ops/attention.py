@@ -9,17 +9,25 @@ GluonNLP BERT calls) for TPU:
   - the interleaved-matmul API is preserved exactly (projections stored
     interleaved as (T, B, H*3*Ch)) so GluonNLP-shaped model code runs;
   - the *blessed* path is ``multi_head_attention`` which dispatches to a
-    Pallas flash-attention kernel on TPU (O(L) memory, MXU-tiled) and a
-    jnp reference path elsewhere — see ``mxnet_tpu.ops.flash_attention``.
+    Pallas flash-attention kernel on TPU (O(L) memory, MXU-tiled; long
+    unmasked sequences only) and a jnp reference path elsewhere — see
+    ``mxnet_tpu.ops.flash_attention``;
+  - ``self_attention_packed`` takes the packed ``(B, T, 3C)`` projection of
+    a self-attention block as its Dense wrote it: short masked sequences
+    on a TPU run ``mxnet_tpu.ops.pallas_packed_attention``, everything else
+    unpacks and calls ``multi_head_attention``.
 """
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 
 from ..registry import register
+
+logger = logging.getLogger(__name__)
 
 
 @register("_contrib_div_sqrt_dim")
@@ -302,3 +310,66 @@ def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
     else:
         out = _reference_mha(q, k, v, mask=mask, causal=causal)
     return out.astype(orig_dtype)
+
+
+# --------------------------------------------------------------------------
+# self-attention over the packed q/k/v projection
+# --------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _log_refusal_once(why):
+    logger.info("self_attention_packed: no packed kernel: %s", why)
+
+
+def _unpack_qkv(qkv, heads):
+    """(B, T, 3C) with columns ordered [3][H][D] -> q, k, v each
+    (B, H, T, D): what a ``Dense(3 * units)`` projection emits."""
+    b, t, c3 = qkv.shape
+    x = qkv.reshape((b, t, 3, heads, c3 // (3 * heads)))
+    x = x.transpose((2, 0, 3, 1, 4))
+    return x[0], x[1], x[2]
+
+
+def _merge_heads(x):
+    """(B, H, T, D) -> (B, T, H*D): the context as a projection reads it."""
+    b, h, t, d = x.shape
+    return x.transpose((0, 2, 1, 3)).reshape((b, t, h * d))
+
+
+@register("self_attention_packed")
+def self_attention_packed(qkv, mask=None, heads=1):
+    """Self-attention of ``heads`` heads over the packed projection ``qkv``
+    ``(B, T, 3C)``, columns ordered ``[3][H][D]`` as a ``Dense(3C)`` writes
+    them; returns the context ``(B, T, C)`` as the output projection reads
+    it. ``mask`` is anything :func:`multi_head_attention` takes.
+
+    One semantics, the path chosen from what the operands and the process
+    show (backend, dtype, shapes, the mask's form, the active mesh; no knob):
+    short sequences with a keys-only mask on a TPU run
+    :mod:`mxnet_tpu.ops.pallas_packed_attention`, which reads ``qkv`` in
+    place and keeps scores and probabilities in VMEM; anything else
+    unpacks to ``(B, H, T, D)``, calls :func:`multi_head_attention` (so long
+    sequences still reach the flash kernel) and transposes back. The
+    ``attention_path_total{path}`` counter says at trace time which ran
+    (``packed_kernel``, ``flash`` or ``einsum``); the first reason the
+    kernel was refused is logged once. Same dtype policy on every path.
+    """
+    from .. import observability as obs
+    from ..contrib.amp import cast_inputs
+    from . import flash_attention as fa
+    from . import pallas_packed_attention as ppa
+
+    heads = int(heads)
+    orig_dtype = qkv.dtype
+    (qkv,) = cast_inputs(qkv)  # AMP, as multi_head_attention casts q, k, v
+    why = ppa.packed_attention_refusal(qkv, mask, heads)
+    if why is None:
+        obs.counter("attention_path_total").inc(path="packed_kernel")
+        return ppa.packed_attention(qkv, mask, heads).astype(orig_dtype)
+    _log_refusal_once(why)
+    q, k, v = _unpack_qkv(qkv, heads)
+    # multi_head_attention's own choice, made here so that it can be counted
+    use_flash = fa.flash_supported(q, k, v, mask)
+    obs.counter("attention_path_total").inc(
+        path="flash" if use_flash else "einsum")
+    out = multi_head_attention(q, k, v, mask=mask, use_flash=use_flash)
+    return _merge_heads(out).astype(orig_dtype)

@@ -21,7 +21,14 @@ batch 4, 16 heads, seq 2048, head 64, causal and not). Their speed against
 the einsum and chunked paths under the installed jax: not measured.
 
 On non-TPU backends the kernels run in interpret mode (tests) or callers fall
-back to the einsum path via ``flash_supported``.
+back to the einsum path via ``flash_supported``. Which model reaches it: none
+of the listed ones at their published shapes. ``flash_supported`` refuses any
+mask and any sequence under 2048, BERT always passes a key-padding mask and
+GPT-2's context is 1024. BERT's short masked sequences are the opposite
+shape and have a kernel of their own
+(:mod:`mxnet_tpu.ops.pallas_packed_attention`, reached through
+``attention.self_attention_packed``); that operator's fallback still comes
+here for long unmasked sequences.
 """
 from __future__ import annotations
 
