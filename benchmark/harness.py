@@ -187,6 +187,17 @@ class Check:
                 f"{r['limit']}) {'ok' if r['ok'] else 'FAILED'}")
 
 
+def compared(rows):
+    """{short plain name: {value, limit, ok}} of a run's check rows: the
+    name up to its bracketed remark, spaces as underscores."""
+    def plain(v):  # json has no inf or nan
+        return str(v) if isinstance(v, float) and not math.isfinite(v) else v
+
+    return {r["name"].split(" (")[0].replace(" ", "_"):
+            {"value": plain(r["value"]), "limit": r["limit"], "ok": r["ok"]}
+            for r in rows}
+
+
 def result_line(bench, cell, run, trace_on, devices, root=ROOT):
     """The last line of stdout, to the driver's contract."""
     metrics = {}
@@ -212,6 +223,7 @@ def result_line(bench, cell, run, trace_on, devices, root=ROOT):
         device["window_s"] = trace["window_s"]
         line["breakdown"] = {"device_ops": top(trace["ops"]),
                              "idle_gaps": top(trace["idle_gaps"])}
+    line["compared"] = compared(run["check"])  # last, as the contract has it
     return json.dumps(line)
 
 
@@ -243,7 +255,12 @@ def main(argv, platform="tpu", root=ROOT, parked=False):
         cell=cell, config=config, mix=mix, seed=args.seed,
         seconds=args.seconds, trace_on=bool(args.trace), devices=devices,
         peaks=peaks, t_start=t_start, root=root)
+    run.update(cell=cell, config=config, mix=mix)  # for the readers
     sys.stdout.flush()
+    for name, row in compared(run["check"]).items():  # stderr's last lines
+        print(f"compared {name}: {row['value']} limit {row['limit']} "
+              f"{'ok' if row['ok'] else 'FAILED'}", file=sys.stderr)
+    sys.stderr.flush()
     print(result_line(bench, cell, run, bool(args.trace), devices, root),
           flush=True)
     return run

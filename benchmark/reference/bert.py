@@ -159,3 +159,16 @@ def train_flops(cfg, batch, seq, masked):
     fwd = batch * seq * per_token_layer * cfg["num_hidden_layers"]
     head = batch * masked * h * cfg["vocab_size"] * 2
     return 3 * (fwd + head)
+
+
+def packed_attention_bytes(cfg, batch, seq, itemsize=2):
+    """Bytes the packed attention kernels have to move in one step: in every
+    layer the forward reads the packed ``qkv`` projection (3 x hidden a
+    token) and writes the context (hidden); the backward, which recomputes
+    the scores, reads ``qkv`` and the context's cotangent and writes
+    ``dqkv``. bfloat16 as the step computes; the valid lengths (one number a
+    row) are not counted."""
+    h = cfg["hidden_size"]
+    forward = batch * seq * (3 * h + h) * itemsize
+    backward = batch * seq * (3 * h + h + 3 * h) * itemsize
+    return cfg["num_hidden_layers"] * (forward + backward)

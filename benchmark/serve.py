@@ -126,14 +126,17 @@ def latency_stats(name, seconds):
     return out
 
 
-def widest_gap(ref, weights, config, sample, shape, precision=None):
-    """How far below the reference's best logit the judged tokens lie, at
-    worst, over every generated position of the sampled requests. The
-    judged tokens are the served ones; with ``precision`` (the control)
-    they are the tokens that precision puts first on the same positions."""
+def logit_gaps(ref, weights, config, sample, shape, precision=None):
+    """How far below the reference's best logit the judged tokens lie, over
+    every generated position of the sampled requests: ``widest_gap`` (the
+    worst position) and ``mean_gap`` (over all of them: most positions read
+    0, so it counts how often and how far the judged token is not the
+    reference's first), and the number of positions. The judged tokens are
+    the served ones; with ``precision`` (the control) they are the tokens
+    that precision puts first on the same positions."""
     import numpy as np
 
-    worst, count = 0.0, 0
+    worst, total, count = 0.0, 0.0, 0
     for prompt, output in sample:
         tokens, first, n = prompt + output[:-1], len(prompt) - 1, len(output)
         logits = ref.next_token_logits(weights, config, tokens, first, n,
@@ -144,8 +147,9 @@ def widest_gap(ref, weights, config, sample, shape, precision=None):
                 weights, config, tokens, first, n, precision=precision,
                 pad_to=shape[0], out_pad=shape[1]).argmax(axis=-1)
         gaps = logits.max(axis=-1) - logits[np.arange(n), judged]
-        worst, count = max(worst, float(gaps.max())), count + n
-    return worst, count
+        worst, total = max(worst, float(gaps.max())), total + float(gaps.sum())
+        count += n
+    return {"widest_gap": worst, "mean_gap": total / max(count, 1)}, count
 
 
 def check_shape(mix):
@@ -203,9 +207,6 @@ def run(cell, config, mix, seed, seconds, trace_on, devices, peaks, t_start,
     compiles, host = CompileCounter(), HostLoad()
     programs_before = engine.compiled_programs
     origin = clock()
-    t_w0 = origin + schedule["window"][0]
-    t_w1 = origin + schedule["window"][1]
-    setup_s = t_w0 - t_start
     gen = Generator(schedule["requests"], origin)
     server = Server(engine, batcher, gen)
     due_recs = [r for r in schedule["requests"] if r["in_window"]]
@@ -217,11 +218,17 @@ def run(cell, config, mix, seed, seconds, trace_on, devices, peaks, t_start,
     trace, trace_span = None, None
     gen.start()
     try:
-        server.until(t_w0)
+        # the window opens where the lead-in's last step ended and closes
+        # where the step under way at the end of --seconds ended: whole steps
+        # with all their tokens, none cut by an edge
+        server.until(origin + schedule["window"][0])
+        t_w0 = clock()
+        setup_s = t_w0 - t_start
         phase("lead_in")
         compiles.start()
         host.start()
-        server.until(t_w1)
+        server.until(origin + schedule["window"][1])
+        t_w1 = clock()
         host_load = host.stop()
         window_compiles = max(compiles.stop(),
                               engine.compiled_programs - programs_before)
@@ -279,13 +286,17 @@ def run(cell, config, mix, seed, seconds, trace_on, devices, peaks, t_start,
             f"{sum(d > 1.5 * median for d in decode)} of {len(decode)} over "
             f"1.5 medians, {sum(decode) - len(decode) * median:.3f} s above "
             f"the median in all; host: {host_load}")
+    if trace:  # the device's own time for each program of the traced slice
+        say("programs of the traced slice (calls x device ms each): " + ", ".join(
+            f"{name} {n} x {1e3 * total / n:.3f}" for name, (n, total)
+            in sorted(trace["modules"].items(), key=lambda kv: -kv[1][1])))
     if trace_span:  # how much later the generator ran with tracing on
         late = [r["handoff_t"] - (origin + r["due"])
                 for r in schedule["requests"] if "handoff_t" in r
                 and trace_span[0] <= origin + r["due"] < trace_span[1]]
         say(f"generator lateness p99 in the traced slice: "
             f"{1e3 * percentile(late, 99):.3f} ms over {len(late)} requests")
-    say(f"setup by phase (s): {phases}; window {seconds} s: "
+    say(f"setup by phase (s): {phases}; window {t_w1 - t_w0:.3f} s: "
         f"{len(window_recs)} requests, {len(finished)} finished, "
         f"{len(served)} tokens served, {window_compiles} compiles")
 
@@ -298,15 +309,17 @@ def run(cell, config, mix, seed, seconds, trace_on, devices, peaks, t_start,
     gc.collect()
     t_ref = clock()
     check = Check()
-    worst, compared = widest_gap(ref, weights, config, sample, check_shape(mix))
-    check.add(f"widest gap of a served token below the reference's best "
-              f"logit ({compared} tokens of {len(sample)} requests)", worst,
-              config["check"]["widest_gap"])
-    check.add("served tokens compared", compared >= 1, True, "true")
-    check.add("programs compiled inside the window", window_compiles, 0,
-              "equal")
-    check.add("requests of the window refused, shed or unfinished", failed, 0,
-              "equal")
+    gaps, compared = logit_gaps(ref, weights, config, sample, check_shape(mix))
+    for name, limit in config["check"].items():  # widest_gap, mean_gap
+        check.add(f"{name} (of a served token below the reference's best "
+                  f"logit; {compared} tokens of {len(sample)} requests)",
+                  gaps[name], limit)
+    check.add("served_tokens_compared (at least one)", compared >= 1, True,
+              "true")
+    check.add("window_compiles (programs compiled inside the window)",
+              window_compiles, 0, "equal")
+    check.add("requests_failed (of the window: refused, shed or unfinished)",
+              failed, 0, "equal")
     check.report()
     say(f"reference check took {clock() - t_ref:.1f} s")
     return {
@@ -314,7 +327,7 @@ def run(cell, config, mix, seed, seconds, trace_on, devices, peaks, t_start,
         "origin": origin, "requests": schedule["requests"], "steps": steps,
         "trace_span": trace_span, "num_pages": num_pages,
         "batch_size": batch_size, "window_compiles": window_compiles,
-        "chips": len(devices), "peaks": peaks, "config": config, "mix": mix,
+        "chips": len(devices), "peaks": peaks,
         "latency": latency,
         "end_to_end": {**latency, "setup_s": setup_s,
                        "serve_tokens_per_s": len(served) / (t_w1 - t_w0)},
@@ -329,6 +342,5 @@ def control(run, config, mix, seed, devices, precision):
     place."""
     ref = reference_for(config)
     weights = make_weights(ref.param_specs(config), seed)
-    worst, _ = widest_gap(ref, weights, config, run["sample"],
-                          check_shape(mix), precision)
-    return {"widest": worst}
+    return logit_gaps(ref, weights, config, run["sample"], check_shape(mix),
+                      precision)[0]

@@ -3,7 +3,10 @@ file were recorded (on the chip, by hand): a few steps of a small program
 with a loop in it, and on several chips a reduction across them, under the
 benchmark's own host spans. ``python3 -m benchmark.trace.record_sample``
 writes ``chiprun_out/sample_<n>chip.xplane.pb`` and prints what the trace
-holds. The tests reduce the committed copies."""
+holds. With the argument ``kernel`` (one chip) the program also runs a
+Pallas kernel whose instruction is NOT named ``custom_call*``, and the file
+is ``sample_kernel.xplane.pb``: what ``reduce.is_custom_call`` has to find
+by the operation's text. The tests reduce the committed copies."""
 from __future__ import annotations
 
 import os
@@ -14,7 +17,7 @@ from .. import harness, tracing
 from . import reduce as tr
 
 
-def main():
+def main(kernel=False):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -24,11 +27,22 @@ def main():
     mesh = Mesh(devices, ("d",))
     rows = NamedSharding(mesh, P("d"))
 
+    def doubled(h):
+        from jax.experimental import pallas as pl
+
+        def probe(x_ref, o_ref):
+            o_ref[...] = x_ref[...] * 2
+
+        return pl.pallas_call(probe, name="probe_scale",
+                              out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype))(h)
+
     @jax.jit
     def step(x, w):
         def body(_, h):
             return jnp.tanh(h @ w)
         h = jax.lax.fori_loop(0, 4, body, x)
+        if kernel:
+            h = doubled(h)
         return jnp.sum(h * h)  # over the sharded rows: an all-reduce
 
     x = jax.device_put(jnp.ones((256 * len(devices), 512), jnp.bfloat16), rows)
@@ -54,7 +68,8 @@ def main():
                 y.block_until_ready()
     jax.profiler.stop_trace()
     path = tr.find_trace(directory)
-    keep = os.path.join(out, f"sample_{len(devices)}chip.xplane.pb")
+    keep = os.path.join(out, "sample_kernel.xplane.pb" if kernel
+                        else f"sample_{len(devices)}chip.xplane.pb")
     shutil.copy(path, keep)
     from jax.profiler import ProfileData
 
@@ -64,9 +79,13 @@ def main():
             events = list(line.events)
             print("  LINE", line.name, len(events),
                   [(e.name[:60], e.start_ns, e.duration_ns) for e in events[:6]])
+            for e in events:  # a kernel's record in full, with its stats
+                if "custom" in e.name or "probe" in e.name:
+                    print("    KERNEL?", e.name, dict(e.stats))
+                    break
     result.update(tr.reduce(tr.load(path, "tpu"), tracing.WINDOW))
     print(os.path.getsize(keep), "bytes;", result)
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(kernel=sys.argv[1:] == ["kernel"]))

@@ -35,12 +35,37 @@ def is_collective(name):
     return op_base(name).startswith(COLLECTIVES)
 
 
+def op_code(text):
+    """The opcode of an operation's text as the trace records it, ``%name =
+    <result shape> <opcode>(<operands>), <attributes>``: ``fusion``,
+    ``custom-call``, ``copy-done``. None where the record is a bare name."""
+    _, eq, rest = text.partition(" = ")
+    if not eq:
+        return None
+    if rest.startswith("("):  # a tuple's shape: skip to its closing bracket
+        depth = 0
+        for at, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = rest[at + 1:]
+                break
+    else:  # a shape has no space in it
+        rest = rest.partition(" ")[2]
+    found = re.match(r"\s*([a-z][\w-]*)\(", rest)
+    return found.group(1) if found else None
+
+
 def is_custom_call(name):
-    """A Pallas kernel: a ``custom-call`` whose target, where the trace
-    gives the operation's text, is ``tpu_custom_call``."""
-    if not re.match(r"(tpu_)?custom[-_]call", op_base(name)):
-        return False
-    return "custom_call_target" not in name or "tpu_custom_call" in name
+    """A Pallas kernel, whatever its instruction is called: by the
+    operation's own text where the trace records it (the opcode
+    ``custom-call`` with ``custom_call_target="tpu_custom_call"``; XLA's own
+    custom calls have other targets), and only for a bare name by the name
+    (``custom-call*``/``custom_call*``/``tpu_custom_call*``)."""
+    code = op_code(name)
+    if code is not None:
+        return code == "custom-call" and (
+            'custom_call_target="tpu_custom_call"' in name)
+    return bool(re.match(r"(tpu_)?custom[-_]call", op_base(name)))
 
 
 def clip(events, lo, hi):

@@ -5,11 +5,15 @@ Every seed gets the SAME work. A serving mix is one period of traffic, as
 long as the window: lengths are the distribution's quantiles (as many as
 there are requests), arrival gaps the exponential's quantiles scaled to the
 period, both shuffled once by the mix's own ``pattern_seed``. A run plays
-that period round and round, starting at a phase drawn from its seed, so its
-window holds the same requests with the same neighbours as any other seed's,
-begun at another point; the seed also draws the tokens. A tail percentile
-over a few hundred requests is steady only so: with the order itself drawn
-from the seed, ``ttft_p90_ms`` spread by 13% over three seeds (PERF.md).
+that period round and round from its beginning, so every seed's window holds
+the same requests at the same times; the seed draws the tokens (and the
+weights). A tail percentile over a few hundred requests is steady only so:
+with the order itself drawn from the seed, ``ttft_p90_ms`` spread by 13% over
+three seeds, and with only the cycle's starting point drawn from it, tokens
+per second above the knee still moved by 4.1% between seeds against 0.1-0.8%
+between two runs of one seed (the engine serves two thirds of what is
+offered, in order, so another starting point serves another stretch of the
+cycle with other lengths; PERF.md, PR 26).
 """
 from __future__ import annotations
 
@@ -78,22 +82,20 @@ def base_pattern(mix, seconds):
 
 def serve_schedule(mix, vocab_size, seed, seconds, extra_s=0.0):
     """The open-loop schedule: the mix's one period of traffic
-    (:func:`base_pattern`) played round and round from a phase drawn from
-    the seed, through a lead-in, the window and a tail (and, in a traced
-    run, ``extra_s`` more). The window is exactly one period long, so every
-    seed's window holds the same requests with the same neighbours, starting
-    at another point of the cycle; the seed also draws the prompts' tokens.
-    Each request is a dict with its due time (seconds from the schedule's
-    start), its prompt and the number of tokens to generate; ``window`` is
-    (start, end) on the same clock."""
+    (:func:`base_pattern`) played round and round from its beginning through
+    a lead-in, the window and a tail (and, in a traced run, ``extra_s``
+    more). The window is one period long and begins where the cycle does, so
+    it is one fixed stretch of requests for every seed; the seed draws the
+    prompts' tokens. Each request is a dict with its due time (seconds from
+    the schedule's start), its prompt and the number of tokens to generate;
+    ``window`` is (start, end) on the same clock."""
     due, prompts, answers = base_pattern(mix, seconds)
     rng = rng_for(seed, 2)
-    phase = rng.uniform(0.0, seconds)
     lead = mix["lead_in_s"]
     end = lead + seconds + mix["tail_s"] + extra_s
     rows = []
     for k in range(-int(lead // seconds) - 2, int(end // seconds) + 2):
-        t = due + k * seconds - phase + lead
+        t = due + k * seconds + lead
         rows += [(float(t[i]), int(prompts[i]), int(answers[i]))
                  for i in np.flatnonzero((t >= 0.0) & (t < end))]
     requests = []

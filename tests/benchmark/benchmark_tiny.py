@@ -12,9 +12,9 @@ import json
 import os
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-# the serving cell and the four-chip ZeRO cell are parked (PERF.md, Open
-# questions): their runner, mixes, readers and the mesh path are in the tree,
-# and the CPU (four virtual devices for the last) drives them here
+# the four-chip ZeRO cell is parked (PERF.md, Open questions): its mix, its
+# readers and the mesh path are in the tree, and the CPU (four virtual
+# devices) drives it here beside the two cells of BENCHMARK.json
 ZERO4 = "tiny_train_zero4"
 CELLS = {"bert_large_train_s128": "tiny_train",
          "gpt2_345m_serve_saturate": "tiny_serve",

@@ -1,6 +1,5 @@
 """Percentiles, token gaps, timing from due time, the schedule generator and
 the trace reduction's arithmetic, on hand-made numbers."""
-import collections
 import math
 import types
 
@@ -108,24 +107,32 @@ def test_same_seed_same_schedule_and_a_large_seed_is_taken():
     assert [r["prompt"] for r in a["requests"]] != [r["prompt"] for r in c["requests"]]
 
 
-def test_every_seeds_window_is_the_same_cycle_begun_at_another_point():
+def test_every_seed_gets_the_same_requests_at_the_same_times():
+    """Above the knee even the cycle's starting point changes which requests
+    a window serves (PERF.md, PR 26): one rule for every mix, the cycle is
+    played from its beginning and the seed draws only the tokens."""
     a = traffic.serve_schedule(MIX, 50257, 1, 20.0)["requests"]
-    b = traffic.serve_schedule(MIX, 50257, 2, 20.0)["requests"]
-    sizes = lambda rs: [(len(r["prompt"]), r["max_new_tokens"])  # noqa: E731
-                        for r in rs if r["in_window"]]
-    sa, sb = sizes(a), sizes(b)
-    assert len(sa) == len(sb) == 160 and sa != sb
-    assert collections.Counter(sa) == collections.Counter(sb)
-    # the same requests with the same neighbours: one is a rotation of the other
-    assert any(sb == sa[k:] + sa[:k] for k in range(len(sa)))
-    # and so are the gaps between their arrivals, window edge aside
-    gaps = lambda rs: [round(y["due"] - x["due"], 9) for x, y  # noqa: E731
-                       in zip(rs, rs[1:]) if x["in_window"] and y["in_window"]]
-    assert len(set(gaps(a)) - set(gaps(b))) <= 1
-    # the lead-in and the tail replay the neighbouring parts of the cycle
-    lead = [(len(r["prompt"]), r["max_new_tokens"]) for r in a
-            if r["due"] < 8.0]
-    assert lead and lead == sa[-len(lead):]
+    b = traffic.serve_schedule(MIX, 50257, 2**31 + 7, 20.0)["requests"]
+    shape = lambda rs: [(r["due"], len(r["prompt"]), r["max_new_tokens"],  # noqa: E731
+                         r["in_window"]) for r in rs]
+    assert shape(a) == shape(b) and len(a) > 160
+    assert [r["prompt"] for r in a] != [r["prompt"] for r in b]
+
+
+def test_the_window_is_one_period_and_its_neighbours_replay_the_cycle():
+    a = traffic.serve_schedule(MIX, 50257, 1, 20.0)["requests"]
+    due, prompts, answers = traffic.base_pattern(MIX, 20.0)
+    window = [r for r in a if r["in_window"]]
+    assert len(window) == 160
+    assert [(len(r["prompt"]), r["max_new_tokens"]) for r in window] == \
+        list(zip(prompts.tolist(), answers.tolist()))
+    assert [r["due"] for r in window] == pytest.approx((due + 8.0).tolist())
+    # the lead-in is the end of the cycle, the tail its beginning again
+    sizes = [(len(r["prompt"]), r["max_new_tokens"]) for r in a]
+    lead = sum(r["due"] < 8.0 for r in a)
+    tail = len(a) - lead - 160
+    assert lead and tail and sizes[:lead] == sizes[lead + 160 - lead:lead + 160]
+    assert sizes[lead + 160:] == sizes[lead:lead + tail]
 
 
 def test_lengths_stay_inside_their_clips_and_the_rate_is_the_mixs():
@@ -174,6 +181,37 @@ def test_op_names_group_by_kind():
     assert tr.op_base("multiply_reduce_fusion") == "multiply_reduce_fusion"
     assert tr.is_collective("all-gather-start.12") and not tr.is_collective("copy.1")
     assert tr.is_custom_call("%custom-call.3") and tr.is_custom_call("tpu_custom_call.1")
+
+
+KERNEL = ('%packed_attention_fwd.24 = bf16[64,128,1024]{2,1,0:T(8,128)(2,1)} '
+          'custom-call(bf16[64,128,3072]{2,1,0:T(8,128)(2,1)} %fusion.1, '
+          's32[64]{0:T(128)} %valid), custom_call_target="tpu_custom_call", '
+          'operand_layout_constraints={bf16[64,128,3072]{2,1,0}}')
+
+
+@pytest.mark.parametrize("text,code,kernel", [
+    (KERNEL, "custom-call", True),
+    # a Pallas kernel that returns several results
+    ('%probe.2 = (f32[8,128]{1,0}, f32[8]{0:T(128)}) custom-call(f32[8,128]{1,0} '
+     '%x), custom_call_target="tpu_custom_call"', "custom-call", True),
+    # XLA's own custom calls, and a fusion that is only NAMED like a kernel
+    ('%custom-call.5 = f32[4096,1024]{1,0:T(8,128)S(1)} custom-call(), '
+     'custom_call_target="AllocateBuffer"', "custom-call", False),
+    ('%custom_call_fusion.3 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kLoop, '
+     'calls=%fused', "fusion", False),
+    ('%copy-done = bf16[512,512]{1,0:T(8,128)(2,1)S(1)} copy-done((bf16[512,512]'
+     '{1,0:T(8,128)(2,1)S(1)}, bf16[512,512]{1,0}, u32[]{:S(2)}) %copy-start)',
+     "copy-done", False),
+    ('%while = (s32[]{:T(128)}, bf16[256,512]{1,0:T(8,128)(2,1)S(1)}) while((s32[]'
+     '{:T(128)}, bf16[256,512]{1,0}) %tuple.12), condition=%c, body=%b',
+     "while", False),
+    # a bare name carries no text: the name decides, as it did
+    ("custom_call_packed_attention_bwd.7", None, True),
+    ("packed_attention_bwd.7", None, False), ("fusion.3", None, False)])
+def test_a_kernel_is_found_by_the_operations_text_and_only_then_by_its_name(
+        text, code, kernel):
+    assert tr.op_code(text) == code
+    assert tr.is_custom_call(text) is kernel
 
 
 def test_busy_is_the_union_of_intervals_clipped_to_the_window():

@@ -85,25 +85,40 @@ def small_root(tmp_path_factory):
     path = os.path.join(root, "benchmark", "configs", "gpt2_tiny.json")
     config = harness.load_json(path)
     config.update(n_embd=256, n_head=4, n_layer=4, n_vocab=2000)
-    config["check"] = {"widest_gap": 0.002}  # the CPU program is exact float32
+    # the CPU program is float32 as the reference is: its gaps are near-ties
+    # that two orders of summation settle differently
+    config["check"] = {"widest_gap": LIMIT, "mean_gap": LIMIT / 10}
     with open(path, "w") as f:
         json.dump(config, f)
+    # which requests END in a two-second window on the CPU goes by the clock,
+    # and four short answers may hold no position at which fp8 differs: the
+    # requests DUE in it (a mix that drains), and more of them compared
+    path = os.path.join(root, "benchmark", "traffic", "tiny_serve.json")
+    mix = harness.load_json(path)
+    with open(path, "w") as f:
+        json.dump(dict(mix, check_requests=24, drain=True), f)
     return root
+
+
+# between the two readings over seeds 3, 5, 7, 11, 13 at this size: the
+# program's largest 0.00047, the fp8 control's smallest 0.060
+LIMIT = 0.003
 
 
 def test_the_serving_control_comes_out_not_correct_at_test_size(small_root):
     with cpu_settings(small_root):
-        out = control.main(["--workload", "tiny_serve", "--seeds", "3,5",
+        out = control.main(["--workload", "tiny_serve", "--seeds", "3,5,7",
                             "--seconds", "2", "--control", "fp8,bfloat16,kv8"],
                            platform="cpu", root=small_root)
-    limit = 0.002
     for row in out:
-        assert row["correct"] and row["program"]["widest"] <= limit / 10
-        assert row["control_fp8"]["widest"] > 3 * limit
-        assert 0 <= row["control_bfloat16"]["widest"] <= row["control_fp8"]["widest"]
-        # the cache alone in 8 bits: a milder lower precision
-        assert 0 <= row["control_kv8"]["widest"] <= row["control_fp8"]["widest"]
-    assert max(row["control_kv8"]["widest"] for row in out) > limit
+        assert row["correct"] and row["program"]["widest_gap"] <= LIMIT / 3
+        assert row["control_fp8"]["widest_gap"] > 3 * LIMIT
+        for milder in ("bfloat16", "kv8"):  # kv8: the cache alone in 8 bits
+            assert 0 <= row["control_" + milder]["widest_gap"] <= \
+                row["control_fp8"]["widest_gap"]
+        assert row["program"]["mean_gap"] <= LIMIT / 30  # tokens of 24 requests
+        assert row["control_fp8"]["mean_gap"] > 3 * LIMIT / 10
+    assert max(row["control_kv8"]["widest_gap"] for row in out) > LIMIT
 
 
 def test_the_training_control_runs_at_test_size(small_root):

@@ -11,7 +11,7 @@ from benchmark import harness
 
 from benchmark_tiny import CELLS, make_root, run_cell
 
-LAST_LINE = {"correct", "attempted", "failed", "metrics", "device"}
+LAST_LINE = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -44,8 +44,14 @@ def test_a_tiny_run_is_correct_and_prints_the_contracts_last_line(traced, cell):
     assert 1 <= len(line["breakdown"]["device_ops"]) <= 10
     assert all(isinstance(v["value"], float) and v["unit"]
                for v in line["metrics"].values())
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit, and is the result
+    # line's last key: short plain names, each with its number and its limit
     assert stdout.count("[benchmark] check ") == len(run["check"]) >= 4
+    assert list(line)[-1] == "compared" and len(line["compared"]) == len(run["check"])
+    assert all(set(v) == {"value", "limit", "ok"} and v["ok"] and " " not in k
+               for k, v in line["compared"].items())
+    assert ("widest_gap" if run["kind"] == "serve" else "loss_rel.first") in \
+        line["compared"]
     assert "setup by phase" in stdout
 
 
@@ -56,9 +62,11 @@ def test_each_cell_reports_its_metrics_and_no_others(traced, root, cell):
     entry = harness.find_cell(bench, cell)
     declared = {m["name"] for m in harness.metrics_of(bench, entry, "per_layer")}
     # what the CPU has nothing to read for: the decode program's device
-    # time (a TPU trace's module line) and the device's peak memory
+    # time (a TPU trace's module line), the packed attention kernels (the
+    # CPU takes the einsum path) and the device's peak memory
     assert declared - set(line["metrics"]) <= {
-        "decode_hbm_roofline_pct.serve", "hbm_peak_gb.serve", "hbm_peak_gb.train"}
+        "decode_hbm_roofline_pct.serve", "hbm_peak_gb.serve", "hbm_peak_gb.train",
+        "packed_attention_roofline_pct.train"}
     assert set(line["metrics"]) <= declared
     # the same run's end-to-end line, as --trace 0 prints it
     devices = [type("D", (), {"platform": "cpu", "device_kind": "cpu"})()]
@@ -82,8 +90,17 @@ def test_the_serving_window_counts_what_ended_and_what_was_served_in_it(traced):
     assert all(r["token_times"] == sorted(r["token_times"]) for r in ended)
     served = sum(lo <= t < hi for r in run["requests"]
                  for t in r.get("token_times", ()))
+    # the judged rate is every token served in the window over its length
     assert run["end_to_end"]["serve_tokens_per_s"] == pytest.approx(
         served / run["window_s"])
+    # and the window holds whole steps: it opens where the lead-in's last
+    # step ended and closes where the step under way at --seconds ended
+    assert run["window_s"] == hi - lo
+    opens = run["origin"] + run["mix"]["lead_in_s"]
+    assert opens <= lo < opens + 0.5 and opens + 1.0 <= hi < opens + 1.5
+    assert [s for s in run["steps"] if lo <= s["t1"] < hi]
+    assert not any(s["t0"] < edge <= s["t1"] for s in run["steps"]
+                   for edge in (lo, hi))
     assert line["metrics"]["window_compiles.serve"]["value"] == 0.0
     assert run["end_to_end"]["setup_s"] > run["mix"]["lead_in_s"]
     assert len(run["sample"]) == run["mix"]["check_requests"]

@@ -65,3 +65,25 @@ def test_the_whole_trace_holds_three_equal_steps(trace):
     assert sum(per_op.values()) == pytest.approx(busy, rel=1e-6)
     assert busy == pytest.approx(sum(d for _, _, d in chip["modules"]), rel=0.01)
     assert tr.exposed_collective_seconds(chip["ops"], lo, hi) == 0.0
+
+
+def test_a_pallas_kernel_is_found_by_its_operations_text_whatever_its_name():
+    """``sample_kernel.xplane.pb``: the same program with a Pallas kernel
+    named ``probe_scale`` after the loop. Its record in the trace is the
+    instruction's text, ``custom-call(...), custom_call_target=
+    "tpu_custom_call"``; by the name alone (PR 25) it read as no kernel."""
+    trace = tr.load(os.path.join(os.path.dirname(SAMPLE),
+                                 "sample_kernel.xplane.pb"), "tpu")
+    ops = trace["devices"]["/device:TPU:0"]["ops"]
+    kernels = [n for n, _, _ in ops if tr.is_custom_call(n)]
+    assert len(kernels) == 3  # one a step
+    assert {tr.op_base(n) for n in kernels} == {"probe_scale"}
+    assert {tr.op_code(n) for n in kernels} == {"custom-call"}
+    assert all('custom_call_target="tpu_custom_call"' in n for n in kernels)
+    assert not any(tr.is_custom_call(tr.op_base(n)) for n in kernels)
+    codes = {tr.op_code(n) for n, _, _ in ops}
+    assert {"while", "fusion", "copy", "copy-done", "custom-call"} <= codes
+    got = tr.reduce(trace)
+    assert got["custom_call_s"] == pytest.approx(got["ops"]["probe_scale"])
+    assert got["custom_call_s"] == pytest.approx(6.0e-8, rel=0.01)
+    assert 100 * got["custom_call_s"] / got["busy_s"] == pytest.approx(1.12, abs=0.01)
