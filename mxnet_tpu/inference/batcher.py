@@ -84,6 +84,7 @@ a local ``b{id}`` trace. Tracing off costs each site one
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import time
 from collections import deque
@@ -811,8 +812,19 @@ class ContinuousBatcher:
                 elif len(req.output) >= req.max_new_tokens:
                     self._finish(slot, "length")
         else:
-            step_fn = self.engine.plain_step if speculative \
-                else self.engine.decode_step
+            if speculative:
+                step_fn = self.engine.plain_step
+            else:
+                # every slot holds a request with two or more tokens to go:
+                # none ends on this step and none can be admitted, so the
+                # rows stay as they are until the next step (a cancellation
+                # or a deadline aside) and the engine may dispatch it ahead
+                steady = all(r is not None
+                             and r.max_new_tokens - len(r.output) >= 2
+                             for r in self._slots)
+                step_fn = (functools.partial(self.engine.decode_step,
+                                             ahead=True)
+                           if steady else self.engine.decode_step)
 
             def _step():
                 with self._watchdog.guard("decode", self._step_id,

@@ -123,11 +123,28 @@ class GenerationEngine:
 
     Parameters
     ----------
-    net : GPT2Model (or any block whose ``hybrid_forward`` threads
-        ``cache=``/``start_pos=`` (and, for paged mode, ``page_table=``)
-        and that provides ``init_cache``/``init_paged_cache``).
-        Must be initialized; dropout should be 0 for exact equivalence
-        (evaluation mode disables it regardless).
+    net : an initialized model of the zoo (GPT-2, DeepSeek-V2) or any block
+        that provides what the engine asks of a model:
+
+          - ``hybrid_forward(F, tokens, cache=, start_pos=[, page_table=])``
+            returning ``(logits, new_cache)`` when ``cache`` is given, or
+            ``(logits, new_cache, counts)``: ``counts`` is {name: small int
+            array} of what the forward itself counted (an expert layer's
+            loads). A decode step's counts leave its program with the
+            tokens and land in the step's record;
+          - ``_max_length``; ``init_cache(batch, length, dtype)`` (dense) or
+            ``init_paged_cache(num_pages, page_size, dtype)`` (paged): the
+            model DECLARES its per-layer state, a list with one tuple of
+            arrays a layer. The engine reads nothing from their shapes but
+            that axis 0 of a paged array is the page (page 0 the trash
+            page): a ``(k_pool, v_pool)`` pair, one latent pool, anything;
+          - optionally ``paged_read_path(batch_size, pools, page_table)``
+            (what the decode program reads the cache by, for
+            ``engine.read_path``) and ``logits_width()`` (the vocabulary
+            the logits span).
+
+        Dropout should be 0 for exact equivalence (evaluation mode disables
+        it regardless).
     batch_size : rows of the static decode batch (= serving slots).
     max_length : per-row sequence capacity (default: the net's max_length).
     prefill_buckets : ascending prompt-length buckets; each bucket used
@@ -243,9 +260,11 @@ class GenerationEngine:
             #: device carry: per-row page tables (0 = unallocated/trash)
             self.page_table = jnp.zeros(
                 (self.batch_size, self._n_row_pages), jnp.int32)
-            #: device carry: per-layer (k_pool, v_pool) page pools
-            self.pools = net.init_paged_cache(self.num_pages, self.page_size,
-                                              dtype=cache_dtype)
+            #: device carry: the model's per-layer state, one tuple of page
+            #: pools a layer (axis 0 = pages; GPT-2: (k_pool, v_pool),
+            #: DeepSeek-V2: one latent pool)
+            self.pools = [tuple(layer) for layer in net.init_paged_cache(
+                self.num_pages, self.page_size, dtype=cache_dtype)]
             self.cache = None  # dense-only state
             # host allocator (authoritative; the device table mirrors it
             # through compiled update vectors shipped with each program)
@@ -275,26 +294,16 @@ class GenerationEngine:
             self.prefix_cache = (RadixPrefixCache(self.page_size)
                                  if prefix_cache else None)
             self._page_gauges()
-            # the kernel-or-gather choice is made per shape at trace time
-            # (ops.attention._paged_cached_mha); say here, once, what the
-            # decode program of this engine will be built with
-            from ..ops.pallas_common import on_tpu
-            from ..ops.pallas_paged_attention import paged_attention_refusal
-
-            k_pool = self.pools[0][0]
-            q = jax.ShapeDtypeStruct(
-                (self.batch_size, k_pool.shape[1], 1, k_pool.shape[3]),
-                self._plist[0]._nd._data.dtype)
-            why = paged_attention_refusal(q, k_pool, self.page_table)
-            #: read path of the paged decode program: the Pallas page-table
-            #: kernel, or the XLA ``pool[page_table]`` gather and why
-            if why is not None:
-                self.read_path = f"xla_gather ({why})"
-            elif on_tpu():
-                self.read_path = "pallas_paged_kernel"
-            else:
-                self.read_path = ("pallas_paged_kernel (interpreted: the "
-                                  "backend is not a TPU)")
+            _obs.gauge("gen_cache_bytes_per_token",
+                       "bytes the paged cache holds for one token, all "
+                       "layers").set(self.cache_bytes_per_token)
+            #: read path of the paged decode program, as the model says it
+            #: (the choice is made per shape at trace time, in the operator)
+            describe = getattr(net, "paged_read_path", None)
+            self.read_path = (
+                describe(self.batch_size, self.pools, self.page_table)
+                if describe is not None
+                else "unknown (the model names no read path)")
             logger.info("paged decode read path: %s", self.read_path)
         else:
             #: device state: per-layer (k_buf, v_buf), the donated carry
@@ -321,6 +330,12 @@ class GenerationEngine:
         self.last_round_drafted = 0
         self.last_round_accepted = 0
         self._plain_decode_jit = None  # lazy spec-engine fallback program
+        self._decode_calls = 0  # the decode step records' step id
+        #: a decode step dispatched ahead of its call, and the row state it
+        #: was built from (decode_step(ahead=True)); bumped by whatever
+        #: gives a row another occupant
+        self._ahead = None
+        self._row_epoch = 0
         #: RetryPolicy for the in-round gen.verify retry (None = config
         #: defaults); ContinuousBatcher installs its own policy here so
         #: one knob governs every serving retry
@@ -401,6 +416,16 @@ class GenerationEngine:
     @property
     def pages_in_use(self) -> int:
         return self.num_pages - len(self._free_pages) if self.paged else 0
+
+    @property
+    def cache_bytes_per_token(self) -> float:
+        """Bytes the paged cache holds for one token over all layers: the
+        pools' bytes over the pool's token capacity."""
+        if not self.paged:
+            return 0.0
+        total = sum(b.size * b.dtype.itemsize
+                    for layer in self.pools for b in layer)
+        return total / float((self.num_pages + 1) * self.page_size)
 
     def pages_for(self, length: int) -> int:
         """Pages a ``length``-token sequence occupies."""
@@ -701,15 +726,24 @@ class GenerationEngine:
         return tuple(p._nd._data for p in self._plist)
 
     def _last_vocab(self) -> int:
-        """Logits width of the target model (the tied word embedding's
-        input dim) — shape info for audit()'s stochastic-verify dummy."""
-        return int(self.net.word_embed._input_dim)
+        """Logits width of the target model — shape info for audit()'s
+        stochastic-verify dummy. The model says it; a model that does not
+        is taken to tie its head to ``word_embed``."""
+        width = getattr(self.net, "logits_width", None)
+        return int(width() if width is not None
+                   else self.net.word_embed._input_dim)
 
     def _draft_params(self):
         return tuple(p._nd._data for p in self._draft_plist)
 
     def _cache_nd(self, pools):
-        return [(NDArray(k), NDArray(v)) for k, v in pools]
+        return [tuple(NDArray(b) for b in layer) for layer in pools]
+
+    @staticmethod
+    def _cached(out):
+        """``(logits, new_cache, counts)`` of a model's cached forward;
+        ``counts`` is {} for a model that returns none."""
+        return out if len(out) == 3 else (*out, {})
 
     # -- pure programs (dense) -----------------------------------------------
     def _prefill_fn(self, params, cache, tokens, slot, length, key):
@@ -719,10 +753,10 @@ class GenerationEngine:
                            for b in layer) for layer in cache]
         start = jnp.zeros((1,), jnp.int32)
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_rows = self.net(
+            logits, new_rows, _ = self._cached(self.net(
                 NDArray(tokens),
                 cache=[(NDArray(k), NDArray(v)) for k, v in row_cache],
-                start_pos=NDArray(start))
+                start_pos=NDArray(start)))
         logits = logits._data  # (1, Lb, vocab)
         new_cache = [
             tuple(jax.lax.dynamic_update_slice_in_dim(full, row._data, slot,
@@ -738,10 +772,10 @@ class GenerationEngine:
         """One token for every row: (cache', next tokens, done', logits).
         Finished rows emit ``pad_id`` and keep their cache frontier."""
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_cache = self.net(
+            logits, new_cache, _ = self._cached(self.net(
                 NDArray(tokens.reshape(self.batch_size, 1)),
                 cache=[(NDArray(k), NDArray(v)) for k, v in cache],
-                start_pos=NDArray(positions))
+                start_pos=NDArray(positions)))
         logits = logits._data[:, 0]  # (B, vocab)
         sampled = self._sample(logits, key)
         next_tok = jnp.where(done, jnp.int32(self.pad_id), sampled)
@@ -775,9 +809,9 @@ class GenerationEngine:
         row_table = jax.lax.dynamic_slice(table, (slot, 0),
                                           (1, self._n_row_pages))
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools = self.net(
+            logits, new_pools, _ = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
-                start_pos=NDArray(start), page_table=NDArray(row_table))
+                start_pos=NDArray(start), page_table=NDArray(row_table)))
         logits = logits._data  # (1, Lb, vocab)
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
         last = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=1,
@@ -795,13 +829,13 @@ class GenerationEngine:
         row_table = jax.lax.dynamic_slice(table, (slot, 0),
                                           (1, self._n_row_pages))
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools = self.net(
+            logits, new_pools, _ = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
-                start_pos=NDArray(start), page_table=NDArray(row_table))
+                start_pos=NDArray(start), page_table=NDArray(row_table)))
         with _HybridTrace(self._draft_plist, list(dparams), False, key):
-            _, new_dpools = self.draft_net(
+            _, new_dpools, _ = self._cached(self.draft_net(
                 NDArray(tokens), cache=self._cache_nd(dpools),
-                start_pos=NDArray(start), page_table=NDArray(row_table))
+                start_pos=NDArray(start), page_table=NDArray(row_table)))
         logits = logits._data
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
         new_dpools = [tuple(b._data for b in layer) for layer in new_dpools]
@@ -813,21 +847,24 @@ class GenerationEngine:
     def _paged_decode_fn(self, params, carry, tokens, positions, done,
                          upd_slots, upd_pages, clear, key):
         """The paged decode step: apply page-table updates, then exactly the
-        dense decode semantics with pool-indirect storage."""
+        dense decode semantics with pool-indirect storage. The model's counts
+        of the step, if it returns any, leave with the tokens."""
         table, pools = carry
         table = self._apply_table_updates(table, upd_slots, upd_pages, clear)
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools = self.net(
+            logits, new_pools, stats = self._cached(self.net(
                 NDArray(tokens.reshape(self.batch_size, 1)),
                 cache=self._cache_nd(pools), start_pos=NDArray(positions),
-                page_table=NDArray(table))
+                page_table=NDArray(table)))
         logits = logits._data[:, 0]
         sampled = self._sample(logits, key)
         next_tok = jnp.where(done, jnp.int32(self.pad_id), sampled)
         if self.eos_id is not None:
             done = done | (sampled == self.eos_id)
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
-        return (table, new_pools), next_tok.astype(jnp.int32), done, logits
+        # stats: {} for a model that keeps none, so its program is unchanged
+        return ((table, new_pools), next_tok.astype(jnp.int32), done, logits,
+                stats)
 
     def _draft_fn(self, dparams, carry, tokens, positions, done,
                   upd_slots, upd_pages, clear, key):
@@ -845,11 +882,11 @@ class GenerationEngine:
         def step(c, i):
             pools_c, tok = c
             with _HybridTrace(self._draft_plist, list(dparams), False, key):
-                logits, new_pools = self.draft_net(
+                logits, new_pools, _ = self._cached(self.draft_net(
                     NDArray(tok.reshape(self.batch_size, 1)),
                     cache=self._cache_nd(pools_c),
                     start_pos=NDArray(positions + i),
-                    page_table=NDArray(table))
+                    page_table=NDArray(table)))
             new_pools = [tuple(b._data for b in layer)
                          for layer in new_pools]
             nxt = jnp.argmax(logits._data[:, 0], axis=-1).astype(jnp.int32)
@@ -873,9 +910,9 @@ class GenerationEngine:
         k = self.speculate_k
         x = jnp.concatenate([tokens[:, None], drafted], axis=1)  # (B, k+1)
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools = self.net(
+            logits, new_pools, _ = self._cached(self.net(
                 NDArray(x), cache=self._cache_nd(pools),
-                start_pos=NDArray(positions), page_table=NDArray(table))
+                start_pos=NDArray(positions), page_table=NDArray(table)))
         logits = logits._data  # (B, k+1, vocab)
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
         g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # greedy next
@@ -935,11 +972,11 @@ class GenerationEngine:
         def step(c, i):
             pools_c, tok = c
             with _HybridTrace(self._draft_plist, list(dparams), False, key):
-                logits, new_pools = self.draft_net(
+                logits, new_pools, _ = self._cached(self.draft_net(
                     NDArray(tok.reshape(self.batch_size, 1)),
                     cache=self._cache_nd(pools_c),
                     start_pos=NDArray(positions + i),
-                    page_table=NDArray(table))
+                    page_table=NDArray(table)))
             new_pools = [tuple(b._data for b in layer)
                          for layer in new_pools]
             lg = self._sample_logits(logits._data[:, 0])  # (B, V)
@@ -970,9 +1007,9 @@ class GenerationEngine:
         B = self.batch_size
         x = jnp.concatenate([tokens[:, None], drafted], axis=1)  # (B, k+1)
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools = self.net(
+            logits, new_pools, _ = self._cached(self.net(
                 NDArray(x), cache=self._cache_nd(pools),
-                start_pos=NDArray(positions), page_table=NDArray(table))
+                start_pos=NDArray(positions), page_table=NDArray(table)))
         logits = logits._data  # (B, k+1, vocab)
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
         p = jax.nn.softmax(self._sample_logits(logits), axis=-1)
@@ -1149,6 +1186,7 @@ class GenerationEngine:
                 self._next_key())
             self.cache = cache
         tok = int(tok)  # host sync: the first token is ready here
+        self._row_epoch += 1
         self.positions[slot] = length
         self.last_tokens[slot] = tok
         self.done[slot] = (self.eos_id is not None and tok == self.eos_id)
@@ -1169,15 +1207,24 @@ class GenerationEngine:
         self._last_logits = last
         return tok
 
-    def decode_step(self):
+    def decode_step(self, ahead: bool = False):
         """One compiled step over the whole batch. Returns
         ``(next_tokens (B,) np.int32, done (B,) np.bool_, logits (B, V)
-        device array)``. Rows that were already done emit ``pad_id``."""
+        device array)``. Rows that were already done emit ``pad_id``.
+
+        ``ahead=True`` says that the caller expects to call ``decode_step``
+        again before any row changes hands (no prefill, release or fork in
+        between). The engine may then dispatch that next step at once,
+        behind this one and before it reads this one's tokens, so the
+        device does not stand idle while the host turns round
+        (docs/INFERENCE.md "Decoding ahead"). Should a row change hands
+        after all, the step dispatched ahead is dropped and run again:
+        the same tokens, at the cost of one step's device time."""
         if self.speculative:
             raise RuntimeError("speculative engine decodes in rounds; "
                                "use spec_step() (or plain_step() for the "
                                "degrade-to-plain fallback)")
-        return self._plain_decode_step()
+        return self._plain_decode_step(ahead)
 
     def plain_step(self):
         """One plain (non-speculative) decode step on ANY engine — the
@@ -1189,9 +1236,96 @@ class GenerationEngine:
         a re-arm — an accept-rate cost only, never a correctness one."""
         return self._plain_decode_step()
 
-    def _plain_decode_step(self):
+    def _plain_decode_step(self, ahead=False):
         _faults.fire("gen.decode")
         t0 = time.perf_counter()
+        self._decode_calls += 1
+        # the always-on record of this call (obs.step_records("decode_step")):
+        # host clock marks, and the model's own counts of the step, which
+        # come back with the tokens in the one blocking read below
+        with _obs.step_record("decode_step", self._decode_calls,
+                              name="mx.gen.decode") as rec:
+            tok, done, logits, active_in, stats = (
+                self._take_ahead() or self._dispatch_decode())
+            # rows active going into the step consumed one cache index
+            positions = self.positions + active_in.astype(np.int32)
+            # a row whose frontier hit the buffer end cannot take another
+            # token
+            full = active_in & (positions >= self.max_length)
+            if ahead and self._may_decode_ahead():
+                # without an EOS id the rows' state after this step is known
+                # before its tokens are: the next step takes them from the
+                # device and is queued behind this one
+                self.positions, self.done = positions, self.done | full
+                self._ahead = [self._dispatch_decode(tok), None]
+            with _obs.span("mx.gen.decode.read"):
+                # np.array (copy): zero-copy views of jax buffers are
+                # read-only, and this host state is mutated by
+                # release_slot/prefill
+                tok, done, stats = jax.device_get((tok, done, stats))
+                tok, done = np.array(tok), np.array(done)
+                if stats:
+                    rec.counts = {k: v.tolist() for k, v in stats.items()}
+        self.positions = positions
+        if full.any():
+            done = done | full
+            _obs.counter("gen_cache_overflow_total",
+                         "rows force-finished at the KV-cache end").inc(
+                             int(full.sum()))
+        self.done = done
+        self.last_tokens = tok
+        if self._ahead is not None:  # the rows as the step ahead left them
+            self._ahead[1] = (self._row_epoch, positions.copy(), done.copy(),
+                              tok.copy())
+        if _obs.enabled():
+            dt = time.perf_counter() - t0
+            _obs.histogram("gen_decode_step_seconds",
+                           "one compiled decode step wall clock",
+                           unit="s").observe(dt)
+            # slot utilization of this step: fraction of the static batch
+            # that decoded real tokens (the fleet report's serving rollup)
+            _obs.gauge("gen_slot_utilization",
+                       "fraction of decode slots active this step").set(
+                           float(active_in.sum()) / self.batch_size)
+        return tok, done, logits
+
+    def _may_decode_ahead(self):
+        """What the next step needs is known before this step's tokens are:
+        a plain paged greedy engine with no EOS id (``done`` then follows
+        from lengths alone, and a dropped step costs no random key), and a
+        free page for every row (growing the rows' tables cannot evict)."""
+        return (self.paged and not self.speculative and self.eos_id is None
+                and not self.sampling.stochastic
+                and len(self._free_pages) >= self.batch_size)
+
+    def _take_ahead(self):
+        """The step dispatched ahead, if the rows are as it left them;
+        else None, and that step is dropped (its writes lie at positions
+        the rows' next step writes again, or past a released row's end)."""
+        pending, self._ahead = self._ahead, None
+        if pending is None:
+            return None
+        out, rows = pending
+        same = (rows is not None and rows[0] == self._row_epoch
+                and np.array_equal(rows[1], self.positions)
+                and np.array_equal(rows[2], self.done)
+                and np.array_equal(rows[3], self.last_tokens))
+        _obs.counter("gen_decode_ahead_total",
+                     "decode steps dispatched ahead of their call").inc(
+                         outcome="used" if same else "dropped")
+        return out if same else None
+
+    def _dispatch_decode(self, tokens=None):
+        """Dispatch the single-token decode program and commit its carry;
+        returns device ``(tokens, done, logits)``, the rows that were
+        active going in, and the model's step counts (``{}`` if none).
+        ``tokens``: the rows' last tokens still on the device, for a step
+        dispatched ahead (host span ``mx.gen.decode.ahead``); default the
+        host's."""
+        stats = {}
+        span = "mx.gen.decode." + ("dispatch" if tokens is None else "ahead")
+        if tokens is None:
+            tokens = self.last_tokens
         if self.paged:
             upd_slots, upd_pages = self._grow_pages(0)
             clear = self._take_clear_mask()
@@ -1208,46 +1342,24 @@ class GenerationEngine:
             else:
                 decode_jit = self._decode_jit
             self._note_program(("decode", self.batch_size, "paged"), "decode")
-            carry, tok, done, logits = decode_jit(
-                self._params(), (self.page_table, self.pools),
-                jnp.asarray(self.last_tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.done), jnp.asarray(upd_slots),
-                jnp.asarray(upd_pages), jnp.asarray(clear), self._next_key())
+            with _obs.span(span):
+                carry, tok, done, logits, stats = decode_jit(
+                    self._params(), (self.page_table, self.pools),
+                    jnp.asarray(tokens), jnp.asarray(self.positions),
+                    jnp.asarray(self.done), jnp.asarray(upd_slots),
+                    jnp.asarray(upd_pages), jnp.asarray(clear),
+                    self._next_key())
             self.page_table, self.pools = carry
         else:
             active_in = ~self.done
             self._note_program(("decode", self.batch_size), "decode")
-            cache, tok, done, logits = self._decode_jit(
-                self._params(), self.cache, jnp.asarray(self.last_tokens),
-                jnp.asarray(self.positions), jnp.asarray(self.done),
-                self._next_key())
+            with _obs.span(span):
+                cache, tok, done, logits = self._decode_jit(
+                    self._params(), self.cache, jnp.asarray(tokens),
+                    jnp.asarray(self.positions), jnp.asarray(self.done),
+                    self._next_key())
             self.cache = cache
-        # np.array (copy): zero-copy views of jax buffers are read-only,
-        # and this host state is mutated by release_slot/prefill
-        tok = np.array(tok)
-        done = np.array(done)
-        # rows active going into the step consumed one cache index
-        self.positions = self.positions + active_in.astype(np.int32)
-        # a row whose frontier hit the buffer end cannot take another token
-        full = active_in & (self.positions >= self.max_length)
-        if full.any():
-            done = done | full
-            _obs.counter("gen_cache_overflow_total",
-                         "rows force-finished at the KV-cache end").inc(
-                             int(full.sum()))
-        self.done = done
-        self.last_tokens = tok
-        if _obs.enabled():
-            dt = time.perf_counter() - t0
-            _obs.histogram("gen_decode_step_seconds",
-                           "one compiled decode step wall clock",
-                           unit="s").observe(dt)
-            # slot utilization of this step: fraction of the static batch
-            # that decoded real tokens (the fleet report's serving rollup)
-            _obs.gauge("gen_slot_utilization",
-                       "fraction of decode slots active this step").set(
-                           float(active_in.sum()) / self.batch_size)
-        return tok, done, logits
+        return tok, done, logits, active_in, stats
 
     def spec_step(self):
         """One speculative round: ONE draft dispatch (k tokens through the
@@ -1384,6 +1496,34 @@ class GenerationEngine:
         return self._decode_jit.lower(
             self._params(), (self.page_table, self.pools), toks, pos, done,
             upd, upd, clear, key)
+
+    def lower_prefill(self, bucket: int):
+        """Lower (don't run) the prefill program of ``bucket`` (a plain
+        paged engine's), as :meth:`lower_decode` does the decode step."""
+        if not self.paged or self.speculative:
+            raise RuntimeError("lower_prefill is for a plain paged engine; "
+                               "see audit(bucket=)")
+        bucket = self.bucket_for(bucket)
+        return self._prefill_jit.lower(
+            self._params(), (self.page_table, self.pools),
+            jnp.full((1, bucket), self.pad_id, jnp.int32),
+            jnp.asarray(0, jnp.int32), jnp.asarray(bucket, jnp.int32),
+            jnp.zeros((self._n_row_pages,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jax.random.key(0))
+
+    def op_scopes(self, bucket: Optional[int] = None):
+        """{HLO instruction name: scope path} of the decode program (or of
+        the prefill program of ``bucket``): the join key for a device
+        trace (``MeasuredReport.scope_seconds``; docs/OBSERVABILITY.md
+        "Named scopes"). Paths start at the model's own block scope
+        (``<model>/layer3/mla/core``). Compiles the lowered program once
+        more (``scopes.scoped_text`` says why)."""
+        from ..observability.scopes import op_scopes_from_hlo, scoped_text
+
+        lowered = (self.lower_decode() if bucket is None
+                   else self.lower_prefill(bucket))
+        return op_scopes_from_hlo(scoped_text(lowered),
+                                  scopes=(self.net._scope_label(None),))
 
     def audit(self, bucket: Optional[int] = None, compile: bool = True,
               program: str = "decode"):
@@ -1593,6 +1733,7 @@ class GenerationEngine:
             raise ValueError(f"bad fork {src} -> {dst}")
         if self.done[src] or not self._row_pages[src]:
             raise RuntimeError(f"cannot fork finished/empty row {src}")
+        self._row_epoch += 1
         self._reclaim_row(dst)  # previous occupant's pages, if any
         self._pending_clear.discard(dst)
         self.page_exhausted[dst] = False
@@ -1647,6 +1788,7 @@ class GenerationEngine:
         (pages still backing a fork or the prefix cache stay allocated);
         the row's device page-table row is cleared before the next
         compiled step writes anything."""
+        self._row_epoch += 1
         self.done[slot] = True
         self.last_tokens[slot] = self.pad_id
         if self.paged:

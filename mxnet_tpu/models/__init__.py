@@ -7,13 +7,17 @@
   #5 GPT-2 345M         -> models.gpt2
 
 Plus detection: models.ssd (example/ssd + GluonCV SSD shape, exercising the
-full contrib MultiBox family).
+full contrib MultiBox family), and models.deepseek_v2 (latent attention,
+group-limited routed experts of which one chip holds a share; served through
+inference.GenerationEngine from a paged latent cache).
 """
+from . import deepseek_v2  # noqa: F401
 from . import bert  # noqa: F401
 from . import gpt2  # noqa: F401
 from . import ssd  # noqa: F401
 from . import transformer  # noqa: F401
 from .bert import BERTModel, BERTForPretrain, get_bert  # noqa: F401
+from .deepseek_v2 import DeepseekV2Model, get_deepseek_v2  # noqa: F401
 from .gpt2 import GPT2Model, get_gpt2  # noqa: F401
 from .ssd import SSD, get_ssd  # noqa: F401
 from .transformer import Transformer, get_transformer  # noqa: F401

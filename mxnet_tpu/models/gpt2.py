@@ -121,6 +121,31 @@ class GPT2Model(HybridBlock):
                                     self._units // self._num_heads,
                                     self._num_layers, dtype=dtype)
 
+    def paged_read_path(self, batch_size, pools, page_table):
+        """What a paged engine's decode program will read this model's
+        ``(k_pool, v_pool)`` pools by: the Pallas page-table kernel, or the
+        XLA ``pool[page_table]`` gather and why (the operator makes the
+        same choice from the same shapes at trace time)."""
+        import jax
+
+        from ..ops.pallas_common import on_tpu
+        from ..ops.pallas_paged_attention import paged_attention_refusal
+
+        k_pool = pools[0][0]  # (P+1, H, page, Ch)
+        q = jax.ShapeDtypeStruct(
+            (batch_size, k_pool.shape[1], 1, k_pool.shape[3]),
+            self.word_embed.weight.data()._data.dtype)
+        why = paged_attention_refusal(q, k_pool, page_table)
+        if why is not None:
+            return f"xla_gather ({why})"
+        if on_tpu():
+            return "pallas_paged_kernel"
+        return "pallas_paged_kernel (interpreted: the backend is not a TPU)"
+
+    def logits_width(self):
+        """The vocabulary the logits span (the head is the word embedding)."""
+        return self.word_embed._input_dim
+
     def hybrid_forward(self, F, token_ids, cache=None, start_pos=None,
                        page_table=None):
         b, t = token_ids.shape

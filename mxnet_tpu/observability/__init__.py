@@ -210,11 +210,14 @@ class StepRecord(NamedTuple):
     are contiguous by construction (one's end is the next one's start), so
     their durations sum to the call's."""
 
-    loop: str                            # "train_step" / "run_window"
+    loop: str                            # "train_step" / "run_window" / "decode_step"
     step: int                            # optimizer.num_update once it ran
     t0_ns: int
     marks: Tuple[Tuple[str, int], ...]   # (span name, end ns)
     compiled: bool                       # this call lowered or compiled
+    #: what the program itself counted in this call and handed back with
+    #: its result ({name: value}; a decode step's expert-layer loads)
+    counts: Optional[dict] = None
 
     @property
     def duration_ns(self) -> int:
@@ -263,12 +266,12 @@ class step_record:
     else: no device value is touched. ``rec.compiled = True`` marks a call
     that lowered or compiled a program."""
 
-    __slots__ = ("loop", "step", "name", "t0", "marks", "compiled", "_ann",
-                 "_outer")
+    __slots__ = ("loop", "step", "name", "t0", "marks", "compiled", "counts",
+                 "_ann", "_outer")
 
     def __init__(self, loop: str, step: int, name: str = "mx.train.step"):
         self.loop, self.step, self.name = loop, int(step), name
-        self.marks, self.compiled = [], False
+        self.marks, self.compiled, self.counts = [], False, None
 
     def __enter__(self):
         self._ann = _trace_annotation()(self.name, step=self.step)
@@ -282,7 +285,8 @@ class step_record:
         _open.rec = self._outer
         self._ann.__exit__(*exc)
         _records.append(StepRecord(self.loop, self.step, self.t0,
-                                   tuple(self.marks), self.compiled))
+                                   tuple(self.marks), self.compiled,
+                                   self.counts))
         return False
 
 

@@ -17,7 +17,7 @@ import re
 from typing import Dict, Iterable, Optional
 
 __all__ = ["SCOPES", "BACKWARD", "MIXED", "UNSCOPED", "ScopeTable", "scope_path",
-           "op_scopes_from_hlo", "instruction_name", "at_depth"]
+           "op_scopes_from_hlo", "scoped_text", "instruction_name", "at_depth"]
 
 #: the scopes ``parallel.TrainStep`` opens in its traced step
 SCOPES = ("forward", "loss", "optimizer", "amp", "grad_norm", "accumulate")
@@ -113,6 +113,25 @@ class ScopeTable(dict):
     def __init__(self):
         super().__init__()
         self.shared: Dict[str, Dict[str, int]] = {}
+
+
+def scoped_text(lowered) -> str:
+    """The compiled text of ``lowered`` with every scope in it. jax leaves
+    metadata out of its compile cache's key, so the running executable may
+    have been cached before the scopes existed, and its text then names none
+    of them (its instruction names are the same). This compiles once more:
+    an explicit compiler option, set to its default, keeps the compile from
+    being handed that executable, and the metadata is made part of the key."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return lowered.compile(compiler_options={
+            "xla_embed_ir_in_executable": False}).as_text()
+    finally:
+        jax.config.update(flag, was)
 
 
 def op_scopes_from_hlo(text: str, scopes: Iterable[str] = SCOPES

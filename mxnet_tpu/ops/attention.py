@@ -240,6 +240,208 @@ def _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
 
 
 # --------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+# --------------------------------------------------------------------------
+def yarn_inv_freq(dim, base=10000.0, factor=1.0, original_max_position=4096,
+                  beta_fast=32.0, beta_slow=1.0):
+    """Rotary inverse frequencies under YaRN (Peng et al. 2023), as a tuple
+    of ``dim // 2`` floats: each frequency is a blend of the plain one and
+    the one interpolated by ``factor``, by a linear ramp between the two
+    correction dimensions (the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context). ``factor`` 1 gives the
+    plain frequencies."""
+    import math
+
+    plain = [base ** (-2.0 * i / dim) for i in range(dim // 2)]
+    if factor <= 1:
+        return tuple(plain)
+
+    def correction_dim(turns):
+        return (dim * math.log(original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    high = high + 0.001 if low == high else high
+    ramp = [min(max((i - low) / (high - low), 0.0), 1.0)
+            for i in range(dim // 2)]
+    return tuple(f / factor * r + f * (1.0 - r) for f, r in zip(plain, ramp))
+
+
+@register("rotary_embedding")
+def rotary_embedding(x, position=None, inv_freq=(), factor=1.0):
+    """Rotate the last axis of ``x`` (B, T, ..., dim), laid out as two
+    halves (``rotate_half``), by the angles ``pos * inv_freq``; a row's
+    positions are ``position[b] + arange(T)`` (``position`` None: from 0).
+    Angles, cos and sin are float32; ``factor`` scales both."""
+    pos = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+    if position is not None:
+        pos = pos + jnp.asarray(_unwrap(position), jnp.int32).reshape(-1, 1)
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq, jnp.float32)
+    ang = jnp.concatenate([ang, ang], axis=-1)             # (B|1, T, dim)
+    shape = ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:]
+    cos, sin = jnp.cos(ang).reshape(shape) * factor, jnp.sin(ang).reshape(shape) * factor
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return (x.astype(jnp.float32) * cos
+            + turned.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+def alloc_paged_latent_cache(num_pages, page_size, width, num_layers,
+                             dtype="float32"):
+    """Per-layer ``(pool,)`` of shape (num_pages + 1, page_size, width): the
+    paged cache of latent attention, one ``width``-wide vector a token a
+    layer (the normalised latent and the rotated shared key side by side),
+    no head axis. Page 0 is the trash page, as in
+    :func:`alloc_paged_kv_cache`."""
+    from ..base import dtype_np
+
+    shape = (int(num_pages) + 1, int(page_size), int(width))
+    return [(jnp.zeros(shape, dtype_np(dtype)),) for _ in range(int(num_layers))]
+
+
+def mla_form(tq, nope, rope, vd, kl):
+    """The cheaper of latent attention's two forms for ``tq`` queries a row,
+    by the operations each needs per cached position: absorbed scores and
+    outputs are ``kl``-wide per head and query, decompressed ones pay the
+    up-projection of every position once and are then head-sized. One token
+    a row (decode) is absorbed; a long prefill chunk is decompressed."""
+    absorbed = tq * (2 * kl + rope)
+    decompressed = kl * (nope + vd) + tq * (nope + rope + vd)
+    return "absorbed" if absorbed <= decompressed else "decompressed"
+
+
+def _mla_softmax(scores, mask, scale, dtype):
+    scores = jnp.where(mask[:, None], scores * scale, -jnp.inf)
+    return jax.nn.softmax(scores, axis=-1).astype(dtype)
+
+
+def _mla_absorbed(q_nope, q_rope, c_hist, r_hist, w_kvb, mask, scale):
+    """Scores and outputs in the latent space: ``W_UK`` is folded into the
+    queries and ``W_UV`` applied after the weighted sum, so nothing
+    head-sized exists per cached position."""
+    nope = q_nope.shape[-1]
+    w_uk, w_uv = w_kvb[:, :nope], w_kvb[:, nope:]          # (H, d, kl)
+    f32 = dict(preferred_element_type=jnp.float32)
+    q_lat = jnp.einsum("bthd,hdl->bthl", q_nope, w_uk, **f32).astype(q_nope.dtype)
+    scores = (jnp.einsum("bthl,bkl->bhtk", q_lat, c_hist, **f32)
+              + jnp.einsum("bthr,bkr->bhtk", q_rope, r_hist, **f32))
+    att = _mla_softmax(scores, mask, scale, q_nope.dtype)
+    o_lat = jnp.einsum("bhtk,bkl->bthl", att, c_hist, **f32).astype(q_nope.dtype)
+    return jnp.einsum("bthl,hvl->bthv", o_lat, w_uv, **f32).astype(q_nope.dtype)
+
+
+def _mla_decompressed(q_nope, q_rope, c_hist, r_hist, w_kvb, mask, scale):
+    """Keys and values up-projected from the latent, heads in blocks of 16
+    so that a long chunk's scores fit."""
+    b, t, heads, nope = q_nope.shape
+    block = 16 if heads % 16 == 0 else heads
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def heads_of(args):
+        qn, qr, w = args          # (B,T,hb,nope), (B,T,hb,rope), (hb,nope+vd,kl)
+        kv = jnp.einsum("bkl,hdl->bkhd", c_hist, w, **f32).astype(qn.dtype)
+        scores = (jnp.einsum("bthd,bkhd->bhtk", qn, kv[..., :nope], **f32)
+                  + jnp.einsum("bthr,bkr->bhtk", qr, r_hist, **f32))
+        att = _mla_softmax(scores, mask, scale, qn.dtype)
+        return jnp.einsum("bhtk,bkhv->bthv", att, kv[..., nope:],
+                          **f32).astype(qn.dtype)
+
+    def blocks(a):                # (B,T,H,d) -> (H/hb, B,T,hb,d)
+        return jnp.moveaxis(a.reshape(b, t, heads // block, block, -1), 2, 0)
+
+    out = jax.lax.map(heads_of, (blocks(q_nope), blocks(q_rope),
+                                 w_kvb.reshape(heads // block, block,
+                                               *w_kvb.shape[1:])))
+    return jnp.moveaxis(out, 0, 2).reshape(b, t, heads, -1)
+
+
+@register("latent_attention")
+def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
+                     cache=None, position=None, page_table=None):
+    """Multi-head latent attention over ``(B, T, ...)`` activations.
+
+    ``q_nope`` (B, T, H, nope) and ``q_rope`` (B, T, H, rope, rotated) are
+    the queries' two parts; ``c_kv`` (B, T, kl) is the NORMALISED latent and
+    ``k_rope`` (B, T, rope) the rotated key all heads share; ``w_kvb``
+    (H * (nope + vd), kl) up-projects a latent into each head's key and
+    value. Scores are ``(q_nope . k_nope + q_rope . k_rope) * scale``,
+    causal; the softmax is float32 whatever the inputs (the policy of
+    :func:`multi_head_attention`); returns the context (B, T, H * vd).
+
+    The same mathematics in two forms, chosen by :func:`mla_form` from the
+    shapes: ``absorbed`` folds the up-projection into the queries and the
+    output; ``decompressed`` up-projects keys and values. The ``mla_path_total{form, read}`` counter says at trace time
+    which was built.
+
+    ``cache=(pool,), position=, page_table=`` is the paged path
+    (docs/INFERENCE.md "A model's per-layer state"): the new tokens'
+    ``[c_kv ; k_rope]`` are scattered into the pool at their rows' pages,
+    each row's history is gathered back by its page table (a chunk of
+    more than one token whose rows all start at position 0 reads the chunk
+    itself: a ``lax.cond`` on the positions), and the call returns
+    ``(context, pool')``. Nothing decompressed is ever cached.
+    """
+    from .. import observability as obs
+
+    b, t, heads, nope = q_nope.shape
+    kl, rope = c_kv.shape[-1], k_rope.shape[-1]
+    w_kvb = w_kvb.reshape(heads, -1, kl)
+    vd = w_kvb.shape[1] - nope
+    form = mla_form(t, nope, rope, vd, kl)
+    core = {"absorbed": _mla_absorbed, "decompressed": _mla_decompressed}[form]
+    q_idx = jnp.arange(t, dtype=jnp.int32)
+    if cache is None:
+        obs.counter("mla_path_total").inc(form=form, read="none")
+        mask = jnp.broadcast_to(q_idx[None, :] <= q_idx[:, None], (b, t, t))
+        with jax.named_scope("core"):
+            out = core(q_nope, q_rope, c_kv, k_rope, w_kvb, mask, scale)
+        return out.reshape(b, t, heads * vd)
+    if position is None or page_table is None:
+        raise ValueError("latent_attention(cache=...) is paged: it needs "
+                         "position= and page_table=")
+    obs.counter("mla_path_total").inc(form=form, read="xla_gather")
+    (pool,) = (_unwrap(c) for c in cache)
+    position = jnp.asarray(_unwrap(position), jnp.int32)
+    table = jnp.asarray(_unwrap(page_table), jnp.int32)
+    ps, n_pages = pool.shape[1], table.shape[1]
+    cap = n_pages * ps
+    with jax.named_scope("kv"):
+        pos = position[:, None] + q_idx[None, :]                   # (B, T)
+        pid = jnp.take_along_axis(table, jnp.clip(pos // ps, 0, n_pages - 1),
+                                  axis=1)
+        pid = jnp.where(pos < cap, pid, 0)                 # overflow -> trash
+        new = jnp.concatenate([c_kv, k_rope], axis=-1).astype(pool.dtype)
+        pool = pool.at[pid.reshape(-1), (pos % ps).reshape(-1)].set(
+            new.reshape(b * t, kl + rope))
+
+    def from_pool():
+        # every row's history by its page table: the table's whole width
+        with jax.named_scope("kv"):
+            hist = pool[table].reshape(b, cap, kl + rope).astype(c_kv.dtype)
+        mask = jnp.arange(cap, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
+        with jax.named_scope("core"):
+            return core(q_nope, q_rope, hist[..., :kl], hist[..., kl:], w_kvb,
+                        mask, scale)
+
+    def from_chunk():
+        # rows that start at 0 have no history but the chunk itself, as the
+        # pool now holds it (rounded to the pool's dtype)
+        held = new.astype(c_kv.dtype)
+        mask = jnp.broadcast_to(q_idx[None, :] <= q_idx[:, None], (b, t, t))
+        with jax.named_scope("core"):
+            return core(q_nope, q_rope, held[..., :kl], held[..., kl:], w_kvb,
+                        mask, scale)
+
+    # a prefill chunk that opens its rows (no adopted prefix) reads t keys,
+    # not the page table's width; the program holds both and the positions
+    # it is handed choose. One token a row always has a history.
+    out = from_pool() if t == 1 else jax.lax.cond(
+        jnp.all(position == 0), from_chunk, from_pool)
+    return out.reshape(b, t, heads * vd), pool
+
+
+# --------------------------------------------------------------------------
 # blessed fused attention entry point
 # --------------------------------------------------------------------------
 def _reference_mha(q, k, v, mask=None, causal=False):

@@ -31,7 +31,8 @@ def shard_map(f, mesh, in_specs, out_specs):
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                          check_vma=False)
 
-__all__ = ["moe_ffn", "init_moe_params", "moe_param_specs"]
+__all__ = ["moe_ffn", "init_moe_params", "moe_param_specs",
+           "group_limited_topk", "held_expert_ffn"]
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, num_experts: int,
@@ -113,3 +114,79 @@ def moe_ffn(x, params, mesh: Mesh, axis: str = "ep",
         out_specs=(P(axis), P()),
     )(x, params["gate"], params["w1"], params["w2"])
     return out, aux
+
+
+# --------------------------------------------------------------------------
+# group-limited top-k routing over held experts (no capacity, no drops)
+# --------------------------------------------------------------------------
+def group_limited_topk(probs, n_group: int, topk_group: int, top_k: int):
+    """``group_limited_greedy`` routing: ``probs`` [n, E] in ``n_group``
+    equal groups; a group's score is its largest probability; the best
+    ``topk_group`` groups stay open; the ``top_k`` largest probabilities
+    among them are the token's experts. Returns (weights [n, k] — the
+    probabilities unchanged —, expert ids [n, k])."""
+    n, e = probs.shape
+    group_best = probs.reshape(n, n_group, e // n_group).max(axis=-1)
+    _, keep = lax.top_k(group_best, topk_group)
+    is_open = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], keep].set(True)
+    masked = jnp.where(jnp.repeat(is_open, e // n_group, axis=1), probs, 0.0)
+    return lax.top_k(masked, top_k)
+
+
+def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
+                    n_group: int, topk_group: int, top_k: int,
+                    scale: float = 1.0, norm_topk_prob: bool = False):
+    """What the experts held here add to an expert layer's output.
+
+    ``h`` [n, d] are the (normed) tokens; ``router_w`` [E, d] routes over
+    ALL ``E`` experts (softmax in float32, :func:`group_limited_topk`);
+    ``held_experts`` are the ids of the experts whose SwiGLU weights this
+    chip holds, stacked in that order: ``w_gate``/``w_up`` [held, d, w],
+    ``w_down`` [held, w, d]. Every (token, expert) pair whose expert is
+    held is computed, whatever the load (no capacity, no dropped token):
+    the pairs are sorted by expert, the held ones first, and each
+    projection is one grouped product (``lax.ragged_dot``) whose rows past
+    the held pairs belong to no group. Pairs routed to absent experts add
+    nothing here: their chips add them.
+
+    Returns ``(y [n, d], stats)``; ``stats`` are two int32 scalars, the
+    pairs routed to held experts and the largest load of one. The
+    ``moe_path_total{path}`` counter says at trace time what was built.
+    """
+    from .. import observability as obs
+
+    n, d = h.shape
+    held = tuple(int(e) for e in held_experts)
+    n_held, n_experts = len(held), router_w.shape[0]
+    obs.counter("moe_path_total").inc(path="sorted_ragged_dot")  # trace time
+    with jax.named_scope("router"):
+        logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        weights, ids = group_limited_topk(jax.nn.softmax(logits, axis=-1),
+                                          n_group, topk_group, top_k)
+        if norm_topk_prob:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights * scale
+        # slot of each pair's expert among the held ones; n_held = absent
+        slot_of = jnp.full((n_experts,), n_held, jnp.int32).at[
+            jnp.asarray(held, jnp.int32)].set(jnp.arange(n_held, dtype=jnp.int32))
+        slot = slot_of[ids].reshape(-1)                        # [n * k]
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
+        token = order // top_k
+        is_held = slot[order] < n_held
+        pair_w = weights.reshape(-1)[order]
+    with jax.named_scope("experts"):
+        f32 = dict(preferred_element_type=jnp.float32)
+        x = h[token]                                           # [n * k, d]
+        gate = lax.ragged_dot(x, w_gate, sizes, **f32)
+        up = lax.ragged_dot(x, w_up, sizes, **f32)
+        y = lax.ragged_dot((jax.nn.silu(gate) * up).astype(h.dtype), w_down,
+                           sizes, **f32)
+        # rows past the held pairs belong to no group: a backend may leave
+        # them unwritten (the TPU's does), so they are masked, not weighted 0
+        out = jnp.zeros((n, d), jnp.float32).at[token].add(
+            jnp.where(is_held[:, None], y * pair_w[:, None], 0.0))
+    return out.astype(h.dtype), (sizes.sum(), sizes.max())

@@ -1285,19 +1285,11 @@ class TrainStep:
         same). An explicit compiler option, set to its default, keeps this
         compile from being handed that executable, and the scopes are made
         part of the key."""
-        from ..observability.scopes import op_scopes_from_hlo
+        from ..observability.scopes import op_scopes_from_hlo, scoped_text
 
         lowered = (self.lower_window_hlo(*batch, window=window, accum=accum)
                    if window else self.lower_hlo(*batch))
-        flag = "jax_compilation_cache_include_metadata_in_key"
-        was = getattr(jax.config, flag)
-        jax.config.update(flag, True)
-        try:
-            text = lowered.compile(compiler_options={
-                "xla_embed_ir_in_executable": False}).as_text()
-        finally:
-            jax.config.update(flag, was)
-        return op_scopes_from_hlo(text)
+        return op_scopes_from_hlo(scoped_text(lowered))
 
     def audit(self, *batch, window: Optional[int] = None, accum: int = 1,
               compile: bool = True, rules: Optional[ShardingRules] = None):

@@ -426,9 +426,9 @@ class TestWatchdog:
         bat = ContinuousBatcher(eng, watchdog_s=0.05)
         real = eng.decode_step
 
-        def stalled():
+        def stalled(**kw):
             time.sleep(0.25)
-            return real()
+            return real(**kw)
 
         monkeypatch.setattr(eng, "decode_step", stalled)
         r = bat.submit(_prompt(5, 50), max_new_tokens=3)

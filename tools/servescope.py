@@ -1,0 +1,107 @@
+"""A serving cell's decode step and prefill, looked at with the program's
+own named scopes (docs/OBSERVABILITY.md "Named scopes"). A builder's
+instrument, not the benchmark: it builds the cell's engine through the
+benchmark's own adaptor and weights, fills every slot with a prompt of the
+mix's lengths, decodes ``--ahead`` steps so that the rows hold what they
+hold in a window, then traces ``--steps`` decode steps and ``--prefills``
+prefills of the mix's median prompt, and prints the device's own time of
+ONE decode step and ONE prefill by scope, layer numbers folded.
+
+    python tools/servescope.py --workload deepseek_v2_serve_reason --seed 7
+    JAX_PLATFORMS=cpu python tools/servescope.py --tiny     # rehearsal
+
+Ends in one JSON line, also appended to ``chiprun_out/servescope.jsonl``.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def build(workload, tiny, seed):
+    """(engine, mix, config) of the cell, or of the CPU tests' toy copy."""
+    from benchmark import harness
+    from benchmark.weights import make_weights
+
+    root, platform = ROOT, "tpu"
+    if tiny:
+        import tempfile
+
+        sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
+        import test_benchmark_deepseek_v2 as toy
+
+        root = toy.make_root(tempfile.mkdtemp(prefix="servescope-"))
+        workload, platform = toy.TINY, "cpu"
+    bench = harness.load_benchmark(root)
+    cell = harness.find_cell(bench, workload)
+    config, mix = harness.load_config(bench, cell, root), harness.load_mix(cell, root)
+    harness.place_compile_cache(ROOT)
+    harness.require_devices(cell["chips"], platform)
+    weights = make_weights(harness.reference_for(config).param_specs(config), seed)
+    engine, _ = harness.system_for(config).build_serve(config, weights)
+    del weights
+    return engine, mix, config
+
+
+def by_scope(report, table, per):
+    """{scope path, layers folded, model name dropped, three components
+    deep: ms of own time per ``per`` calls}, largest first."""
+    out = {}
+    for path, s in report.scope_seconds(table, depth=None).items():
+        path = re.sub(r"layer\d+", "layerN", path.split("/", 1)[-1])
+        path = "/".join(path.split("/")[:3])  # layerN/mla/core, no deeper
+        out[path] = out.get(path, 0.0) + 1e3 * s / per
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
+
+
+def main(argv):
+    from benchmark import traffic
+    from mxnet_tpu.observability import profiling
+
+    ap = argparse.ArgumentParser(prog="servescope")
+    ap.add_argument("--workload", default="deepseek_v2_serve_reason")
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--steps", type=int, default=8)
+    ap.add_argument("--prefills", type=int, default=4)
+    ap.add_argument("--ahead", type=int, default=300)
+    ap.add_argument("--tiny", action="store_true")
+    args = ap.parse_args(argv)
+    engine, mix, config = build(args.workload, args.tiny, args.seed)
+    rng = traffic.rng_for(args.seed, 5)
+    lengths = traffic.quantile_lengths(mix["prompt_len"], engine.batch_size)
+    for slot, n in enumerate(rng.permutation(lengths)[1:], start=1):
+        engine.prefill(rng.integers(1, config["n_vocab"], int(n)).tolist(), slot)
+    for _ in range(min(args.ahead, mix["answer_len"]["min"] - 1)):
+        engine.decode_step()
+    held = int(engine.positions[~engine.done].sum())
+    out = {"workload": args.workload, "rows": int((~engine.done).sum()),
+           "held_positions": held, "read_path": engine.read_path}
+    cap = profiling.capture(engine.decode_step, steps=args.steps, warmup=2)
+    out["decode_ms_by_scope"] = by_scope(cap.report, engine.op_scopes(),
+                                         args.steps)
+    median = mix["prompt_len"]["median"]
+    prompt = rng.integers(1, config["n_vocab"], median).tolist()
+    cap = profiling.capture(lambda: engine.prefill(prompt, 0),
+                            steps=args.prefills, warmup=1)
+    out["prefill_bucket"] = engine.bucket_for(median)
+    out["prefill_ms_by_scope"] = by_scope(
+        cap.report, engine.op_scopes(bucket=median), args.prefills)
+    for key in ("decode_ms_by_scope", "prefill_ms_by_scope"):
+        print(f"[servescope] {key} (sum {sum(out[key].values()):.3f} ms):")
+        for path, ms in out[key].items():
+            print(f"[servescope]   {ms:9.3f}  {path}")
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "servescope.jsonl"), "a") as f:
+        f.write(json.dumps(out) + "\n")
+    print(json.dumps(out), flush=True)
+    return out
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
