@@ -391,9 +391,10 @@ def check_flash(causal, b=4, h=16, t=2048, d=64, interpret=None):
             "rel_err": {n: round(e, 5) for n, e in errs.items()}}
 
 
-def check_paged(rows=8, h=8, ch=128, ps=16, n_pages=64, interpret=None):
-    """A geometry the kernel's own gate admits: the engine's decode layout
-    (float32 activations over a bf16 pool), one query token a row."""
+def check_paged(rows=8, h=16, ch=64, ps=16, n_pages=64, interpret=None):
+    """The serving cell's geometry, which the kernel's own gate admits:
+    GPT-2 345M's 16 heads of 64 over a token-major bfloat16 pool, float32
+    activations, one query token a row, rows of every length."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -403,7 +404,7 @@ def check_paged(rows=8, h=8, ch=128, ps=16, n_pages=64, interpret=None):
 
     rs = np.random.RandomState(SEED)
     pool_pages = rows * n_pages
-    k_pool, v_pool = (jnp.asarray(rs.randn(pool_pages + 1, h, ps, ch),
+    k_pool, v_pool = (jnp.asarray(rs.randn(pool_pages + 1, ps, h * ch),
                                   jnp.bfloat16) for _ in range(2))
     table = jnp.asarray(rs.permutation(pool_pages).reshape(rows, n_pages)
                         + 1, jnp.int32)
@@ -432,9 +433,7 @@ def check_paged(rows=8, h=8, ch=128, ps=16, n_pages=64, interpret=None):
                              "XLA gather path")
     return {"rows": rows, "heads": h, "head": ch, "page": ps,
             "pages_per_row": n_pages, "pool": "bfloat16",
-            "tpu_custom_calls": calls, "rel_err": round(err, 6),
-            "bit_identical_to_gather": bool(jnp.array_equal(got[0],
-                                                            want[0]))}
+            "tpu_custom_calls": calls, "rel_err": round(err, 6)}
 
 
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):

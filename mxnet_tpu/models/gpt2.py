@@ -113,8 +113,9 @@ class GPT2Model(HybridBlock):
 
     def init_paged_cache(self, num_pages, page_size, dtype="float32"):
         """Allocate per-layer ``(k_pool, v_pool)`` page pools of shape
-        (num_pages + 1, H, page_size, Ch) — the paged decode carry; page 0
-        is the reserved trash page (docs/INFERENCE.md "Paged cache")."""
+        (num_pages + 1, page_size, H * Ch), token-major: the paged decode
+        carry; page 0 is the reserved trash page (docs/INFERENCE.md "Paged
+        cache", ``ops.attention.alloc_paged_kv_cache``)."""
         from ..ops.attention import alloc_paged_kv_cache
 
         return alloc_paged_kv_cache(num_pages, self._num_heads, page_size,
@@ -123,24 +124,21 @@ class GPT2Model(HybridBlock):
 
     def paged_read_path(self, batch_size, pools, page_table):
         """What a paged engine's decode program will read this model's
-        ``(k_pool, v_pool)`` pools by: the Pallas page-table kernel, or the
-        XLA ``pool[page_table]`` gather and why (the operator makes the
-        same choice from the same shapes at trace time)."""
+        ``(k_pool, v_pool)`` pools by: the Pallas kernel that fetches the
+        pages a row holds, or the XLA ``pool[page_table]`` gather and why
+        (the operator makes the same choice from the same shapes at trace
+        time)."""
         import jax
 
-        from ..ops.pallas_common import on_tpu
         from ..ops.pallas_paged_attention import paged_attention_refusal
 
-        k_pool = pools[0][0]  # (P+1, H, page, Ch)
+        k_pool = pools[0][0]  # (P+1, page, H*Ch)
         q = jax.ShapeDtypeStruct(
-            (batch_size, k_pool.shape[1], 1, k_pool.shape[3]),
+            (batch_size, self._num_heads, 1,
+             k_pool.shape[2] // self._num_heads),
             self.word_embed.weight.data()._data.dtype)
         why = paged_attention_refusal(q, k_pool, page_table)
-        if why is not None:
-            return f"xla_gather ({why})"
-        if on_tpu():
-            return "pallas_paged_kernel"
-        return "pallas_paged_kernel (interpreted: the backend is not a TPU)"
+        return f"xla_gather ({why})" if why else "pallas_paged_kernel"
 
     def logits_width(self):
         """The vocabulary the logits span (the head is the word embedding)."""

@@ -122,13 +122,17 @@ def alloc_kv_cache(batch_size, num_heads, max_length, channels, num_layers,
 def alloc_paged_kv_cache(num_pages, num_heads, page_size, channels, num_layers,
                          dtype="float32"):
     """Per-layer ``(k_pool, v_pool)`` page pools of shape
-    (num_pages + 1, H, page_size, Ch) — the global block pool of the paged
-    decode cache (docs/INFERENCE.md "Paged cache"). Page 0 is the reserved
-    **trash page**: page-table entries of released / past-capacity rows are
-    0, so their (masked) writes land there instead of in live pages."""
+    (num_pages + 1, page_size, H * Ch): the global block pool of the paged
+    decode cache (docs/INFERENCE.md "Paged cache"). Token-major: the page
+    axis first (all the engine, the copy-on-write program and the allocator
+    index), a page one contiguous block, a token's heads side by side on the
+    minor axis, so a token is written at ``[page, offset]`` and a row is read
+    by whole pages. Page 0 is the reserved **trash page**: page-table entries
+    of released / past-capacity rows are 0, so their (masked) writes land
+    there instead of in live pages."""
     from ..base import dtype_np
 
-    shape = (int(num_pages) + 1, int(num_heads), int(page_size), int(channels))
+    shape = (int(num_pages) + 1, int(page_size), int(num_heads) * int(channels))
     return [(jnp.zeros(shape, dtype_np(dtype)), jnp.zeros(shape, dtype_np(dtype)))
             for _ in range(int(num_layers))]
 
@@ -140,17 +144,27 @@ def _frontier_masked_attention(q, k_hist, v_hist, position):
     frontier (zeros, stale rejected-draft K/V, trash-page garbage) are
     masked to -inf before the softmax, so they contribute *exactly* 0.0 —
     which is what makes the paged layout bit-identical to the contiguous
-    one: both feed this very function."""
+    one: both feed this very function.
+
+    The two products take their operands in the history's dtype when that
+    is bfloat16 (a float32 query and the float32 weights are rounded where
+    the MXU's one default pass rounds them anyway, and the history is not
+    converted), else in the promoted dtype; they accumulate in float32, and
+    the softmax is float32. Returns float32."""
     tq, ch = q.shape[2], q.shape[3]
     tmax = k_hist.shape[2]
+    mm = (k_hist.dtype if k_hist.dtype == jnp.bfloat16
+          else jnp.promote_types(q.dtype, k_hist.dtype))
+    f32 = dict(preferred_element_type=jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(ch, jnp.float32))
-    scores = jnp.einsum("bhqc,bhkc->bhqk", q, k_hist).astype(jnp.float32) * scale
+    scores = jnp.einsum("bhqc,bhkc->bhqk", q.astype(mm), k_hist.astype(mm),
+                        **f32) * scale
     key_idx = jnp.arange(tmax, dtype=jnp.int32)[None, None, None, :]
     q_pos = (position[:, None, None, None]
              + jnp.arange(tq, dtype=jnp.int32)[None, None, :, None])
     scores = jnp.where(key_idx <= q_pos, scores, -jnp.inf)
-    att = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkc->bhqc", att, v_hist)
+    att = jax.nn.softmax(scores, axis=-1).astype(mm)
+    return jnp.einsum("bhqk,bhkc->bhqc", att, v_hist.astype(mm), **f32)
 
 
 def _cached_mha(q, k_new, v_new, k_buf, v_buf, position):
@@ -182,60 +196,82 @@ def _paged_cached_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
     """Incremental attention against a paged (block) KV pool.
 
     q/k_new/v_new: (B, H, Tq, Ch) — the Tq new positions of each row;
-    k_pool/v_pool: (P+1, H, ps, Ch) — the global page pool (page 0 = trash);
+    k_pool/v_pool: (P+1, ps, H*Ch) — the global page pool, token-major
+                   (:func:`alloc_paged_kv_cache`; page 0 = trash);
     page_table:    (B, n_pages) int32 — per-row page ids in slot order
                    (slot s holds sequence positions ``s*ps .. (s+1)*ps-1``;
                    unallocated slots are 0 and only ever masked);
     position:      (B,) int32 — per-row start index of this chunk.
 
-    Writes scatter each new token into ``pool[table[pos // ps], :, pos % ps]``
-    (positions past the table's capacity, and any slot a released row's
-    cleared table maps to, redirect to the trash page). Reads run the
-    Pallas paged-attention kernel when it qualifies
-    (:mod:`mxnet_tpu.ops.pallas_paged_attention` — the per-row page gather
-    happens *inside* the kernel, so no pool-wide ``pool[page_table]``
-    materialization ever exists in the program); otherwise the XLA
-    fallback gathers the row histories into a (B, H, cap, Ch) view and
-    runs the shared :func:`_frontier_masked_attention`. Both paths mask
-    stale/trash/garbage K/V to a softmax weight of exactly 0.0, and the
-    kernel replicates the fallback's op order — so logits are
-    bit-identical to the contiguous cache either way.
+    Writes put each new token at ``pool[table[pos // ps], pos % ps]``, two
+    leading indices, in place on a donated pool (positions past the table's
+    capacity, and any slot a released row's cleared table maps to, redirect
+    to the trash page). Reads are one algorithm with two paths, chosen from
+    what the operands and the process show
+    (:func:`~mxnet_tpu.ops.pallas_paged_attention.paged_attention_refusal`:
+    backend, head size and count, page size, dtypes, the VMEM that Tq
+    queries against a row's history need): the Pallas kernel copies the
+    pages each row HOLDS into VMEM and attends there (decode, speculative
+    verification); the XLA path gathers ``pool[page_table]``, the table's
+    whole width, into a (B, H, cap, Ch) view and runs the shared
+    :func:`_frontier_masked_attention` (long prefill chunks, the CPU, every
+    refused shape). Both mask stale/trash/garbage K/V to a softmax weight of
+    exactly 0.0; the XLA path is bit-identical to the contiguous cache.
+    The trace-time counter ``paged_read_path_total{path, reason}`` says which
+    was built, and why the kernel was not.
     """
+    from .. import observability as obs
     from . import pallas_paged_attention as ppa
 
-    if ppa.paged_attention_supported(q, k_pool, page_table):
-        return ppa.paged_attention(q, k_new, v_new, k_pool, v_pool,
-                                   page_table, position)
-    return _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table,
-                             position)
+    k_pool, v_pool = _paged_write(k_new, v_new, k_pool, v_pool, page_table,
+                                  position)
+    why = ppa.paged_attention_refusal(q, k_pool, page_table)
+    obs.counter("paged_read_path_total").inc(
+        path="xla_gather" if why else "kernel", reason=why or "")
+    read = _paged_gather_read if why else ppa.paged_attention_read
+    return read(q, k_pool, v_pool, page_table, position), k_pool, v_pool
 
 
-def _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
-    """The XLA read path of :func:`_paged_cached_mha` (same contract), and
-    the reference the paged kernel is checked against: scatter the new
-    K/V, gather every row's history out of the pool, attend."""
-    b, h, tq, ch = q.shape
-    ps = k_pool.shape[2]
-    n_pages = page_table.shape[1]
-    cap = n_pages * ps
-
+def _paged_write(k_new, v_new, k_pool, v_pool, page_table, position):
+    """The Tq new keys and values of each row, (B, H, Tq, Ch), written into
+    the pools at ``[page, offset]``; positions past the table's capacity go
+    to the trash page."""
+    b, h, tq, ch = k_new.shape
+    ps, n_pages = k_pool.shape[1], page_table.shape[1]
     pos = (position[:, None]
            + jnp.arange(tq, dtype=jnp.int32)[None, :])          # (B, Tq)
     slot = jnp.clip(pos // ps, 0, n_pages - 1)
     pid = jnp.take_along_axis(page_table, slot, axis=1)          # (B, Tq)
-    pid = jnp.where(pos < cap, pid, 0)                           # overflow -> trash
-    off = pos % ps
-    pid_f, off_f = pid.reshape(-1), off.reshape(-1)
-    # (B,H,Tq,Ch) -> (B*Tq, H, Ch) token-major values for the scatter
-    vals_k = k_new.transpose(0, 2, 1, 3).reshape(b * tq, h, ch)
-    vals_v = v_new.transpose(0, 2, 1, 3).reshape(b * tq, h, ch)
-    k_pool = k_pool.at[pid_f, :, off_f, :].set(vals_k.astype(k_pool.dtype))
-    v_pool = v_pool.at[pid_f, :, off_f, :].set(vals_v.astype(v_pool.dtype))
+    pid = jnp.where(pos < n_pages * ps, pid, 0).reshape(-1)      # overflow -> trash
+    off = (pos % ps).reshape(-1)
 
-    # gather the row histories: (B, n_pages, H, ps, Ch) -> (B, H, cap, Ch)
-    k_hist = k_pool[page_table].transpose(0, 2, 1, 3, 4).reshape(b, h, cap, ch)
-    v_hist = v_pool[page_table].transpose(0, 2, 1, 3, 4).reshape(b, h, cap, ch)
-    out = _frontier_masked_attention(q, k_hist, v_hist, position)
+    def token_major(x, pool):   # (B,H,Tq,Ch) -> (B*Tq, H*Ch)
+        return x.transpose(0, 2, 1, 3).reshape(b * tq, h * ch).astype(pool.dtype)
+
+    return (k_pool.at[pid, off].set(token_major(k_new, k_pool)),
+            v_pool.at[pid, off].set(token_major(v_new, v_pool)))
+
+
+def _paged_gather_read(q, k_pool, v_pool, page_table, position):
+    """The XLA read path: every row's history gathered by its page table,
+    the table's whole width, and brought to (B, H, cap, Ch) for the dense
+    cache's own arithmetic."""
+    b, h, _, ch = q.shape
+    cap = page_table.shape[1] * k_pool.shape[1]
+
+    def history(pool):   # (B, n_pages, ps, H*Ch) -> (B, H, cap, Ch)
+        return pool[page_table].reshape(b, cap, h, ch).transpose(0, 2, 1, 3)
+
+    return _frontier_masked_attention(q, history(k_pool), history(v_pool),
+                                      position)
+
+
+def _paged_gather_mha(q, k_new, v_new, k_pool, v_pool, page_table, position):
+    """:func:`_paged_cached_mha` through the XLA read path whatever the gate
+    says: the reference the paged kernel is checked against."""
+    k_pool, v_pool = _paged_write(k_new, v_new, k_pool, v_pool, page_table,
+                                  position)
+    out = _paged_gather_read(q, k_pool, v_pool, page_table, position)
     return out, k_pool, v_pool
 
 
@@ -481,7 +517,7 @@ def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
     the same causal structure as ``causal=True`` on the full sequence.
 
     With ``page_table=`` ((B, n_pages) int32) the cache entries are read as
-    **page pools** ``(P+1, H, page_size, Ch)`` instead of contiguous per-row
+    **page pools** ``(P+1, page_size, H*Ch)`` instead of contiguous per-row
     buffers — the paged variant (docs/INFERENCE.md "Paged cache"): same
     frontier mask, same return convention, storage indirected through the
     per-row page table.

@@ -1,33 +1,45 @@
-"""Paged decode-attention Pallas kernel (profile-directed: memcheck's
-``kv_gather_materialize`` detector).
+"""Paged attention over a token-major page pool, one Pallas kernel
+(docs/INFERENCE.md "Paged cache", docs/PERFORMANCE.md "Custom kernels").
 
-The XLA lowering of the paged decode/verify read path
-(``attention._paged_cached_mha``) gathers the whole per-row history out of
-the page pool every step::
+The pools are ``(P+1, page_size, H*Ch)``: the page axis first, a page one
+contiguous block, the minor axis every head's channels side by side (1,024
+lanes for GPT-2 345M whatever the head size). The XLA read path
+(``attention._paged_gather_mha``) gathers ``pool[page_table]``, the page
+table's whole width for every row, and brings it to ``(B, H, cap, Ch)``.
+This kernel reads what a row holds:
 
-    k_hist = k_pool[page_table]        # materializes (B, n_pages, H, ps, Ch)
+  - *pages*: the page table and the positions ride in as scalars, the pools
+    stay in HBM, and for row ``b`` the kernel copies pages ``0 .. (position[b]
+    + Tq - 1) // page_size`` of each pool into a VMEM history, all of a row's
+    copies in flight together and the next row's started before this row's
+    products begin (two history slots). The loop bound is read from the
+    scalars: one program serves every length.
+  - *heads*: columns are taken 128 at a time (a lane tile: two heads of 64,
+    one of 128). A tile's heads are stacked over the rows of the left
+    operand, each with the other heads' lanes zeroed, so one product
+    contracts over the MXU's native 128 and gives every head's scores;
+    ``pallas_packed_attention`` reads BERT's projection the same way. No
+    64-lane slice is taken and the history is never transposed.
+  - *lengths*: the products run over the first 128, 256, 512, ... keys of the
+    history, the smallest such stretch that covers the pages fetched
+    (branches of one kernel, chosen by the scalars), so a row of 100
+    positions does an eighth of the work of a full one.
+  - *what was not fetched counts for nothing*: VMEM past a row's pages holds
+    another row's values or none at all. A score there is masked to ``-inf``
+    by the frontier mask, whatever it is; the VALUES there are zeroed before
+    the second product, because a weight of 0 does not clear a NaN.
+  - *dtype policy*: ``attention._frontier_masked_attention``'s. Products
+    of operands in the pool's dtype with float32 accumulation, the softmax
+    in float32 over a whole row at once (no streaming softmax).
+  - *name*: ``paged_attention_decode`` in a device trace; the benchmark's
+    ``custom_call_share_pct.serve`` finds it by the operation's text.
 
-— a full second copy of every live row's KV bytes per decode step, pinned
-at ×4 (two pools × two layers) in the committed ``mem_decode_paged.json`` /
-``mem_verify_spec.json`` goldens. This kernel deletes that materialization:
-the page *table* rides in as a scalar-prefetch operand, the pools stay in
-``ANY`` (HBM) memory space, and the kernel DMAs exactly the pages named by
-the current row's table into a VMEM scratch history — no pool-wide gather
-ever exists in the program.
-
-Numerics contract: the in-kernel read path is the *same composition* as
-:func:`mxnet_tpu.ops.attention._frontier_masked_attention` (einsum → f32
-scale/mask → ``jax.nn.softmax`` → einsum), evaluated per batch row — so
-paged decode/verify logits stay **bit-identical** to the gather path (and
-therefore to the contiguous dense cache), which
-``tests/test_paged_inference.py`` asserts exactly. No online/streaming
-softmax: associativity changes would break bit-identity for zero benefit at
-decode history lengths.
-
-Gating: CPU interpret mode always qualifies (tier-1 CI correctness); the
-hardware path additionally wants lane-aligned heads and a VMEM-bounded
-scratch history — callers fall back to the XLA gather otherwise
-(``paged_attention_supported``).
+The gate (:func:`paged_attention_refusal`) reads what it can observe:
+backend, head size and count, page size, dtypes, the active mesh, and the
+VMEM a row's two histories and score block need, which is what sends decode
+and speculative verification here and a long prefill chunk to the XLA path.
+On the CPU the operator takes the XLA path (it is the dense cache's own
+arithmetic, bit for bit); the tests run this kernel interpreted against it.
 """
 from __future__ import annotations
 
@@ -35,149 +47,248 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .._mesh_state import current_mesh
 from .pallas_common import LANES as _LANES
 from .pallas_common import on_tpu as _on_tpu
 from .pallas_common import resolve_interpret as _resolve_interpret
 
-# VMEM budget for the two (H, cap, Ch) scratch histories plus the f32
-# score block — half the ~16MB/core so the q/out blocks and DMA staging fit
-_MAX_SCRATCH_BYTES = 8 * 1024 * 1024
+# What the kernel may hold: two slots of a row's two histories, the float32
+# score-shaped temporaries of one tile and the double-buffered query and
+# output blocks. A v5e core has 128 MiB of VMEM, of which a kernel gets
+# 16 MiB unless it asks for more; the kernel asks for what _vmem_bytes counts
+# and a margin
+_MAX_VMEM_BYTES = 24 * 1024 * 1024
+_SCORE_TEMPS = 3  # float32 score-shaped values live at once
+# Up to this many stacked rows the loop over a row's lane tiles is unrolled:
+# the tiles' products are independent, and in one block the scheduler can
+# load one tile's keys into an MXU while another's scores are in the softmax
+_UNROLL_UP_TO = 64
+
+
+def _tiling(h, ch):
+    """(tile width, heads a tile): 128 lanes where the heads fill whole
+    lane tiles, else every head in one tile (the interpreter's toy shapes)."""
+    hc = h * ch
+    w = _LANES if hc % _LANES == 0 and _LANES % ch == 0 else hc
+    return w, w // ch
+
+
+def _rows(g, tq, itemsize):
+    """Rows of a tile's stacked left operand, padded to whole sublane tiles
+    of the pool's dtype (8 of float32, 16 of bfloat16)."""
+    sub = 8 * (4 // itemsize)
+    return -(-g * tq // sub) * sub
+
+
+def _vmem_bytes(h, ch, tq, cap, itemsize):
+    w, g = _tiling(h, ch)
+    r = _rows(g, tq, itemsize)
+    blocks = 2 * (h * ch // w) * r * w * (itemsize + 4)
+    return 4 * cap * h * ch * itemsize + _SCORE_TEMPS * r * cap * 4 + blocks
 
 
 def paged_attention_refusal(q, k_pool, page_table):
-    """Why the paged kernel does NOT replace the XLA pool gather for these
-    operands (anything with ``.shape``/``.dtype``), or None when it does.
-
-    Interpret mode (CPU CI) has no tiling constraints, so the only gate
-    there is the config knob — this is what keeps the compiled
-    decode/verify programs gather-free in the committed memory goldens.
-    On a TPU the scratch history must be tile-aligned (``Ch % 128``,
-    ``page_size % 8``) and fit the VMEM budget, and the query must be
-    float32: Mosaic refuses the kernel's bf16 x bf16 einsums ("'tpu.matmul'
-    op Expected matmul acc to be 32-bit"). Callers fall back to the gather
-    path otherwise.
-    """
+    """Why the paged kernel does NOT read the pools for these operands
+    (anything with ``.shape``/``.dtype``), or None when it does: ``q``
+    ``(B, H, Tq, Ch)``, ``k_pool`` ``(P+1, page_size, H*Ch)``, ``page_table``
+    ``(B, n_pages)``. The first condition that fails is the one named;
+    callers take the XLA gather then."""
     from .. import config as _config
 
     if not _config.get("paged_attention_kernel"):
         return "paged_attention_kernel knob is off"
     if not _on_tpu():
-        return None
+        return "the backend is not a TPU"
     b, h, tq, ch = q.shape
-    ps = k_pool.shape[2]
+    ps, hc = k_pool.shape[1], k_pool.shape[2]
     cap = page_table.shape[1] * ps
-    if ch % _LANES:
-        return f"head size {ch} is not a multiple of {_LANES} lanes"
-    if ps % 8:
-        return f"page size {ps} is not a multiple of 8 sublanes"
-    if q.dtype != jnp.float32:
-        return f"query dtype {jnp.dtype(q.dtype).name} is not float32"
     if k_pool.dtype not in (jnp.float32, jnp.bfloat16):
-        return f"pool dtype {jnp.dtype(k_pool.dtype).name} is not f32/bf16"
+        return f"pool dtype {jnp.dtype(k_pool.dtype).name} is not float32 or bfloat16"
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"query dtype {jnp.dtype(q.dtype).name} is not float32 or bfloat16"
+    if hc != h * ch or hc % _LANES or _LANES % ch:
+        return (f"{h} heads of {ch} are not whole {_LANES}-lane tiles of the "
+                f"pool's {hc} columns")
     itemsize = jnp.dtype(k_pool.dtype).itemsize
-    scratch = 2 * h * cap * ch * itemsize + 4 * h * tq * cap
-    if scratch > _MAX_SCRATCH_BYTES:
-        return (f"row history needs {scratch} bytes of VMEM scratch "
-                f"(budget {_MAX_SCRATCH_BYTES})")
+    sub = 8 * (4 // itemsize)
+    if ps % sub or (_LANES % ps and ps % _LANES):
+        return (f"page size {ps} is not a multiple of {sub} sublanes that "
+                f"divides or is a multiple of {_LANES}")
+    need = _vmem_bytes(h, ch, tq, cap, itemsize)
+    if need > _MAX_VMEM_BYTES:
+        return (f"{tq} queries a row against {cap} positions need {need} "
+                f"bytes of VMEM (budget {_MAX_VMEM_BYTES})")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
     return None
 
 
 def paged_attention_supported(q, k_pool, page_table) -> bool:
-    """True when the paged kernel should replace the XLA pool gather
-    (see :func:`paged_attention_refusal` for the rules)."""
+    """True when the paged kernel should read the pools (see
+    :func:`paged_attention_refusal` for the rules)."""
     return paged_attention_refusal(q, k_pool, page_table) is None
 
 
-def _paged_kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
-                  ks, vs, sem, *, ps, n_pages, tq, cap):
-    b = pl.program_id(0)
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
-    def gather_page(j, carry):
-        # DMA page table[b, j] of each pool into slot j of the row history.
-        # Trash-page ids (0) are gathered like the XLA path — their garbage
-        # K/V sit past the frontier and get an exact 0.0 softmax weight.
-        pid = table_ref[b, j]
-        pltpu.make_async_copy(kp_ref.at[pid],
-                              ks.at[:, pl.ds(j * ps, ps), :], sem).start()
-        pltpu.make_async_copy(kp_ref.at[pid],
-                              ks.at[:, pl.ds(j * ps, ps), :], sem).wait()
-        pltpu.make_async_copy(vp_ref.at[pid],
-                              vs.at[:, pl.ds(j * ps, ps), :], sem).start()
-        pltpu.make_async_copy(vp_ref.at[pid],
-                              vs.at[:, pl.ds(j * ps, ps), :], sem).wait()
-        return carry
 
-    jax.lax.fori_loop(0, n_pages, gather_page, 0)
+def _page_buckets(ps, n_pages):
+    """The stretches of history the products run over, in pages: 128 keys,
+    doubled up to the table's width."""
+    out, n = [], max(1, _LANES // ps)
+    while n < n_pages:
+        out.append(n)
+        n *= 2
+    return out + [n_pages]
 
-    # From here on: _frontier_masked_attention verbatim, one batch row.
-    q = q_ref[0]                                    # (H, Tq, Ch)
-    ch = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(ch, jnp.float32))
-    scores = jnp.einsum("hqc,hkc->hqk", q, ks[...]).astype(jnp.float32) * scale
-    key_idx = jax.lax.broadcasted_iota(jnp.int32, (tq, cap), 1)
-    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (tq, cap), 0)
-    scores = jnp.where((key_idx <= q_pos)[None], scores, -jnp.inf)
-    att = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    o_ref[0] = jnp.einsum("hqk,hkc->hqc", att, vs[...]).astype(o_ref.dtype)
+
+def _kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref, ks, vs, sem, *,
+            ps, n_pages, tq, g, scale):
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    n_tiles, r, w = q_ref.shape[1:]
+    slot = b % 2
+
+    def pages_of(row):
+        # the pages the row's queries can see; a released or overflowing row
+        # reads what its table names, like the XLA path
+        return jnp.clip((pos_ref[row] + (tq - 1)) // ps + 1, 1, n_pages)
+
+    def for_each_copy(row, into, act):
+        def page(j, carry):
+            pid = table_ref[row * n_pages + j]
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for pool, hist in ((kp_ref, ks), (vp_ref, vs)):
+                act(pltpu.make_async_copy(pool.at[pid], hist.at[into, at, :],
+                                          sem.at[into]))
+            return carry
+
+        lax.fori_loop(0, pages_of(row), page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        for_each_copy(0, 0, lambda copy: copy.start())
+
+    @pl.when(b + 1 < rows)
+    def _():
+        for_each_copy(b + 1, 1 - slot, lambda copy: copy.start())
+
+    for_each_copy(b, slot, lambda copy: copy.wait())
+    held = pages_of(b)
+
+    def attend(n):
+        keys = n * ps
+
+        def clear(j, carry):
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            vs[slot, at, :] = jnp.zeros((ps, vs.shape[2]), vs.dtype)
+            return carry
+
+        lax.fori_loop(held, n, clear, 0)
+        # row s*tq + i of the stacked operand is query i of the tile's head s
+        row = lax.broadcasted_iota(jnp.int32, (r, keys), 0)
+        i = row
+        for s in range(1, g):
+            i = jnp.where(row >= s * tq, row - s * tq, i)
+        visible = (lax.broadcasted_iota(jnp.int32, (r, keys), 1)
+                   <= pos_ref[b] + jnp.minimum(i, tq - 1))
+
+        def tile(t, carry):
+            lanes = pl.ds(pl.multiple_of(t * w, w), w)
+            k = ks[slot, pl.ds(0, keys), lanes]
+            v = vs[slot, pl.ds(0, keys), lanes]
+            s = lax.dot_general(q_ref[0, t], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+            p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
+            o_ref[0, t] = lax.dot_general(p.astype(v.dtype), v, _NN,
+                                          preferred_element_type=jnp.float32)
+            return carry
+
+        lax.fori_loop(0, n_tiles, tile, 0, unroll=r <= _UNROLL_UP_TO)
+
+    below = 0
+    for n in _page_buckets(ps, n_pages):
+        pl.when((held > below) & (held <= n))(functools.partial(attend, n))
+        below = n
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _call(table, position, q2, k_pool, v_pool, tq, g, interpret):
+    """The kernel over batch rows. Jitted, so that a model's layers trace and
+    lower it once."""
+    b, n_tiles, r, w = q2.shape
+    ps, hc = k_pool.shape[1:]
+    n_pages = table.shape[0] // b
+    ch = w // g
+    row = lambda i, t, p: (i, 0, 0, 0)  # noqa: E731
+    need = _vmem_bytes(hc // ch, ch, tq, n_pages * ps,
+                       jnp.dtype(k_pool.dtype).itemsize)
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, ps=ps, n_pages=n_pages, tq=tq, g=g,
+            scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
+        out_shape=jax.ShapeDtypeStruct((b, n_tiles, r, w), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, n_tiles, r, w), row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n_tiles, r, w), row),
+            scratch_shapes=[pltpu.VMEM((2, n_pages * ps, hc), k_pool.dtype),
+                            pltpu.VMEM((2, n_pages * ps, hc), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        name="paged_attention_decode",
+        interpret=interpret,
+        # rows run in order: each starts the next one's copies
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + 8 * 1024 * 1024),
+    )(table, position, q2, k_pool, v_pool)
+
+
+def paged_attention_read(q, k_pool, v_pool, page_table, position,
+                         interpret=None):
+    """Attention of ``q`` ``(B, H, Tq, Ch)`` over each row's paged history
+    under the frontier mask (query ``i`` of row ``b`` sees positions ``<=
+    position[b] + i``), the pools ``(P+1, page_size, H*Ch)`` read by the
+    pages the row holds. Returns ``(B, H, Tq, Ch)`` float32. Callers gate
+    via :func:`paged_attention_refusal`."""
+    b, h, tq, ch = q.shape
+    w, g = _tiling(h, ch)
+    n_tiles = h // g
+    r = _rows(g, tq, jnp.dtype(k_pool.dtype).itemsize)
+    # (B, tiles, g*Tq, W): head s of a tile in rows s*Tq.., its channels in
+    # lanes s*Ch.. and zeros in the other heads' lanes
+    own = jnp.eye(g, dtype=bool)[None, None, :, None, :, None]
+    x = q.astype(k_pool.dtype).reshape(b, n_tiles, g, tq, 1, ch)
+    q2 = jnp.where(own, x, jnp.zeros((), x.dtype)).reshape(b, n_tiles, g * tq, w)
+    q2 = jnp.pad(q2, ((0, 0), (0, 0), (0, r - g * tq), (0, 0)))
+    o2 = _call(jnp.asarray(page_table, jnp.int32).reshape(-1),
+               jnp.asarray(position, jnp.int32), q2, k_pool, v_pool, tq, g,
+               _resolve_interpret(interpret))
+    # each head's own lanes of its own rows
+    o = o2[:, :, :g * tq].reshape(b, n_tiles, g, tq, g, ch)
+    o = jnp.sum(jnp.where(own, o, 0.0), axis=4) if g > 1 else o[:, :, :, :, 0]
+    return o.reshape(b, h, tq, ch)
 
 
 def paged_attention(q, k_new, v_new, k_pool, v_pool, page_table, position,
                     interpret=None):
-    """Paged-cache attention with the in-kernel page gather.
+    """``attention._paged_cached_mha``'s contract through the kernel: write
+    the Tq new keys and values of each row at ``[page, offset]`` (an XLA
+    scatter, in place on a donated pool), then read. Returns ``(out, k_pool,
+    v_pool)``."""
+    from .attention import _paged_write
 
-    Same contract as the gather path: scatter the Tq new K/V of each row
-    into ``pool[table[pos // ps], :, pos % ps]`` (overflow → trash page 0),
-    then attend each row's query against its full paged history under the
-    frontier mask. Returns ``(out, k_pool, v_pool)``.
-
-    The scatter stays XLA (token-granular ``.at[].set`` is already optimal
-    and aliases the donated decode carry); only the read path — where the
-    pool-wide gather used to materialize — runs in the kernel.
-    """
-    interpret = _resolve_interpret(interpret)
-    b, h, tq, ch = q.shape
-    ps = k_pool.shape[2]
-    n_pages = page_table.shape[1]
-    cap = n_pages * ps
-
-    pos = (position[:, None]
-           + jnp.arange(tq, dtype=jnp.int32)[None, :])          # (B, Tq)
-    slot = jnp.clip(pos // ps, 0, n_pages - 1)
-    pid = jnp.take_along_axis(page_table, slot, axis=1)          # (B, Tq)
-    pid = jnp.where(pos < cap, pid, 0)                           # overflow -> trash
-    off = pos % ps
-    pid_f, off_f = pid.reshape(-1), off.reshape(-1)
-    vals_k = k_new.transpose(0, 2, 1, 3).reshape(b * tq, h, ch)
-    vals_v = v_new.transpose(0, 2, 1, 3).reshape(b * tq, h, ch)
-    k_pool = k_pool.at[pid_f, :, off_f, :].set(vals_k.astype(k_pool.dtype))
-    v_pool = v_pool.at[pid_f, :, off_f, :].set(vals_v.astype(v_pool.dtype))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, tq, ch), lambda b_, t, p: (b_, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, h, tq, ch), lambda b_, t, p: (b_, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, cap, ch), k_pool.dtype),
-            pltpu.VMEM((h, cap, ch), v_pool.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, ps=ps, n_pages=n_pages,
-                          tq=tq, cap=cap),
-        out_shape=jax.ShapeDtypeStruct((b, h, tq, ch), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), jnp.asarray(position, jnp.int32),
-      q, k_pool, v_pool)
+    k_pool, v_pool = _paged_write(k_new, v_new, k_pool, v_pool, page_table,
+                                  position)
+    out = paged_attention_read(q, k_pool, v_pool, page_table, position,
+                               interpret=interpret)
     return out, k_pool, v_pool

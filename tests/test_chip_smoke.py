@@ -72,7 +72,7 @@ def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
 
     x = jnp.zeros((8, 128), jnp.float32)
     q = jnp.zeros((1, 1, 128, 64), jnp.float32)
-    pool = jnp.zeros((3, 1, 8, 128), jnp.float32)
+    pool = jnp.zeros((3, 8, 64), jnp.float32)
     calls = [
         lambda: flash_attention(q, q, q, interpret=True),
         lambda: layer_norm_fused(x, x[0], x[0], interpret=True),

@@ -276,7 +276,7 @@ def test_two_kinds_of_per_layer_state_in_one_process(model):
         "latent": GenerationEngine(latent_net, batch_size=2, paged=True,
                                    page_size=8, num_pages=16, max_length=64)}
     assert [len(e.pools[0]) for e in engines.values()] == [2, 1]
-    assert engines["kv"].pools[0][0].shape == (17, 2, 8, 16)
+    assert engines["kv"].pools[0][0].shape == (17, 8, 32)
     assert engines["latent"].pools[0][0].shape == (17, 8, 40)
     assert engines["kv"].cache_bytes_per_token == 2 * 2 * 32 * 4
     assert engines["kv"]._last_vocab() == 100

@@ -350,36 +350,48 @@ def test_dense_decode_kv_category_and_no_materializations(engines):
 
 def test_paged_decode_kv_pages_attribution_and_gather_detector(engines):
     """The paged decode's pool+table bytes are auditor-attributed exactly
-    and the compiled program is gather-free with the paged attention
-    kernel on (ISSUE 18) — while the detector still proves it would
-    catch the pool gather if the kernel were bypassed (knob off: one
-    gather per K/V pool per layer, as before the kernel existed)."""
+    and the compiled program is gather-free where the paged attention
+    kernel reads the pools (ISSUE 18; on the CPU the operator takes the XLA
+    path, so the gate's backend check is patched and the kernel traced
+    interpreted) — while the detector still proves it would catch the pool
+    gather if the kernel were bypassed (knob off: one gather per K/V pool
+    per layer, as before the kernel existed)."""
+    import unittest.mock as mock
+
     from mxnet_tpu import config as _config
+    from mxnet_tpu import nd
+    from mxnet_tpu.inference import GenerationEngine
+    from mxnet_tpu.models import gpt2
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    def fresh_engine(units):
+        net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2,
+                            units=units, num_heads=2, max_length=64,
+                            vocab_size=64)
+        net.initialize()
+        _ = net(nd.array(np.zeros((1, 4), np.int32)))
+        return GenerationEngine(net, batch_size=2, max_length=64,
+                                prefill_buckets=(8, 16), paged=True,
+                                page_size=16)
 
     _, paged = engines
     mem = paged.audit().memory
     hand = int(sum(b.nbytes for layer in paged.pools for b in layer)) \
         + int(paged.page_table.nbytes)
     assert mem.by_category["kv_pages"] == hand
-    assert mem.materialization_kinds().get("kv_gather_materialize", 0) == 0
+    # two heads of 64 fill a lane tile: the kernel's gate passes, and its
+    # decode program holds no gather of the pool
+    with mock.patch.object(ppa, "_on_tpu", return_value=True):
+        kernel = fresh_engine(128)
+        assert kernel.read_path == "pallas_paged_kernel"
+        kinds = kernel.audit().memory.materialization_kinds()
+    assert kinds.get("kv_gather_materialize", 0) == 0
     # a FRESH engine with the kernel knob off re-traces the gather path
     # (the knob is trace-time; an existing engine's decode jaxpr is cached,
     # so toggling it on `paged` would silently audit the old trace)
-    from mxnet_tpu.inference import GenerationEngine
-    from mxnet_tpu.models import gpt2
-    from mxnet_tpu import nd
-
     _config.set("paged_attention_kernel", False)
     try:
-        net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2,
-                            units=32, num_heads=2, max_length=64,
-                            vocab_size=64)
-        net.initialize()
-        _ = net(nd.array(np.zeros((1, 4), np.int32)))
-        gathering = GenerationEngine(net, batch_size=2, max_length=64,
-                                     prefill_buckets=(8, 16), paged=True,
-                                     page_size=16)
-        kinds = gathering.audit().memory.materialization_kinds()
+        kinds = fresh_engine(32).audit().memory.materialization_kinds()
     finally:
         _config.set("paged_attention_kernel", True)
     assert kinds.get("kv_gather_materialize") == 4  # 2 layers x (K, V)

@@ -85,8 +85,8 @@ class ScriptedDraft:
         return {}
 
     def init_paged_cache(self, num_pages, page_size, dtype="float32"):
-        return [(jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32),
-                 jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32))]
+        return [(jnp.zeros((num_pages + 1, page_size, 1), jnp.float32),
+                 jnp.zeros((num_pages + 1, page_size, 1), jnp.float32))]
 
     def __call__(self, tokens, cache=None, start_pos=None, page_table=None):
         t = tokens._data.shape[1]
@@ -402,9 +402,9 @@ class TestSpeculative:
             pid = table[pos // 8]
             # self-draft: the draft entry must equal the target's, and in
             # particular must not be the all-zero initial page content
-            np.testing.assert_array_equal(k_pool[pid, :, pos % 8, :],
-                                          t_pool[pid, :, pos % 8, :])
-            assert np.abs(k_pool[pid, :, pos % 8, :]).sum() > 0.0
+            np.testing.assert_array_equal(k_pool[pid, pos % 8],
+                                          t_pool[pid, pos % 8])
+            assert np.abs(k_pool[pid, pos % 8]).sum() > 0.0
 
     def test_spec_batcher_matches_solo(self, net):
         prompts = [_prompt(4, 170), _prompt(11, 171), _prompt(7, 172)]

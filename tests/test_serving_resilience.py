@@ -110,8 +110,8 @@ class ConstDraft:
         return {}
 
     def init_paged_cache(self, num_pages, page_size, dtype="float32"):
-        return [(jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32),
-                 jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32))]
+        return [(jnp.zeros((num_pages + 1, page_size, 1), jnp.float32),
+                 jnp.zeros((num_pages + 1, page_size, 1), jnp.float32))]
 
     def __call__(self, tokens, cache=None, start_pos=None, page_table=None):
         shape = (tokens._data.shape[0], tokens._data.shape[1])

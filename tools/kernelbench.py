@@ -32,12 +32,16 @@ ATTN_CASES = [
 ]
 LN_CASES = [(8192, 1024), (32768, 1024), (8192, 4096)]
 
-# paged decode attention: (b, h, ch, page_size, n_pages) — serving-shaped
-# single-query rows; the A/B is kernel vs the XLA pool[table] gather
-# (the last two sit at the edges of the kernel's own gate: the largest row
-# history its VMEM budget admits, and 8-token pages under a bf16 pool)
-PAGED_CASES = [(8, 8, 128, 16, 64), (32, 8, 128, 16, 64),
-               (8, 8, 128, 16, 120), (8, 8, 128, 8, 64)]
+# paged attention: (b, h, ch, page_size, n_pages, tq, held): b rows of tq
+# queries whose histories hold held/2 .. held positions (0: anything up to the
+# table's width); the A/B is the kernel, which fetches the pages a row holds,
+# against the XLA pool[table] gather of the table's whole width. GPT-2 345M's
+# decode batch short, mixed and full, its verification and a prefill chunk;
+# head 128; the widest table the kernel's VMEM budget admits
+PAGED_CASES = [(64, 16, 64, 16, 64, 1, 128), (64, 16, 64, 16, 64, 1, 0),
+               (64, 16, 64, 16, 64, 1, 1024), (64, 16, 64, 16, 64, 5, 256),
+               (1, 16, 64, 16, 64, 64, 64), (8, 8, 128, 16, 64, 1, 512),
+               (8, 8, 128, 16, 128, 1, 0)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -55,7 +59,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     ATTN_CASES = [(1, 2, 256, 64)]
     LN_CASES = [(512, 256)]
     CONV_CASES = [(2, 8, 14, 14, 8, 3)]
-    PAGED_CASES = [(2, 2, 32, 8, 4)]
+    PAGED_CASES = [(2, 2, 32, 8, 4, 1, 0), (2, 2, 64, 16, 8, 3, 40)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -226,53 +230,59 @@ def run_conv_case(b, c, h, w, o, k, reps):
     return case
 
 
-def run_paged_case(b, h, ch, ps, n_pages, reps):
+def run_paged_case(b, h, ch, ps, n_pages, tq, held, reps):
     import jax.numpy as jnp
     import numpy as np
 
+    from mxnet_tpu.ops import attention as att
     from mxnet_tpu.ops import pallas_paged_attention as ppa
 
     rng = np.random.RandomState(0)
     pool_pages = b * n_pages
-    k_pool = jnp.asarray(rng.randn(pool_pages + 1, h, ps, ch), jnp.bfloat16)
-    v_pool = jnp.asarray(rng.randn(pool_pages + 1, h, ps, ch), jnp.bfloat16)
+    cap = n_pages * ps
+    k_pool = jnp.asarray(rng.randn(pool_pages + 1, ps, h * ch), jnp.bfloat16)
+    v_pool = jnp.asarray(rng.randn(pool_pages + 1, ps, h * ch), jnp.bfloat16)
     table = jnp.asarray(rng.randint(1, pool_pages + 1, (b, n_pages)), jnp.int32)
-    position = jnp.asarray(rng.randint(0, n_pages * ps - 1, (b,)), jnp.int32)
-    # f32 activations over a bf16 pool: the engine's decode layout, and the
-    # combination the bit-identity contract covers (mixed-dtype dots promote
-    # to f32; all-bf16 dots pick up backend-dependent accumulation).
-    q = jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32)
-    kn = jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32)
-    vn = jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32)
+    hi = min(held or cap, cap) - tq
+    position = jnp.asarray(rng.randint(hi // 2 if held else 0, hi + 1, (b,)),
+                           jnp.int32)
+    # f32 activations over a bf16 pool: the engine's decode layout
+    q = jnp.asarray(rng.randn(b, h, tq, ch), jnp.float32)
+    kn = jnp.asarray(rng.randn(b, h, tq, ch), jnp.float32)
+    vn = jnp.asarray(rng.randn(b, h, tq, ch), jnp.float32)
     case = {"kind": "paged_attn", "b": b, "h": h, "ch": ch, "ps": ps,
-            "n_pages": n_pages}
+            "n_pages": n_pages, "tq": tq,
+            "held_positions": int(position.sum()) + b * tq}
+    if not _INTERP:
+        case["gate"] = ppa.paged_attention_refusal(q, k_pool, table) or "kernel"
+    # the write is the same XLA scatter on both paths: once, outside the A/B
+    k_pool, v_pool = att._paged_write(kn, vn, k_pool, v_pool, table, position)
 
     def gather_ref(q):
-        from mxnet_tpu.ops import attention as att
-
-        return att._paged_gather_mha(q, kn, vn, k_pool, v_pool, table,
-                                     position)[0]
+        return att._paged_gather_read(q, k_pool, v_pool, table, position)
 
     def kernel(q):
-        return ppa.paged_attention(q, kn, vn, k_pool, v_pool, table,
-                                   position, interpret=_INTERP)[0]
+        return ppa.paged_attention_read(q, k_pool, v_pool, table, position,
+                                        interpret=_INTERP)
 
     ref, out = gather_ref(q), kernel(q)
-    err = float(jnp.max(jnp.abs(
-        out.astype(jnp.float32) - ref.astype(jnp.float32))))
-    case["max_err"] = round(err, 5)
-    # bit identity is the interpret-mode contract; Mosaic and XLA need not
-    # round a matmul alike, so on the chip the bound is bf16's
-    case["bit_identical"] = err == 0.0
-    case["correct"] = err < 0.02
+    err = float(jnp.max(jnp.abs(out - ref)))
+    case["max_err"] = round(err, 6)
+    # the two paths sum in another order and a softmax weight may round to
+    # the next bfloat16 (tests/test_pallas_paged_attention.py): outputs of
+    # size about 1 agree to some 1e-3
+    case["correct"] = bool(err < 0.01 and jnp.isfinite(out).all())
     del ref, out
     for label, f in (("kernel", kernel), ("gather", gather_ref)):
         try:
-            case[f"{label}_ms"] = round(_timeit(f, (q,), reps) * 1e3, 3)
+            case[f"{label}_ms"] = round(_timeit(f, (q,), reps) * 1e3, 4)
         except Exception as e:
             case[f"{label}_error"] = repr(e)[:120]
     if "kernel_ms" in case and "gather_ms" in case:
         case["kernel_vs_gather"] = round(case["gather_ms"] / case["kernel_ms"], 2)
+    if "kernel_ms" in case:   # bytes of the positions held, both pools
+        gb = 2 * case["held_positions"] * h * ch * 2 / 1e9
+        case["kernel_gb_per_s"] = round(gb / (case["kernel_ms"] / 1e3), 1)
     return case
 
 
@@ -376,7 +386,8 @@ def run_one(argv):
                                  spec["o"], spec["k"], spec["reps"])
         elif spec["kind"] == "paged_attn":
             case = run_paged_case(spec["b"], spec["h"], spec["ch"],
-                                  spec["ps"], spec["n_pages"], spec["reps"])
+                                  spec["ps"], spec["n_pages"], spec["tq"],
+                                  spec["held"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -414,8 +425,8 @@ def main():
                "k": k, "reps": args.reps}
               for b, c, h, w, o, k in CONV_CASES]
     specs += [{"kind": "paged_attn", "b": b, "h": h, "ch": ch, "ps": ps,
-               "n_pages": np_, "reps": args.reps}
-              for b, h, ch, ps, np_ in PAGED_CASES]
+               "n_pages": np_, "tq": tq, "held": held, "reps": args.reps}
+              for b, h, ch, ps, np_, tq, held in PAGED_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}

@@ -85,8 +85,8 @@ def _paged_attention_case():
     return (jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32),
             jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32),
             jnp.asarray(rng.randn(b, h, 1, ch), jnp.float32),
-            jnp.asarray(rng.randn(pool_pages + 1, h, ps, ch), jnp.bfloat16),
-            jnp.asarray(rng.randn(pool_pages + 1, h, ps, ch), jnp.bfloat16),
+            jnp.asarray(rng.randn(pool_pages + 1, ps, h * ch), jnp.bfloat16),
+            jnp.asarray(rng.randn(pool_pages + 1, ps, h * ch), jnp.bfloat16),
             jnp.asarray(rng.randint(1, pool_pages + 1, (b, n_pages)),
                         jnp.int32),
             jnp.asarray(rng.randint(0, n_pages * ps - 1, (b,)), jnp.int32))

@@ -101,8 +101,8 @@ class AdversarialDraft:
     def init_paged_cache(self, num_pages, page_size, dtype="float32"):
         import jax.numpy as jnp
 
-        return [(jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32),
-                 jnp.zeros((num_pages + 1, 1, page_size, 1), jnp.float32))]
+        return [(jnp.zeros((num_pages + 1, page_size, 1), jnp.float32),
+                 jnp.zeros((num_pages + 1, page_size, 1), jnp.float32))]
 
     def __call__(self, tokens, cache=None, start_pos=None, page_table=None):
         import jax
