@@ -19,26 +19,19 @@
 #             upcasts, remat-defeating live ranges), donation drops vs
 #             mxnet_tpu/analysis/goldens/mem_*.json, plus a
 #             memory_analysis() cross-validation of the estimator
-#   schedcheck - golden-program schedule gate (tools/schedcheck.py):
-#             critical-path latency regressions > 5%, overlap-fraction
-#             drops, newly exposed collectives and exposed-comm-byte
-#             regressions per mesh axis vs
-#             mxnet_tpu/analysis/goldens/sched_*.json
 #   kernelcheck - Pallas kernel correctness gate: CPU interpret-mode
 #             parity/bit-identity suites for every custom kernel (flash
 #             attention, fused layernorm, paged decode attention, fused
 #             Adam, fused softmax-xent), docs/PERFORMANCE.md
 #   profcheck - measured-profiling gate (tools/profcheck.py): traces two
 #             shared golden families for real, asserts non-empty device
-#             op timelines, a predicted/measured calibration table
-#             against the sched goldens, measured overlap next to the
-#             static overlap fraction, and step-time agreement with the
-#             metrics registry
+#             op timelines, measured overlap, and step-time agreement
+#             with the metrics registry
 #   native  - build libmxtpu.so (C++ runtime: recordio/jpeg/runtime/c_api)
 #   fast    - pytest without @slow (target < 10 min on 8 virtual CPU devs)
 #   slow    - the @slow remainder (model compiles, 4-process launches)
 #   ci      - sanity + lint + native + fast + audit + shardcheck +
-#             memcheck + schedcheck + profcheck + kernelcheck +
+#             memcheck + profcheck + kernelcheck +
 #             chaos-elastic + chaos-serve +
 #             chaos-fleet (the pre-merge gate; chaos-elastic is the slow
 #             4-process kill-a-worker drill, chaos-serve the
@@ -56,9 +49,9 @@ PY ?= python
 # 3-attempt retry policy can never see an injected failure twice in a row.
 CHAOS_FAULTS ?= ckpt.save:every=3;ckpt.load:every=3;kv.save_states:every=2;kv.load_states:every=3;kv.dcn_psum:every=4;kv.dcn_psum_batch:every=4;data.batch:every=7;seed=1234
 
-.PHONY: ci sanity lint audit shardcheck memcheck schedcheck profcheck kernelcheck native fast slow test chaos chaos-elastic chaos-serve chaos-fleet obs obsfleet perfwin multichip genbench ampbench bench clean
+.PHONY: ci sanity lint audit shardcheck memcheck profcheck kernelcheck native fast slow test chaos chaos-elastic chaos-serve chaos-fleet obs obsfleet perfwin genbench ampbench bench clean
 
-ci: sanity lint native fast audit shardcheck memcheck schedcheck profcheck kernelcheck chaos-elastic chaos-serve chaos-fleet obsfleet
+ci: sanity lint native fast audit shardcheck memcheck profcheck kernelcheck chaos-elastic chaos-serve chaos-fleet obsfleet
 
 sanity:
 	$(PY) -m compileall -q mxnet_tpu tools tests examples bench.py chip_smoke.py __graft_entry__.py
@@ -94,20 +87,9 @@ shardcheck:
 memcheck:
 	$(PY) tools/memcheck.py
 
-# golden-program schedule gate (docs/ANALYSIS.md "Schedule & overlap"):
-# runs the static critical-path + overlap model over the same program
-# families and diffs critical-path latency, overlap fraction, the
-# exposed-collective census and exposed comm bytes per mesh axis against
-# the committed sched_*.json goldens. Rebless intentional changes with
-# `python tools/schedcheck.py --update-golden`
-schedcheck:
-	$(PY) tools/schedcheck.py
-
 # measured-profiling gate (docs/OBSERVABILITY.md "Measured profiling"):
 # captures real traces of the fsdp step + decode golden families, parses
-# the XPlane timelines, and asserts non-empty op rows, the
-# predicted-vs-measured calibration table (anchored on the committed
-# sched goldens), measured overlap next to ScheduleReport's fraction,
+# the XPlane timelines, and asserts non-empty op rows, measured overlap
 # and measured-vs-registry step-time agreement. The failure path stays
 # tested via `python tools/profcheck.py --inject-empty-trace`
 profcheck:
@@ -194,14 +176,6 @@ obsfleet: native
 # the single-step path; artifact committed as BENCH_r06.json
 perfwin: native
 	$(PY) tools/benchall.py --window 4 --out BENCH_r06.json
-
-# async-collective overlap artifact (docs/PARALLELISM.md "Hiding
-# collective time"): the mesh families priced sync vs through the
-# asyncify pass — per-axis comm bytes + critical-path/overlap deltas;
-# fails unless every family beats the 0.0 sync baseline. Committed as
-# MULTICHIP_r06.json
-multichip: native
-	$(PY) tools/benchall.py --overlap --out MULTICHIP_r06.json
 
 # compiled-generation gates (docs/INFERENCE.md), tiny GPT-2, CPU, median
 # of alternating A/B pairs, identical greedy tokens required everywhere:

@@ -1,7 +1,7 @@
 """Static-analysis subsystem (docs/ANALYSIS.md).
 
 Passes over two different artifacts — program text (the HLO auditor and
-the comm/memory/schedule models layered on its tables) and Python source
+the comm/memory models layered on its tables) and Python source
 (the AST linter):
 
   - :mod:`~mxnet_tpu.analysis.hlo_audit` — structural analysis of the
@@ -42,17 +42,6 @@ from .memory import (  # noqa: F401
     jax_expected_peak,
     memory_report,
 )
-from .schedule import (  # noqa: F401
-    CollectiveSpan,
-    ScheduleReport,
-    SerializationPoint,
-    schedule_report,
-)
-from .overlap import (  # noqa: F401
-    ASYNCABLE_OPS,
-    OverlapStats,
-    asyncify,
-)
 from .comm import (  # noqa: F401
     CollectiveCost,
     CommReport,
@@ -81,9 +70,6 @@ __all__ = [
     "ShardingInfo", "parse_sharding", "ValueDef",
     "MemoryReport", "BufferLife", "Materialization", "memory_report",
     "jax_expected_peak", "VALIDATION_TOLERANCE",
-    "ScheduleReport", "CollectiveSpan", "SerializationPoint",
-    "schedule_report",
-    "ASYNCABLE_OPS", "OverlapStats", "asyncify",
     "CollectiveCost", "CommReport", "Reshard", "comm_report",
     "detect_accidental_reshards",
     "ContractViolation", "check_contract", "expected_tiles",

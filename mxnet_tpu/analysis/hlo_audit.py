@@ -11,10 +11,12 @@ Two text dialects are understood, matching the two stages a jitted program
 passes through:
 
   - **stablehlo** — ``jax.jit(f).lower(...).as_text()``: MLIR, one
-    ``stablehlo.<op>`` per line, donation as ``tf.aliasing_output`` arg
-    attributes. This is *the program XLA is asked to run* — dtype
-    assertions (bf16 dots, no f64 leaks) belong here, because the CPU
-    backend legalizes low-precision GEMMs back to f32 at compile time.
+    ``stablehlo.<op>`` per line, donation as ``tf.aliasing_output`` /
+    ``jax.buffer_donor`` arg attributes, layouts as ``sdy.sharding`` (or,
+    from a jax that still partitions with GSPMD, ``mhlo.sharding``). This
+    is *the program XLA is asked to run* — dtype assertions (bf16 dots, no
+    f64 leaks) belong here, because the CPU backend legalizes
+    low-precision GEMMs back to f32 at compile time.
   - **hlo** — ``...compile().as_text()``: post-optimization HLO, donation
     in the ``input_output_alias`` module header, GSPMD-inserted collectives
     (``all-reduce`` et al. with ``replica_groups``). Collective/fusion/
@@ -31,6 +33,7 @@ See docs/ANALYSIS.md for the schema and a how-to.
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from collections import Counter as _Counter
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -38,7 +41,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 __all__ = ["Op", "Collective", "DonationReport", "ProgramReport",
            "ProgramAudit", "audit_text", "audit_lowered", "audit_compiled",
            "Fingerprint", "fingerprint_diff", "RecompileGuard",
-           "ShardingInfo", "parse_sharding", "ValueDef", "DTYPE_BYTES"]
+           "ShardingInfo", "parse_sharding", "parse_sdy_meshes",
+           "parse_sdy_shardings", "sharding_info", "ValueDef", "DTYPE_BYTES"]
 
 #: element width in bytes per HLO dtype token (pred stored as one byte).
 #: Lives here (not comm.py, which re-exports it) because both the comm
@@ -104,15 +108,19 @@ def _normalize_op(name: str) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class ShardingInfo:
-    """One parsed sharding annotation — the GSPMD layout of a tensor.
+    """One parsed sharding annotation — the layout of a tensor.
 
-    Both spellings normalize here: the lowered dialect's
-    ``mhlo.sharding = "{devices=[4,1,2]<=[2,4]T(1,0) last_tile_dim_replicate}"``
-    arg attribute and the compiled dialect's ``sharding={...}`` parameter
-    attribute. ``tile_dims`` is the number of shards along each *tensor*
-    dimension (the subgroup-replication tile — ``last_tile_dim_replicate``
-    — already stripped), so "is this tensor laid out the way the rules
-    declared" is a per-dim integer comparison, never a device-list diff.
+    Every spelling normalizes here: the lowered dialect's GSPMD attribute
+    ``mhlo.sharding = "{devices=[4,1,2]<=[2,4]T(1,0) last_tile_dim_replicate}"``,
+    its Shardy attribute ``sdy.sharding = #sdy.sharding<@mesh, [{"fsdp"}, {}]>``
+    (axis names a dimension, sized by the module's ``sdy.mesh``; what jax
+    writes since the Shardy partitioner became its default), the compiled
+    dialect's ``sharding={...}`` parameter attribute, and a
+    ``jax.sharding.Sharding`` the compiler hands back. ``tile_dims`` is the
+    number of shards along each *tensor* dimension (the subgroup-replication
+    tile — ``last_tile_dim_replicate`` — already stripped), so "is this
+    tensor laid out the way the rules declared" is a per-dim integer
+    comparison, never a device-list diff.
     """
 
     kind: str  # "replicated" | "tiled" | "maximal" | "manual" | "unknown"
@@ -163,6 +171,71 @@ def parse_sharding(raw: str) -> ShardingInfo:
         return ShardingInfo("tiled", tile_dims=dims, replicate_last=rep_last,
                             raw=raw)
     return ShardingInfo("unknown", raw=raw)
+
+
+def _tiled(tiles: Tuple[int, ...], n_devices: int, raw: str) -> ShardingInfo:
+    """Per-dim shard counts over ``n_devices`` as a :class:`ShardingInfo`."""
+    if all(t == 1 for t in tiles):
+        return ShardingInfo("replicated", raw=raw)
+    return ShardingInfo("tiled", tile_dims=tiles,
+                        replicate_last=math.prod(tiles) < n_devices, raw=raw)
+
+
+# Shardy: `sdy.mesh @mesh = <["dp"=2, "fsdp"=4]>` once a module, then
+# `<@mesh, [{"dp", "fsdp"}, {}]>` wherever a tensor is laid out — inside
+# `#sdy.sharding<...>` on an argument, `#sdy.sharding_per_value<[...]>` on
+# an op, bare on `sdy.sharding_constraint`. A dimension lists the axes it
+# is split over (`"x":(2)4` is a sub-axis of size 4, `?` leaves it open)
+_SDY_MESH = re.compile(r'sdy\.mesh\s+@([\w.$-]+)\s*=\s*<\[([^\]]*)\]')
+_SDY_MESH_AXIS = re.compile(r'"([^"]+)"\s*=\s*(\d+)')
+_SDY_LAYOUT = re.compile(r'<@([\w.$-]+),\s*\[([^\]]*)\]')
+_SDY_DIM = re.compile(r'\{([^}]*)\}')
+_SDY_AXIS_REF = re.compile(r'"([^"]+)"(?::\(\d+\)(\d+))?')
+
+
+def _sdy_tiles(dims: str, axes: Dict[str, int]) -> Optional[Tuple[int, ...]]:
+    """Shards per dimension of one Shardy dimension list, None where it
+    names an axis the mesh does not have."""
+    tiles = []
+    for dim in _SDY_DIM.findall(dims):
+        n = 1
+        for name, sub in _SDY_AXIS_REF.findall(dim):
+            size = int(sub) if sub else axes.get(name)
+            if size is None:
+                return None
+            n *= size
+        tiles.append(n)
+    return tuple(tiles)
+
+
+def parse_sdy_shardings(text: str, meshes: Dict[str, Dict[str, int]]
+                        ) -> List[ShardingInfo]:
+    """Every Shardy tensor layout spelled in ``text``, in order, sized by
+    ``meshes`` (``{mesh name: {axis: size}}``, from :func:`parse_sdy_meshes`).
+    A layout over a mesh or an axis the module does not declare is
+    ``unknown``, never silently replicated."""
+    out = []
+    for m in _SDY_LAYOUT.finditer(text):
+        raw, axes = m.group(0) + ">", meshes.get(m.group(1))
+        tiles = None if axes is None else _sdy_tiles(m.group(2), axes)
+        out.append(ShardingInfo("unknown", raw=raw) if tiles is None
+                   else _tiled(tiles, math.prod(axes.values()), raw))
+    return out
+
+
+def parse_sdy_meshes(text: str) -> Dict[str, Dict[str, int]]:
+    """``{mesh name: {axis: size}}`` of a module's ``sdy.mesh`` lines."""
+    return {m.group(1): {a: int(n) for a, n in
+                         _SDY_MESH_AXIS.findall(m.group(2))}
+            for m in _SDY_MESH.finditer(text)}
+
+
+def sharding_info(sharding, shape: Sequence[int]) -> ShardingInfo:
+    """A ``jax.sharding.Sharding`` (what ``Compiled.input_shardings``
+    holds) laid over a tensor of global ``shape``."""
+    shard = sharding.shard_shape(tuple(shape))
+    return _tiled(tuple(-(-g // l) if l else 1 for g, l in zip(shape, shard)),
+                  len(sharding.device_set), str(sharding))
 
 
 @dataclasses.dataclass
@@ -245,7 +318,9 @@ class DonationReport:
     through to the executable)."""
 
     n_inputs: int
-    aliased: Dict[int, str]  # flat input index -> "may-alias"|"must-alias"
+    # flat input index -> "may-alias" | "must-alias" | "buffer-donor" (the
+    # lowered dialect's donated argument whose output the compiler picks)
+    aliased: Dict[int, str]
     # flat OUTPUT index -> flat input index it aliases (the direction the
     # liveness pass needs: a donated carry's output element costs zero
     # extra bytes because it writes the input's buffer in place)
@@ -271,7 +346,11 @@ class DonationReport:
 
 # -- text parsing ------------------------------------------------------------
 # stablehlo: `%2 = stablehlo.dot_general %0, %1, ...` or `"stablehlo.case"(`
-_MLIR_OP = re.compile(r'"?(?:stablehlo|mhlo|chlo)\.([a-z0-9_]+)"?')
+# (`sdy.sharding_constraint`, `sdy.manual_computation`: Shardy's own ops
+# define values like any other; the look-arounds keep the ATTRIBUTE
+# `sdy.sharding = #sdy.sharding<..>` of a line from reading as its op)
+_MLIR_OP = re.compile(r'"?(?<![#\w.])(?:stablehlo|mhlo|chlo|sdy)\.'
+                      r'([a-z0-9_]+)(?![a-z0-9_])"?(?!\s*=)')
 # HLO: `%name.3 = bf16[4,2]{1,0} op-name(` — result type optional, and may
 # be a TUPLE `(f32[4]{0}, u32[], u32[])` (async collective starts, variadic
 # all-reduces) nesting one level (`((f32[4]{0}), token[])`, infeed)
@@ -291,6 +370,9 @@ _HLO_DTYPES = frozenset({"pred", "s4", "s8", "s16", "s32", "s64", "u4", "u8",
 # `}` and would truncate the capture before tf.aliasing_output
 _MLIR_ARG = re.compile(r"%arg(\d+):\s*tensor<([^>]*)>")
 _MLIR_ALIAS = re.compile(r"tf\.aliasing_output\s*=\s*(\d+)")
+# donated, the output left to the compiler (jax writes this where it cannot
+# pair the argument with a result itself)
+_MLIR_DONOR = re.compile(r"jax\.buffer_donor\s*=\s*true")
 # donation, compiled: input_output_alias={ {0}: (0, {}, may-alias), ... }
 # — the brace key is the OUTPUT tuple index, the first paren int the
 # input. A single-(non-tuple)-output program spells the key `{}` (empty
@@ -326,8 +408,9 @@ _RG_MLIR = re.compile(r"replica_groups\s*=\s*dense<(\[\[.*?\]\]|\d+)>")
 # must never be mistaken for a collective operand/result
 _RG_MLIR_CLAUSE = re.compile(
     r"replica_groups\s*=\s*dense<(?:\[\[.*?\]\]|\d+)>\s*:\s*tensor<[^>]*>")
-# sharding annotations: lowered args/ops carry a quoted mhlo.sharding attr;
-# compiled HLO parameters/ops carry a bare sharding={...} (the negative
+# sharding annotations: lowered args/ops carry a quoted mhlo.sharding attr
+# (GSPMD) or an sdy.sharding one (Shardy: parse_sdy_shardings); compiled
+# HLO parameters/ops carry a bare sharding={...} (the negative
 # lookbehind keeps `mhlo.sharding` and header fields like
 # allow_spmd_sharding_propagation_to_parameters from matching)
 _MLIR_SHARDING = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
@@ -661,10 +744,22 @@ def _parse_stablehlo(text: str) -> ProgramReport:
     fn_outputs: Dict[str, Tuple[str, ...]] = {}
     cur_fn: Optional[str] = None
     lines = text.splitlines()
+    meshes = parse_sdy_meshes(text)
     in_sig = False
     sig_fn: Optional[str] = None
     sig_buf: List[str] = []
     main_sig = ""
+
+    def _sharding_of(s: str) -> Optional[ShardingInfo]:
+        """The one layout an argument's or an op's text carries (several
+        values' layouts on one op stay unknown, as a tuple sharding does)."""
+        m = _MLIR_SHARDING.search(s)
+        if m:
+            return parse_sharding(m.group(1))
+        found = parse_sdy_shardings(s, meshes)
+        if len(found) > 1:
+            return ShardingInfo("unknown", raw=s)
+        return found[0] if found else None
 
     def _close_sig(i: int):
         """Sig buffered to completion: emit parameter ValueDefs for the
@@ -734,7 +829,8 @@ def _parse_stablehlo(text: str) -> ProgramReport:
             if cur_fn is not None:
                 fn_outputs[cur_fn] = tuple(_MLIR_OUT_TOKEN.findall(s))
             continue
-        if not s or s.startswith(("module", "func.func", "}", "^")):
+        if not s or s.startswith(("module", "func.func", "}", "^",
+                                  "sdy.mesh")):
             continue
         name = _mlir_line_op(s)
         if name is None:
@@ -752,8 +848,8 @@ def _parse_stablehlo(text: str) -> ProgramReport:
         rdt, rshape = (tensors[-1] if tensors else (None, ()))
         dtypes = tuple(dt for dt, _ in tensors)
         shapes = tuple(sh for _, sh in tensors)
-        sm = _MLIR_SHARDING.search(s)
-        op_sharding = parse_sharding(sm.group(1)) if sm else None
+        op_sharding = (_sharding_of(s) if name == "sharding_constraint"
+                       or "sharding = " in s else None)
         if name == "custom_call":
             m = re.search(r'call_target_name\s*=\s*"([^"]+)"', s)
             custom_calls.append(m.group(1) if m else "?")
@@ -793,6 +889,9 @@ def _parse_stablehlo(text: str) -> ProgramReport:
                       sharding=op_sharding, dot_meta=meta))
     sig = main_sig
     matches = list(_MLIR_ARG.finditer(sig))
+    args_end = sig.rfind(") ->")
+    if not matches or args_end < matches[-1].end():
+        args_end = len(sig)
     for k, m in enumerate(matches):
         idx = int(m.group(1))
         tdesc = m.group(2)
@@ -809,14 +908,17 @@ def _parse_stablehlo(text: str) -> ProgramReport:
         # opening) — quoted values (mhlo.sharding = "{replicated}") hold
         # braces, so a brace-bounded capture would truncate before
         # tf.aliasing_output
-        end = matches[k + 1].start() if k + 1 < len(matches) else len(sig)
+        # (the last argument's end where the results' attributes begin)
+        end = matches[k + 1].start() if k + 1 < len(matches) else args_end
         am = _MLIR_ALIAS.search(sig, m.end(), end)
         if am:
             aliased[idx] = "may-alias"
             out_alias[int(am.group(1))] = idx
-        shm = _MLIR_SHARDING.search(sig[m.end():end])
-        if shm:
-            arg_shardings[idx] = parse_sharding(shm.group(1))
+        elif _MLIR_DONOR.search(sig, m.end(), end):
+            aliased[idx] = "buffer-donor"
+        sh = _sharding_of(sig[m.end():end])
+        if sh is not None:
+            arg_shardings[idx] = sh
     values = funcs.pop("main", [])
     return ProgramReport(
         dialect="stablehlo", ops=ops, collectives=collectives,
@@ -991,13 +1093,6 @@ class ProgramAudit:
     # buffer-liveness residency estimate (analysis.memory.MemoryReport):
     # peak bytes, timeline, category attribution, materializations
     memory: Optional[object] = None
-    # static schedule model (analysis.schedule.ScheduleReport): critical
-    # path, exposed vs hidden collective time, overlap fraction, MFU bound
-    schedule: Optional[object] = None
-    # what the asyncify pass did (analysis.overlap.OverlapStats): async
-    # start→done pairs created in the audited program, None when the
-    # layout's overlap policy is off (schedule model stays sync)
-    overlap: Optional[object] = None
 
     def carry_donation(self) -> float:
         """Donation coverage of the carry (params/opt-state for TrainStep,
@@ -1022,13 +1117,6 @@ class ProgramAudit:
             out["comm"] = self.comm.summary()
         if self.memory is not None:
             out["memory"] = self.memory.summary()
-        if self.schedule is not None:
-            out["schedule"] = self.schedule.summary()
-        if self.overlap is not None:
-            out["overlap"] = {
-                "async_pairs": self.overlap.async_pairs,
-                "deferred": self.overlap.deferred,
-                "per_computation": dict(self.overlap.per_computation)}
         return out
 
 
@@ -1047,8 +1135,24 @@ def audit_lowered(lowered) -> ProgramReport:
 
 def audit_compiled(compiled) -> ProgramReport:
     """``lowered.compile()`` (or anything with ``as_text``) -> report over
-    the optimized executable (collectives, fusion, donation live here)."""
-    return audit_text(compiled.as_text())
+    the optimized executable (collectives, fusion, donation live here).
+    Where the executable itself says how its inputs are laid out
+    (``jax.stages.Compiled.input_shardings``), that is taken over the
+    text's ``sharding={...}``: it is the same fact without a parser."""
+    report = audit_text(compiled.as_text())
+    shardings = getattr(compiled, "input_shardings", None)
+    if shardings is not None:
+        import jax
+
+        # arguments the compiler dropped hold None and have no parameter
+        kept = [(s, a.shape) for s, a in zip(
+            jax.tree_util.tree_leaves(shardings, is_leaf=lambda x: x is None),
+            jax.tree_util.tree_leaves(compiled.in_avals)) if s is not None]
+        if len(kept) == len(report.inputs):
+            report.arg_shardings = {
+                i: sharding_info(s, shape)
+                for i, (s, shape) in enumerate(kept)}
+    return report
 
 
 # -- program fingerprints & the recompile guard ------------------------------

@@ -58,19 +58,13 @@ _KNOBS: Dict[str, tuple] = {
                          "dkv); off = XLA chunked-recompute backward "
                          "(kernel-free)"),
     "paged_attention_kernel": (bool, True, ("MXNET_TPU_PAGED_ATTENTION_KERNEL",),
-                               "paged decode/verify read path through the "
-                               "Pallas page-table kernel (in-kernel page "
-                               "gather, no pool-wide materialization); off "
-                               "= XLA pool[page_table] gather. On a TPU the "
-                               "kernel's gate admits head sizes of 128n "
-                               "only, so every listed GPT-2 (head 64) "
-                               "decodes through the gather either way. "
-                               "v5e, PR 21: compiles and agrees within "
-                               "0.3% (not bit-identically) at head 128, "
-                               "pages of 8 and 16, bf16 pool, f32 query; "
-                               "Mosaic refuses a bf16 query ('tpu.matmul' "
-                               "op Expected matmul acc to be 32-bit) and "
-                               "the gate now does too"),
+                               "paged attention reads the pools through "
+                               "the Pallas kernel (the pages a row holds) "
+                               "where ops.pallas_paged_attention."
+                               "paged_attention_refusal passes: a TPU, no "
+                               "mesh, float32/bfloat16, heads that fill "
+                               "128-lane tiles (GPT-2's 64 do), page size, "
+                               "VMEM; else, and off, the XLA gather"),
     "fused_adam": (bool, False, ("MXNET_TPU_FUSED_ADAM",),
                    "route Adam/AdamW updates through the fused Pallas "
                    "kernel on TPU (one pass over grad/m/v/master; opt-in. "
@@ -275,26 +269,6 @@ _KNOBS: Dict[str, tuple] = {
     "peak_flops": (float, 0.0, ("MXNET_TPU_PEAK_FLOPS",),
                    "accelerator peak FLOP/s per process for train_mfu "
                    "(e.g. 1.97e14 for one v5e chip); 0 = MFU not computed"),
-    # -- schedule auditor roofline constants (docs/ANALYSIS.md
-    # "Schedule & overlap"); 0/empty = the analysis.schedule defaults
-    # (one v5e chip), sched_peak_flops falls back to peak_flops first ----
-    "sched_peak_flops": (float, 0.0, ("MXNET_TPU_SCHED_PEAK_FLOPS",),
-                         "peak FLOP/s the schedule auditor's roofline "
-                         "prices compute at; 0 = peak_flops, else the "
-                         "v5e default"),
-    "sched_hbm_gbps": (float, 0.0, ("MXNET_TPU_SCHED_HBM_GBPS",),
-                       "HBM bandwidth (GB/s) for the roofline's memory "
-                       "side; 0 = the v5e default"),
-    "sched_ici_gbps": (float, 0.0, ("MXNET_TPU_SCHED_ICI_GBPS",),
-                       "ICI link bandwidth (GB/s) collectives are priced "
-                       "at; 0 = the v5e default"),
-    "sched_dcn_gbps": (float, 0.0, ("MXNET_TPU_SCHED_DCN_GBPS",),
-                       "DCN bandwidth (GB/s) for collectives spanning a "
-                       "sched_dcn_axes axis; 0 = the default"),
-    "sched_dcn_axes": (str, "", ("MXNET_TPU_SCHED_DCN_AXES",),
-                       "comma-separated mesh axes priced at DCN speed by "
-                       "the schedule auditor (e.g. 'dp' on a multi-pod "
-                       "fleet); empty = every collective rides ICI"),
 }
 
 _values: Dict[str, Any] = {}

@@ -1546,10 +1546,7 @@ class GenerationEngine:
         program's own temporaries under ``activations`` /
         ``draft_temp`` / ``verify_temp`` — including the
         ``kv_gather_materialize`` detector for the paged decode's XLA
-        gather of the pool (docs/ANALYSIS.md). ``audit(...).schedule``
-        is the static schedule model (critical-path latency, overlap,
-        MFU bound — serving programs are collective-free by contract, so
-        its exposed-comm census must stay empty)."""
+        gather of the pool (docs/ANALYSIS.md)."""
         from .. import analysis as _analysis
 
         params = self._params()
@@ -1668,18 +1665,13 @@ class GenerationEngine:
             default_cat = "activations"
         memory = _analysis.memory_report(rep, categories=mem_cats,
                                          default_category=default_cat)
-        # static schedule model over the same (scheduled) report: serving
-        # programs are mesh-less today so comm time is zero by contract —
-        # the critical path and MFU bound still price the decode step
-        schedule = _analysis.schedule_report(rep, comm=comm)
         return _analysis.ProgramAudit(
             lowered=lowered_rep, compiled=compiled_rep,
             carry_indices=tuple(range(n_pre, n_pre + n_carry)),
-            comm=comm, memory=memory, schedule=schedule)
+            comm=comm, memory=memory)
 
     def profile(self, prompt=None, steps: int = 8, warmup: int = 2,
-                trace_dir: Optional[str] = None, calibrate: bool = True,
-                band: float = 3.0):
+                trace_dir: Optional[str] = None):
         """Trace ``steps`` REAL decode steps (speculative rounds on a
         speculative engine) and return the
         :class:`~mxnet_tpu.observability.profiling.Capture` — the
@@ -1690,11 +1682,7 @@ class GenerationEngine:
         the program continuous batching dispatches. ``prompt`` (default
         a short synthetic one) is prefilled into slot 0 first, outside
         the traced window, so the decode has a live row to extend; the
-        slot is released afterwards.
-
-        With ``calibrate=True`` the capture carries per-op-class
-        predicted/measured ratios against :meth:`audit`'s schedule model
-        of the same decode program."""
+        slot is released afterwards."""
         from ..observability import profiling as _profiling
 
         if prompt is None:
@@ -1702,15 +1690,10 @@ class GenerationEngine:
         self.prefill(prompt, slot=0)
         fn = self.spec_step if self.speculative else self.decode_step
         try:
-            cap = _profiling.capture(fn, steps=steps, warmup=warmup,
-                                     trace_dir=trace_dir)
+            return _profiling.capture(fn, steps=steps, warmup=warmup,
+                                      trace_dir=trace_dir)
         finally:
             self.release_slot(0)
-        if calibrate:
-            cap.schedule = self.audit().schedule
-            cap.calibration = _profiling.calibrate(cap.schedule, cap.report,
-                                                   band=band)
-        return cap
 
     def fork_slot(self, src: int, dst: int,
                   resample_first: bool = False) -> int:

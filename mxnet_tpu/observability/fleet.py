@@ -366,11 +366,6 @@ class RankStats:
     tokens_per_sec: Optional[float] = None
     flops_per_step: Optional[float] = None
     mfu: Optional[float] = None
-    # the schedule auditor's static bound + exposed-comm share
-    # (train_mfu_bound / train_comm_exposed_share gauges, set by
-    # TrainStep.audit — docs/ANALYSIS.md "Schedule & overlap")
-    mfu_bound: Optional[float] = None
-    comm_exposed_share: Optional[float] = None
     last_ts: Optional[float] = None
     # serving-replica self-report (mxnet_tpu.serving.replica publishes
     # replica_* series through the same rank-dir transport; None for a
@@ -386,8 +381,6 @@ class RankStats:
                 "queue_depths": dict(self.queue_depths),
                 "tokens_per_sec": self.tokens_per_sec,
                 "flops_per_step": self.flops_per_step, "mfu": self.mfu,
-                "mfu_bound": self.mfu_bound,
-                "comm_exposed_share": self.comm_exposed_share,
                 "replica": self.replica,
                 "last_ts": self.last_ts}
 
@@ -781,10 +774,7 @@ class FleetAggregator:
                 stats.queue_depths[key] = float(s["value"])
         for name, attr in (("train_tokens_per_sec", "tokens_per_sec"),
                            ("train_model_flops_per_step", "flops_per_step"),
-                           ("train_mfu", "mfu"),
-                           ("train_mfu_bound", "mfu_bound"),
-                           ("train_comm_exposed_share",
-                            "comm_exposed_share")):
+                           ("train_mfu", "mfu")):
             for s in series(name):
                 setattr(stats, attr, float(s["value"]))
         for name, key in _REPLICA_SERIES:

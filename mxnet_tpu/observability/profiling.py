@@ -1,12 +1,9 @@
-"""Measured profiling: trace capture, XPlane timelines, calibration
+"""Measured profiling: trace capture and XPlane timelines
 (docs/OBSERVABILITY.md "Measured profiling").
 
-The analysis subsystem *predicts* cost — liveness peaks
-(:mod:`~mxnet_tpu.analysis.memory`), roofline critical paths and overlap
-(:mod:`~mxnet_tpu.analysis.schedule`) — but predictions pinned by goldens
-drift silently unless something measures what actually executes. This
-module is the measured half (the roofline-vs-measured methodology of
-arXiv:2301.13062; TVM's measured-cost feedback loop, arXiv:1802.04799):
+The analysis subsystem reads what a program holds (collectives, donation,
+liveness peaks: :mod:`~mxnet_tpu.analysis`); this module measures what a
+program that ran took:
 
   - :func:`capture` — programmatic windowed trace capture:
     ``capture(fn, steps=K)`` wraps ``jax.profiler.start_trace`` /
@@ -23,14 +20,7 @@ arXiv:2301.13062; TVM's measured-cost feedback loop, arXiv:1802.04799):
     measured step time + per-span breakdowns correlated to step ids
     through the ``obs.span`` TraceAnnotations, and measured
     compute/collective overlap (interval union of collective rows vs
-    concurrent compute) comparable 1:1 to
-    ``ScheduleReport.overlap_fraction``;
-  - :func:`calibrate` — per-op-class predicted/measured ratios against a
-    :class:`~mxnet_tpu.analysis.schedule.ScheduleReport`. Ratios are
-    normalized by the whole-program ratio, so a uniformly-slower host
-    (CPU CI) calibrates cleanly while a *class* drifting against its
-    peers flags the matching ``MXNET_TPU_SCHED_*`` roofline constant —
-    instead of letting the schedcheck goldens diverge from reality;
+    concurrent compute);
   - :class:`CaptureController` — live-loop wiring: periodic capture
     every ``MXNET_TPU_PROF_EVERY_N_STEPS`` steps, straggler-triggered
     capture (the fleet aggregator drops a ``prof-request-h{rank}.json``
@@ -62,7 +52,6 @@ __all__ = ["TraceEvent", "TraceLine", "TracePlane", "Timeline",
            "parse_xplane_bytes", "parse_trace", "encode_xplane",
            "OpRow", "SpanRow", "MeasuredReport", "measured_report",
            "Capture", "capture", "op_class",
-           "CalibrationRow", "CalibrationReport", "calibrate",
            "CaptureController", "step_capture_begin", "step_capture_end",
            "latest_profile", "PROF_STEP_SPAN"]
 
@@ -416,7 +405,7 @@ def encode_xplane(planes: Sequence[dict]) -> bytes:
     return space
 
 
-# -- op classification (shared with analysis.schedule's per-class fold) -------
+# -- op classification --------------------------------------------------------
 _COLLECTIVE_CLASSES = {
     "all-reduce": "all_reduce", "all_reduce": "all_reduce",
     "all-gather": "all_gather", "all_gather": "all_gather",
@@ -441,8 +430,8 @@ _CLASS_OF = {
 def op_class(name: str) -> str:
     """Map an op/instruction name (either an HLO instruction like
     ``dot.3`` / ``all-reduce-start.1`` from a trace row, or a normalized
-    op from the static auditors like ``all_reduce``) onto the small class
-    vocabulary calibration compares across: ``dot`` / ``conv`` /
+    op from the static auditors like ``all_reduce``) onto a small class
+    vocabulary: ``dot`` / ``conv`` /
     ``fusion`` / one class per collective kind / ``custom_call`` /
     ``copy`` / ``other``."""
     base = name.split(".", 1)[0].strip().lower()
@@ -653,8 +642,7 @@ class MeasuredReport:
         with the union of concurrent compute-row intervals — hidden time
         is collective time during which that device was also computing.
         Sync collectives serialized on the compute lane intersect
-        nothing and read fully exposed, matching the schedule model's
-        sync rule."""
+        nothing and read fully exposed."""
         coll_s = hid_s = comp_s = 0.0
         by_dev: Dict[str, Tuple[list, list]] = {}
         for r in self.op_rows:
@@ -671,17 +659,15 @@ class MeasuredReport:
 
     @property
     def overlap_fraction(self) -> float:
-        """Hidden / total collective seconds — directly comparable to
-        ``ScheduleReport.overlap_fraction`` (a collective-free trace
-        counts as fully hidden, same convention)."""
+        """Hidden / total collective seconds (a collective-free trace
+        counts as fully hidden)."""
         coll, hid, _ = self.overlap()
         if coll <= 0:
             return 1.0
         return hid / coll
 
     def class_seconds(self) -> Dict[str, float]:
-        """Total self seconds per op class — the measured side of
-        :func:`calibrate`."""
+        """Total self seconds per op class."""
         out: Dict[str, float] = {}
         for r, sns in zip(self.op_rows, self._self_times()):
             cls = r.op_class
@@ -835,19 +821,11 @@ class Capture:
     seconds: float                 # wall clock of the traced window
     steps: int
     trigger: str = "api"
-    calibration: Optional["CalibrationReport"] = None
-    # the ScheduleReport calibration was computed against (set by the
-    # profile() entry points; not serialized) — consumers get the
-    # predicted side without re-auditing the program
-    schedule: Optional[object] = None
 
     def summary(self) -> dict:
-        out = {"trace_dir": self.trace_dir, "run_dir": self.run_dir,
-               "seconds": round(self.seconds, 6), "steps": self.steps,
-               "trigger": self.trigger, "report": self.report.summary()}
-        if self.calibration is not None:
-            out["calibration"] = self.calibration.summary()
-        return out
+        return {"trace_dir": self.trace_dir, "run_dir": self.run_dir,
+                "seconds": round(self.seconds, 6), "steps": self.steps,
+                "trigger": self.trigger, "report": self.report.summary()}
 
 
 def capture(fn, *args, steps: int = 2, warmup: int = 1,
@@ -949,137 +927,6 @@ def latest_profile(directory: str) -> Optional[dict]:
         except (OSError, ValueError):
             continue
     return None
-
-
-# -- calibration --------------------------------------------------------------
-@dataclasses.dataclass
-class CalibrationRow:
-    """One op class's predicted-vs-measured comparison."""
-
-    op_class: str
-    predicted_seconds: float
-    measured_seconds: float
-    ratio: Optional[float]        # predicted / measured
-    normalized: Optional[float]   # ratio / whole-program ratio
-    drift: bool = False
-
-    def describe(self) -> str:
-        r = f"{self.ratio:.3e}" if self.ratio is not None else "-"
-        nrm = f"{self.normalized:.2f}" if self.normalized is not None else "-"
-        flag = "  << DRIFT" if self.drift else ""
-        return (f"{self.op_class:<20} pred {self.predicted_seconds:.3e}s  "
-                f"meas {self.measured_seconds:.3e}s  ratio {r}  "
-                f"norm {nrm}{flag}")
-
-
-#: which roofline knob a drifting class points at
-_DRIFT_KNOB = {
-    "dot": "MXNET_TPU_SCHED_PEAK_FLOPS",
-    "conv": "MXNET_TPU_SCHED_PEAK_FLOPS",
-    "fusion": "MXNET_TPU_SCHED_HBM_GBPS",
-    "other": "MXNET_TPU_SCHED_HBM_GBPS",
-    "copy": "MXNET_TPU_SCHED_HBM_GBPS",
-    "custom_call": "MXNET_TPU_SCHED_HBM_GBPS",
-}
-
-
-def _knob_for(cls: str) -> str:
-    if is_collective_class(cls):
-        return "MXNET_TPU_SCHED_ICI_GBPS/MXNET_TPU_SCHED_DCN_GBPS"
-    return _DRIFT_KNOB.get(cls, "MXNET_TPU_SCHED_HBM_GBPS")
-
-
-@dataclasses.dataclass
-class CalibrationReport:
-    """Predicted (static schedule) vs measured (trace) per op class.
-
-    ``overall_ratio`` is the MEDIAN per-class predicted/measured ratio
-    over classes present on both sides (median, so one drifting class
-    cannot drag the baseline it is judged against); per-class ratios
-    are reported raw AND normalized by it. The normalization is what
-    makes the comparison portable: on CPU CI everything is uniformly
-    ~1000× slower than the v5e roofline, but the *relative* balance
-    between classes still validates the constants. A class whose
-    normalized ratio leaves ``[1/band, band]`` is flagged as
-    roofline-constant drift with the ``MXNET_TPU_SCHED_*`` knob it
-    points at."""
-
-    rows: List[CalibrationRow]
-    overall_ratio: Optional[float]
-    predicted_step_seconds: float   # schedule critical path
-    measured_step_seconds: Optional[float]
-    predicted_overlap: float
-    measured_overlap: float
-    band: float
-    drifting: List[dict] = dataclasses.field(default_factory=list)
-
-    def summary(self) -> dict:
-        return {
-            "rows": [{"op_class": r.op_class,
-                      "predicted_seconds": r.predicted_seconds,
-                      "measured_seconds": r.measured_seconds,
-                      "ratio": r.ratio, "normalized": r.normalized,
-                      "drift": r.drift} for r in self.rows],
-            "overall_ratio": self.overall_ratio,
-            "predicted_step_seconds": self.predicted_step_seconds,
-            "measured_step_seconds": self.measured_step_seconds,
-            "predicted_overlap": round(self.predicted_overlap, 6),
-            "measured_overlap": round(self.measured_overlap, 6),
-            "band": self.band,
-            "drifting": list(self.drifting),
-        }
-
-
-def calibrate(schedule, measured: MeasuredReport,
-              steps: Optional[int] = None, band: float = 3.0,
-              emit: bool = True) -> CalibrationReport:
-    """Compare a :class:`~mxnet_tpu.analysis.schedule.ScheduleReport`'s
-    per-op-class roofline seconds against a trace's measured class
-    seconds (per step — ``steps`` defaults to the capture's ``prof_step``
-    window count). A class whose normalized predicted/measured ratio
-    falls outside ``[1/band, band]`` is flagged; with ``emit=True`` each
-    flag lands in the event log as a ``calibration_drift`` event naming
-    the roofline knob to re-tune — the measured guardrail under the
-    ``make schedcheck`` goldens."""
-    if steps is None:
-        steps = len(measured.step_rows()) or 1
-    pred = dict(getattr(schedule, "op_class_seconds", {}) or {})
-    meas = {k: v / steps for k, v in measured.class_seconds().items()}
-    shared = [c for c in pred if pred[c] > 0 and meas.get(c, 0.0) > 0]
-    ratios = sorted(pred[c] / meas[c] for c in shared)
-    n = len(ratios)
-    overall = None
-    if n:  # median ratio: one drifting class can't drag its own baseline
-        overall = ratios[n // 2] if n % 2 \
-            else (ratios[n // 2 - 1] + ratios[n // 2]) / 2
-    rows: List[CalibrationRow] = []
-    drifting: List[dict] = []
-    for cls in sorted(set(pred) | set(meas)):
-        p = pred.get(cls, 0.0)
-        m = meas.get(cls, 0.0)
-        ratio = (p / m) if m > 0 else None
-        norm = (ratio / overall) if (ratio is not None and overall) else None
-        drift = norm is not None and not (1.0 / band <= norm <= band)
-        rows.append(CalibrationRow(op_class=cls, predicted_seconds=p,
-                                   measured_seconds=m, ratio=ratio,
-                                   normalized=norm, drift=drift))
-        if drift:
-            finding = {"op_class": cls, "normalized_ratio": round(norm, 4),
-                       "predicted_seconds": p, "measured_seconds": m,
-                       "knob": _knob_for(cls)}
-            drifting.append(finding)
-            if emit:
-                _events.LOG.emit("calibration_drift", band=band, **finding)
-    steps_meas = measured.step_seconds()
-    return CalibrationReport(
-        rows=rows, overall_ratio=overall,
-        predicted_step_seconds=getattr(schedule, "critical_path_seconds",
-                                       0.0),
-        measured_step_seconds=(sum(steps_meas) / len(steps_meas)
-                               if steps_meas else None),
-        predicted_overlap=getattr(schedule, "overlap_fraction", 0.0),
-        measured_overlap=measured.overlap_fraction,
-        band=band, drifting=drifting)
 
 
 # -- live-loop wiring (periodic + straggler-triggered capture) ----------------

@@ -110,7 +110,7 @@ class TrainStep:
                 layout = None  # mesh outside the AXES vocabulary
         self.layout = layout
         # async gradient-collective overlap (layout policy): bucketed
-        # barrier hints in the program + the asyncify schedule model
+        # barrier hints in the program
         self._overlap_on = bool(layout is not None and layout.overlap
                                 and mesh is not None)
         self.donate = donate
@@ -322,10 +322,9 @@ class TrainStep:
         values but adds a scheduling edge: a bucket's optimizer update
         cannot be hoisted before the next bucket's gradients exist, so a
         latency-hiding backend keeps each bucket's reduce-scatter/
-        all-reduce in flight while later backprop still computes —
-        exactly the start→done deferral the schedule auditor's asyncify
-        pass models. (XLA's CPU backend expands the barrier away after
-        SPMD partitioning; on TPU it constrains the scheduler.)"""
+        all-reduce in flight while later backprop still computes.
+        (XLA's CPU backend expands the barrier away after SPMD
+        partitioning; on TPU it constrains the scheduler.)"""
         if not self._overlap_on or len(grads) < 2:
             return grads
         names = sorted(grads)
@@ -1318,16 +1317,7 @@ class TrainStep:
         attribution — ``params`` / ``opt_state`` leaves of the carry,
         ``batch`` for the data inputs, everything the program
         materializes under ``activations`` (``make memcheck`` gates
-        these per program family).
-
-        ``audit.schedule`` is the static schedule model
-        (:class:`~mxnet_tpu.analysis.ScheduleReport`): critical-path
-        latency lower bound, per-axis exposed vs hidden collective time,
-        overlap fraction, top serialization points and a static MFU
-        upper bound — exported as the ``train_mfu_bound`` /
-        ``train_comm_exposed_share`` gauges so fleet observability can
-        print achieved MFU next to what the schedule permits
-        (``make schedcheck`` gates these per program family)."""
+        these per program family)."""
         from .. import analysis as _analysis
 
         if window:
@@ -1377,29 +1367,14 @@ class TrainStep:
             # that crept into a single-device program is still priced
             comm = _analysis.comm_report(
                 compiled_rep if compiled_rep is not None else lowered_rep)
-        # schedule truth follows the same precedence as memory: the
-        # compiled executable is scheduled text (async pairs, fusions);
-        # comm= reuses the pricing just computed over the same report.
-        # Under the layout's overlap policy the asyncify pass first
-        # derives the async view — literal start→done pairs with
-        # independent compute list-scheduled into each span — modeling
-        # the TPU latency-hiding scheduler the CPU audit backend lacks
-        # (docs/PARALLELISM.md "Hiding collective time")
-        sched_src, overlap_info = mem_rep, None
-        if self._overlap_on:
-            sched_src, overlap_info = _analysis.asyncify(mem_rep)
-        schedule = _analysis.schedule_report(sched_src, self.mesh, comm=comm)
-        self._record_schedule_bound(schedule)
         return _analysis.ProgramAudit(
             lowered=lowered_rep, compiled=compiled_rep,
             carry_indices=tuple(range(n_carry)),
-            contract=contract, comm=comm, memory=memory,
-            schedule=schedule, overlap=overlap_info)
+            contract=contract, comm=comm, memory=memory)
 
     def profile(self, *batch, steps: int = 2, warmup: int = 1,
                 window: Optional[int] = None, accum: int = 1,
-                trace_dir: Optional[str] = None, calibrate: bool = True,
-                band: float = 3.0):
+                trace_dir: Optional[str] = None):
         """Trace ``steps`` REAL training steps of this batch signature
         (after ``warmup`` untraced ones) and return the
         :class:`~mxnet_tpu.observability.profiling.Capture` — measured
@@ -1408,15 +1383,8 @@ class TrainStep:
         profiling"). The dispatch goes through ``__call__``/``run``'s own
         jit cache, so the traced program IS the production program — and
         the profiled steps advance the training state exactly like any
-        other steps.
-
-        With ``calibrate=True`` (default) the capture also carries a
-        :class:`~mxnet_tpu.observability.profiling.CalibrationReport`:
-        per-op-class predicted/measured ratios against this program's
-        :meth:`audit` schedule model, flagging roofline-constant drift
-        (``MXNET_TPU_SCHED_*``). ``window=`` profiles the fused k-step
-        scan program instead of the single step (one traced dispatch per
-        window)."""
+        other steps. ``window=`` profiles the fused k-step scan program
+        instead of the single step (one traced dispatch per window)."""
         if window:
             raws = tuple(b._data if isinstance(b, NDArray)
                          else jnp.asarray(b) for b in batch)
@@ -1438,26 +1406,5 @@ class TrainStep:
             self.flush_telemetry()
             return out
 
-        cap = _profiling.capture(fn, steps=steps, warmup=warmup,
-                                 trace_dir=trace_dir)
-        if calibrate:
-            cap.schedule = self.audit(*batch, window=window,
-                                      accum=accum).schedule
-            cap.calibration = _profiling.calibrate(cap.schedule, cap.report,
-                                                   band=band)
-        return cap
-
-    def _record_schedule_bound(self, schedule) -> None:
-        """Export the schedule auditor's static bound next to the live
-        ``train_mfu`` gauge (docs/OBSERVABILITY.md): the fleet report
-        prints achieved MFU against what the compiled schedule permits,
-        and how much collective time is exposed on the critical path."""
-        _obs.gauge("train_mfu_bound",
-                   "static MFU upper bound from the schedule auditor's "
-                   "critical-path model").set(schedule.mfu_bound)
-        share = (schedule.exposed_comm_seconds
-                 / schedule.critical_path_seconds
-                 if schedule.critical_path_seconds > 0 else 0.0)
-        _obs.gauge("train_comm_exposed_share",
-                   "exposed collective seconds / critical-path seconds "
-                   "(schedule auditor)").set(share)
+        return _profiling.capture(fn, steps=steps, warmup=warmup,
+                                  trace_dir=trace_dir)

@@ -29,6 +29,20 @@ def natkey(item):
             for t in re.split(r"(\d+)", item[0])]
 
 
+def load_tool(name):
+    """A script of ``tools/`` (no package) as a module of its own; the
+    golden families' shared instance is ``load_tool("families").load()``."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        f"tools_{name}_for_tests",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def pytest_configure(config):
     # chaos marker (resilience subsystem): tests that *arm* fault injection
     # themselves, as opposed to the `make chaos` pass which arms

@@ -41,7 +41,11 @@ def _batches(k, b=4, seed=123, scale=1.0):
 
 
 def _params(ts):
-    return [np.asarray(v) for _, v in sorted(ts.params.items())]
+    # natural sort: block counters are process-global, and two nets built
+    # one after the other can straddle a digit (dense9, dense10)
+    from conftest import natkey
+
+    return [np.asarray(v) for _, v in sorted(ts.params.items(), key=natkey)]
 
 
 def _tiny_gpt2_step(remat=None, amp=None, optimizer=None, seed=0, **cfg):

@@ -88,10 +88,20 @@ def test_report_collectives_replica_groups():
     assert len(rep.replica_group_specs()) == 1
 
 
+def _sharding_dialect(text):
+    """The attribute the installed jax lays tensors out with: Shardy's
+    ``sdy.sharding`` or GSPMD's ``mhlo.sharding``. Each parser is also held
+    to a committed text of the OTHER dialect (``_LOWERED_FIXTURES``)."""
+    found = [d for d in ("sdy.sharding", "mhlo.sharding") if d in text]
+    assert len(found) == 1, found
+    return found[0]
+
+
 def test_stablehlo_donation_survives_sharding_attrs():
-    """Arg attrs like ``mhlo.sharding = "{replicated}"`` hold a ``}``
-    inside a quoted value — the lowered-dialect alias scan must not stop
-    there and drop tf.aliasing_output (the compile=False audit path)."""
+    """Arg attrs like ``mhlo.sharding = "{replicated}"`` or ``sdy.sharding =
+    #sdy.sharding<@mesh, [{}]>`` hold a ``}`` inside their value — the
+    lowered-dialect alias scan must not stop there and drop
+    tf.aliasing_output (the compile=False audit path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from mxnet_tpu.parallel import MeshConfig, make_mesh
 
@@ -106,9 +116,64 @@ def test_stablehlo_donation_survives_sharding_attrs():
                       out_shardings=NamedSharding(mesh, P())).lower(
         jnp.ones((4,)), jnp.ones((8, 4)))
     rep = analysis.audit_lowered(lowered)
-    assert "mhlo.sharding" in lowered.as_text()  # the trap is present
+    _sharding_dialect(lowered.as_text())  # the trap is present
     assert rep.donation.aliased == {0: "may-alias"}
     assert rep.donation.coverage([0]) == 1.0
+
+
+# jax's own lowering of one function (a donated replicated vector, a matrix
+# split over both mesh axes and constrained to one inside) under the Shardy
+# and under the GSPMD partitioner, mesh dp=2 x fsdp=4; a third argument was
+# added by hand to both: a buffer donor split over fsdp, the LAST argument,
+# so that its attributes end where the results' begin
+_LOWERED_FIXTURES = ("lowered_sdy.mlir", "lowered_mhlo.mlir")
+
+
+@pytest.fixture(params=_LOWERED_FIXTURES)
+def lowered_fixture(request):
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures", request.param)
+    with open(path) as f:
+        return analysis.audit_text(f.read())
+
+
+def test_lowered_fixture_donation_in_either_sharding_dialect(lowered_fixture):
+    """``tf.aliasing_output`` and ``jax.buffer_donor`` both survive the
+    layout attributes beside them, whichever partitioner wrote the text."""
+    don = lowered_fixture.donation
+    assert don.aliased == {0: "may-alias", 2: "buffer-donor"}
+    assert don.out_alias == {0: 0}
+    assert don.coverage([0, 2]) == 1.0 and don.missing([0, 1, 2]) == [1]
+
+
+def test_lowered_fixture_layouts_in_either_sharding_dialect(lowered_fixture):
+    """Arguments and the constraint inside read the same from either
+    dialect; the last argument's layout is its own, not a result's."""
+    rep = lowered_fixture
+    assert rep.inputs == [("f32", (4,)), ("f32", (8, 4)), ("f32", (4,))]
+    assert rep.arg_sharding(0).is_replicated
+    assert rep.arg_sharding(1).tile_dims == (8, 1)
+    assert not rep.arg_sharding(1).replicate_last
+    assert rep.arg_sharding(2).tile_dims == (4,)
+    assert rep.arg_sharding(2).replicate_last
+    assert rep.sharded_inputs() == [1, 2]
+    inner = [o.sharding for o in rep.ops if o.sharding is not None]
+    assert [(s.tile_dims, s.replicate_last) for s in inner] == [((4, 1), True)]
+    # the value the constraint defines is in the value table under either
+    # spelling (a custom_call @Sharding or Shardy's own op)
+    assert any(v.vid == "2" and v.bytes == 8 * 4 * 4 for v in rep.values)
+
+
+def test_sdy_layout_over_an_undeclared_mesh_or_axis_is_unknown():
+    from mxnet_tpu.analysis.hlo_audit import (parse_sdy_meshes,
+                                              parse_sdy_shardings)
+
+    meshes = parse_sdy_meshes('sdy.mesh @mesh = <["dp"=2, "fsdp"=4]>')
+    assert meshes == {"mesh": {"dp": 2, "fsdp": 4}}
+    kinds = [s.kind for s in parse_sdy_shardings(
+        '<@mesh, [{"tp"}, {}]> <@other, [{}]> <@mesh, [{"fsdp":(1)2, ?}, {}]>'
+        ' <@mesh, []>', meshes)]
+    assert kinds == ["unknown", "unknown", "tiled", "replicated"]
 
 
 def test_async_collective_pair_counts_once():
@@ -390,8 +455,37 @@ def test_hlo_parameter_shardings_parsed():
     assert rep.summary()["sharded_inputs"] == 1
 
 
+def test_compiled_input_layouts_come_from_the_executable():
+    """``audit_compiled`` takes ``Compiled.input_shardings`` over the text's
+    ``sharding={...}``, keyed like the text's parameters: an argument the
+    compiler dropped has neither, and the text's own answer is the same."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(dp=2, fsdp=4))
+
+    def f(p, unused, x):
+        return p * x.sum()
+
+    compiled = jax.jit(
+        f, in_shardings=(NamedSharding(mesh, P()), None,
+                         NamedSharding(mesh, P("fsdp", "dp"))),
+        out_shardings=NamedSharding(mesh, P())).lower(
+            jnp.ones((4,)), jnp.ones((3,)), jnp.ones((8, 4))).compile()
+    rep = analysis.audit_compiled(compiled)
+    text = analysis.audit_text(compiled.as_text())
+    assert len(rep.inputs) == 2
+    assert rep.arg_sharding(0).is_replicated
+    assert rep.arg_sharding(1).tile_dims == (4, 2)
+    assert "NamedSharding" in rep.arg_sharding(1).raw
+    assert text.arg_sharding(1).tile_dims == (4, 2)
+    assert "devices=" in text.arg_sharding(1).raw
+    assert rep.sharded_inputs() == text.sharded_inputs() == [1]
+
+
 def test_stablehlo_arg_and_op_shardings_parsed():
-    """Lowered-dialect mhlo.sharding attrs: per-arg annotations on a live
+    """Lowered-dialect layout attrs (``sdy.sharding`` or ``mhlo.sharding``,
+    whichever the installed jax writes): per-arg annotations on a live
     mesh lowering parse into arg_shardings (and per-op attrs onto Op)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from mxnet_tpu.parallel import MeshConfig, make_mesh
@@ -407,7 +501,7 @@ def test_stablehlo_arg_and_op_shardings_parsed():
         out_shardings=NamedSharding(mesh, P())).lower(
             jnp.ones((4,)), jnp.ones((8, 4)))
     rep = analysis.audit_lowered(lowered)
-    assert "mhlo.sharding" in lowered.as_text()
+    _sharding_dialect(lowered.as_text())
     assert rep.arg_sharding(0) is not None
     assert rep.arg_sharding(0).is_replicated
     assert rep.arg_sharding(1) is not None

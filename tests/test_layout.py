@@ -162,10 +162,35 @@ def test_layout_equivalence_trainstep_window_prefetch():
             [i for i in a2.lowered.inputs]
         assert a1.compiled.op_census() == a2.compiled.op_census()
         # overlap policy defaults on through either construction path
-        assert a1.overlap is not None and a1.overlap.async_pairs > 0
-        assert a1.schedule.overlap_fraction > 0
-        assert a1.schedule.overlap_fraction == \
-            pytest.approx(a2.schedule.overlap_fraction)
+        assert a1.lowered.count("optimization_barrier") == \
+            a2.lowered.count("optimization_barrier") > 0
+
+
+def test_overlap_policy_is_in_the_program_and_changes_no_result():
+    """What the program shows of ``Layout(overlap=)``: with it the lowered
+    step chains each gradient bucket behind the next one's with
+    ``optimization_barrier`` (four parameters in four buckets: three),
+    without it there is none, and the barrier being the identity on
+    values, the two steps train alike."""
+    loss = lambda out, *l: ((out - l[0]) ** 2).mean()  # noqa: E731
+    steps = {}
+    for overlap in (True, False):
+        net, x, y = _tiny_net()
+        ts = TrainStep(net, loss, opt.Adam(learning_rate=1e-3),
+                       layout=Layout(dp=2, fsdp=4, fsdp_axis="fsdp",
+                                     min_fsdp_size=1, overlap=overlap,
+                                     overlap_buckets=4))
+        barriers = ts.audit(x, y, compile=False).lowered.count(
+            "optimization_barrier")
+        losses = [float(ts(x, y)) for _ in range(3)]
+        ts.sync()
+        steps[overlap] = (barriers, losses, {
+            k: v.data().asnumpy() for k, v in net.collect_params().items()})
+    assert steps[True][0] == 3 and steps[False][0] == 0
+    assert steps[True][1] == steps[False][1]
+    for (_, a), (_, b) in zip(sorted(steps[True][2].items()),
+                              sorted(steps[False][2].items())):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_trainer_run_cache_keys_on_canonical_layout():

@@ -8,22 +8,19 @@ Runs tools/memcheck.py in-process (importlib) so each case can pick one
 cheap program family and capture the JSON verdict without a subprocess
 per family.
 """
-import importlib.util
 import json
-import os
 
 import pytest
+from conftest import load_tool
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the families' names are plain data of tools/families.py: importing it
+# builds and traces nothing
+FAMILIES = load_tool("families").FAMILY_NAMES
 
 
 @pytest.fixture(scope="module")
 def memcheck():
-    spec = importlib.util.spec_from_file_location(
-        "memcheck_mod", os.path.join(REPO, "tools", "memcheck.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_tool("memcheck")
 
 
 def _verdict(capsys):
@@ -32,20 +29,23 @@ def _verdict(capsys):
     return row, out
 
 
-def test_gate_matches_committed_goldens(memcheck, capsys):
-    """ISSUE 12 acceptance: the committed goldens describe the current
-    programs — peak residency within tolerance, donation intact, no new
+@pytest.mark.parametrize("family", FAMILIES)
+def test_gate_matches_committed_goldens(memcheck, capsys, family):
+    """ISSUE 12 acceptance, one case a family so that a red one names
+    itself and hides no other: the committed golden describes the current
+    program — peak residency within tolerance, donation intact, no new
     materialization classes."""
-    rc = memcheck.main(["--family", "step_fsdp", "--skip-validate"])
-    row, _ = _verdict(capsys)
-    assert rc == 0 and row["ok"]
-    fam = row["families"]["step_fsdp"]
+    rc = memcheck.main(["--family", family, "--skip-validate"])
+    row, out = _verdict(capsys)
+    assert rc == 0 and row["ok"], out
+    fam = row["families"][family]
     assert fam["carry_donation"] == 1.0
     assert fam["peak_bytes"] > 0
     assert fam["materializations"] == {}
-    # the fsdp step's carry categories are per-device shards
-    assert set(fam["by_category"]) >= {"params", "opt_state",
-                                       "activations", "batch"}
+    if family == "step_fsdp":
+        # the fsdp step's carry categories are per-device shards
+        assert set(fam["by_category"]) >= {"params", "opt_state",
+                                           "activations", "batch"}
 
 
 def test_injected_peak_regression_fails_gate(memcheck, capsys):

@@ -356,23 +356,12 @@ def test_paged_decode_kv_pages_attribution_and_gather_detector(engines):
     interpreted) — while the detector still proves it would catch the pool
     gather if the kernel were bypassed (knob off: one gather per K/V pool
     per layer, as before the kernel existed)."""
-    import unittest.mock as mock
-
+    from conftest import load_tool
     from mxnet_tpu import config as _config
-    from mxnet_tpu import nd
-    from mxnet_tpu.inference import GenerationEngine
-    from mxnet_tpu.models import gpt2
-    from mxnet_tpu.ops import pallas_paged_attention as ppa
 
-    def fresh_engine(units):
-        net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2,
-                            units=units, num_heads=2, max_length=64,
-                            vocab_size=64)
-        net.initialize()
-        _ = net(nd.array(np.zeros((1, 4), np.int32)))
-        return GenerationEngine(net, batch_size=2, max_length=64,
-                                prefill_buckets=(8, 16), paged=True,
-                                page_size=16)
+    # the gates' own builders: one definition of "the paged program as the
+    # chip runs it"
+    fams = load_tool("families").load()
 
     _, paged = engines
     mem = paged.audit().memory
@@ -381,8 +370,8 @@ def test_paged_decode_kv_pages_attribution_and_gather_detector(engines):
     assert mem.by_category["kv_pages"] == hand
     # two heads of 64 fill a lane tile: the kernel's gate passes, and its
     # decode program holds no gather of the pool
-    with mock.patch.object(ppa, "_on_tpu", return_value=True):
-        kernel = fresh_engine(128)
+    with fams.kernel_traced():
+        kernel = fams.paged_engine()
         assert kernel.read_path == "pallas_paged_kernel"
         kinds = kernel.audit().memory.materialization_kinds()
     assert kinds.get("kv_gather_materialize", 0) == 0
@@ -391,7 +380,7 @@ def test_paged_decode_kv_pages_attribution_and_gather_detector(engines):
     # so toggling it on `paged` would silently audit the old trace)
     _config.set("paged_attention_kernel", False)
     try:
-        kinds = fresh_engine(32).audit().memory.materialization_kinds()
+        kinds = fams.paged_engine(32).audit().memory.materialization_kinds()
     finally:
         _config.set("paged_attention_kernel", True)
     assert kinds.get("kv_gather_materialize") == 4  # 2 layers x (K, V)

@@ -1,12 +1,11 @@
 """Measured profiling layer (docs/OBSERVABILITY.md "Measured
-profiling", ISSUE 14): XPlane parsing, MeasuredReport, capture,
-calibration, the step-capture controller, and the event-log gz-rotation
-hardening it rides with."""
+profiling", ISSUE 14): XPlane parsing, MeasuredReport, capture, the
+step-capture controller, and the event-log gz-rotation hardening it rides
+with."""
 import glob
 import gzip
 import json
 import os
-import types
 
 import pytest
 
@@ -143,78 +142,6 @@ def test_op_class_vocabulary():
     assert prof.op_class("reduce.1") == "other"
 
 
-# -- calibration --------------------------------------------------------------
-def _fake_schedule(classes, crit=1e-6, overlap=0.0):
-    return types.SimpleNamespace(op_class_seconds=classes,
-                                 critical_path_seconds=crit,
-                                 overlap_fraction=overlap)
-
-
-def _fake_measured(classes, steps=1):
-    rows = []
-    t = 0.0
-    for cls, secs in classes.items():
-        name = {"dot": "dot.1", "fusion": "fusion.1",
-                "all_reduce": "all-reduce.1"}.get(cls, "reduce.1")
-        rows.append(prof.OpRow(device="/device:TPU:0", lane="XLA Ops",
-                               name=name, start_ns=t,
-                               dur_ns=secs * steps * 1e9))
-        t += secs * steps * 1e9
-    spans = [prof.SpanRow(name=prof.PROF_STEP_SPAN, start_ns=0,
-                          dur_ns=1e6, step=i) for i in range(steps)]
-    return prof.MeasuredReport(op_rows=rows, spans=spans)
-
-
-def test_calibrate_normalized_ratios_quiet_when_consistent():
-    # measured exactly 1000x the prediction in EVERY class: a uniformly
-    # slower host, not constant drift — nothing may flag
-    pred = {"dot": 1e-6, "fusion": 2e-6, "other": 5e-7}
-    meas = {c: v * 1000 for c, v in pred.items()}
-    cal = prof.calibrate(_fake_schedule(pred), _fake_measured(meas),
-                         emit=False)
-    assert cal.overall_ratio == pytest.approx(1e-3)
-    assert not cal.drifting
-    by = {r.op_class: r for r in cal.rows}
-    for cls in pred:
-        assert by[cls].normalized == pytest.approx(1.0)
-
-
-def test_calibrate_flags_single_class_drift_with_knob():
-    pred = {"dot": 1e-6, "fusion": 2e-6, "all_reduce": 1e-6}
-    meas = {"dot": 1e-3, "fusion": 2e-3,
-            "all_reduce": 1e-2}  # collectives 10x slower than peers
-    cal = prof.calibrate(_fake_schedule(pred), _fake_measured(meas),
-                         band=3.0, emit=False)
-    flagged = {d["op_class"]: d for d in cal.drifting}
-    assert "all_reduce" in flagged
-    assert "ICI" in flagged["all_reduce"]["knob"]
-    assert "dot" not in flagged and "fusion" not in flagged
-    json.dumps(cal.summary())
-
-
-def test_calibrate_divides_measured_by_step_count():
-    pred = {"dot": 1e-6}
-    meas3 = _fake_measured({"dot": 1e-3}, steps=3)  # 3e-3 total over 3 steps
-    cal = prof.calibrate(_fake_schedule(pred), meas3, emit=False)
-    row = {r.op_class: r for r in cal.rows}["dot"]
-    assert row.measured_seconds == pytest.approx(1e-3)
-
-
-def test_schedule_report_carries_op_class_seconds():
-    net = nn.HybridSequential()
-    net.add(nn.Dense(8, in_units=8))
-    net.initialize()
-    _ = net(nd.ones((2, 8)))
-    ts = TrainStep(net, lambda o, y: ((o - y) ** 2).mean(),
-                   optimizer.SGD(learning_rate=0.1))
-    sched = ts.audit(nd.ones((2, 8)), nd.zeros((2, 8))).schedule
-    assert sched.op_class_seconds
-    # the class rollup partitions the modeled time: compute + comm
-    assert sum(sched.op_class_seconds.values()) == pytest.approx(
-        sched.compute_seconds + sched.comm_seconds, rel=1e-6)
-    assert "op_class_seconds" in sched.summary()
-
-
 # -- live capture (CPU) -------------------------------------------------------
 def _live_capture(tmp_path, steps=2):
     import jax
@@ -262,13 +189,7 @@ def test_trainstep_profile_shares_jit_cache(tmp_path):
     # the traced dispatches reused the production program — no new entry
     assert len(ts._compiled) == n_programs
     assert cap.report.op_rows and len(cap.report.step_seconds()) == 2
-    cal = cap.calibration
-    assert cal is not None and cal.rows
-    assert any(r.predicted_seconds > 0 and r.measured_seconds > 0
-               for r in cal.rows)
-    # measured overlap sits next to the predicted fraction, 1:1
-    assert 0.0 <= cal.measured_overlap <= 1.0
-    assert 0.0 <= cal.predicted_overlap <= 1.0
+    assert 0.0 <= cap.report.overlap_fraction <= 1.0
 
 
 # -- the step-capture controller ---------------------------------------------

@@ -7,22 +7,19 @@ Runs tools/shardcheck.py in-process (importlib) so each case can pick one
 cheap program family and capture the JSON verdict without a subprocess
 per family.
 """
-import importlib.util
 import json
-import os
 
 import pytest
+from conftest import load_tool
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the families' names are plain data of tools/families.py: importing it
+# builds and traces nothing
+FAMILIES = load_tool("families").FAMILY_NAMES
 
 
 @pytest.fixture(scope="module")
 def shardcheck():
-    spec = importlib.util.spec_from_file_location(
-        "shardcheck_mod", os.path.join(REPO, "tools", "shardcheck.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_tool("shardcheck")
 
 
 def _verdict(capsys):
@@ -31,19 +28,22 @@ def _verdict(capsys):
     return row, out
 
 
-def test_gate_matches_committed_goldens(shardcheck, capsys):
-    """ISSUE 8 acceptance: the committed goldens describe the current
-    programs — zero contract violations, no new collective kinds, comm
+@pytest.mark.parametrize("family", FAMILIES)
+def test_gate_matches_committed_goldens(shardcheck, capsys, family):
+    """ISSUE 8 acceptance, one case a family so that a red one names
+    itself and hides no other: the committed golden describes the current
+    program — zero contract violations, no new collective kinds, comm
     bytes within tolerance."""
-    rc = shardcheck.main(["--family", "step_fsdp"])
-    row, _ = _verdict(capsys)
-    assert rc == 0 and row["ok"]
-    fam = row["families"]["step_fsdp"]
+    rc = shardcheck.main(["--family", family])
+    row, out = _verdict(capsys)
+    assert rc == 0 and row["ok"], out
+    fam = row["families"][family]
     assert fam["contract_violations"] == []
     assert fam["accidental_reshards"] == []
     assert fam["carry_donation"] == 1.0
-    assert fam["comm_total_bytes"] > 0          # a non-empty CommReport
-    assert set(fam["comm_by_axis"]) == {"fsdp", "dp×fsdp"}
+    if family == "step_fsdp":
+        assert fam["comm_total_bytes"] > 0      # a non-empty CommReport
+        assert set(fam["comm_by_axis"]) == {"fsdp", "dp×fsdp"}
 
 
 def test_injected_all_gather_fails_gate(shardcheck, capsys):

@@ -322,8 +322,7 @@ def test_scope_seconds_rolls_own_time_up_by_depth():
 def test_a_traced_step_rolls_up_under_its_scopes(tmp_path):
     ts = _step()
     ts(*_batch())
-    cap = ts.profile(*_batch(), steps=2, warmup=0, calibrate=False,
-                     trace_dir=str(tmp_path))
+    cap = ts.profile(*_batch(), steps=2, warmup=0, trace_dir=str(tmp_path))
     by_scope = cap.report.scope_seconds(ts.op_scopes(*_batch()))
     assert by_scope and set(by_scope) - {"unscoped", "mixed"}
     assert set(by_scope) <= (set(scopes.SCOPES)

@@ -54,8 +54,11 @@ def _batches(k, b=4, seed=123):
 
 def _param_values(ts):
     # the Dense name counter is process-global, so two structurally
-    # identical nets carry different param names — compare by sorted order
-    return [np.asarray(v) for _, v in sorted(ts.params.items())]
+    # identical nets carry different param names — compare by NATURAL
+    # sorted order (two nets can straddle a digit: dense9, dense10)
+    from conftest import natkey
+
+    return [np.asarray(v) for _, v in sorted(ts.params.items(), key=natkey)]
 
 
 def _state_leaves(ts):

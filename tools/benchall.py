@@ -1,5 +1,5 @@
-"""The two CPU structure gates that carry a record (neither is a device
-measurement, and neither number is quoted as speed):
+"""The CPU structure gate that carries a record (not a device measurement,
+and its number is not quoted as speed):
 
   python tools/benchall.py --window 4 [--out BENCH_r06.json]
       # `make perfwin`: times the single-step TrainStep.__call__ loop
@@ -7,11 +7,8 @@ measurement, and neither number is quoted as speed):
       # lowering + prefetch queue metrics present, and FAILS unless the
       # amortized per-step time of the window path is strictly below
       # single-step.
-  python tools/benchall.py --overlap [--out MULTICHIP_r06.json]
-      # `make multichip`: the mesh families of tools/families.py priced
-      # sync vs asyncified by the static schedule model.
 
-Both force the CPU platform; measuring on the chip is chip_smoke.py's and
+It forces the CPU platform; measuring on the chip is chip_smoke.py's and
 the benchmark's job.
 """
 from __future__ import annotations
@@ -192,132 +189,19 @@ def window_bench(window, steps=96, reps=9, out_path=None):
     return rec
 
 
-def overlap_bench(out_path=None):
-    """Async-collective overlap artifact (``make multichip``, docs/
-    PARALLELISM.md "Hiding collective time"): for every mesh family in
-    tools/families.py, score the SAME compiled program twice through the
-    static schedule model — raw (sync collectives, the XLA:CPU audit
-    text as written) vs asyncified (the start→done view the TPU
-    latency-hiding scheduler achieves, the one the schedcheck goldens
-    lock in) — and record per-axis comm bytes plus the critical-path /
-    overlap / exposed-collective deltas. FAILS unless every mesh family
-    raises overlap strictly above the 0.0 sync baseline without growing
-    the critical path."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "benchall_families_loader", os.path.join(REPO, "tools",
-                                                 "families.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    fams = mod.load()
-
-    from mxnet_tpu.analysis import schedule_report
-
-    def _view(s):
-        return {
-            "critical_path_seconds": s.critical_path_seconds,
-            "comm_seconds": s.comm_seconds,
-            "exposed_comm_seconds": s.exposed_comm_seconds,
-            "hidden_comm_seconds": s.hidden_comm_seconds,
-            "overlap_fraction": round(s.overlap_fraction, 6),
-            "exposed_collectives": s.exposed_collectives(),
-            "mfu_bound": round(s.mfu_bound, 6),
-        }
-
-    mesh_families = ("step_dp8", "step_fsdp", "window_fsdp", "step_pp",
-                    "step_moe_fsdp")
-    meshes = {
-        "step_dp8": lambda: None,  # resolved from the audit below
-        "step_fsdp": lambda: fams._fsdp_step()[0].mesh,
-        "window_fsdp": lambda: fams._fsdp_step()[0].mesh,
-        "step_pp": lambda: fams._pp_step()[0].mesh,
-        "step_moe_fsdp": lambda: fams._moe_step()[0].mesh,
-    }
-    rows, checks, constants = {}, {}, {}
-    for name in mesh_families:
-        audit = fams.FAMILIES[name]()
-        mesh = meshes[name]()
-        if mesh is None:  # step_dp8 has no memoized builder to read from
-            from mxnet_tpu.parallel import Layout
-
-            mesh = Layout(dp=8).mesh()
-        # before: the compiled text as written — sync collectives
-        before = _view(schedule_report(audit.compiled, mesh))
-        after = _view(audit.schedule)  # the audit schedules the async view
-        rows[name] = {
-            "async_pairs": audit.overlap.async_pairs if audit.overlap
-            else 0,
-            "comm_by_axis_bytes": {
-                ax: d["bytes"] for ax, d in
-                sorted(audit.schedule.by_axis().items())},
-            "comm_by_axis_seconds": {
-                ax: d["seconds"] for ax, d in
-                sorted(audit.schedule.by_axis().items())},
-            "before_sync": before,
-            "after_async": after,
-            "critical_path_improvement": round(
-                1 - after["critical_path_seconds"] /
-                before["critical_path_seconds"], 4),
-        }
-        checks[name] = (after["overlap_fraction"] >
-                        before["overlap_fraction"] == 0.0 and
-                        after["critical_path_seconds"] <=
-                        before["critical_path_seconds"] * (1 + 1e-9))
-        constants = dict(audit.schedule.constants)
-    rec = {
-        "metric": "multichip_overlap_before_vs_after",
-        "platform": "cpu", "utc": _utc(),
-        "constants": constants,
-        "families": rows,
-        "checks": checks,
-        "note": "static schedule model over the golden mesh families: the "
-                "same compiled program priced sync (as XLA:CPU emits it) "
-                "vs through the asyncify start→done pass the TrainStep "
-                "audit applies under the layout overlap policy — the "
-                "before/after the sched_*.json goldens lock in",
-    }
-    out_path = out_path or os.path.join(REPO, "MULTICHIP_r06.json")
-    with open(out_path, "w") as f:
-        json.dump(rec, f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(json.dumps(rec), flush=True)
-    failed = [k for k, ok in checks.items() if not ok]
-    if failed:
-        print(f"multichip: FAIL - {failed}", file=sys.stderr)
-        sys.exit(1)
-    print("multichip: OK - " + ", ".join(
-        f"{n} {rows[n]['before_sync']['overlap_fraction']:.3f}->"
-        f"{rows[n]['after_async']['overlap_fraction']:.3f}"
-        for n in mesh_families), flush=True)
-    return rec
-
-
 def main():
     ap = argparse.ArgumentParser()
-    mode = ap.add_mutually_exclusive_group(required=True)
-    mode.add_argument("--window", type=int, default=0,
-                      help="run the fused multi-step window benchmark with "
-                           "this window size (CPU dry-run)")
-    mode.add_argument("--overlap", action="store_true",
-                      help="write the async-collective overlap artifact "
-                           "(sync vs asyncified schedule over the mesh "
-                           "families)")
+    ap.add_argument("--window", type=int, required=True,
+                    help="run the fused multi-step window benchmark with "
+                         "this window size (CPU dry-run)")
     ap.add_argument("--steps", type=int, default=96,
-                    help="timed steps for --window mode")
+                    help="timed steps")
     ap.add_argument("--out", type=str, default=None,
-                    help="artifact path (default BENCH_r06.json / "
-                         "MULTICHIP_r06.json)")
+                    help="artifact path (default BENCH_r06.json)")
     args = ap.parse_args()
 
-    out_path = args.out and os.path.join(REPO, args.out)
-    if args.overlap:
-        overlap_bench(out_path=out_path)
-    else:
-        window_bench(args.window, steps=args.steps, out_path=out_path)
+    window_bench(args.window, steps=args.steps,
+                 out_path=args.out and os.path.join(REPO, args.out))
 
 
 if __name__ == "__main__":

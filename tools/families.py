@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """The golden program families — ONE definition shared by every gate.
 
-``make shardcheck`` (sharding + comm), ``make memcheck`` (buffer
-liveness) and ``make schedcheck`` (critical path + overlap) all audit the
-same ten representative programs; this module owns their constructors
-so a family change can never drift between gates (ISSUE 13). Builders are
+``make shardcheck`` (sharding + comm) and ``make memcheck`` (buffer
+liveness) audit the same ten representative programs; this module owns
+their constructors so a family change can never drift between gates
+(ISSUE 13). Builders are
 memoized where two families audit the SAME object (the two fsdp families
 share one TrainStep — step vs window program — and the serving families
 share engines), so one model build/compile serves each pair per run.
@@ -15,10 +15,12 @@ Import via ``importlib`` from the gate scripts (tools/ is not a package):
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import importlib.util
 import os
 import sys
+import unittest.mock
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -26,7 +28,7 @@ sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-#: gate-facing family order (memcheck/schedcheck default ordering)
+#: gate-facing family order (memcheck's default ordering)
 FAMILY_NAMES = ("step_dp8", "step_fsdp", "window_fsdp", "step_pp",
                 "step_moe_fsdp", "prefill", "decode", "decode_paged",
                 "verify_spec", "decode_prefix")
@@ -152,21 +154,26 @@ def family_step_moe_fsdp():
     return ts.audit(*batch)
 
 
-@functools.lru_cache(maxsize=None)
-def _engine():
+def _tiny_gpt2(units):
     import numpy as np
 
     import mxnet_tpu as mx
     from mxnet_tpu import nd
-    from mxnet_tpu.inference import GenerationEngine
     from mxnet_tpu.models import gpt2
 
     mx.random.seed(0)
-    net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2, units=32,
+    net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2, units=units,
                         num_heads=2, max_length=64, vocab_size=64)
     net.initialize()
     _ = net(nd.array(np.zeros((1, 4), np.int32)))
-    return GenerationEngine(net, batch_size=2, max_length=64,
+    return net
+
+
+@functools.lru_cache(maxsize=None)
+def _engine():
+    from mxnet_tpu.inference import GenerationEngine
+
+    return GenerationEngine(_tiny_gpt2(32), batch_size=2, max_length=64,
                             prefill_buckets=(8, 16))
 
 
@@ -180,62 +187,64 @@ def family_prefill():
     return _engine().audit(bucket=8)
 
 
-@functools.lru_cache(maxsize=None)
-def _paged_engines():
-    """One paged + one speculative engine over the SAME net as _engine()
-    (separate build: engine caches are engine-local state)."""
-    import numpy as np
+# -- the paged families, built as the chip runs them -------------------------
+#: two heads of 64 fill one 128-lane tile: the narrowest model whose paged
+#: reads pass ``pallas_paged_attention.paged_attention_refusal``
+KERNEL_UNITS = 128
 
-    import mxnet_tpu as mx
-    from mxnet_tpu import nd
+
+@contextlib.contextmanager
+def kernel_traced():
+    """Programs traced inside hold the paged attention kernel
+    (interpreted) where the chip's would: the gate's backend check is told
+    it stands on a TPU. The check is read at trace time, so an engine must
+    be BUILT and its programs lowered inside (an engine's read path and
+    its cached traces do not change afterwards)."""
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    with unittest.mock.patch.object(ppa, "_on_tpu", return_value=True):
+        yield
+
+
+def paged_engine(units=KERNEL_UNITS, net=None, **kw):
+    """A paged engine over the tiny GPT-2 (two heads of ``units // 2``)."""
     from mxnet_tpu.inference import GenerationEngine
-    from mxnet_tpu.models import gpt2
 
-    mx.random.seed(0)
-    net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2, units=32,
-                        num_heads=2, max_length=64, vocab_size=64)
-    net.initialize()
-    _ = net(nd.array(np.zeros((1, 4), np.int32)))
-    paged = GenerationEngine(net, batch_size=2, max_length=64,
-                             prefill_buckets=(8, 16), paged=True,
-                             page_size=16)
-    spec = GenerationEngine(net, batch_size=2, max_length=64,
-                            prefill_buckets=(8, 16), paged=True,
-                            page_size=16, draft_net=net, speculate_k=4)
-    return paged, spec
+    return GenerationEngine(net or _tiny_gpt2(units), batch_size=2,
+                            max_length=64, prefill_buckets=(8, 16),
+                            paged=True, page_size=16, **kw)
 
 
+@functools.lru_cache(maxsize=None)
+@kernel_traced()
+def _paged_engines():
+    """One paged + one speculative engine over one net."""
+    net = _tiny_gpt2(KERNEL_UNITS)
+    return (paged_engine(net=net),
+            paged_engine(net=net, draft_net=net, speculate_k=4))
+
+
+@kernel_traced()
 def family_decode_paged():
     """The paged decode step: page-table carry + pools, zero collectives."""
     return _paged_engines()[0].audit()
 
 
+@kernel_traced()
 def family_verify_spec():
     """The speculative verify pass (k+1 positions, one program)."""
     return _paged_engines()[1].audit(program="verify")
 
 
 @functools.lru_cache(maxsize=None)
+@kernel_traced()
 def _prefix_engine():
-    """A prefix-cache paged engine over the same tiny net — audited on
-    the copy-on-write page-copy program (prefix sharing, ISSUE 19)."""
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import nd
-    from mxnet_tpu.inference import GenerationEngine
-    from mxnet_tpu.models import gpt2
-
-    mx.random.seed(0)
-    net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=2, units=32,
-                        num_heads=2, max_length=64, vocab_size=64)
-    net.initialize()
-    _ = net(nd.array(np.zeros((1, 4), np.int32)))
-    return GenerationEngine(net, batch_size=2, max_length=64,
-                            prefill_buckets=(8, 16), paged=True,
-                            page_size=16, prefix_cache=True)
+    """A prefix-cache paged engine, audited on the copy-on-write
+    page-copy program (prefix sharing, ISSUE 19)."""
+    return paged_engine(prefix_cache=True)
 
 
+@kernel_traced()
 def family_decode_prefix():
     """The CoW page-copy program behind prefix sharing: carry-only
     inputs, 100% donation, zero collectives — same serving contract."""

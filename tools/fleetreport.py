@@ -107,25 +107,13 @@ def render(s: dict) -> str:
              if rs.get("flops_per_step")]
     mfus = [rs["mfu"] for rs in s["ranks"].values()
             if rs.get("mfu") is not None]
-    bounds = [rs["mfu_bound"] for rs in s["ranks"].values()
-              if rs.get("mfu_bound") is not None]
-    exposed = [rs["comm_exposed_share"] for rs in s["ranks"].values()
-               if rs.get("comm_exposed_share") is not None]
-    if flops or mfus or bounds:
+    if flops or mfus:
         w("-- mfu")
         if flops:
             w(f"   model flops/step: {_fmt_flops(max(flops))}")
         if mfus:
             w(f"   train_mfu: mean={sum(mfus) / len(mfus):.4g} "
               f"max={max(mfus):.4g}")
-        if bounds:
-            # the schedule auditor's static ceiling: achieved MFU can
-            # only approach this; a widening gap is scheduling loss, a
-            # LOW bound is exposed communication (the share line)
-            w(f"   static bound (schedule auditor): {max(bounds):.4g}")
-        if exposed:
-            w(f"   exposed-comm share of critical path: "
-              f"{max(exposed):.3f}")
 
     profiles = s.get("profiles", {})
     if profiles:

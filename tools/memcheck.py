@@ -55,7 +55,7 @@ GOLDEN_DIR = os.path.join(REPO, "mxnet_tpu", "analysis", "goldens")
 def _shardcheck():
     """The shared program-family builders (tools/families.py) — one
     definition of what 'the representative programs' are, every gate
-    (shardcheck / memcheck / schedcheck) audits the same seven. Loaded
+    (shardcheck / memcheck) audits the same ten. Loaded
     under families.load()'s stable module name so the memoized model
     builds are shared per process. (Name kept: validate() reads
     ``_engine`` off it, as it always did off shardcheck.)"""

@@ -197,8 +197,7 @@ def summarize(run_dir):
     # -- measured profile (docs/OBSERVABILITY.md "Measured profiling") -------
     # the newest capture snapshot under the run dir (periodic captures
     # land in {run_dir}/prof/ when telemetry is on), rendered next to the
-    # achieved-MFU gauges and the schedule auditor's static bound so the
-    # measured hot list and the static ceiling sit in one report
+    # achieved-MFU gauge
     def _gauge(name):
         m = metrics.get(name)
         if not m or not m.get("series"):
@@ -215,7 +214,6 @@ def summarize(run_dir):
             "hot_ops": r.get("hot_ops", [])[:10],
             "overlap_fraction": r.get("overlap_fraction"),
             "mfu": _gauge("train_mfu"),
-            "mfu_bound": _gauge("train_mfu_bound"),
         }
     return summary
 
@@ -280,9 +278,8 @@ def render(s):
         ctx = " ".join(f"{k}={meta[k]}" for k in ("step", "trigger")
                        if k in meta)
         w(f"-- hot ops (measured profile{', ' + ctx if ctx else ''})")
-        if p.get("mfu") is not None or p.get("mfu_bound") is not None:
-            w(f"   achieved mfu={p['mfu'] if p['mfu'] is not None else '-'}"
-              f"  static bound={p['mfu_bound'] if p['mfu_bound'] is not None else '-'}"
+        if p.get("mfu") is not None:
+            w(f"   achieved mfu={p['mfu']}"
               f"  measured overlap={p.get('overlap_fraction')}")
         for h in p.get("hot_ops", []):
             w(f"   {h['name'][:40]:<40} {h['op_class']:<12} "

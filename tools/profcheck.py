@@ -3,23 +3,15 @@
 "Measured profiling", ISSUE 14).
 
 Traces two of the shared golden program families (tools/families.py — the
-SAME builders shardcheck/memcheck/schedcheck audit, so the profiled
+SAME builders shardcheck/memcheck audit, so the profiled
 programs can never drift from the gated ones): 2 real training steps of
 the fsdp TrainStep and a window of real decode steps of the serving
 engine, both on CPU with 8 virtual devices. The gate FAILS unless:
 
   - the **measured op timeline is non-empty** for both families — the
     XPlane parser produced real per-device op rows with timestamps;
-  - ``calibrate()`` **emits predicted/measured ratios** per op class
-    against each program's live :class:`ScheduleReport` — whose
-    critical path must sit within ``--golden-band`` of the committed
-    ``sched_*.json`` golden (the telemetry-mode grad-norm output makes
-    the profiled step a slightly larger program than the golden's
-    telemetry-off one; the band absorbs that, schedcheck pins the
-    exact program);
-  - **measured overlap** is computed and reported next to
-    ``ScheduleReport.overlap_fraction`` (zero measured overlap is
-    allowed — CPU compiles collectives synchronously);
+  - **measured overlap** is computed and reported (zero measured
+    overlap is allowed — CPU compiles collectives synchronously);
   - the **measured step time** sits within a sane band of the metrics
     registry's ``train_step_seconds`` histogram over the same steps
     (both watches timed the same wall clock);
@@ -44,8 +36,6 @@ sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-GOLDEN_DIR = os.path.join(REPO, "mxnet_tpu", "analysis", "goldens")
-
 #: measured-vs-registry step-time agreement band (both are wall clocks of
 #: the same steps; the trace adds parse/snapshot overhead outside the
 #: step windows, so the band is generous but not vacuous)
@@ -61,14 +51,6 @@ def _families():
     return mod.load()
 
 
-def _sched_golden(name: str):
-    try:
-        with open(os.path.join(GOLDEN_DIR, f"sched_{name}.json")) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
 def _inject_empty(cap):
     """Failure-path hook: replace the capture's parsed result with what
     an empty trace dir yields — every downstream assertion must fail."""
@@ -77,19 +59,10 @@ def _inject_empty(cap):
     empty = tempfile.mkdtemp(prefix="profcheck-empty-")
     cap.timeline = profiling.parse_trace(empty)
     cap.report = profiling.measured_report(cap.timeline)
-    if cap.calibration is not None:
-        cap.calibration = profiling.calibrate(
-            _Dummy(), cap.report, emit=False)
     return cap
 
 
-class _Dummy:
-    op_class_seconds: dict = {}
-    critical_path_seconds = 0.0
-    overlap_fraction = 0.0
-
-
-def check_family(name, cap, schedule, golden, golden_band, fails, notes):
+def check_family(name, cap, fails):
     """Run one family's assertions; returns the JSON row."""
     r = cap.report
     row = {
@@ -100,8 +73,6 @@ def check_family(name, cap, schedule, golden, golden_band, fails, notes):
         if r.step_seconds() else None,
         "hot_ops": [h["name"] for h in r.hot_ops(5)],
         "overlap_measured": round(r.overlap_fraction, 6),
-        "overlap_predicted": round(schedule.overlap_fraction, 6)
-        if schedule is not None else None,
     }
     if not r.op_rows:
         fails.append(f"{name}: measured op timeline is EMPTY — the trace "
@@ -110,36 +81,6 @@ def check_family(name, cap, schedule, golden, golden_band, fails, notes):
     if not r.step_seconds():
         fails.append(f"{name}: no prof_step windows in the trace — step "
                      "correlation broken")
-    cal = cap.calibration
-    if cal is None or not cal.rows:
-        fails.append(f"{name}: calibrate() produced no predicted/measured "
-                     "rows")
-    else:
-        both = [c for c in cal.rows
-                if c.predicted_seconds > 0 and c.measured_seconds > 0]
-        if not both:
-            fails.append(f"{name}: calibration table has no op class with "
-                         "BOTH a predicted and a measured side")
-        row["calibration"] = cal.summary()
-    if schedule is not None and golden is not None:
-        g, c = golden["critical_path_seconds"], \
-            schedule.critical_path_seconds
-        row["golden_critical_path_seconds"] = g
-        row["live_critical_path_seconds"] = c
-        if not (g * (1 - golden_band) <= c <= g * (1 + golden_band)):
-            fails.append(
-                f"{name}: live schedule critical path {c:.3e}s sits "
-                f"outside ±{golden_band:.0%} of the committed golden "
-                f"{g:.3e}s — the calibration's predicted side no longer "
-                "matches what schedcheck pins (rebless the sched golden "
-                "first)")
-        if golden.get("constants") != schedule.constants:
-            notes.append(f"{name}: roofline constants differ from the "
-                         "golden's (env overrides?)")
-    elif golden is None:
-        notes.append(f"{name}: no committed sched golden to anchor the "
-                     "predicted side (run tools/schedcheck.py "
-                     "--update-golden)")
     return row
 
 
@@ -147,12 +88,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--steps", type=int, default=2,
                     help="traced steps per family (default 2)")
-    ap.add_argument("--golden-band", type=float, default=0.5,
-                    help="allowed relative gap between the live schedule "
-                         "critical path and the committed sched golden "
-                         "(default 50%% — the profiled step compiles the "
-                         "telemetry grad-norm in; schedcheck pins the "
-                         "exact telemetry-off program)")
     ap.add_argument("--inject-empty-trace", action="store_true",
                     help="test hook: parse an empty trace dir instead of "
                          "the real capture (the gate must fail)")
@@ -164,7 +99,7 @@ def main(argv=None):
     obs.enable(run_dir)
 
     fams = _families()
-    fails, notes = [], []
+    fails = []
     row = {"gate": "profcheck", "families": {}}
 
     # -- family 1: the fsdp training step (step_fsdp golden family) ----------
@@ -183,10 +118,7 @@ def main(argv=None):
                      trace_dir=trace_dir)
     if args.inject_empty_trace:
         cap = _inject_empty(cap)
-    # the predicted side rides the capture (profile() audited once)
-    row["families"]["step_fsdp"] = check_family(
-        "step_fsdp", cap, cap.schedule, _sched_golden("step_fsdp"),
-        args.golden_band, fails, notes)
+    row["families"]["step_fsdp"] = check_family("step_fsdp", cap, fails)
 
     # measured step time vs the metrics registry's step histogram over
     # the SAME (warm) steps: two watches on one wall clock must agree
@@ -214,9 +146,7 @@ def main(argv=None):
                       trace_dir=trace_dir)
     if args.inject_empty_trace:
         cap = _inject_empty(cap)
-    row["families"]["decode"] = check_family(
-        "decode", cap, cap.schedule, _sched_golden("decode"),
-        args.golden_band, fails, notes)
+    row["families"]["decode"] = check_family("decode", cap, fails)
 
     # -- capture accounting ---------------------------------------------------
     ctr = obs.REGISTRY.get("prof_captures_total")
@@ -229,19 +159,14 @@ def main(argv=None):
     row["ok"] = not fails
     if fails:
         row["failures"] = fails
-    if notes:
-        row["notes"] = notes
     print(json.dumps(row, indent=1, sort_keys=True, default=str))
-    for msg in notes:
-        print(f"NOTE: {msg}")
     if fails:
         for msg in fails:
             print(f"FAIL: {msg}")
         return 1
     print("OK: measured op timelines non-empty for 2 shared golden "
-          "families, calibration table emitted against the sched goldens, "
-          "measured overlap reported next to the predicted fraction, "
-          "measured step time agrees with the registry histogram")
+          "families, measured overlap reported, measured step time agrees "
+          "with the registry histogram")
     return 0
 
 

@@ -8,9 +8,7 @@ capture directory containing one, or a raw trace directory (the jax
 ``plugins/profile/...`` layout — parsed on the spot), and prints one
 operator-facing summary: measured step time, the hot-op table (self
 time, count, bytes where the trace carries them), per-device totals,
-span breakdown, measured compute/collective overlap, and — when the
-snapshot carries one — the predicted-vs-measured calibration table with
-any flagged roofline-constant drift.
+span breakdown and measured compute/collective overlap.
 
 Usage::
 
@@ -102,25 +100,6 @@ def render(s: dict) -> str:
       f"hidden={_fmt_s(r.get('hidden_collective_seconds'))} "
       f"compute={_fmt_s(r.get('compute_seconds'))} "
       f"measured overlap_fraction={r.get('overlap_fraction')}")
-    cal = s.get("calibration")
-    if cal:
-        w("-- calibration (predicted roofline vs measured, "
-          f"band={cal.get('band')})")
-        w(f"   predicted step {cal['predicted_step_seconds']:.3e}s vs "
-          f"measured {cal['measured_step_seconds'] and format(cal['measured_step_seconds'], '.3e') or '-'}s  "
-          f"overall pred/meas ratio "
-          f"{cal['overall_ratio'] and format(cal['overall_ratio'], '.3e') or '-'}")
-        w(f"   predicted overlap {cal['predicted_overlap']} vs measured "
-          f"{cal['measured_overlap']}")
-        for row in cal.get("rows", []):
-            flag = "  << DRIFT" if row.get("drift") else ""
-            w(f"   {row['op_class']:<16} pred {row['predicted_seconds']:.3e}s"
-              f"  meas {row['measured_seconds']:.3e}s  norm "
-              f"{row['normalized'] and format(row['normalized'], '.2f') or '-'}"
-              f"{flag}")
-        for d in cal.get("drifting", []):
-            w(f"   DRIFT: {d['op_class']} normalized ratio "
-              f"{d['normalized_ratio']} — re-tune {d['knob']}")
     return "\n".join(out)
 
 

@@ -49,7 +49,7 @@ GOLDEN_DIR = os.path.join(REPO, "mxnet_tpu", "analysis", "goldens")
 def _families_mod():
     """The shared golden-family builders (tools/families.py) — ONE
     definition of the representative programs for every gate
-    (shardcheck / memcheck / schedcheck), loaded under a stable module
+    (shardcheck / memcheck), loaded under a stable module
     name so the memoized model builds are shared per process."""
     spec = importlib.util.spec_from_file_location(
         "shardcheck_families_loader", os.path.join(REPO, "tools",
