@@ -100,7 +100,8 @@ profcheck:
 # standalone before blessing perf artifacts on hardware
 kernelcheck:
 	$(PY) -m pytest tests/test_flash_attention.py tests/test_pallas_layernorm.py \
-	    tests/test_pallas_paged_attention.py tests/test_pallas_optimizer.py \
+	    tests/test_pallas_paged_attention.py \
+	    tests/test_pallas_paged_latent_attention.py tests/test_pallas_optimizer.py \
 	    tests/test_pallas_softmax_xent.py tests/test_packed_attention.py -q
 
 native:

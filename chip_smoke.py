@@ -11,6 +11,10 @@ configuration can select:
   serve    GPT-2 345M through GenerationEngine(paged=True, bf16 cache) and
            ContinuousBatcher; greedy tokens checked against a plain
            re-forward of the same net
+  latent   DeepSeek-V2 at its toy widths (the published sizes do not fit
+           beside the other phases) through GenerationEngine(paged=True,
+           bf16 cache): the decode program's latent kernel compiled by
+           Mosaic, its logits checked against a plain re-forward
   kernels  flash attention fwd+bwd, paged attention and packed attention
            fwd+bwd (BERT-large's: batch 64 x seq 128 x 3 x 1024, key mask),
            compiled by Mosaic and compared with their XLA references
@@ -22,7 +26,7 @@ with exactly those keys; what each phase found is on the ``summary:`` line
 before it. This is a smoke run: its seconds say the program ran, they are
 not a benchmark.
 
-    python chip_smoke.py            # one chip, all four phases
+    python chip_smoke.py            # one chip, all five phases
     python chip_smoke.py --chips 4  # device + train under the ZeRO layout
                                     # over four chips, against one chip
 
@@ -333,6 +337,76 @@ def phase_serve(devices, model="gpt2_345m", slots=4,
     return out
 
 
+def phase_serve_latent(devices, prompt_lens=(5, 40, 100, 124), n_new=8):
+    """A toy-width DeepSeek-V2 decoded through the paged latent kernel, rows
+    short and long enough to take both of its stretches of keys (128 and
+    256), against one plain forward of each row's whole sequence (teacher
+    forcing on the engine's own tokens: a near tie cannot split the two).
+    The cache is bfloat16 and the re-forward is not, so logits are held to a
+    quarter of their own spread: rounding reads about a hundredth, a row that
+    attends to another's pages reads one."""
+    import re
+
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu.inference import GenerationEngine
+    from mxnet_tpu.models import deepseek_v2
+
+    mx.random.seed(SEED)
+    net = deepseek_v2.get_deepseek_v2("deepseek_v2_tiny")
+    net.initialize()
+    net(nd.array(np.zeros((1, 4)), dtype="int32"))  # deferred init
+    engine = GenerationEngine(net, batch_size=len(prompt_lens), paged=True,
+                              page_size=16, max_length=256,
+                              cache_dtype="bfloat16")
+    say(f"latent: paged decode read path: {engine.read_path}")
+    if not engine.read_path.startswith("pallas_paged_latent_kernel"):
+        raise AssertionError(f"latent: the kernel's gate refuses: "
+                             f"{engine.read_path}")
+    rs = np.random.RandomState(SEED + 2)
+    seqs = [rs.randint(1, net.logits_width(), n).tolist() for n in prompt_lens]
+    for slot, prompt in enumerate(seqs):
+        seqs[slot] = prompt + [engine.prefill(prompt, slot)]
+    got = [[] for _ in seqs]
+    for _ in range(n_new):
+        tok, _, logits = engine.decode_step()
+        for slot, seq in enumerate(seqs):
+            got[slot].append(np.asarray(logits[slot]))
+            seq.append(int(tok[slot]))
+    worst, margin = 0.0, float("inf")
+    for n, seq, rows in zip(prompt_lens, seqs, got):
+        buf = np.zeros((1, 256), np.int32)
+        buf[0, :len(seq)] = seq
+        want = net(nd.array(buf, dtype="int32")).asnumpy()[0, n:n + n_new]
+        rows = np.stack(rows)
+        if not np.isfinite(rows).all():
+            raise AssertionError("latent: the decode program's logits are "
+                                 "not finite")
+        worst = max(worst, float(np.max(np.abs(rows - want)) / np.std(want)))
+        top2 = np.partition(want, -2, axis=-1)[:, -2:]
+        margin = min(margin, float(np.min(top2[:, 1] - top2[:, 0])
+                                   / np.std(want)))
+    if worst > 0.25:
+        raise AssertionError(f"latent: decode logits are {worst:.3f} of their "
+                             f"spread off the re-forward's")
+    text = engine.lower_decode().compile().as_text()
+    calls = len(re.findall(r"%paged_latent_attention_decode[.\d]* = ", text))
+    if calls != len(engine.pools):
+        raise AssertionError(f"latent: {calls} latent kernels in the decode "
+                             f"program, want one a layer ({len(engine.pools)})")
+    out = {"model": "deepseek_v2_tiny", "cache_dtype": "bfloat16",
+           "pool_shape": list(engine.pools[0][0].shape),
+           "prompt_lens": list(prompt_lens), "new_tokens_each": n_new,
+           "read_path": engine.read_path,
+           "decode_latent_kernels": calls,
+           "max_logit_diff_over_spread": round(worst, 5),
+           "reforward_min_top2_margin_over_spread": round(margin, 5)}
+    say(f"latent: {out}")
+    return out
+
+
 # --------------------------------------------------------------------------
 # kernels
 # --------------------------------------------------------------------------
@@ -530,6 +604,7 @@ def main():
     else:
         run("train", phase_train, devices)
         run("serve", phase_serve, devices)
+        run("latent", phase_serve_latent, devices)
         run("kernels", phase_kernels)
     say("summary: " + json.dumps({
         "versions": versions, "chips": args.chips,

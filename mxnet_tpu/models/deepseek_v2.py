@@ -5,10 +5,11 @@ Attention is multi-head latent attention: queries through a low-rank pair
 (``q_a``, ``q_b``), keys and values through one shared latent per token
 (``kv_a`` down, ``kv_b`` up) plus one rotated key all heads share; rotary
 positions under YaRN on the decoupled 64 dimensions only. The cache holds
-the normalised latent and the rotated key, one ``kv_lora_rank +
-qk_rope_head_dim`` wide vector a token a layer, and nothing decompressed
-(``F.latent_attention``). The first ``first_k_dense`` layers have a dense
-SwiGLU; the others an expert layer: group-limited top-k over all routed
+the normalised latent and the rotated key, ``kv_lora_rank +
+qk_rope_head_dim`` values a token a layer in a vector of whole lane tiles,
+and nothing decompressed (``F.latent_attention``). The first
+``first_k_dense`` layers have a dense SwiGLU; the others an expert layer:
+group-limited top-k over all routed
 experts, the SwiGLUs of the experts THIS chip holds (``held_experts``; the
 others' terms are their chips' to add) and the shared experts as one wider
 SwiGLU (``F.held_expert_ffn``).
@@ -267,16 +268,32 @@ class DeepseekV2Model(HybridBlock):
         return self._cfg["kv_lora_rank"] + self._cfg["qk_rope_head_dim"]
 
     def init_paged_cache(self, num_pages, page_size, dtype="float32"):
-        """Per-layer ``(pool,)`` of shape (num_pages + 1, page_size,
-        kv_lora_rank + qk_rope_head_dim): the latent cache."""
+        """Per-layer ``(pool,)`` of shape (num_pages + 1, page_size, W): the
+        latent cache, ``W`` the ``cache_width`` values a token in whole lane
+        tiles (``alloc_paged_latent_cache``)."""
         from ..ops.attention import alloc_paged_latent_cache
 
         return alloc_paged_latent_cache(num_pages, page_size, self.cache_width,
                                         self._cfg["num_layers"], dtype=dtype)
 
     def paged_read_path(self, batch_size, pools, page_table):
-        """One token a row is read in the absorbed form (``mla_form``)."""
-        return "xla_gather_latent (absorbed; no latent kernel yet)"
+        """What a paged engine's decode program will read the latent pools
+        by: the Pallas kernel that fetches the pages a row holds, or the XLA
+        ``pool[page_table]`` gather and why (``F.latent_attention`` makes the
+        same choice from the same shapes at trace time). One token a row is
+        read in the form ``mla_form`` names."""
+        from ..ops.attention import mla_form
+        from ..ops.pallas_paged_attention import paged_latent_attention_refusal
+
+        c = self._cfg
+        form = mla_form(1, c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                        c["v_head_dim"], c["kv_lora_rank"])
+        q = jax.ShapeDtypeStruct(
+            (batch_size, 1, c["num_heads"], c["qk_nope_head_dim"]),
+            self.word_embed.weight.data()._data.dtype)
+        why = paged_latent_attention_refusal(q, pools[0][0], page_table, form)
+        return (f"xla_gather_latent ({form}; {why})" if why
+                else f"pallas_paged_latent_kernel ({form})")
 
     def logits_width(self):
         return self._cfg["vocab_size"]

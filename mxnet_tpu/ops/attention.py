@@ -325,14 +325,19 @@ def rotary_embedding(x, position=None, inv_freq=(), factor=1.0):
 
 def alloc_paged_latent_cache(num_pages, page_size, width, num_layers,
                              dtype="float32"):
-    """Per-layer ``(pool,)`` of shape (num_pages + 1, page_size, width): the
-    paged cache of latent attention, one ``width``-wide vector a token a
-    layer (the normalised latent and the rotated shared key side by side),
-    no head axis. Page 0 is the trash page, as in
+    """Per-layer ``(pool,)`` of shape (num_pages + 1, page_size, W): the
+    paged cache of latent attention, one vector a token a layer (the
+    normalised latent and the rotated shared key side by side, ``width``
+    values), no head axis. ``W`` is ``width`` rounded up to whole 128-lane
+    tiles (576 -> 640): a page is then a block the paged kernel can copy
+    (Mosaic refuses a slice of 576 lanes) and the lanes past ``width`` hold
+    zeros, always. Page 0 is the trash page, as in
     :func:`alloc_paged_kv_cache`."""
     from ..base import dtype_np
+    from .pallas_common import LANES
 
-    shape = (int(num_pages) + 1, int(page_size), int(width))
+    shape = (int(num_pages) + 1, int(page_size),
+             -(-int(width) // LANES) * LANES)
     return [(jnp.zeros(shape, dtype_np(dtype)),) for _ in range(int(num_layers))]
 
 
@@ -352,19 +357,36 @@ def _mla_softmax(scores, mask, scale, dtype):
     return jax.nn.softmax(scores, axis=-1).astype(dtype)
 
 
+def _absorb_queries(q_nope, w_kvb):
+    """``q_nope . W_UK``: the queries in the latent space, (B, T, H, kl)."""
+    w_uk = w_kvb[:, :q_nope.shape[-1]]                     # (H, d, kl)
+    return jnp.einsum("bthd,hdl->bthl", q_nope, w_uk,
+                      preferred_element_type=jnp.float32).astype(q_nope.dtype)
+
+
+def _up_project_values(o_lat, w_kvb, nope):
+    """``W_UV`` applied to the weighted latents (B, T, H, kl)."""
+    return jnp.einsum("bthl,hvl->bthv", o_lat, w_kvb[:, nope:],
+                      preferred_element_type=jnp.float32).astype(o_lat.dtype)
+
+
+def _weighted_latents(q_lat, q_rope, c_hist, r_hist, mask, scale):
+    """``softmax((q_lat . c + q_rope . r) * scale) . c``: the absorbed form
+    between its two up-projections, (B, T, H, kl) in the queries' dtype."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    scores = (jnp.einsum("bthl,bkl->bhtk", q_lat, c_hist, **f32)
+              + jnp.einsum("bthr,bkr->bhtk", q_rope, r_hist, **f32))
+    att = _mla_softmax(scores, mask, scale, q_lat.dtype)
+    return jnp.einsum("bhtk,bkl->bthl", att, c_hist, **f32).astype(q_lat.dtype)
+
+
 def _mla_absorbed(q_nope, q_rope, c_hist, r_hist, w_kvb, mask, scale):
     """Scores and outputs in the latent space: ``W_UK`` is folded into the
     queries and ``W_UV`` applied after the weighted sum, so nothing
     head-sized exists per cached position."""
-    nope = q_nope.shape[-1]
-    w_uk, w_uv = w_kvb[:, :nope], w_kvb[:, nope:]          # (H, d, kl)
-    f32 = dict(preferred_element_type=jnp.float32)
-    q_lat = jnp.einsum("bthd,hdl->bthl", q_nope, w_uk, **f32).astype(q_nope.dtype)
-    scores = (jnp.einsum("bthl,bkl->bhtk", q_lat, c_hist, **f32)
-              + jnp.einsum("bthr,bkr->bhtk", q_rope, r_hist, **f32))
-    att = _mla_softmax(scores, mask, scale, q_nope.dtype)
-    o_lat = jnp.einsum("bhtk,bkl->bthl", att, c_hist, **f32).astype(q_nope.dtype)
-    return jnp.einsum("bthl,hvl->bthv", o_lat, w_uv, **f32).astype(q_nope.dtype)
+    o_lat = _weighted_latents(_absorb_queries(q_nope, w_kvb), q_rope, c_hist,
+                              r_hist, mask, scale)
+    return _up_project_values(o_lat, w_kvb, q_nope.shape[-1])
 
 
 def _mla_decompressed(q_nope, q_rope, c_hist, r_hist, w_kvb, mask, scale):
@@ -412,13 +434,24 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
 
     ``cache=(pool,), position=, page_table=`` is the paged path
     (docs/INFERENCE.md "A model's per-layer state"): the new tokens'
-    ``[c_kv ; k_rope]`` are scattered into the pool at their rows' pages,
-    each row's history is gathered back by its page table (a chunk of
-    more than one token whose rows all start at position 0 reads the chunk
-    itself: a ``lax.cond`` on the positions), and the call returns
-    ``(context, pool')``. Nothing decompressed is ever cached.
+    ``[c_kv ; k_rope ; 0]`` (:func:`alloc_paged_latent_cache`'s whole lane
+    tiles) are scattered into the pool at their rows' pages, in place on a
+    donated pool, and the call returns ``(context, pool')``. Nothing
+    decompressed is ever cached. The read is one algorithm with two paths,
+    chosen from what the operands and the process show
+    (:func:`~mxnet_tpu.ops.pallas_paged_attention.paged_latent_attention_refusal`:
+    backend, dtypes, the pool's width and page size, the form, the VMEM
+    that Tq queries of every head against a row's history need): the Pallas
+    kernel copies the pages each row HOLDS into VMEM and attends there in
+    the absorbed form (decode); the XLA path gathers each row's history by
+    its page table, the table's whole width (a chunk of more than one token
+    whose rows all start at position 0 reads the chunk itself: a
+    ``lax.cond`` on the positions). ``mla_path_total{form, read}`` and
+    ``paged_read_path_total{path, reason}`` say which was built, and why
+    the kernel was not.
     """
     from .. import observability as obs
+    from . import pallas_paged_attention as ppa
 
     b, t, heads, nope = q_nope.shape
     kl, rope = c_kv.shape[-1], k_rope.shape[-1]
@@ -436,29 +469,43 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
     if position is None or page_table is None:
         raise ValueError("latent_attention(cache=...) is paged: it needs "
                          "position= and page_table=")
-    obs.counter("mla_path_total").inc(form=form, read="xla_gather")
     (pool,) = (_unwrap(c) for c in cache)
     position = jnp.asarray(_unwrap(position), jnp.int32)
     table = jnp.asarray(_unwrap(page_table), jnp.int32)
-    ps, n_pages = pool.shape[1], table.shape[1]
+    ps, n_pages, width = pool.shape[1], table.shape[1], pool.shape[2]
     cap = n_pages * ps
+    why = ppa.paged_latent_attention_refusal(q_nope, pool, table, form)
+    read = "xla_gather" if why else "kernel"
+    obs.counter("mla_path_total").inc(form=form, read=read)
+    obs.counter("paged_read_path_total").inc(path=read, reason=why or "")
     with jax.named_scope("kv"):
         pos = position[:, None] + q_idx[None, :]                   # (B, T)
         pid = jnp.take_along_axis(table, jnp.clip(pos // ps, 0, n_pages - 1),
                                   axis=1)
         pid = jnp.where(pos < cap, pid, 0)                 # overflow -> trash
-        new = jnp.concatenate([c_kv, k_rope], axis=-1).astype(pool.dtype)
+        # the pool's lanes past kl + rope are written as zeros, and never
+        # anything else: a zero query lane does not clear a NaN
+        new = jnp.pad(jnp.concatenate([c_kv, k_rope], axis=-1),
+                      ((0, 0), (0, 0), (0, width - kl - rope))).astype(pool.dtype)
         pool = pool.at[pid.reshape(-1), (pos % ps).reshape(-1)].set(
-            new.reshape(b * t, kl + rope))
+            new.reshape(b * t, width))
+
+    def from_kernel():
+        # the pages each row holds, in VMEM; W_UK and W_UV stay XLA's
+        with jax.named_scope("core"):
+            o_lat = ppa.paged_latent_attention_read(
+                _absorb_queries(q_nope, w_kvb), q_rope, pool, table, position,
+                scale)
+            return _up_project_values(o_lat.astype(q_nope.dtype), w_kvb, nope)
 
     def from_pool():
         # every row's history by its page table: the table's whole width
         with jax.named_scope("kv"):
-            hist = pool[table].reshape(b, cap, kl + rope).astype(c_kv.dtype)
+            hist = pool[table].reshape(b, cap, width).astype(c_kv.dtype)
         mask = jnp.arange(cap, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
         with jax.named_scope("core"):
-            return core(q_nope, q_rope, hist[..., :kl], hist[..., kl:], w_kvb,
-                        mask, scale)
+            return core(q_nope, q_rope, hist[..., :kl], hist[..., kl:kl + rope],
+                        w_kvb, mask, scale)
 
     def from_chunk():
         # rows that start at 0 have no history but the chunk itself, as the
@@ -466,14 +513,17 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
         held = new.astype(c_kv.dtype)
         mask = jnp.broadcast_to(q_idx[None, :] <= q_idx[:, None], (b, t, t))
         with jax.named_scope("core"):
-            return core(q_nope, q_rope, held[..., :kl], held[..., kl:], w_kvb,
-                        mask, scale)
+            return core(q_nope, q_rope, held[..., :kl], held[..., kl:kl + rope],
+                        w_kvb, mask, scale)
 
     # a prefill chunk that opens its rows (no adopted prefix) reads t keys,
     # not the page table's width; the program holds both and the positions
     # it is handed choose. One token a row always has a history.
-    out = from_pool() if t == 1 else jax.lax.cond(
-        jnp.all(position == 0), from_chunk, from_pool)
+    if why is None:
+        out = from_kernel()
+    else:
+        out = from_pool() if t == 1 else jax.lax.cond(
+            jnp.all(position == 0), from_chunk, from_pool)
     return out.reshape(b, t, heads * vd), pool
 
 

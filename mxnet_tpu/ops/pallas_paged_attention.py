@@ -40,6 +40,16 @@ VMEM a row's two histories and score block need, which is what sends decode
 and speculative verification here and a long prefill chunk to the XLA path.
 On the CPU the operator takes the XLA path (it is the dense cache's own
 arithmetic, bit for bit); the tests run this kernel interpreted against it.
+
+**The latent pool's kernel** (``paged_latent_attention_decode``; DeepSeek-V2's
+absorbed form, :func:`paged_latent_attention_read`) is the same pattern over
+ONE pool ``(P+1, page_size, W)``, ``W`` whole lane tiles holding ``[latent ;
+rotated key ; 0]`` a token: a page is one copy, the history ``(cap, W)`` is
+key and value at once, and because latent attention has one key for every
+head, all heads are simply the rows of one left operand ``(H*Tq, W)`` =
+``[q . W_UK ; q_rope ; 0]``: scores are one product over ``W`` lanes, the
+output one product over the latent's lanes. Its gate is
+:func:`paged_latent_attention_refusal`.
 """
 from __future__ import annotations
 
@@ -292,3 +302,165 @@ def paged_attention(q, k_new, v_new, k_pool, v_pool, page_table, position,
     out = paged_attention_read(q, k_pool, v_pool, page_table, position,
                                interpret=interpret)
     return out, k_pool, v_pool
+
+
+# --------------------------------------------------------------------------
+# the latent pool (multi-head latent attention, absorbed form)
+# --------------------------------------------------------------------------
+def _latent_vmem_bytes(r, w, cap, itemsize):
+    """Two history slots, the score-shaped temporaries of ``r`` stacked
+    queries, and the double-buffered query and output blocks (the output
+    counted at the pool's whole width)."""
+    return (2 * cap * w * itemsize + _SCORE_TEMPS * r * cap * 4
+            + 2 * r * w * (itemsize + 4))
+
+
+def paged_latent_attention_refusal(q, pool, page_table, form="absorbed"):
+    """Why the latent kernel does NOT read the pool for these operands
+    (anything with ``.shape``/``.dtype``), or None when it does: ``q``
+    ``(B, Tq, H, d)`` (either part of the queries), ``pool`` ``(P+1,
+    page_size, W)``, ``page_table`` ``(B, n_pages)``, ``form`` what
+    ``attention.mla_form`` chose for ``Tq``. The first condition that fails
+    is the one named; callers take the XLA gather then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    _, tq, h, _ = q.shape
+    ps, w = pool.shape[1], pool.shape[2]
+    cap = page_table.shape[1] * ps
+    if pool.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"pool dtype {jnp.dtype(pool.dtype).name} is not float32 or bfloat16"
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"query dtype {jnp.dtype(q.dtype).name} is not float32 or bfloat16"
+    if w % _LANES:
+        return f"the pool's {w} columns are not whole {_LANES}-lane tiles"
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    sub = 8 * (4 // itemsize)
+    if ps % sub or (_LANES % ps and ps % _LANES):
+        return (f"page size {ps} is not a multiple of {sub} sublanes that "
+                f"divides or is a multiple of {_LANES}")
+    if form != "absorbed":
+        return f"the {form} form reads keys and values up-projected by XLA"
+    need = _latent_vmem_bytes(_rows(h, tq, itemsize), w, cap, itemsize)
+    if need > _MAX_VMEM_BYTES:
+        return (f"{tq} queries a row of {h} heads against {cap} positions "
+                f"need {need} bytes of VMEM (budget {_MAX_VMEM_BYTES})")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _latent_kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, hist, sem, *,
+                   ps, n_pages, tq, heads, scale):
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    r, kw = o_ref.shape[1:]
+    slot = b % 2
+
+    def pages_of(row):
+        # as _kernel: a released or overflowing row reads what its table names
+        return jnp.clip((pos_ref[row] + (tq - 1)) // ps + 1, 1, n_pages)
+
+    def for_each_copy(row, into, act):
+        def page(j, carry):
+            pid = table_ref[row * n_pages + j]
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            act(pltpu.make_async_copy(pool_ref.at[pid], hist.at[into, at, :],
+                                      sem.at[into]))
+            return carry
+
+        lax.fori_loop(0, pages_of(row), page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        for_each_copy(0, 0, lambda copy: copy.start())
+
+    @pl.when(b + 1 < rows)
+    def _():
+        for_each_copy(b + 1, 1 - slot, lambda copy: copy.start())
+
+    for_each_copy(b, slot, lambda copy: copy.wait())
+    held = pages_of(b)
+
+    def attend(n):
+        keys = n * ps
+
+        def clear(j, carry):
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            hist[slot, at, :] = jnp.zeros((ps, hist.shape[2]), hist.dtype)
+            return carry
+
+        lax.fori_loop(held, n, clear, 0)
+        # row i*heads + h of the stacked operand is query i of head h
+        row = lax.broadcasted_iota(jnp.int32, (r, keys), 0)
+        i = jnp.zeros_like(row)
+        for s in range(1, tq):
+            i = jnp.where(row >= s * heads, s, i)
+        visible = (lax.broadcasted_iota(jnp.int32, (r, keys), 1)
+                   <= pos_ref[b] + i)
+        s = lax.dot_general(q_ref[0], hist[slot, pl.ds(0, keys), :], _NT,
+                            preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
+        # the history is its own value: the latent's lanes
+        o_ref[0] = lax.dot_general(
+            p.astype(hist.dtype), hist[slot, pl.ds(0, keys), pl.ds(0, kw)],
+            _NN, preferred_element_type=jnp.float32)
+
+    below = 0
+    for n in _page_buckets(ps, n_pages):
+        pl.when((held > below) & (held <= n))(functools.partial(attend, n))
+        below = n
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _latent_call(table, position, q2, pool, tq, heads, kw, scale, interpret):
+    b, r, w = q2.shape
+    ps = pool.shape[1]
+    n_pages = table.shape[0] // b
+    row = lambda i, t, p: (i, 0, 0)  # noqa: E731
+    need = _latent_vmem_bytes(r, w, n_pages * ps,
+                              jnp.dtype(pool.dtype).itemsize)
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, ps=ps, n_pages=n_pages, tq=tq,
+                          heads=heads, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, r, kw), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, r, w), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, r, kw), row),
+            scratch_shapes=[pltpu.VMEM((2, n_pages * ps, w), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        name="paged_latent_attention_decode",
+        interpret=interpret,
+        # rows run in order: each starts the next one's copies
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + 8 * 1024 * 1024),
+    )(table, position, q2, pool)
+
+
+def paged_latent_attention_read(q_lat, q_rope, pool, page_table, position,
+                                scale, interpret=None):
+    """Absorbed latent attention of ``q_lat`` ``(B, Tq, H, kl)`` (the
+    queries with ``W_UK`` folded in) and ``q_rope`` ``(B, Tq, H, rope)``
+    over each row's paged history under the frontier mask, the pool ``(P+1,
+    page_size, W)`` of ``[latent ; rotated key ; 0]`` read by the pages the
+    row holds: ``softmax((q_lat . c + q_rope . r) * scale) . c``. Returns
+    the weighted latents ``(B, Tq, H, kl)`` float32; ``W_UV`` is the
+    caller's. Callers gate via :func:`paged_latent_attention_refusal`."""
+    b, tq, h, kl = q_lat.shape
+    w = pool.shape[2]
+    q2 = jnp.concatenate([q_lat, q_rope], axis=-1).astype(pool.dtype)
+    r = _rows(h, tq, jnp.dtype(pool.dtype).itemsize)
+    q2 = jnp.pad(q2.reshape(b, tq * h, -1),
+                 ((0, 0), (0, r - tq * h), (0, w - q2.shape[-1])))
+    kw = -(-kl // _LANES) * _LANES  # the latent's lanes, whole tiles
+    o2 = _latent_call(jnp.asarray(page_table, jnp.int32).reshape(-1),
+                      jnp.asarray(position, jnp.int32), q2, pool, tq, h, kw,
+                      float(scale), _resolve_interpret(interpret))
+    return o2[:, :tq * h, :kl].reshape(b, tq, h, kl)

@@ -18,7 +18,7 @@ def test_smoke_refuses_cpu_before_building_anything(monkeypatch):
         raise AssertionError("a phase ran without a TPU")
 
     for phase in ("phase_train", "phase_train_four_chips", "phase_serve",
-                  "phase_kernels"):
+                  "phase_serve_latent", "phase_kernels"):
         monkeypatch.setattr(chip_smoke, phase, built)
     monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
     with pytest.raises(RuntimeError, match=r"needs a TPU.*'cpu'"):
@@ -31,7 +31,8 @@ def test_smoke_last_line_is_the_drivers_contract(monkeypatch, capsys):
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     monkeypatch.setattr(chip_smoke, "phase_device",
                         lambda n: ([object()], device, {"jax": "x"}))
-    for phase in ("phase_train", "phase_serve", "phase_kernels"):
+    for phase in ("phase_train", "phase_serve", "phase_serve_latent",
+                  "phase_kernels"):
         monkeypatch.setattr(chip_smoke, phase, lambda *a: {"found": 1})
     monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
     chip_smoke.main()
@@ -39,7 +40,7 @@ def test_smoke_last_line_is_the_drivers_contract(monkeypatch, capsys):
     # exactly these keys: the driver refuses a line that carries any other
     assert json.loads(lines[-1]) == {"ok": True, "device": device}
     assert all(p in lines[-2] for p in ("summary", "train", "serve",
-                                        "kernels"))
+                                        "latent", "kernels"))
 
 
 def test_compile_cache_placement(monkeypatch, tmp_path):
@@ -67,7 +68,8 @@ def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
     from mxnet_tpu.ops.flash_attention import flash_attention
     from mxnet_tpu.ops.pallas_layernorm import layer_norm_fused
     from mxnet_tpu.ops.pallas_optimizer import adam_update_fused
-    from mxnet_tpu.ops.pallas_paged_attention import paged_attention
+    from mxnet_tpu.ops.pallas_paged_attention import (
+        paged_attention, paged_latent_attention_read)
     from mxnet_tpu.ops.pallas_softmax_xent import softmax_cross_entropy_fused
 
     x = jnp.zeros((8, 128), jnp.float32)
@@ -83,6 +85,10 @@ def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
         lambda: paged_attention(
             q[:, :, :1, :], q[:, :, :1, :], q[:, :, :1, :], pool, pool,
             jnp.ones((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+            interpret=True),
+        lambda: paged_latent_attention_read(
+            q[:, :1, :4, :32], q[:, :1, :4, :8], jnp.zeros((3, 8, 128)),
+            jnp.ones((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 1.0,
             interpret=True),
     ]
     assert pallas_common.resolve_interpret(None) is True  # the CPU tests

@@ -112,11 +112,12 @@ def test_prefill_then_decode_through_the_latent_cache_matches_the_reference(mode
         got = np.stack([first[i]] + logits[i])
         np.testing.assert_allclose(got, want, atol=2e-5)
         assert out == want.argmax(axis=-1).tolist()
-    # one 40-wide pool a layer, nothing decompressed in it
+    # one pool a layer, the 40 values a token in one whole lane tile, nothing
+    # decompressed in it; the gauge is what is allocated
     assert [tuple(b.shape for b in layer) for layer in engine.pools] == \
-        [((65, 8, 40),)] * 3
-    assert engine.cache_bytes_per_token == 40 * 4 * 3
-    assert obs.gauge("gen_cache_bytes_per_token").value() == 480.0
+        [((65, 8, 128),)] * 3
+    assert engine.cache_bytes_per_token == 128 * 4 * 3
+    assert obs.gauge("gen_cache_bytes_per_token").value() == 1536.0
     assert "latent" in engine.read_path
     record = obs.step_records("decode_step")[-1]
     assert set(record.counts) == {"moe_pairs_held", "moe_max_load"}
@@ -277,7 +278,7 @@ def test_two_kinds_of_per_layer_state_in_one_process(model):
                                    page_size=8, num_pages=16, max_length=64)}
     assert [len(e.pools[0]) for e in engines.values()] == [2, 1]
     assert engines["kv"].pools[0][0].shape == (17, 8, 32)
-    assert engines["latent"].pools[0][0].shape == (17, 8, 40)
+    assert engines["latent"].pools[0][0].shape == (17, 8, 128)
     assert engines["kv"].cache_bytes_per_token == 2 * 2 * 32 * 4
     assert engines["kv"]._last_vocab() == 100
     assert engines["latent"]._last_vocab() == cfg["n_vocab"]

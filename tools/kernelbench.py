@@ -1,5 +1,6 @@
 """On-chip check + timing of the Pallas kernels (flash attention, fused
-LayerNorm, paged decode-attention, fused Adam, fused softmax-xent) against
+LayerNorm, paged decode-attention over key/value pools and over a latent
+pool, fused Adam, fused softmax-xent) against
 their XLA compositions.
 
 Send it through the chip tool. The parent never imports jax, so it never
@@ -42,6 +43,12 @@ PAGED_CASES = [(64, 16, 64, 16, 64, 1, 128), (64, 16, 64, 16, 64, 1, 0),
                (64, 16, 64, 16, 64, 1, 1024), (64, 16, 64, 16, 64, 5, 256),
                (1, 16, 64, 16, 64, 64, 64), (8, 8, 128, 16, 64, 1, 512),
                (8, 8, 128, 16, 128, 1, 0)]
+# paged latent attention (DeepSeek-V2's absorbed decode): (b, h, kl, rope,
+# page_size, n_pages, tq, held), held as above; one layer of the serving
+# cell's decode batch with short rows, the cell's rows, and rows of any length
+LATENT_CASES = [(128, 128, 512, 64, 16, 256, 1, 128),
+                (128, 128, 512, 64, 16, 256, 1, 1024),
+                (128, 128, 512, 64, 16, 256, 1, 0)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -60,6 +67,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     LN_CASES = [(512, 256)]
     CONV_CASES = [(2, 8, 14, 14, 8, 3)]
     PAGED_CASES = [(2, 2, 32, 8, 4, 1, 0), (2, 2, 64, 16, 8, 3, 40)]
+    LATENT_CASES = [(2, 4, 32, 8, 16, 8, 1, 0), (2, 4, 32, 8, 16, 16, 2, 100)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -286,6 +294,70 @@ def run_paged_case(b, h, ch, ps, n_pages, tq, held, reps):
     return case
 
 
+def run_latent_case(b, h, kl, rope, ps, n_pages, tq, held, reps):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rng = np.random.RandomState(0)
+    pool_pages = b * n_pages
+    cap = n_pages * ps
+    (pool,), = att.alloc_paged_latent_cache(pool_pages, ps, kl + rope, 1,
+                                            "bfloat16")
+    pool = pool.at[..., :kl + rope].set(jnp.asarray(
+        rng.randn(pool_pages + 1, ps, kl + rope), jnp.bfloat16))
+    table = jnp.asarray(rng.randint(1, pool_pages + 1, (b, n_pages)), jnp.int32)
+    hi = min(held or cap, cap) - tq
+    position = jnp.asarray(rng.randint(hi // 2 if held else 0, hi + 1, (b,)),
+                           jnp.int32)
+    # queries already in the latent space, bfloat16 as the cell's activations
+    q_lat = jnp.asarray(rng.randn(b, tq, h, kl) * 0.3, jnp.bfloat16)
+    q_rope = jnp.asarray(rng.randn(b, tq, h, rope) * 0.3, jnp.bfloat16)
+    scale = 0.1147   # DeepSeek-V2's, YaRN's factor in it
+    case = {"kind": "paged_latent", "b": b, "h": h, "kl": kl, "rope": rope,
+            "ps": ps, "n_pages": n_pages, "tq": tq, "pool_width": pool.shape[2],
+            "held_positions": int(position.sum()) + b * tq}
+    if not _INTERP:
+        case["gate"] = ppa.paged_latent_attention_refusal(
+            q_lat, pool, table) or "kernel"
+
+    def gather_ref(q_lat):
+        hist = pool[table].reshape(b, cap, pool.shape[2])
+        pos = position[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+        mask = jnp.arange(cap, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
+        return att._weighted_latents(q_lat, q_rope, hist[..., :kl],
+                                     hist[..., kl:kl + rope], mask, scale)
+
+    def kernel(q_lat):
+        return ppa.paged_latent_attention_read(q_lat, q_rope, pool, table,
+                                               position, scale,
+                                               interpret=_INTERP)
+
+    if _INTERP:   # XLA:CPU lacks some bfloat16 products with float32 results
+        q_lat, q_rope, pool = (x.astype(jnp.float32)
+                               for x in (q_lat, q_rope, pool))
+    ref, out = gather_ref(q_lat).astype(jnp.float32), kernel(q_lat)
+    err = float(jnp.max(jnp.abs(out - ref)))
+    case["max_err"] = round(err, 6)
+    # the gather path rounds its output to bfloat16; the kernel returns the
+    # float32 sums: weighted means of values of size about 1 agree to 1e-2
+    case["correct"] = bool(err < 0.02 and jnp.isfinite(out).all())
+    del ref, out
+    for label, f in (("kernel", kernel), ("gather", gather_ref)):
+        try:
+            case[f"{label}_ms"] = round(_timeit(f, (q_lat,), reps) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:120]
+    if "kernel_ms" in case and "gather_ms" in case:
+        case["kernel_vs_gather"] = round(case["gather_ms"] / case["kernel_ms"], 2)
+    if "kernel_ms" in case:   # bytes of the positions held, as the pool holds them
+        gb = case["held_positions"] * pool.shape[2] * 2 / 1e9
+        case["kernel_gb_per_s"] = round(gb / (case["kernel_ms"] / 1e3), 1)
+    return case
+
+
 def run_adam_case(n, reps):
     import jax.numpy as jnp
     import numpy as np
@@ -388,6 +460,10 @@ def run_one(argv):
             case = run_paged_case(spec["b"], spec["h"], spec["ch"],
                                   spec["ps"], spec["n_pages"], spec["tq"],
                                   spec["held"], spec["reps"])
+        elif spec["kind"] == "paged_latent":
+            case = run_latent_case(spec["b"], spec["h"], spec["kl"],
+                                   spec["rope"], spec["ps"], spec["n_pages"],
+                                   spec["tq"], spec["held"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -408,7 +484,7 @@ def main():
     ap.add_argument("--fwd-only", action="store_true")
     ap.add_argument("--kinds", default="",
                     help="comma-separated case kinds to run (attn, ln, "
-                         "conv_layout, paged_attn, fused_adam, "
+                         "conv_layout, paged_attn, paged_latent, fused_adam, "
                          "softmax_xent); default all")
     ap.add_argument("--timeout", type=int, default=600)
     args = ap.parse_args()
@@ -427,6 +503,10 @@ def main():
     specs += [{"kind": "paged_attn", "b": b, "h": h, "ch": ch, "ps": ps,
                "n_pages": np_, "tq": tq, "held": held, "reps": args.reps}
               for b, h, ch, ps, np_, tq, held in PAGED_CASES]
+    specs += [{"kind": "paged_latent", "b": b, "h": h, "kl": kl, "rope": rope,
+               "ps": ps, "n_pages": np_, "tq": tq, "held": held,
+               "reps": args.reps}
+              for b, h, kl, rope, ps, np_, tq, held in LATENT_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}

@@ -9,7 +9,9 @@ ONE decode step and ONE prefill by scope, layer numbers folded, and by
 operation the time of every operation whose result has a page pool's shape
 (``pool_shaped_ms``: an in-place scatter is some 0.01 ms; a layout change
 or a copy of the pool is 0.8 ms for GPT-2 345M's 134 MB, and 86 of them
-were a prefill's 72 ms until PR 28; no CPU test can see them).
+were a prefill's 72 ms until PR 28; no CPU test can see them), and the time of
+the paged kernels by name (``paged_kernel_ms``; they also stand under their
+scope, ``layerN/attn`` or ``layerN/mla/core``).
 
     python tools/servescope.py --workload deepseek_v2_serve_reason --seed 7
     JAX_PLATFORMS=cpu python tools/servescope.py --tiny     # rehearsal
@@ -66,8 +68,9 @@ def by_scope(report, table, per):
 
 def scopes_and_pool_ops(engine, report, lowered, per):
     """(ms by scope, {opcode: ms} of the traced operations whose result has
-    the shape of one of the engine's page pools), per ``per`` calls, from one
-    more compile of ``lowered`` (``scopes.scoped_text`` says why)."""
+    the shape of one of the engine's page pools, {kernel: ms} of the paged
+    Pallas kernels), per ``per`` calls, from one more compile of ``lowered``
+    (``scopes.scoped_text`` says why)."""
     from mxnet_tpu.observability.scopes import (instruction_name,
                                                 op_scopes_from_hlo,
                                                 scoped_text)
@@ -82,12 +85,17 @@ def scopes_and_pool_ops(engine, report, lowered, per):
             text, re.M):
         if shape in pools:
             opcode[name] = op
-    ms = {}
+    ms, kernels = {}, {}
     for row, own in zip(report.op_rows, report._self_times()):
-        op = opcode.get(instruction_name(row.hlo_op or row.name))
+        name = instruction_name(row.hlo_op or row.name)
+        op = opcode.get(name)
         if op:
             ms[op] = ms.get(op, 0.0) + own / 1e6 / per
-    return by_scope(report, table, per), {k: round(v, 4) for k, v in ms.items()}
+        kernel = re.match(r"paged_\w*attention\w*?(?=[.\d]*$)", name)
+        if kernel:
+            kernels[kernel[0]] = kernels.get(kernel[0], 0.0) + own / 1e6 / per
+    rounded = lambda d: {k: round(v, 4) for k, v in d.items()}  # noqa: E731
+    return by_scope(report, table, per), rounded(ms), rounded(kernels)
 
 
 def main(argv):
@@ -113,22 +121,24 @@ def main(argv):
     out = {"workload": args.workload, "rows": int((~engine.done).sum()),
            "held_positions": held, "read_path": engine.read_path}
     cap = profiling.capture(engine.decode_step, steps=args.steps, warmup=2)
-    out["decode_ms_by_scope"], pool_ms = scopes_and_pool_ops(
+    out["decode_ms_by_scope"], pool_ms, kernel_ms = scopes_and_pool_ops(
         engine, cap.report, engine.lower_decode(), args.steps)
     out["pool_shaped_ms"] = {"decode": pool_ms}
+    out["paged_kernel_ms"] = {"decode": kernel_ms}
     median = mix["prompt_len"]["median"]
     prompt = rng.integers(1, config["n_vocab"], median).tolist()
     cap = profiling.capture(lambda: engine.prefill(prompt, 0),
                             steps=args.prefills, warmup=1)
     out["prefill_bucket"] = engine.bucket_for(median)
-    out["prefill_ms_by_scope"], out["pool_shaped_ms"]["prefill"] = (
-        scopes_and_pool_ops(engine, cap.report, engine.lower_prefill(median),
-                            args.prefills))
+    (out["prefill_ms_by_scope"], out["pool_shaped_ms"]["prefill"],
+     out["paged_kernel_ms"]["prefill"]) = scopes_and_pool_ops(
+        engine, cap.report, engine.lower_prefill(median), args.prefills)
     for key in ("decode_ms_by_scope", "prefill_ms_by_scope"):
         print(f"[servescope] {key} (sum {sum(out[key].values()):.3f} ms):")
         for path, ms in out[key].items():
             print(f"[servescope]   {ms:9.3f}  {path}")
     print(f"[servescope] pool_shaped_ms: {out['pool_shaped_ms']}")
+    print(f"[servescope] paged_kernel_ms: {out['paged_kernel_ms']}")
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "servescope.jsonl"), "a") as f:
         f.write(json.dumps(out) + "\n")
