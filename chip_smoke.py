@@ -510,6 +510,56 @@ def check_paged(rows=8, h=16, ch=64, ps=16, n_pages=64, interpret=None):
             "tpu_custom_calls": calls, "rel_err": round(err, 6)}
 
 
+def check_index_scores(rows=8, j=64, d=128, ps=16, n_pages=128,
+                       interpret=None):
+    """The long-context cell's geometry, which the kernel's own gate admits:
+    64 indexer heads of 128 over a bfloat16 key pool, one query a row, rows
+    of every length; the pages no row holds are NaN and count for nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rs = np.random.RandomState(SEED)
+    pool_pages = rows * n_pages
+    position = rs.randint(0, n_pages * ps - 1, rows)
+    table = rs.permutation(pool_pages).reshape(rows, n_pages) + 1
+    pool = rs.randn(pool_pages + 1, ps, d).astype("f4")
+    for r, p in enumerate(position):          # what a row does not hold
+        pool[table[r, p // ps + 1:]] = np.nan
+    pool, table = jnp.asarray(pool, jnp.bfloat16), jnp.asarray(table, jnp.int32)
+    position = jnp.asarray(position, jnp.int32)
+    q = jnp.asarray(rs.randn(rows, j, d), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(rows, j), jnp.float32)
+    why = ppa.paged_index_scores_refusal(q[:, None], pool, table)
+    if why is not None:
+        raise AssertionError(f"index scores: the kernel's gate refuses: {why}")
+
+    def kernel(*a):
+        return ppa.paged_index_scores(*a, interpret=interpret)
+
+    a = (q, w, pool, table, position)
+    calls = _custom_calls(kernel, *a)
+    if calls < 1:
+        raise AssertionError("index scores: lowered without its Mosaic kernel")
+    got = jax.jit(kernel)(*a)
+    held = jnp.arange(n_pages * ps)[None, :] <= position[:, None]
+    keys = jnp.nan_to_num(pool)[table].reshape(rows, n_pages * ps, d)
+    want = att.index_scores(q[:, None], keys, w[:, None])[:, 0]
+    if not bool(jnp.array_equal(jnp.isneginf(got), ~held)):
+        raise AssertionError("index scores: -inf is not exactly what the "
+                             "rows do not hold")
+    err = _rel_err(jnp.where(held, got, 0.0), jnp.where(held, want, 0.0))
+    if not err < 2e-2:
+        raise AssertionError(f"index scores: relative error {err} against "
+                             "the XLA gather path")
+    return {"rows": rows, "heads": j, "head": d, "page": ps,
+            "pages_per_row": n_pages, "pool": "bfloat16",
+            "tpu_custom_calls": calls, "rel_err": round(err, 6)}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -574,6 +624,7 @@ def phase_kernels():
     out = {"flash_attention": [check_flash(causal=False),
                                check_flash(causal=True)],
            "paged_attention": check_paged(),
+           "paged_index_scores": check_index_scores(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

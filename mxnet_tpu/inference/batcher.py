@@ -696,9 +696,10 @@ class ContinuousBatcher:
             # cached prefix is adopted by refcount bump, so its pages are
             # free as far as admission is concerned; eviction headroom
             # (available_pages >= free_pages) counts too — prefill evicts
-            # cache-only pages itself when the free list runs short
+            # cache-only pages itself when the free list runs short. Every
+            # pool group has to cover it: the one that runs short decides
             need = eng.pages_needed(head.prompt)
-            if eng.available_pages >= need:
+            if eng.covers(head.prompt):
                 eng.reserve_pages(0)
                 self._head_id = None
                 self._head_deferrals = 0
@@ -723,10 +724,9 @@ class ContinuousBatcher:
                 break
             # bypass: the first later request the unreserved pool covers
             # (the head keeps its queue position)
-            avail = eng.free_pages - eng.reserved_pages
             cand = next((i for i in range(1, len(self._queue))
-                         if eng.pages_needed(self._queue[i].prompt)
-                         <= avail), None)
+                         if eng.covers(self._queue[i].prompt,
+                                       unreserved=True)), None)
             if cand is None:
                 break
             req = self._queue[cand]

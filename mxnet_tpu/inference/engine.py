@@ -118,6 +118,76 @@ def _default_buckets(max_length: int) -> Tuple[int, ...]:
     return tuple(out) or (max_length - 1,)
 
 
+class _WindowPages:
+    """The host allocator of a ``window`` page group: the pools of layers
+    that attend only the last ``window`` positions. A row holds the pages
+    its window reaches and no other: the pages behind the window go back to
+    the free list while the row lives. The group has a page table of its
+    own, a RING of ``columns = window // page_size + 3`` columns a row:
+    logical page ``s`` (positions ``s * page_size ...``) lives in column
+    ``s % columns``, and a row never holds more pages than ``columns - 1``.
+    Pages are never shared (no prefix cache, no fork), so there are no
+    reference counts."""
+
+    def __init__(self, num_pages, batch_size, page_size, window):
+        self.num_pages, self.page_size = int(num_pages), int(page_size)
+        self.window = int(window)
+        self.columns = self.window // self.page_size + 3
+        self.free: deque = deque(range(1, self.num_pages + 1))
+        #: per row {logical page: page id}
+        self.rows: List[dict] = [{} for _ in range(batch_size)]
+        self.reserved = 0  # free pages held back for a parked queue head
+        self.freed_total = 0
+
+    @property
+    def in_use(self) -> int:
+        return self.num_pages - len(self.free)
+
+    def low_page(self, position: int) -> int:
+        """The first logical page a row whose next token lies at
+        ``position`` still reads."""
+        return max(0, position - self.window + 1) // self.page_size
+
+    def needed(self, length: int) -> int:
+        """Pages a prefill of ``length`` tokens takes."""
+        return (length - 1) // self.page_size - self.low_page(length) + 1
+
+    def release(self, slot: int) -> int:
+        pages = self.rows[slot]
+        self.rows[slot] = {}
+        self.free.extend(pages.values())
+        return len(pages)
+
+    def admit(self, slot: int, length: int) -> np.ndarray:
+        """Give row ``slot`` the pages a ``length``-token prompt keeps (the
+        caller has checked that they are there); returns its table row."""
+        row = np.zeros(self.columns, np.int32)
+        held = {}
+        for s in range(self.low_page(length),
+                       (length - 1) // self.page_size + 1):
+            held[s] = row[s % self.columns] = self.free.popleft()
+        self.rows[slot] = held
+        return row
+
+    def step(self, slot: int, position: int):
+        """Before row ``slot`` writes at ``position``: the page of that
+        position, taken now if the row lacks it, and the pages now behind
+        the window, freed. Returns [(column, page id, or -1 for a freed
+        column)], or None where the pool (less its reservation) is dry."""
+        held, updates = self.rows[slot], []
+        for s in [s for s in held if s < self.low_page(position)]:
+            self.free.append(held.pop(s))
+            updates.append((s % self.columns, -1))
+            self.freed_total += 1
+        page = position // self.page_size
+        if page not in held:
+            if len(self.free) - self.reserved <= 0:
+                return None
+            held[page] = self.free.popleft()
+            updates.append((page % self.columns, held[page]))
+        return updates
+
+
 class GenerationEngine:
     """Compiled autoregressive generation over a static decode batch.
 
@@ -141,7 +211,21 @@ class GenerationEngine:
           - optionally ``paged_read_path(batch_size, pools, page_table)``
             (what the decode program reads the cache by, for
             ``engine.read_path``) and ``logits_width()`` (the vocabulary
-            the logits span).
+            the logits span);
+          - optionally **pool groups** (docs/INFERENCE.md "Pool groups"):
+            ``paged_pool_groups`` = {group: rule}, the rule ``{}`` (the
+            group's layers keep every position) or ``{"window": w}`` (they
+            keep the last ``w``: the engine frees the pages behind the
+            window while the row lives). ``init_paged_cache`` is then given
+            ``num_pages`` as {group: pages} and returns ``(pools, group of
+            each layer)``; the forward takes ``page_table=`` as one table a
+            group. Each group has its own host allocator, free list, page
+            table and gauges. A model that declares nothing has the one
+            group ``all``. The prefix cache, forks and speculation are
+            refused for a model with a ``window`` group;
+          - optionally ``takes_last_pos = True``: the paged prefill passes
+            ``last_pos=`` (the prompt's last real position, (1,) int32) and
+            gets that position's logits alone, (1, 1, V).
 
         Dropout should be 0 for exact equivalence (evaluation mode disables
         it regardless).
@@ -159,6 +243,8 @@ class GenerationEngine:
     num_pages : pool capacity in pages, excluding the reserved trash page.
         Default: ``batch_size * ceil(max_length / page_size)`` (the
         dense-equivalent capacity — size it DOWN to oversubscribe slots).
+        For a model with pool groups, {group: pages} (an int sizes ``all``;
+        a ``window`` group defaults to what every row can hold at once).
     draft_net : small initialized model drafting ``speculate_k`` tokens per
         round through its own paged cache (requires ``paged=True``; pass
         ``net`` itself to self-draft). Greedy sampling verifies by exact
@@ -251,20 +337,68 @@ class GenerationEngine:
             #: page-table width: page slots per row (slot s = positions
             #: s*ps .. (s+1)*ps - 1)
             self._n_row_pages = -(-self.max_length // self.page_size)
+            #: the model's pool groups ({group: rule}); one group ``all``
+            #: where it declares none
+            groups = dict(getattr(net, "paged_pool_groups", None) or {})
+            windows = {g: int(r["window"]) for g, r in groups.items()
+                       if r.get("window")}
+            if len(windows) > 1 or set(groups) - set(windows) - {"all"}:
+                raise ValueError(
+                    f"pool groups {groups}: the engine keeps one group "
+                    "'all' and at most one group with a window")
+            per_group = dict(num_pages) if isinstance(num_pages, dict) \
+                else {"all": num_pages}
+            if set(per_group) - (set(groups) or {"all"}):
+                raise ValueError(f"num_pages names groups {sorted(per_group)}"
+                                 f"; the model has {sorted(groups) or ['all']}")
             # explicit `is None` check: a computed num_pages that underflows
             # to 0 must hit the error below, not the dense-equivalent default
             self.num_pages = int(self.batch_size * self._n_row_pages
-                                 if num_pages is None else num_pages)
+                                 if per_group.get("all") is None
+                                 else per_group["all"])
             if self.num_pages < 1:
                 raise ValueError("num_pages must be >= 1")
-            #: device carry: per-row page tables (0 = unallocated/trash)
+            #: the ``window`` group's allocator (None: the model has none)
+            self._window = None
+            for g, w in windows.items():
+                if prefix_cache or draft_net is not None:
+                    raise ValueError(
+                        f"the model's pool group {g!r} frees the pages behind "
+                        f"a window of {w} positions: a prefix cached or a "
+                        "draft verified there could need a page that is "
+                        "gone. prefix_cache= and draft_net= are refused for "
+                        "such a model")
+                pages = per_group.get(g)
+                self._window = _WindowPages(
+                    self.batch_size * (w // self.page_size + 3)
+                    if pages is None else pages,
+                    self.batch_size, self.page_size, w)
+                if self._window.num_pages < 1:
+                    raise ValueError("num_pages must be >= 1")
+            #: device carry: per-row page tables (0 = unallocated/trash);
+            #: with a window group a tuple, one table a group
             self.page_table = jnp.zeros(
                 (self.batch_size, self._n_row_pages), jnp.int32)
             #: device carry: the model's per-layer state, one tuple of page
             #: pools a layer (axis 0 = pages; GPT-2: (k_pool, v_pool),
             #: DeepSeek-V2: one latent pool)
-            self.pools = [tuple(layer) for layer in net.init_paged_cache(
-                self.num_pages, self.page_size, dtype=cache_dtype)]
+            if groups:
+                sizes = {"all": self.num_pages}
+                if self._window is not None:
+                    sizes[next(iter(windows))] = self._window.num_pages
+                pools, self.layer_groups = net.init_paged_cache(
+                    {g: sizes[g] for g in groups}, self.page_size,
+                    dtype=cache_dtype)
+                self.layer_groups = tuple(self.layer_groups)
+            else:
+                pools = net.init_paged_cache(
+                    self.num_pages, self.page_size, dtype=cache_dtype)
+                self.layer_groups = ("all",) * len(pools)
+            self.pools = [tuple(layer) for layer in pools]
+            self._group_names = tuple(groups) or ("all",)
+            if self._window is not None:
+                self.page_table = self._by_group(self.page_table, jnp.zeros(
+                    (self.batch_size, self._window.columns), jnp.int32))
             self.cache = None  # dense-only state
             # host allocator (authoritative; the device table mirrors it
             # through compiled update vectors shipped with each program)
@@ -294,9 +428,12 @@ class GenerationEngine:
             self.prefix_cache = (RadixPrefixCache(self.page_size)
                                  if prefix_cache else None)
             self._page_gauges()
-            _obs.gauge("gen_cache_bytes_per_token",
-                       "bytes the paged cache holds for one token, all "
-                       "layers").set(self.cache_bytes_per_token)
+            per_token = _obs.gauge("gen_cache_bytes_per_token",
+                                   "bytes the paged cache holds for one "
+                                   "token, all layers")
+            per_token.set(self.cache_bytes_per_token)
+            for g in self._group_names:  # and one series a pool group
+                per_token.set(self._group_bytes_per_token(g), group=g)
             #: read path of the paged decode program, as the model says it
             #: (the choice is made per shape at trace time, in the operator)
             describe = getattr(net, "paged_read_path", None)
@@ -310,6 +447,7 @@ class GenerationEngine:
             self.cache = net.init_cache(self.batch_size, self.max_length,
                                         dtype=cache_dtype)
             self.prefix_cache = None
+            self._window = None
 
         if draft_net is not None:
             self._draft_plist = [p for _, p in
@@ -417,15 +555,57 @@ class GenerationEngine:
     def pages_in_use(self) -> int:
         return self.num_pages - len(self._free_pages) if self.paged else 0
 
+    def _group_bytes_per_token(self, group: str) -> float:
+        """Bytes the layers of ``group`` hold for one token: their pools'
+        bytes over their pool's token capacity."""
+        total = sum(b.size * b.dtype.itemsize
+                    for layer, g in zip(self.pools, self.layer_groups)
+                    if g == group for b in layer)
+        pages = self.num_pages if group == "all" else self._window.num_pages
+        return total / float((pages + 1) * self.page_size)
+
     @property
     def cache_bytes_per_token(self) -> float:
         """Bytes the paged cache holds for one token over all layers: the
-        pools' bytes over the pool's token capacity."""
+        pools' bytes over the pool's token capacity (with a window group,
+        the groups' sum: a token within the window)."""
         if not self.paged:
             return 0.0
-        total = sum(b.size * b.dtype.itemsize
-                    for layer in self.pools for b in layer)
-        return total / float((self.num_pages + 1) * self.page_size)
+        return sum(self._group_bytes_per_token(g)
+                   for g in dict.fromkeys(self.layer_groups))
+
+    @property
+    def page_groups(self) -> dict:
+        """{group: {"num_pages", "in_use", "window"}} of a paged engine's
+        pool groups."""
+        if not self.paged:
+            return {}
+        every = {"num_pages": self.num_pages, "in_use": self.pages_in_use,
+                 "window": None}
+        w = self._window
+        return dict(zip(self._group_names, self._by_group(
+            every, w and {"num_pages": w.num_pages, "in_use": w.in_use,
+                          "window": w.window})))
+
+    def _by_group(self, of_all, of_window) -> tuple:
+        """One value a pool group, in the groups' order: ``of_all`` for the
+        group ``all``, ``of_window`` for the window group."""
+        return tuple(of_all if g == "all" else of_window
+                     for g in self._group_names)
+
+    def covers(self, prompt, unreserved: bool = False) -> bool:
+        """Whether every pool group has the pages that admitting ``prompt``
+        takes: the ``all`` group's free pages plus what the prefix cache
+        would give up (``unreserved``: its free pages less the reservation,
+        what a request that bypasses a parked head may take), and a window
+        group's free pages. The group that runs short decides."""
+        have = (self.free_pages - self.reserved_pages if unreserved
+                else self.available_pages)
+        if have < self.pages_needed(prompt):
+            return False
+        w = self._window if self.paged else None
+        return w is None or (len(w.free) - (w.reserved if unreserved else 0)
+                             >= w.needed(len(prompt)))
 
     def pages_for(self, length: int) -> int:
         """Pages a ``length``-token sequence occupies."""
@@ -499,6 +679,9 @@ class GenerationEngine:
         if not self.paged:
             return
         self._reserved_pages = max(0, int(n))
+        if self._window is not None:  # the head's window pages too
+            self._window.reserved = min(
+                self._window.columns, self._reserved_pages)
         _obs.gauge("gen_pages_reserved",
                    "free pages held back for a parked queue head").set(
                        self._reserved_pages)
@@ -507,9 +690,11 @@ class GenerationEngine:
         free = len(self._free_pages)
         _obs.gauge("gen_pages_free",
                    "free pages in the paged KV pool").set(free)
-        _obs.gauge("gen_pages_in_use",
-                   "allocated pages in the paged KV pool").set(
-                       self.num_pages - free)
+        in_use = _obs.gauge("gen_pages_in_use",
+                            "allocated pages in the paged KV pool")
+        in_use.set(self.num_pages - free)
+        for g, of in self.page_groups.items():  # and one series a pool group
+            in_use.set(of["in_use"], group=g)
         _obs.gauge("gen_page_refcount_max",
                    "highest per-page refcount (sharing depth)").set(
                        int(self._page_rc.max()) if self.num_pages else 0)
@@ -529,6 +714,8 @@ class GenerationEngine:
 
     def _reclaim_row(self, slot: int) -> int:
         pages = self._row_pages[slot]
+        if self._window is not None:
+            self._window.release(slot)
         if not pages:
             return 0
         self._row_pages[slot] = []
@@ -631,13 +818,46 @@ class GenerationEngine:
                 self._row_pages[row].append(pid)
                 u += 1
                 allocated += 1
+        grown = self._grow_window(_evict_row)
         if allocated:
             _obs.counter("gen_page_allocs_total",
                          "pages taken from the free pool").inc(
                              allocated, site="decode")
+        if allocated or grown:
             self._page_gauges()
         self._dispatch_cow(copies)
+        if grown is not None:  # one vector a group
+            return (self._by_group(upd_slots, grown[0]),
+                    self._by_group(upd_pages, grown[1]))
         return upd_slots, upd_pages
+
+    def _grow_window(self, evict_row):
+        """The window group's part of :meth:`_grow_pages`: every active row
+        takes the page of its next write and gives back the pages now
+        behind its window; a row that finds the pool dry is force-finished.
+        Returns the group's (B, U) update vectors (page -1 zeroes a freed
+        column), or None where the model has no window group."""
+        w = self._window
+        if w is None:
+            return None
+        slots = np.zeros((self.batch_size, self._upd_width), np.int32)
+        pages = np.zeros((self.batch_size, self._upd_width), np.int32)
+        before = w.freed_total
+        for row in range(self.batch_size):
+            if self.done[row]:
+                continue
+            updates = w.step(row, int(self.positions[row]))
+            if updates is None:
+                evict_row(row)
+                continue
+            for u, (column, pid) in enumerate(updates):
+                slots[row, u], pages[row, u] = column, pid
+        if w.freed_total > before:
+            _obs.counter("gen_window_pages_freed_total",
+                         "pages behind a row's window returned to the "
+                         "free pool while the row lived").inc(
+                             w.freed_total - before)
+        return slots, pages
 
     def _dispatch_cow(self, copies) -> None:
         """Run the page-granular copy-on-write program: each (row, slot,
@@ -789,11 +1009,47 @@ class GenerationEngine:
         """Scatter the host allocator's decisions into the page-table carry:
         install newly allocated pages ((B, U) slot/page vectors, page 0 =
         no-op), then zero the rows of released slots."""
+        if isinstance(table, tuple):  # one table a pool group
+            return tuple(
+                self._apply_table_updates(t, s, p, clear) if g == "all"
+                else self._apply_ring_updates(t, s, p, clear)
+                for g, t, s, p in zip(self._group_names, table, upd_slots,
+                                      upd_pages))
         bidx = jnp.arange(self.batch_size, dtype=jnp.int32)[:, None]
         cur = table[bidx, upd_slots]
         table = table.at[bidx, upd_slots].set(
             jnp.where(upd_pages > 0, upd_pages, cur))
         return jnp.where(clear[:, None], 0, table)
+
+    def _apply_ring_updates(self, table, upd_slots, upd_pages, clear):
+        """A window group's table: as above, and a page of -1 zeroes its
+        column (a page freed behind the window: the trash page from now
+        on). Frees come first in a row's vector, so a column freed and
+        given again in one step ends up given."""
+        bidx = jnp.arange(self.batch_size, dtype=jnp.int32)[:, None]
+        for u in range(upd_slots.shape[1]):
+            col, page = upd_slots[:, u:u + 1], upd_pages[:, u:u + 1]
+            table = table.at[bidx, col].set(
+                jnp.where(page > 0, page,
+                          jnp.where(page < 0, 0, table[bidx, col])))
+        return jnp.where(clear[:, None], 0, table)
+
+    def _row_tables(self, table, new_row, slot):
+        """(tables with ``new_row`` installed at ``slot``, that row's own
+        (1, columns) tables); a tuple of each where there are groups."""
+        if isinstance(table, tuple):
+            both = [self._row_tables(t, r, slot)
+                    for t, r in zip(table, new_row)]
+            return tuple(b[0] for b in both), tuple(b[1] for b in both)
+        table = jax.lax.dynamic_update_slice(table, new_row[None, :],
+                                             (slot, 0))
+        return table, jax.lax.dynamic_slice(table, (slot, 0),
+                                            (1, table.shape[1]))
+
+    @staticmethod
+    def _table_nd(table):
+        return tuple(NDArray(t) for t in table) \
+            if isinstance(table, tuple) else NDArray(table)
 
     def _paged_prefill_fn(self, params, carry, tokens, slot, length,
                           new_row, start, key):
@@ -804,18 +1060,21 @@ class GenerationEngine:
         hit runs only the suffix through this same per-bucket program
         (cold prefill passes 0 — no extra lowering)."""
         table, pools = carry
-        table = jax.lax.dynamic_update_slice(table, new_row[None, :],
-                                             (slot, 0))
-        row_table = jax.lax.dynamic_slice(table, (slot, 0),
-                                          (1, self._n_row_pages))
+        table, row_table = self._row_tables(table, new_row, slot)
+        # a model that asks for it is told the last real position, and
+        # returns that position's logits alone
+        only_last = getattr(self.net, "takes_last_pos", False)
+        told = {"last_pos": NDArray((length - 1).reshape(1))} \
+            if only_last else {}
         with _HybridTrace(self._plist, list(params), False, key):
             logits, new_pools, _ = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
-                start_pos=NDArray(start), page_table=NDArray(row_table)))
-        logits = logits._data  # (1, Lb, vocab)
+                start_pos=NDArray(start), page_table=self._table_nd(row_table),
+                **told))
+        logits = logits._data  # (1, Lb, vocab), or (1, 1, vocab)
         new_pools = [tuple(b._data for b in layer) for layer in new_pools]
-        last = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=1,
-                                            keepdims=False)[0]
+        last = logits[0, 0] if only_last else jax.lax.dynamic_index_in_dim(
+            logits, length - 1, axis=1, keepdims=False)[0]
         tok = self._sample(last[None, :], key)[0].astype(jnp.int32)
         return (table, new_pools), tok, last
 
@@ -855,7 +1114,7 @@ class GenerationEngine:
             logits, new_pools, stats = self._cached(self.net(
                 NDArray(tokens.reshape(self.batch_size, 1)),
                 cache=self._cache_nd(pools), start_pos=NDArray(positions),
-                page_table=NDArray(table)))
+                page_table=self._table_nd(table)))
         logits = logits._data[:, 0]
         sampled = self._sample(logits, key)
         next_tok = jnp.where(done, jnp.int32(self.pad_id), sampled)
@@ -1124,6 +1383,14 @@ class GenerationEngine:
                     f"insufficient free pages for a {length}-token prompt "
                     f"({need} needed, {len(self._free_pages)} free); release "
                     "slots or raise num_pages")
+            w = self._window
+            if w is not None and (len(w.free) + len(w.rows[slot])
+                                  < w.needed(length)):
+                raise RuntimeError(
+                    f"insufficient free pages in the window group for a "
+                    f"{length}-token prompt ({w.needed(length)} needed, "
+                    f"{len(w.free)} free); release slots or raise its "
+                    "num_pages")
             self._reclaim_row(slot)  # previous occupant's pages, if any
             self._pending_clear.discard(slot)  # the new row replaces it
             self.page_exhausted[slot] = False
@@ -1158,6 +1425,9 @@ class GenerationEngine:
             padded[0, :suffix] = prompt[start:]
             new_row = np.zeros(self._n_row_pages, np.int32)
             new_row[:total] = pages
+            if w is not None:  # one row a group
+                new_row = self._by_group(new_row, w.admit(slot, length))
+                self._page_gauges()
             self._note_program(("prefill", bucket), "prefill_bucket")
             start_v = jnp.full((1,), start, jnp.int32)
             if self.speculative:
@@ -1172,7 +1442,7 @@ class GenerationEngine:
                 carry, tok, last = self._prefill_jit(
                     self._params(), (self.page_table, self.pools),
                     jnp.asarray(padded), jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(suffix, jnp.int32), jnp.asarray(new_row),
+                    jnp.asarray(suffix, jnp.int32), self._vectors(new_row),
                     start_v, self._next_key())
                 self.page_table, self.pools = carry
         else:
@@ -1266,6 +1536,11 @@ class GenerationEngine:
                 tok, done = np.array(tok), np.array(done)
                 if stats:
                     rec.counts = {k: v.tolist() for k, v in stats.items()}
+                if self.paged and self._window is not None:
+                    # the engine's own count beside the model's: the window
+                    # group's pages in use as this step left them
+                    rec.counts = {**(rec.counts or {}), "window_pages_in_use":
+                                  [self._window.in_use]}
         self.positions = positions
         if full.any():
             done = done | full
@@ -1293,10 +1568,20 @@ class GenerationEngine:
         """What the next step needs is known before this step's tokens are:
         a plain paged greedy engine with no EOS id (``done`` then follows
         from lengths alone, and a dropped step costs no random key), and a
-        free page for every row (growing the rows' tables cannot evict)."""
+        free page for every row in every pool group (growing the rows'
+        tables cannot evict)."""
         return (self.paged and not self.speculative and self.eos_id is None
                 and not self.sampling.stochastic
-                and len(self._free_pages) >= self.batch_size)
+                and len(self._free_pages) >= self.batch_size
+                and (self._window is None
+                     or len(self._window.free) >= self.batch_size))
+
+    @staticmethod
+    def _vectors(upd):
+        """A dispatch's update vectors on the device: one array, or one a
+        pool group."""
+        return tuple(jnp.asarray(u) for u in upd) \
+            if isinstance(upd, tuple) else jnp.asarray(upd)
 
     def _take_ahead(self):
         """The step dispatched ahead, if the rows are as it left them;
@@ -1346,8 +1631,8 @@ class GenerationEngine:
                 carry, tok, done, logits, stats = decode_jit(
                     self._params(), (self.page_table, self.pools),
                     jnp.asarray(tokens), jnp.asarray(self.positions),
-                    jnp.asarray(self.done), jnp.asarray(upd_slots),
-                    jnp.asarray(upd_pages), jnp.asarray(clear),
+                    jnp.asarray(self.done), self._vectors(upd_slots),
+                    self._vectors(upd_pages), jnp.asarray(clear),
                     self._next_key())
             self.page_table, self.pools = carry
         else:
@@ -1492,6 +1777,8 @@ class GenerationEngine:
             return self._decode_jit.lower(self._params(), self.cache, toks,
                                           pos, done, key)
         upd = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
+        if self._window is not None:
+            upd = self._by_group(upd, upd)
         clear = jnp.zeros((self.batch_size,), bool)
         return self._decode_jit.lower(
             self._params(), (self.page_table, self.pools), toks, pos, done,
@@ -1508,7 +1795,8 @@ class GenerationEngine:
             self._params(), (self.page_table, self.pools),
             jnp.full((1, bucket), self.pad_id, jnp.int32),
             jnp.asarray(0, jnp.int32), jnp.asarray(bucket, jnp.int32),
-            jnp.zeros((self._n_row_pages,), jnp.int32),
+            jax.tree.map(lambda t: jnp.zeros((t.shape[1],), jnp.int32),
+                         self.page_table),
             jnp.zeros((1,), jnp.int32), jax.random.key(0))
 
     def op_scopes(self, bucket: Optional[int] = None):
@@ -1575,7 +1863,9 @@ class GenerationEngine:
             if bucket is not None:
                 bucket = self.bucket_for(bucket)
                 tokens = jnp.full((1, bucket), self.pad_id, jnp.int32)
-                new_row = jnp.zeros((self._n_row_pages,), jnp.int32)
+                new_row = jax.tree.map(
+                    lambda t: jnp.zeros((t.shape[1],), jnp.int32),
+                    self.page_table)
                 start0 = jnp.zeros((1,), jnp.int32)
                 if self.speculative:
                     dparams = self._draft_params()
@@ -1709,6 +1999,11 @@ class GenerationEngine:
         after :meth:`prefill`, before any decode step — later forks would
         re-sample a stale position). Returns ``dst``'s current last token.
         """
+        if self.paged and self._window is not None:
+            raise RuntimeError(
+                "fork_slot shares pages between rows; a model with a window "
+                "pool group frees a row's pages behind its window, so its "
+                "rows cannot be forked")
         if not self.paged:
             raise RuntimeError("fork_slot needs a paged engine")
         if src == dst or not (0 <= src < self.batch_size

@@ -9,15 +9,19 @@
 Plus detection: models.ssd (example/ssd + GluonCV SSD shape, exercising the
 full contrib MultiBox family), and models.deepseek_v2 (latent attention,
 group-limited routed experts of which one chip holds a share; served through
-inference.GenerationEngine from a paged latent cache).
+inference.GenerationEngine from a paged latent cache) and models.dots3_note
+(latent attention over a learned subset of the cache beside window layers
+with a latent of their own; two page groups in one engine).
 """
 from . import deepseek_v2  # noqa: F401
+from . import dots3_note  # noqa: F401
 from . import bert  # noqa: F401
 from . import gpt2  # noqa: F401
 from . import ssd  # noqa: F401
 from . import transformer  # noqa: F401
 from .bert import BERTModel, BERTForPretrain, get_bert  # noqa: F401
 from .deepseek_v2 import DeepseekV2Model, get_deepseek_v2  # noqa: F401
+from .dots3_note import Dots3NoteModel, get_dots3_note  # noqa: F401
 from .gpt2 import GPT2Model, get_gpt2  # noqa: F401
 from .ssd import SSD, get_ssd  # noqa: F401
 from .transformer import Transformer, get_transformer  # noqa: F401

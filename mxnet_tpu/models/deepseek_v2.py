@@ -157,7 +157,9 @@ class LatentAttention(HybridBlock):
 
 
 class ExpertLayer(HybridBlock):
-    """Group-limited top-k routed experts (those held here) + shared ones.
+    """Top-k routed experts (those held here) + shared ones: group-limited
+    over softmax probabilities, or (``cfg["scoring"] == "sigmoid"``) over
+    sigmoid scores plus a learned selection bias, without groups.
     Returns (output, pairs routed to held experts, largest load of one)."""
 
     def __init__(self, cfg, held_experts, dtype="float32", **kwargs):
@@ -170,6 +172,10 @@ class ExpertLayer(HybridBlock):
             self.router_weight = self.params.get(
                 "router_weight", shape=(c["num_routed_experts"], units),
                 dtype=dtype, init=std)
+            if c.get("scoring", "softmax") == "sigmoid":
+                self.router_bias = self.params.get(
+                    "router_bias", shape=(c["num_routed_experts"],),
+                    dtype=dtype, init=std)
             # the held experts stacked, (in, out) as the grouped product reads
             self.gate_weight = self.params.get(
                 "experts_gate_weight", shape=(held, units, width), dtype=dtype,
@@ -184,13 +190,16 @@ class ExpertLayer(HybridBlock):
                                  prefix="shared_")
 
     def hybrid_forward(self, F, x, router_weight, gate_weight, up_weight,
-                       down_weight):
+                       down_weight, router_bias=None):
         c = self._cfg
+        how = {} if router_bias is None else dict(
+            scoring="sigmoid", router_bias=router_bias,
+            norm_topk_prob=c["norm_topk_prob"])
         routed, pairs, load = F.held_expert_ffn(
             x, router_weight, gate_weight, up_weight, down_weight,
-            held_experts=self._held, n_group=c["n_group"],
-            topk_group=c["topk_group"], top_k=c["experts_per_token"],
-            scale=c["routed_scaling_factor"])
+            held_experts=self._held, n_group=c.get("n_group", 1),
+            topk_group=c.get("topk_group", 1), top_k=c["experts_per_token"],
+            scale=c["routed_scaling_factor"], **how)
         return routed + self.shared(x), pairs, load
 
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 
 import jax
 import jax.numpy as jnp
@@ -286,8 +287,6 @@ def yarn_inv_freq(dim, base=10000.0, factor=1.0, original_max_position=4096,
     correction dimensions (the dimensions that turn ``beta_fast`` and
     ``beta_slow`` times over the original context). ``factor`` 1 gives the
     plain frequencies."""
-    import math
-
     plain = [base ** (-2.0 * i / dim) for i in range(dim // 2)]
     if factor <= 1:
         return tuple(plain)
@@ -525,6 +524,467 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
         out = from_pool() if t == 1 else jax.lax.cond(
             jnp.all(position == 0), from_chunk, from_pool)
     return out.reshape(b, t, heads * vd), pool
+
+
+# --------------------------------------------------------------------------
+# latent attention over a learned subset of the cache, and under a window
+# --------------------------------------------------------------------------
+_F32 = dict(preferred_element_type=jnp.float32)
+_KEY_STRETCH = 2048  # keys a long chunk's softmax takes at once
+
+
+def _queries_of(c_q, w_qb, heads, nope, position, inv_freq):
+    """``c_q`` (B, T, ql) up-projected by ``w_qb`` (heads * (nope + rope), ql)
+    into the queries' two parts, the rotary one rotated: (B, T, heads, nope)
+    and (B, T, heads, rope)."""
+    b, t, _ = c_q.shape
+    q = jnp.einsum("btl,ol->bto", c_q, w_qb, **_F32).astype(c_q.dtype)
+    q = q.reshape(b, t, heads, -1)
+    return q[..., :nope], rotary_embedding(q[..., nope:], position, inv_freq)
+
+
+def _block_of(t, most):
+    return math.gcd(int(t), int(most))
+
+
+def _rows_write(pool, values, pid, offset):
+    """``values`` (B, T, w) set at ``pool[pid, offset]`` (both (B, T)), in
+    place on a donated pool; the pool's lanes past ``w`` are written as
+    zeros, and never anything else: a zero query lane does not clear a NaN."""
+    n, width = values.shape[0] * values.shape[1], pool.shape[2]
+    values = jnp.pad(values, ((0, 0), (0, 0), (0, width - values.shape[-1])))
+    return pool.at[pid.reshape(n), offset.reshape(n)].set(
+        values.reshape(n, width).astype(pool.dtype))
+
+
+def _linear_pages(table, pos, ps):
+    """Page ids of positions ``pos`` (B, T) by a table whose column ``s``
+    holds positions ``s * ps ...``; past the table's width the trash page."""
+    n_pages = table.shape[1]
+    pid = jnp.take_along_axis(table, jnp.clip(pos // ps, 0, n_pages - 1), axis=1)
+    return jnp.where(pos < n_pages * ps, pid, 0)
+
+
+def index_scores(idx_q, idx_k, idx_w):
+    """The indexer's scores of queries (B, T, J, D) against keys (B, S, D):
+    ``sum_j w[t, j] * relu(q[t, j] . k[s])`` in float32, (B, T, S). The head
+    weights ``idx_w`` (B, T, J) carry every constant factor."""
+    dots = jnp.einsum("btjd,bsd->btjs", idx_q, idx_k, **_F32)
+    scores = jnp.einsum("btjs,btj->bts", jax.nn.relu(dots),
+                        idx_w.astype(jnp.float32))
+    # one zero: a sort may tell -0.0 from 0.0, a comparison does not
+    return jnp.where(scores == 0, 0.0, scores)
+
+
+def kth_largest(scores, k):
+    """The ``k``-th largest of every row of float32 ``scores`` (..., S),
+    keepdims, exactly, by bisection on the order-preserving int32 image of
+    the floats: 33 counting passes, where ``lax.top_k`` of a large ``k``
+    sorts every row (XLA:TPU lowers it to a full ``sort``)."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    info = jnp.iinfo(jnp.int32)
+    shape = scores.shape[:-1] + (1,)
+
+    def halve(_, bounds):
+        lo, hi = bounds   # count(keys >= lo) >= k > count(keys >= hi)
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)
+        enough = jnp.sum(keys >= mid, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(
+        0, 33, halve, (jnp.full(shape, info.min, jnp.int32),
+                       jnp.full(shape, info.max, jnp.int32)))
+    # the largest key the bisection cannot separate from its upper bound
+    lo = jnp.where(jnp.sum(keys >= info.max, axis=-1, keepdims=True) >= k,
+                   info.max, lo)
+    back = jnp.where(lo < 0, lo ^ jnp.int32(0x7FFFFFFF), lo)
+    return jax.lax.bitcast_convert_type(back, jnp.float32)
+
+
+def top_k_mask(scores, k):
+    """(..., S) bool: the ``k`` largest of every row of ``scores``, equal
+    scores in the order of their positions, as ``lax.top_k`` chooses them
+    (exactly ``k`` a row: an indexer of few heads scores many positions
+    exactly 0). Of the positions that tie with the ``k``-th largest, those
+    up to a position found by a second bisection are taken (no prefix sum:
+    one over 16,384 positions is a slow operation on a TPU)."""
+    kth = kth_largest(scores, k)
+    above, tie = scores > kth, scores == kth
+    need = k - jnp.sum(above, axis=-1, keepdims=True)
+    at = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    shape = scores.shape[:-1] + (1,)
+
+    def halve(_, bounds):
+        lo, hi = bounds   # ties up to lo are too few, up to hi enough
+        mid = (lo + hi) // 2
+        enough = jnp.sum(tie & (at <= mid), axis=-1, keepdims=True) >= need
+        return jnp.where(enough, lo, mid), jnp.where(enough, mid, hi)
+
+    steps = max(int(scores.shape[-1]).bit_length(), 1)
+    _, last = jax.lax.fori_loop(
+        0, steps, halve, (jnp.full(shape, -1, jnp.int32),
+                          jnp.full(shape, scores.shape[-1] - 1, jnp.int32)))
+    return above | (tie & (at <= last))
+
+
+def dsa_selection_mask(idx_q, idx_k, idx_w, top_k, block=64):
+    """(B, T, T) bool for one whole chunk from position 0: key ``s`` is seen
+    by query ``t`` when ``s <= t`` and its indexer score is among the
+    ``top_k`` largest of ``t``'s row (every ``s <= t`` while ``t < top_k``).
+    Queries are walked in blocks: neither the scores nor the per-head dots
+    are ever T x T at once."""
+    b, t = idx_q.shape[:2]
+    cols = jnp.arange(t, dtype=jnp.int32)
+    if top_k >= t:
+        return jnp.broadcast_to(cols[None, :] <= cols[:, None], (b, t, t))
+    qb = _block_of(t, block)
+
+    def rows_of(start):
+        at = lambda z: jax.lax.dynamic_slice_in_dim(z, start, qb, 1)  # noqa: E731
+        causal = cols[None, :] <= (start + jnp.arange(qb, dtype=jnp.int32))[:, None]
+        scores = jnp.where(causal[None], index_scores(at(idx_q), idx_k,
+                                                      at(idx_w)), -jnp.inf)
+        return causal[None] & top_k_mask(scores, top_k)
+
+    seen = jax.lax.map(rows_of, jnp.arange(0, t, qb, dtype=jnp.int32))
+    return jnp.moveaxis(seen, 0, 1).reshape(b, t, t)
+
+
+def _masked_chunk_attention(c_q, w_qb, c_kv, k_rope, w_kvb, heads, seen,
+                            position, inv_freq, scale, gate, head_block=16,
+                            query_block=256):
+    """Decompressed latent attention of one chunk from position 0 under the
+    mask ``seen`` (B, T, T): heads in blocks (a block's queries, keys and
+    values are made inside the loop, so nothing head-sized exists for all
+    heads at once), queries in blocks inside; ``gate`` (B, T, H) or None
+    multiplies a head's output where it is made. Returns (B, T, H * vd)."""
+    b, t, ql = c_q.shape
+    kl, rope = c_kv.shape[-1], k_rope.shape[-1]
+    nope = w_qb.shape[0] // heads - rope
+    vd = w_kvb.shape[0] // heads - nope
+    hb = head_block if heads % head_block == 0 else heads
+    qb = _block_of(t, query_block)
+
+    # a query sees no key past its own position: the chunk's queries are
+    # taken a quarter at a time, each against the keys up to its end (five
+    # eighths of the products and of the softmax's passes over the scores)
+    parts = 4 if t >= 4096 and t % (4 * qb) == 0 else 1
+
+    def heads_of(args):
+        wq, wkv, g = args    # (hb * (nope + rope), ql), (hb, nope + vd, kl)
+        qn, qr = _queries_of(c_q, wq, hb, nope, position, inv_freq)
+        q = jnp.concatenate([qn, qr], axis=-1)
+        kv = jnp.einsum("bkl,hdl->bkhd", c_kv, wkv, **_F32).astype(c_q.dtype)
+        # one product over a head's nope + rope dims: the shared rotated key
+        # stands beside every head's own
+        keys = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope[:, :, None, :],
+                                              (b, t, hb, rope))], axis=-1)
+
+        def queries_of(start, upto):
+            # the keys a stretch at a time under a running maximum and sum
+            # (one softmax over more than 4,096 keys is a fusion that XLA:TPU
+            # runs forty times slower a byte: PERF.md, PR 31). A stretch in
+            # which a query sees nothing adds exp(-1e30 - m) = 0, or, before
+            # the query's first key, weights that the first real maximum
+            # scales to 0
+            at = lambda z: jax.lax.dynamic_slice_in_dim(z, start, qb, 1)  # noqa: E731
+            qs, mask = at(q), at(seen)
+            top = jnp.full((b, hb, qb, 1), -1e30, jnp.float32)
+            total = jnp.zeros((b, hb, qb, 1), jnp.float32)
+            out = jnp.zeros((b, qb, hb, vd), jnp.float32)
+            for k0 in range(0, upto, _KEY_STRETCH):
+                k1 = min(k0 + _KEY_STRETCH, upto)
+                scores = jnp.einsum("bthd,bkhd->bhtk", qs, keys[:, k0:k1],
+                                    **_F32) * scale
+                scores = jnp.where(mask[:, None, :, k0:k1], scores, -1e30)
+                new_top = jnp.maximum(top, scores.max(axis=-1, keepdims=True))
+                weights, keep = jnp.exp(scores - new_top), jnp.exp(top - new_top)
+                total = total * keep + weights.sum(axis=-1, keepdims=True)
+                out = out * keep.transpose(0, 2, 1, 3) + jnp.einsum(
+                    "bhtk,bkhv->bthv", weights.astype(c_q.dtype),
+                    kv[:, k0:k1, :, nope:], **_F32)
+                top = new_top
+            out = out / total.transpose(0, 2, 1, 3)
+            if g is not None:
+                out = out * at(g)[..., None]
+            return out.astype(c_q.dtype)
+
+        out = jnp.concatenate([
+            jax.lax.map(functools.partial(queries_of, upto=(p + 1) * t // parts),
+                        jnp.arange(p * t // parts, (p + 1) * t // parts, qb,
+                                   dtype=jnp.int32))
+            for p in range(parts)], axis=0)
+        return jnp.moveaxis(out, 0, 1).reshape(b, t, hb, vd)
+
+    gates = None if gate is None else jnp.moveaxis(
+        gate.reshape(b, t, heads // hb, hb), 2, 0)
+    out = jax.lax.map(heads_of, (w_qb.reshape(heads // hb, -1, ql),
+                                 w_kvb.reshape(heads // hb, hb, nope + vd, kl),
+                                 gates))
+    return jnp.moveaxis(out, 0, 2).reshape(b, t, heads * vd)
+
+
+def _gated(out, gate, heads):
+    """``out`` (B, T, H * vd) with head ``h``'s part times ``gate[..., h]``."""
+    if gate is None:
+        return out
+    b, t, _ = out.shape
+    return (out.reshape(b, t, heads, -1) * gate[..., None].astype(out.dtype)
+            ).reshape(b, t, -1)
+
+
+#: why a decode step's selection and sparse read are XLA operations and not
+#: paged kernels: the one place that says it, for an engine's ``read_path``
+#: and the trace-time counter ``sparse_read_path_total{path, reason}``. (The
+#: scoring read has a kernel and a gate: ``paged_index_scores_refusal``.)
+SPARSE_READ_BY_XLA = ("no kernel gathers single cached rows: XLA's gather "
+                      "reads the selected rows in one fusion (PERF.md, PR 31)")
+
+
+@register("sparse_latent_attention")
+def sparse_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, idx_q, idx_k,
+                            idx_w, heads=1, inv_freq=(), scale=1.0,
+                            top_k=2048, gate=None, cache=None, position=None,
+                            page_table=None):
+    """Multi-head latent attention that reads a learned subset of the keys
+    (DeepSeek-V3.2-Exp's sparse attention): query ``t`` attends the
+    ``top_k`` positions ``s <= t`` whose indexer score
+    (:func:`index_scores`) is largest, all of them while ``t < top_k``.
+
+    ``c_q`` (B, T, ql) is the queries' normed latent and ``w_qb`` (H * (nope
+    + rope), ql) its up-projection (taken here, so that a long chunk's
+    queries are made a block of heads at a time; the rotary part is rotated
+    by ``inv_freq``); ``c_kv`` (B, T, kl), ``k_rope`` (B, T, rope, rotated)
+    and ``w_kvb`` as :func:`latent_attention` takes them; ``idx_q`` (B, T,
+    J, D) and ``idx_k`` (B, T, D) the indexer's rotated queries and keys,
+    ``idx_w`` (B, T, J) its head weights with every constant folded in;
+    ``gate`` (B, T, H), if given, multiplies head ``h``'s output (a headwise
+    output gate: taken here, so that a long chunk's gated context is made a
+    block at a time). Returns the context (B, T, H * vd).
+
+    ``cache=(pool, index_pool), position=, page_table=`` is the paged path:
+    the new tokens' ``[c_kv ; k_rope ; 0]`` and indexer keys are scattered
+    into their pools, in place, and the call returns ``(context, pool',
+    index_pool', read, held)``, the last two int32 scalars: the positions
+    the rows' softmaxes read and the positions the rows hold. One token a
+    row (decode) scores the index keys of the row's pages (scope
+    ``dsa/index``: the Pallas kernel ``paged_index_scores`` copies the pages
+    a row HOLDS; where its gate refuses, XLA gathers the table's width of
+    the 128-wide key pool), selects (``dsa/select``: ``lax.top_k``) and
+    reads the selected latents and nothing else, in the absorbed form
+    (``mla/core``: an XLA gather of rows): no operation has the shape rows x
+    table width x the latent pool's width. A chunk of more
+    than one token opens its rows at position 0 (a prefill without an
+    adopted prefix: the only chunk an engine with a window group builds) and
+    attends itself in the decompressed form, the selection as a mask,
+    queries in blocks. ``sparse_read_path_total{path, reason}`` says at
+    trace time what was built.
+    """
+    from .. import observability as obs
+
+    b, t, _ = c_q.shape
+    kl, rope = c_kv.shape[-1], k_rope.shape[-1]
+    nope = w_qb.shape[0] // heads - rope
+    w_kvb3 = w_kvb.reshape(heads, -1, kl)
+
+    def from_chunk(c_hist, r_hist, k_hist, start):
+        with jax.named_scope("dsa"):
+            with jax.named_scope("index"):
+                seen = dsa_selection_mask(idx_q, k_hist, idx_w, top_k)
+        with jax.named_scope("mla"), jax.named_scope("core"):
+            return _masked_chunk_attention(c_q, w_qb, c_hist, r_hist, w_kvb,
+                                           heads, seen, start, inv_freq, scale,
+                                           None if gate is None else
+                                           _unwrap(gate))
+
+    if cache is None:
+        obs.counter("sparse_read_path_total").inc(path="chunk_mask", reason="")
+        return from_chunk(c_kv, k_rope, idx_k, None)
+    if position is None or page_table is None:
+        raise ValueError("sparse_latent_attention(cache=...) is paged: it "
+                         "needs position= and page_table=")
+    pool, ipool = (_unwrap(c) for c in cache)
+    position = jnp.asarray(_unwrap(position), jnp.int32)
+    table = jnp.asarray(_unwrap(page_table), jnp.int32)
+    ps, n_pages = pool.shape[1], table.shape[1]
+    cap = n_pages * ps
+    with jax.named_scope("kv"):
+        pos = position[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        pid, off = _linear_pages(table, pos, ps), pos % ps
+        pool = _rows_write(pool, jnp.concatenate([c_kv, k_rope], axis=-1),
+                           pid, off)
+        ipool = _rows_write(ipool, idx_k, pid, off)
+    held = jnp.sum(position + t)
+    if t > 1:
+        obs.counter("sparse_read_path_total").inc(path="chunk_mask", reason="")
+        read = jnp.sum(jnp.minimum(pos + 1, top_k)) // t
+        out = from_chunk(c_kv.astype(pool.dtype).astype(c_kv.dtype),
+                         k_rope.astype(pool.dtype).astype(k_rope.dtype),
+                         idx_k.astype(ipool.dtype).astype(idx_k.dtype), position)
+        return out, pool, ipool, read, held
+    from . import pallas_paged_attention as ppa
+
+    why = ppa.paged_index_scores_refusal(idx_q, ipool, table)
+    count = obs.counter("sparse_read_path_total")
+    count.inc(path="xla_gather_index" if why else "paged_index_scores",
+              reason=why or "")
+    count.inc(path="xla_gather_rows", reason=SPARSE_READ_BY_XLA)
+    obs.counter("mla_path_total").inc(form="absorbed", read="xla_gather_rows")
+    k = min(int(top_k), cap)
+    with jax.named_scope("dsa"):
+        with jax.named_scope("index"):
+            if why is None:   # the pages each row holds, in VMEM
+                scores = ppa.paged_index_scores(idx_q[:, 0], idx_w[:, 0], ipool,
+                                                table, position)
+            else:             # every row's keys by its table's whole width
+                keys = ipool[table].reshape(b, cap, -1)[..., :idx_q.shape[-1]]
+                scores = index_scores(idx_q, keys.astype(idx_q.dtype),
+                                      idx_w)[:, 0]                    # (B, cap)
+                scores = jnp.where(jnp.arange(cap, dtype=jnp.int32)[None, :]
+                                   <= position[:, None], scores, -jnp.inf)
+        with jax.named_scope("select"):
+            _, chosen = jax.lax.top_k(scores, k)                      # (B, k)
+            seen = chosen <= position[:, None]
+    with jax.named_scope("mla"), jax.named_scope("core"):
+        q_nope, q_rope = _queries_of(c_q, w_qb, heads, nope, position, inv_freq)
+        rows = pool[jnp.take_along_axis(table, chosen // ps, axis=1),
+                    chosen % ps].astype(c_kv.dtype)                   # (B, k, W)
+        # a row short of top_k positions chose some it does not hold: what
+        # lies there counts for nothing, whatever it is (a NaN too)
+        rows = jnp.where(seen[:, :, None], rows, 0)
+        o_lat = _weighted_latents(_absorb_queries(q_nope, w_kvb3), q_rope,
+                                  rows[..., :kl], rows[..., kl:kl + rope],
+                                  seen[:, None, :], scale)
+        out = _up_project_values(o_lat, w_kvb3, nope)
+        out = _gated(out.reshape(b, t, -1),
+                     None if gate is None else _unwrap(gate), heads)
+    read = jnp.sum(jnp.minimum(position + 1, k))
+    return out, pool, ipool, read, held
+
+
+@register("windowed_latent_attention")
+def windowed_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, heads=1,
+                              inv_freq=(), scale=1.0, window=1, gate=None,
+                              cache=None, position=None, page_table=None,
+                              last_pos=None):
+    """Multi-head latent attention under a causal window: query ``t``
+    attends ``t - window < s <= t`` (the window counts the token itself).
+    Operands as :func:`sparse_latent_attention` takes them (``gate``
+    too), less the indexer's. Returns the context (B, T, H * vd).
+
+    ``cache=(pool,), position=, page_table=`` is the paged path over a RING
+    table: a row keeps only the pages its window reaches, and column ``c``
+    of its table row holds the logical page ``s`` (positions ``s * page
+    ...``) with ``s % columns == c`` that is nearest below the row's
+    frontier (an engine's ``window`` pool group frees the pages behind the
+    window and hands out the columns so; ``columns >= window // page + 3``).
+    Returns ``(context, pool')``. One token a row (decode) gathers the
+    row's columns (``columns x page`` positions, a window's worth) and
+    attends in the absorbed form under the window's mask by position; what
+    a freed or never-written column names counts for nothing. A chunk of
+    more than one token opens its rows at position 0, attends itself in
+    bands of queries, and writes only the pages that the row keeps once its
+    last real position ``last_pos`` ((1,) int32: the chunk may be padded)
+    is the frontier.
+    """
+    from .. import observability as obs
+
+    b, t, _ = c_q.shape
+    kl, rope = c_kv.shape[-1], k_rope.shape[-1]
+    nope = w_qb.shape[0] // heads - rope
+    vd = w_kvb.shape[0] // heads - nope
+    w_kvb3 = w_kvb.reshape(heads, nope + vd, kl)
+    window = int(window)
+    gate = None if gate is None else _unwrap(gate)
+
+    def from_chunk(c_hist, r_hist, start):
+        qb = _block_of(t, 512)
+        span = min(t, qb + -(-window // qb) * qb)
+
+        def queries_of(first_q):
+            first_k = jnp.clip(first_q + qb - span, 0, t - span)
+            cut = lambda z, at, n: jax.lax.dynamic_slice_in_dim(z, at, n, 1)  # noqa: E731
+            begin = first_q if start is None else start + first_q
+            qn, qr = _queries_of(cut(c_q, first_q, qb), w_qb, heads, nope,
+                                 jnp.broadcast_to(begin, (b,)), inv_freq)
+            kv = jnp.einsum("bkl,hdl->bkhd", cut(c_hist, first_k, span),
+                            w_kvb3, **_F32).astype(c_q.dtype)
+            keys = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                cut(r_hist, first_k, span)[:, :, None, :],
+                (b, span, heads, rope))], axis=-1)
+            scores = jnp.einsum("bthd,bkhd->bhtk",
+                                jnp.concatenate([qn, qr], axis=-1), keys, **_F32)
+            tq = (first_q + jnp.arange(qb, dtype=jnp.int32))[:, None]
+            ks = (first_k + jnp.arange(span, dtype=jnp.int32))[None, :]
+            seen = jnp.broadcast_to((ks <= tq) & (ks > tq - window),
+                                    (b, qb, span))
+            att = _mla_softmax(scores, seen, scale, c_q.dtype)
+            out = jnp.einsum("bhtk,bkhv->bthv", att, kv[..., nope:], **_F32)
+            if gate is not None:
+                out = out * cut(gate, first_q, qb)[..., None]
+            return out.astype(c_q.dtype)
+
+        with jax.named_scope("swa"), jax.named_scope("core"):
+            out = jax.lax.map(queries_of, jnp.arange(0, t, qb, dtype=jnp.int32))
+            return jnp.moveaxis(out, 0, 1).reshape(b, t, heads * vd)
+
+    if cache is None:
+        obs.counter("mla_path_total").inc(form="decompressed", read="none")
+        return from_chunk(c_kv, k_rope, None)
+    if position is None or page_table is None:
+        raise ValueError("windowed_latent_attention(cache=...) is paged: it "
+                         "needs position= and page_table=")
+    (pool,) = (_unwrap(c) for c in cache)
+    position = jnp.asarray(_unwrap(position), jnp.int32)
+    table = jnp.asarray(_unwrap(page_table), jnp.int32)
+    ps, cols = pool.shape[1], table.shape[1]
+    cap = cols * ps
+    new = jnp.concatenate([c_kv, k_rope], axis=-1)
+    if t > 1:
+        if last_pos is None:
+            raise ValueError("a chunk written through a ring table needs "
+                             "last_pos=: the row's last real position")
+        obs.counter("mla_path_total").inc(form="decompressed", read="chunk")
+        with jax.named_scope("kv"):
+            last = jnp.asarray(_unwrap(last_pos), jnp.int32).reshape(-1)[0]
+            top_page = last // ps
+            low_page = jnp.maximum(last - window + 2, 0) // ps
+            n = min(t, cap)
+            first = jnp.clip((top_page + 1) * ps - n, 0, t - n)
+            pos = jnp.broadcast_to(first + jnp.arange(n, dtype=jnp.int32), (b, n))
+            page = pos // ps
+            kept = (page >= low_page) & (page <= top_page)
+            pid = jnp.where(kept, jnp.take_along_axis(table, page % cols, axis=1), 0)
+            pool = _rows_write(pool, jax.lax.dynamic_slice_in_dim(new, first, n, 1),
+                               pid, pos % ps)
+        held = new.astype(pool.dtype).astype(c_kv.dtype)
+        return from_chunk(held[..., :kl], held[..., kl:], position), pool
+    obs.counter("mla_path_total").inc(form="absorbed", read="xla_gather_ring")
+    obs.counter("paged_read_path_total").inc(
+        path="xla_gather", reason="a ring table: the latent kernel reads a "
+        "table's columns in order from position 0")
+    with jax.named_scope("kv"):
+        page = position // ps                                         # (B,)
+        pool = _rows_write(pool, new, jnp.take_along_axis(
+            table, (page % cols)[:, None], axis=1), (position % ps)[:, None])
+        hist = pool[table].reshape(b, cap, -1).astype(c_kv.dtype)
+        col = jnp.arange(cols, dtype=jnp.int32)[None, :]
+        logical = page[:, None] - (page[:, None] - col) % cols        # (B, cols)
+        kpos = (logical[:, :, None] * ps
+                + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(b, cap)
+        seen = ((kpos <= position[:, None]) & (kpos >= 0)
+                & (kpos > position[:, None] - window))
+        hist = jnp.where(seen[:, :, None], hist, 0)
+    with jax.named_scope("swa"), jax.named_scope("core"):
+        q_nope, q_rope = _queries_of(c_q, w_qb, heads, nope, position, inv_freq)
+        o_lat = _weighted_latents(_absorb_queries(q_nope, w_kvb3), q_rope,
+                                  hist[..., :kl], hist[..., kl:kl + rope],
+                                  seen[:, None, :], scale)
+        out = _gated(_up_project_values(o_lat, w_kvb3, nope).reshape(b, t, -1),
+                     gate, heads)
+    return out, pool
 
 
 # --------------------------------------------------------------------------

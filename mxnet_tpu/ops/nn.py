@@ -455,9 +455,11 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
 @register("held_expert_ffn", nout=3)
 def held_expert_ffn(data, router_weight, gate_weight, up_weight, down_weight,
                     held_experts=(), n_group=1, topk_group=1, top_k=1,
-                    scale=1.0, norm_topk_prob=False):
-    """The held experts' part of a group-limited top-k expert layer over
-    ``data`` (..., d): :func:`mxnet_tpu.parallel.moe.held_expert_ffn`.
+                    scale=1.0, norm_topk_prob=False, scoring="softmax",
+                    router_bias=None):
+    """The held experts' part of a top-k expert layer over ``data`` (..., d)
+    (group-limited softmax, or sigmoid scores with a selection bias):
+    :func:`mxnet_tpu.parallel.moe.held_expert_ffn`.
     Returns (the part, pairs routed to held experts, largest load of one)."""
     from ..parallel.moe import held_expert_ffn as ffn
 
@@ -465,7 +467,8 @@ def held_expert_ffn(data, router_weight, gate_weight, up_weight, down_weight,
         data.reshape(-1, data.shape[-1]), router_weight, gate_weight,
         up_weight, down_weight, held_experts=held_experts, n_group=n_group,
         topk_group=topk_group, top_k=top_k, scale=scale,
-        norm_topk_prob=norm_topk_prob)
+        norm_topk_prob=norm_topk_prob, scoring=scoring,
+        router_bias=router_bias)
     return out.reshape(data.shape), pairs, load
 
 

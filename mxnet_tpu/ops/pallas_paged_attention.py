@@ -54,6 +54,7 @@ output one product over the latent's lanes. Its gate is
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -464,3 +465,146 @@ def paged_latent_attention_read(q_lat, q_rope, pool, page_table, position,
                       jnp.asarray(position, jnp.int32), q2, pool, tq, h, kw,
                       float(scale), _resolve_interpret(interpret))
     return o2[:, :tq * h, :kl].reshape(b, tq, h, kl)
+
+
+# --------------------------------------------------------------------------
+# the indexer's key pool (learned sparse attention: scores of what a row holds)
+# --------------------------------------------------------------------------
+_INDEX_CHUNK = 2048  # keys scored at once: a (J, chunk) float32 block
+
+
+def _index_chunk(cap):
+    return math.gcd(cap, _INDEX_CHUNK)
+
+
+def paged_index_scores_refusal(idx_q, pool, page_table):
+    """Why the kernel does NOT score the index-key pool for these operands
+    (anything with ``.shape``/``.dtype``), or None when it does: ``idx_q``
+    ``(B, 1, J, D)`` one token a row, ``pool`` ``(P+1, page_size, D)``,
+    ``page_table`` ``(B, n_pages)``. The first condition that fails is the
+    one named; callers gather ``pool[page_table]`` through XLA then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    _, tq, j, d = idx_q.shape
+    ps, w = pool.shape[1], pool.shape[2]
+    cap = page_table.shape[1] * ps
+    if tq != 1:
+        return f"{tq} queries a row: the kernel scores one"
+    if pool.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"pool dtype {jnp.dtype(pool.dtype).name} is not float32 or bfloat16"
+    if w != d or d % _LANES:
+        return (f"the pool's {w} columns are not the keys' {d} in whole "
+                f"{_LANES}-lane tiles")
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    sub = 8 * (4 // itemsize)
+    if ps % sub or (_LANES % ps and ps % _LANES):
+        return (f"page size {ps} is not a multiple of {sub} sublanes that "
+                f"divides or is a multiple of {_LANES}")
+    if _index_chunk(cap) % _LANES:
+        return f"{cap} positions a row are not whole {_LANES}-key stretches"
+    need = 2 * cap * d * itemsize + _SCORE_TEMPS * j * _index_chunk(cap) * 4 \
+        + 2 * cap * 4
+    if need > _MAX_VMEM_BYTES:
+        return (f"a row's {cap} index keys need {need} bytes of VMEM (budget "
+                f"{_MAX_VMEM_BYTES})")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _index_kernel(table_ref, pos_ref, q_ref, w_ref, pool_ref, o_ref, hist, sem,
+                  *, ps, n_pages, chunk):
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    slot = b % 2
+
+    def pages_of(row):
+        return jnp.clip(pos_ref[row] // ps + 1, 1, n_pages)
+
+    def for_each_copy(row, into, act):
+        def page(j, carry):
+            pid = table_ref[row * n_pages + j]
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            act(pltpu.make_async_copy(pool_ref.at[pid], hist.at[into, at, :],
+                                      sem.at[into]))
+            return carry
+
+        lax.fori_loop(0, pages_of(row), page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        for_each_copy(0, 0, lambda copy: copy.start())
+
+    @pl.when(b + 1 < rows)
+    def _():
+        for_each_copy(b + 1, 1 - slot, lambda copy: copy.start())
+
+    for_each_copy(b, slot, lambda copy: copy.wait())
+    # what the row does not hold scores -inf, whatever VMEM has there
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, jnp.float32)
+
+    def stretch(c, carry):
+        keys = hist[slot, pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :]
+        dots = lax.dot_general(q_ref[0], keys, _NT,
+                               preferred_element_type=jnp.float32)  # (J, chunk)
+        score = jnp.sum(jnp.maximum(dots, 0.0) * w_ref[0], axis=0,
+                        keepdims=True)
+        score = jnp.where(score == 0, 0.0, score)       # one zero, as XLA's
+        at = c * chunk + lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        o_ref[0, pl.ds(c, 1), :] = jnp.where(at <= pos_ref[b], score, -jnp.inf)
+        return carry
+
+    lax.fori_loop(0, (pages_of(b) * ps + chunk - 1) // chunk, stretch, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _index_call(table, position, q, w, pool, interpret):
+    b, j, d = q.shape
+    ps = pool.shape[1]
+    n_pages = table.shape[0] // b
+    cap = n_pages * ps
+    chunk = _index_chunk(cap)
+    row = lambda i, t, p: (i, 0, 0)  # noqa: E731
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    need = 2 * cap * d * itemsize + _SCORE_TEMPS * j * chunk * 4 + 2 * cap * 4
+    return pl.pallas_call(
+        functools.partial(_index_kernel, ps=ps, n_pages=n_pages, chunk=chunk),
+        out_shape=jax.ShapeDtypeStruct((b, cap // chunk, chunk), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, j, d), row),
+                      pl.BlockSpec((1, j, 1), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, cap // chunk, chunk), row),
+            scratch_shapes=[pltpu.VMEM((2, cap, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        name="paged_index_scores",
+        interpret=interpret,
+        # rows run in order: each starts the next one's copies
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + 8 * 1024 * 1024),
+    )(table, position, q, w, pool).reshape(b, cap)
+
+
+def paged_index_scores(idx_q, idx_w, pool, page_table, position,
+                       interpret=None):
+    """The indexer's scores of one query a row against the index keys of
+    the pages the row holds: ``sum_j w[b, j] * relu(q[b, j] . k[s])`` in
+    float32 for ``s <= position[b]``, ``-inf`` past it, (B, cap) with ``cap``
+    the table's width in positions. ``idx_q`` ``(B, J, D)``, ``idx_w`` ``(B,
+    J)`` float32, ``pool`` ``(P+1, page_size, D)`` read by the pages each row
+    HOLDS (copied into VMEM, the next row's started before this row's
+    products begin), never the table's whole width. Callers gate via
+    :func:`paged_index_scores_refusal`."""
+    b = idx_q.shape[0]
+    return _index_call(jnp.asarray(page_table, jnp.int32).reshape(-1),
+                       jnp.asarray(position, jnp.int32),
+                       idx_q.astype(pool.dtype),
+                       idx_w.astype(jnp.float32).reshape(b, -1, 1), pool,
+                       _resolve_interpret(interpret))

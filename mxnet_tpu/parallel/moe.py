@@ -135,12 +135,17 @@ def group_limited_topk(probs, n_group: int, topk_group: int, top_k: int):
 
 
 def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
-                    n_group: int, topk_group: int, top_k: int,
-                    scale: float = 1.0, norm_topk_prob: bool = False):
+                    n_group: int = 1, topk_group: int = 1, top_k: int,
+                    scale: float = 1.0, norm_topk_prob: bool = False,
+                    scoring: str = "softmax", router_bias=None):
     """What the experts held here add to an expert layer's output.
 
     ``h`` [n, d] are the (normed) tokens; ``router_w`` [E, d] routes over
-    ALL ``E`` experts (softmax in float32, :func:`group_limited_topk`);
+    ALL ``E`` experts in float32. ``scoring`` ``softmax`` chooses by
+    :func:`group_limited_topk` over the probabilities; ``sigmoid`` scores
+    each expert alone and chooses the ``top_k`` largest of score +
+    ``router_bias`` [E] (a learned selection bias, ``noaux_tc``; no groups),
+    the weights being the UNBIASED scores of the chosen;
     ``held_experts`` are the ids of the experts whose SwiGLU weights this
     chip holds, stacked in that order: ``w_gate``/``w_up`` [held, d, w],
     ``w_down`` [held, w, d]. Every (token, expert) pair whose expert is
@@ -164,8 +169,17 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
         logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
                             router_w.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        weights, ids = group_limited_topk(jax.nn.softmax(logits, axis=-1),
-                                          n_group, topk_group, top_k)
+        if scoring == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+            chosen_by = score if router_bias is None else \
+                score + router_bias.astype(jnp.float32)[None, :]
+            _, ids = lax.top_k(chosen_by, top_k)
+            weights = jnp.take_along_axis(score, ids, axis=-1)
+        elif scoring == "softmax":
+            weights, ids = group_limited_topk(
+                jax.nn.softmax(logits, axis=-1), n_group, topk_group, top_k)
+        else:
+            raise ValueError(f"unknown scoring {scoring!r}: softmax or sigmoid")
         if norm_topk_prob:
             weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
         weights = weights * scale

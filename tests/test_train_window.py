@@ -62,8 +62,13 @@ def _param_values(ts):
 
 
 def _state_leaves(ts):
+    # in the same natural order (a list: tree_leaves sorts a dict's keys
+    # lexicographically, dense10 before dense9)
+    from conftest import natkey
+
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(
-        {k: ts.opt_state[k] for k in sorted(ts.opt_state)})]
+        [ts.opt_state[k] for k in sorted(
+            ts.opt_state, key=lambda name: natkey((name,)))])]
 
 
 # -- numerical equivalence ---------------------------------------------------

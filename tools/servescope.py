@@ -14,7 +14,11 @@ the paged kernels by name (``paged_kernel_ms``; they also stand under their
 scope, ``layerN/attn`` or ``layerN/mla/core``).
 
     python tools/servescope.py --workload deepseek_v2_serve_reason --seed 7
+    python tools/servescope.py --workload dots3_note_serve_longctx --depth 4
     JAX_PLATFORMS=cpu python tools/servescope.py --tiny     # rehearsal
+
+``--depth 4`` tells ``layerN/mla/dsa/index`` from ``layerN/mla/dsa/select``
+(a full layer's scoring of the held index keys and its selection).
 
 Ends in one JSON line, also appended to ``chiprun_out/servescope.jsonl``.
 """
@@ -37,11 +41,13 @@ def build(workload, tiny, seed):
 
     root, platform = ROOT, "tpu"
     if tiny:
+        import importlib
         import tempfile
 
+        # the toy copy lives with the CPU tests of the cell's configuration
         sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
-        import test_benchmark_deepseek_v2 as toy
-
+        name = harness.find_cell(harness.load_benchmark(ROOT), workload)["config"]
+        toy = importlib.import_module(f"test_benchmark_{name}")
         root = toy.make_root(tempfile.mkdtemp(prefix="servescope-"))
         workload, platform = toy.TINY, "cpu"
     bench = harness.load_benchmark(root)
@@ -55,18 +61,18 @@ def build(workload, tiny, seed):
     return engine, mix, config
 
 
-def by_scope(report, table, per):
-    """{scope path, layers folded, model name dropped, three components
+def by_scope(report, table, per, depth=3):
+    """{scope path, layers folded, model name dropped, ``depth`` components
     deep: ms of own time per ``per`` calls}, largest first."""
     out = {}
     for path, s in report.scope_seconds(table, depth=None).items():
         path = re.sub(r"layer\d+", "layerN", path.split("/", 1)[-1])
-        path = "/".join(path.split("/")[:3])  # layerN/mla/core, no deeper
+        path = "/".join(path.split("/")[:depth])  # layerN/mla/core, no deeper
         out[path] = out.get(path, 0.0) + 1e3 * s / per
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
-def scopes_and_pool_ops(engine, report, lowered, per):
+def scopes_and_pool_ops(engine, report, lowered, per, depth=3):
     """(ms by scope, {opcode: ms} of the traced operations whose result has
     the shape of one of the engine's page pools, {kernel: ms} of the paged
     Pallas kernels), per ``per`` calls, from one more compile of ``lowered``
@@ -91,11 +97,11 @@ def scopes_and_pool_ops(engine, report, lowered, per):
         op = opcode.get(name)
         if op:
             ms[op] = ms.get(op, 0.0) + own / 1e6 / per
-        kernel = re.match(r"paged_\w*attention\w*?(?=[.\d]*$)", name)
+        kernel = re.match(r"paged_\w*(?:attention|scores)\w*?(?=[.\d]*$)", name)
         if kernel:
             kernels[kernel[0]] = kernels.get(kernel[0], 0.0) + own / 1e6 / per
     rounded = lambda d: {k: round(v, 4) for k, v in d.items()}  # noqa: E731
-    return by_scope(report, table, per), rounded(ms), rounded(kernels)
+    return by_scope(report, table, per, depth), rounded(ms), rounded(kernels)
 
 
 def main(argv):
@@ -108,6 +114,7 @@ def main(argv):
     ap.add_argument("--steps", type=int, default=8)
     ap.add_argument("--prefills", type=int, default=4)
     ap.add_argument("--ahead", type=int, default=300)
+    ap.add_argument("--depth", type=int, default=3)
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args(argv)
     engine, mix, config = build(args.workload, args.tiny, args.seed)
@@ -122,7 +129,7 @@ def main(argv):
            "held_positions": held, "read_path": engine.read_path}
     cap = profiling.capture(engine.decode_step, steps=args.steps, warmup=2)
     out["decode_ms_by_scope"], pool_ms, kernel_ms = scopes_and_pool_ops(
-        engine, cap.report, engine.lower_decode(), args.steps)
+        engine, cap.report, engine.lower_decode(), args.steps, args.depth)
     out["pool_shaped_ms"] = {"decode": pool_ms}
     out["paged_kernel_ms"] = {"decode": kernel_ms}
     median = mix["prompt_len"]["median"]
@@ -132,7 +139,8 @@ def main(argv):
     out["prefill_bucket"] = engine.bucket_for(median)
     (out["prefill_ms_by_scope"], out["pool_shaped_ms"]["prefill"],
      out["paged_kernel_ms"]["prefill"]) = scopes_and_pool_ops(
-        engine, cap.report, engine.lower_prefill(median), args.prefills)
+        engine, cap.report, engine.lower_prefill(median), args.prefills,
+        args.depth)
     for key in ("decode_ms_by_scope", "prefill_ms_by_scope"):
         print(f"[servescope] {key} (sum {sum(out[key].values()):.3f} ms):")
         for path, ms in out[key].items():
