@@ -343,8 +343,11 @@ class Dots3NoteModel(HybridBlock):
         layer's index keys (the Pallas kernel that copies the pages a row
         holds, or the XLA gather and why: ``F.sparse_latent_attention`` makes
         the same choice from the same shapes at trace time), its selection
-        and its sparse read; a window layer's ring."""
+        and its sparse read; a window layer's ring; and what a prefill
+        program's full layers attend their chunk by."""
         from ..ops.attention import SPARSE_READ_BY_XLA
+        from ..ops.flash_attention import masked_prefill_refusal
+        from ..ops.pallas_common import LANES
         from ..ops.pallas_paged_attention import paged_index_scores_refusal
 
         c, out = self._cfg, []
@@ -360,6 +363,15 @@ class Dots3NoteModel(HybridBlock):
                           else "paged_index_scores kernel")
                        + ", selection lax.top_k, sparse read xla_gather_rows ("
                        + SPARSE_READ_BY_XLA + ")")
+            # a prefill chunk of whole lane tiles, a block of heads at a time
+            block = lambda d: jax.ShapeDtypeStruct((1, LANES, 16, d), q.dtype)  # noqa: E731
+            qk = c["qk_nope_head_dim"] + c["qk_rope_head_dim"]
+            why = masked_prefill_refusal(
+                block(qk), block(qk), block(c["v_head_dim"]),
+                jax.ShapeDtypeStruct((1, LANES, LANES), bool))
+            out.append("full layers: prefill attention "
+                       + (f"chunk_mask ({why})" if why
+                          else "masked_prefill kernel"))
         if "window" in self._kinds:
             out.append("window layers: xla_gather_ring")
         return "; ".join(out)

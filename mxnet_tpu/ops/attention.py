@@ -531,6 +531,12 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kvb, scale=1.0,
 # --------------------------------------------------------------------------
 _F32 = dict(preferred_element_type=jnp.float32)
 _KEY_STRETCH = 2048  # keys a long chunk's softmax takes at once
+# the masked prefill kernel's grid step: heads (unrolled, a mask tile read
+# once for them) and the query and key block. On a v5e at 16,384 tokens, 16
+# heads: 1,024 x 1,024 and 4 heads 13.9 ms; 512 x 512 and 8 heads in a loop
+# 24.3; 2,048 keys 15.0 (PERF.md, PR 32)
+_KERNEL_HEADS = 4
+_KERNEL_BLOCK = 1024
 
 
 def _queries_of(c_q, w_qb, heads, nope, position, inv_freq):
@@ -726,6 +732,58 @@ def _masked_chunk_attention(c_q, w_qb, c_kv, k_rope, w_kvb, heads, seen,
     return jnp.moveaxis(out, 0, 2).reshape(b, t, heads * vd)
 
 
+def _masked_chunk_kernel(c_q, w_qb, c_kv, k_rope, w_kvb, heads, seen,
+                         position, inv_freq, scale, gate, head_block=16,
+                         group=_KERNEL_HEADS, block=None, interpret=None):
+    """:func:`_masked_chunk_attention` with the scores kept on the chip: a
+    block of heads' queries, keys and values are made as there (XLA's
+    products, nothing head-sized for all heads at once) and handed to the
+    flash forward kernel under ``seen`` (one mask for every head, read once
+    for the ``group`` heads of a grid step; blocks above the diagonal
+    skipped). The same precision: bfloat16 operands, float32 sums and
+    softmax, the weights cast before the second product, the gate applied
+    to the float32 output; only the order of summation differs (key blocks
+    of ``block``, 1,024 or the largest that divides T, where XLA takes
+    stretches of 2,048). One row (B == 1)."""
+    from . import flash_attention as fa
+
+    b, t, ql = c_q.shape
+    kl, rope = c_kv.shape[-1], k_rope.shape[-1]
+    nope = w_qb.shape[0] // heads - rope
+    vd = w_kvb.shape[0] // heads - nope
+    hb = head_block if heads % head_block == 0 else heads
+    interpret = fa._resolve_interpret(interpret)
+    block = block or fa._pick_block(t, _KERNEL_BLOCK)
+    mask = seen[0].astype(jnp.int8)   # once, not once a block of heads
+
+    def heads_of(args):
+        wq, wkv, g = args    # (hb * (nope + rope), ql), (hb, nope + vd, kl)
+        qn, qr = _queries_of(c_q, wq, hb, nope, position, inv_freq)
+        q = jnp.concatenate([qn, qr], axis=-1)               # (1, T, hb, d)
+        kv = jnp.einsum("bkl,hdl->bhkd", c_kv, wkv, **_F32).astype(c_q.dtype)
+        # one product over a head's nope + rope dims: the shared rotated key
+        # stands beside every head's own
+        keys = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope[:, None],
+                                              (b, hb, t, rope))], axis=-1)
+        out = fa._flash_fwd(jnp.swapaxes(q, 1, 2), keys, kv[..., nope:], True,
+                            block_q=block, block_k=block, interpret=interpret,
+                            mask=mask, scale=scale, group=math.gcd(hb, group),
+                            out_dtype=jnp.float32,
+                            name="masked_latent_prefill")    # (1, hb, T, vd)
+        out = jnp.swapaxes(out, 1, 2)
+        if g is not None:
+            out = out * g[..., None]
+        return out.astype(c_q.dtype)
+
+    gates = None if gate is None else jnp.moveaxis(
+        gate.reshape(b, t, heads // hb, hb), 2, 0)
+    out = jax.lax.map(heads_of, (w_qb.reshape(heads // hb, -1, ql),
+                                 w_kvb.reshape(heads // hb, hb, nope + vd, kl),
+                                 gates))
+    return jnp.moveaxis(out, 0, 2).reshape(b, t, heads * vd)
+
+
 def _gated(out, gate, heads):
     """``out`` (B, T, H * vd) with head ``h``'s part times ``gate[..., h]``."""
     if gate is None:
@@ -778,9 +836,17 @@ def sparse_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, idx_q, idx_k,
     table width x the latent pool's width. A chunk of more
     than one token opens its rows at position 0 (a prefill without an
     adopted prefix: the only chunk an engine with a window group builds) and
-    attends itself in the decompressed form, the selection as a mask,
-    queries in blocks. ``sparse_read_path_total{path, reason}`` says at
-    trace time what was built.
+    attends itself in the decompressed form, the selection as a mask: one
+    algorithm with two paths, chosen by
+    :func:`~mxnet_tpu.ops.flash_attention.masked_prefill_refusal` from
+    what the operands and the process show. The flash forward kernel under
+    the mask (``masked_latent_prefill``: online softmax over key blocks, the
+    scores in VMEM only, blocks above the diagonal skipped) or, where it
+    refuses, XLA with queries in blocks and keys in stretches
+    (:func:`_masked_chunk_attention`, the kernel's oracle).
+    ``sparse_read_path_total{path, reason}`` says at trace time what was
+    built: ``chunk_mask_kernel``, or ``chunk_mask`` and why the kernel was
+    not.
     """
     from .. import observability as obs
 
@@ -789,18 +855,31 @@ def sparse_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, idx_q, idx_k,
     nope = w_qb.shape[0] // heads - rope
     w_kvb3 = w_kvb.reshape(heads, -1, kl)
 
+    # a chunk's own attention: the flash forward kernel under the
+    # selection's mask, or XLA with the keys in stretches, and why
+    hb = 16 if heads % 16 == 0 else heads
+    vd = w_kvb3.shape[1] - nope
+    block = lambda d: jax.ShapeDtypeStruct((b, t, hb, d), c_q.dtype)  # noqa: E731
+    from .flash_attention import masked_prefill_refusal
+
+    why_chunk = masked_prefill_refusal(
+        block(nope + rope), block(nope + rope), block(vd),
+        jax.ShapeDtypeStruct((b, t, t), jnp.bool_))
+
     def from_chunk(c_hist, r_hist, k_hist, start):
+        obs.counter("sparse_read_path_total").inc(
+            path="chunk_mask" if why_chunk else "chunk_mask_kernel",
+            reason=why_chunk or "")
         with jax.named_scope("dsa"):
             with jax.named_scope("index"):
                 seen = dsa_selection_mask(idx_q, k_hist, idx_w, top_k)
+        core = _masked_chunk_attention if why_chunk else _masked_chunk_kernel
         with jax.named_scope("mla"), jax.named_scope("core"):
-            return _masked_chunk_attention(c_q, w_qb, c_hist, r_hist, w_kvb,
-                                           heads, seen, start, inv_freq, scale,
-                                           None if gate is None else
-                                           _unwrap(gate))
+            return core(c_q, w_qb, c_hist, r_hist, w_kvb, heads, seen, start,
+                        inv_freq, scale,
+                        None if gate is None else _unwrap(gate))
 
     if cache is None:
-        obs.counter("sparse_read_path_total").inc(path="chunk_mask", reason="")
         return from_chunk(c_kv, k_rope, idx_k, None)
     if position is None or page_table is None:
         raise ValueError("sparse_latent_attention(cache=...) is paged: it "
@@ -818,7 +897,6 @@ def sparse_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, idx_q, idx_k,
         ipool = _rows_write(ipool, idx_k, pid, off)
     held = jnp.sum(position + t)
     if t > 1:
-        obs.counter("sparse_read_path_total").inc(path="chunk_mask", reason="")
         read = jnp.sum(jnp.minimum(pos + 1, top_k)) // t
         out = from_chunk(c_kv.astype(pool.dtype).astype(c_kv.dtype),
                          k_rope.astype(pool.dtype).astype(k_rope.dtype),

@@ -4,10 +4,20 @@ The marquee custom kernel (SURVEY §5.7): replaces the reference's O(L^2)
 fused attention (``src/operator/contrib/transformer.cu``) with an online-
 softmax blocked kernel — O(L) memory, MXU-tiled q/k blocks, f32 accumulation.
 
-Forward is a Pallas kernel (grid = (batch*heads, q_blocks, k_blocks), with
-m/l/acc scratch carried across the sequential innermost k dimension) that
-also emits the per-row logsumexp (lane-replicated, the standard TPU layout)
-as the backward residual.
+Forward is ONE Pallas kernel body (``_fwd_kernel`` through ``_flash_fwd``;
+grid = (heads / group, q_blocks, k_blocks), with m/l/acc scratch carried
+across the sequential innermost k dimension). It serves two callers. The
+training path (``flash_attention``, causal or not, no mask) also has it emit
+the per-row logsumexp (lane-replicated, the standard TPU layout) as the
+backward residual. A long prefill's masked attention (PR 32:
+``attention._masked_chunk_kernel`` for ``F.sparse_latent_attention``,
+``masked_latent_prefill`` in a trace) hands it ONE (tq, tk) mask that every
+head shares, a value width of its own, several heads a grid step (a mask
+tile is read once for them) and asks for no logsumexp; the gate of that
+caller is :func:`masked_prefill_refusal`. The products take their operands
+as they come (bfloat16 stays bfloat16; float32 sums), the softmax is
+float32, the weights are cast to the values' dtype before the second
+product; key blocks above the diagonal are neither computed nor fetched.
 
 Backward is a pair of Pallas kernels (FlashAttention-2 recomputation split):
 ``dkv`` grids over k blocks with q innermost (accumulating dk/dv in VMEM
@@ -17,18 +27,21 @@ VJP. A ``lax.scan`` chunked recompute backward (`_chunked_attention`) is kept
 as the escape hatch (`config flash_pallas_bwd=False`) and as the long-seq
 correctness oracle. Forward and backward compile under Mosaic and agree
 with the einsum reference on a TPU v5e (``chip_smoke.py`` kernels phase:
-batch 4, 16 heads, seq 2048, head 64, causal and not). Their speed against
-the einsum and chunked paths under the installed jax: not measured.
+batch 4, 16 heads, seq 2048, head 64, causal and not). The training path's
+speed against the einsum and chunked paths under the installed jax: not
+measured (``tools/kernelbench.py --kinds attn``); the masked forward's is
+(``--kinds masked_prefill``; PERF.md, PR 32).
 
 On non-TPU backends the kernels run in interpret mode (tests) or callers fall
-back to the einsum path via ``flash_supported``. Which model reaches it: none
-of the listed ones at their published shapes. ``flash_supported`` refuses any
-mask and any sequence under 2048, BERT always passes a key-padding mask and
-GPT-2's context is 1024. BERT's short masked sequences are the opposite
-shape and have a kernel of their own
+back to the einsum path via ``flash_supported``. Which model reaches the
+training path: none of the listed ones at their published shapes.
+``flash_supported`` refuses any mask and any sequence under 2048, BERT
+always passes a key-padding mask and GPT-2's context is 1024. BERT's short
+masked sequences are the opposite shape and have a kernel of their own
 (:mod:`mxnet_tpu.ops.pallas_packed_attention`, reached through
 ``attention.self_attention_packed``); that operator's fallback still comes
-here for long unmasked sequences.
+here for long unmasked sequences. The forward kernel runs in dots3-note-prev's
+serving cell, in every prefill program's two full layers.
 """
 from __future__ import annotations
 
@@ -56,6 +69,11 @@ _FLASH_MEM_BYTES = 2 << 30  # engage below _FLASH_MIN_SEQ too when the einsum
 # moderate seq): memory is the kernel's unconditional win
 
 
+_SCORE_TEMPS = 4  # float32 (bq, bk) blocks a head holds at once: the scores,
+# the weights, the mask as a select's operand, the weights cast for the MXU;
+# two heads of a step are in flight together
+
+
 def flash_supported(q, k, v, mask=None) -> bool:
     """Kernel eligibility: TPU backend, no arbitrary mask, tile-able lengths,
     and either past the measured speed crossover or under einsum-memory
@@ -80,6 +98,40 @@ def flash_supported(q, k, v, mask=None) -> bool:
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 
+def masked_prefill_refusal(q, k, v, seen):
+    """Why a prefill chunk's attention under a mask is NOT the forward
+    kernel (``_flash_fwd(mask=)``: score-shaped blocks in VMEM only) for
+    these operands (anything with ``.shape``/``.dtype``), or None when it
+    is: ``q`` and ``k`` (B, T, h, d), ``v`` (B, T, h, dv) a block of heads
+    as ``attention._masked_chunk_attention`` makes them, ``seen`` (B, T, T).
+    The first condition that fails is the one named; callers take that XLA
+    path (keys in stretches) then. Training callers never come here:
+    :func:`flash_supported` refuses every mask."""
+    from .. import config as _config
+    from .._mesh_state import current_mesh
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    dtypes = {jnp.dtype(x.dtype) for x in (q, k, v)}
+    if len(dtypes) > 1 or dtypes.pop() not in (jnp.float32, jnp.bfloat16):
+        return ("queries, keys and values are not all float32 or all "
+                "bfloat16")
+    b, t = q.shape[:2]
+    if b != 1:
+        return f"{b} rows: the kernel takes one mask for all its heads"
+    if tuple(seen.shape) != (b, t, t):
+        return (f"a mask of shape {tuple(seen.shape)} is not one chunk's "
+                f"({b}, {t}, {t})")
+    if t % _LANES:
+        return f"{t} tokens are not whole {_LANES}-key tiles"
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
 def _causal_gated(body, causal, qi, ki, bq, bk, off):
     """Run ``body`` only for (q, k) block pairs with live causal entries:
     the block's max row + off must reach its min col. Shared by the forward
@@ -102,13 +154,24 @@ def _block_mask(s, causal, qi, ki, bq, bk, off):
     return jnp.where(rows + off >= cols, s, -jnp.inf)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, bq, bk, scale,
-                off, emit_lse):
-    lse_ref = rest[0] if emit_lse else None
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, bq, bk, scale, off,
+                emit_lse, masked):
+    """One (heads, query block, key block) step of the online softmax.
+    ``q_ref`` (G, bq, d), ``k_ref`` (G, bk, d) and ``v_ref`` (G, bk, dv) hold
+    G heads; ``mask_ref`` (bq, bk) int8, if ``masked``, is one tile of a
+    mask that every head shares: it is read once a step, whatever G. The
+    products take their operands as they come (bfloat16 stays bfloat16, the
+    sums are float32), the softmax is float32, and the weights are cast to
+    the values' dtype before the second product: a score-shaped block never
+    leaves VMEM."""
+    mask_ref = rest[0] if masked else None
+    o_ref = rest[masked]
+    lse_ref = rest[masked + 1] if emit_lse else None
     m_ref, l_ref, acc_ref = rest[-3:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    heads = q_ref.shape[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -117,50 +180,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, bq, bk, scale,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def _body():
-        q = q_ref[0].astype(jnp.float32) * scale  # (bq, d)
-        k = k_ref[0].astype(jnp.float32)  # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (bq, bk)
-        s = _block_mask(s, causal, qi, ki, bq, bk, off)
-        m_prev = m_ref[:, :1]  # (bq, 1), replicated over lanes
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard fully-masked rows (m_new == -inf) against nan exp
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = corr * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        keep = None if mask_ref is None else mask_ref[...].astype(jnp.int32) != 0
+
+        def head(g):
+            s = jax.lax.dot_general(q_ref[g], k_ref[g], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            if keep is None:
+                s = _block_mask(s, causal, qi, ki, bq, bk, off)
+            else:   # the mask holds the diagonal: ``causal`` only skips blocks
+                s = jnp.where(keep, s, -jnp.inf)
+            m_prev = m_ref[g, :, :1]  # (bq, 1), replicated over lanes
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # rows that have seen nothing yet (m_new == -inf) must not make
+            # exp(-inf + inf); a masked score is exp(-inf - finite) = 0
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - m_safe)
+            corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+            l_new = corr * l_ref[g, :, :1] + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[g],
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[g] = acc_ref[g] * corr + pv
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        # unrolled: one head's softmax (the vector units) stands beside the
+        # next head's products (the MXU) in one schedule; a loop ran them in
+        # turn, 13% slower at four heads a step (PERF.md, PR 32)
+        for g in range(heads):
+            head(g)
 
     _causal_gated(_body, causal, qi, ki, bq, bk, off)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         if emit_lse:
             # logsumexp residual for the backward kernels, lane-replicated.
             # Fully-masked rows (l == 0) store lse = 0: the backward then
             # yields p = exp(-inf - 0) = 0 for every masked score, matching
             # the forward's defined-as-zero output for those rows.
-            lg = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-            lse_ref[0] = jnp.where(l_ref[:] == 0.0, 0.0,
-                                   m_ref[:] + jnp.log(lg))
+            lg = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+            lse_ref[...] = jnp.where(l_ref[...] == 0.0, 0.0,
+                                     m_ref[...] + jnp.log(lg))
 
 
 def _pick_block(t, prefer=512):
     """Largest MXU-friendly block (<= prefer) that divides the seq length.
     Bigger tiles keep the MXU pipeline full and cut grid-iteration
-    overhead; 512x512 against smaller tiles on the chip: not measured."""
-    for cand in (prefer, 256, 128):
-        if cand <= t and t % cand == 0:
+    overhead; the training path's 512x512 against smaller tiles on the chip:
+    not measured (the masked forward's blocks are: PERF.md, PR 32)."""
+    for cand in sorted({prefer, 512, 256, 128}, reverse=True):
+        if cand <= min(t, prefer) and t % cand == 0:
             return cand
     return t
 
@@ -174,60 +247,93 @@ def _lane_pad(x):
 
 
 def _flash_fwd(q, k, v, causal, block_q=None, block_k=None, interpret=False,
-               return_lse=False):
+               return_lse=False, mask=None, scale=None, group=1,
+               out_dtype=None, name=None):
+    """The forward kernel over ``q`` (b, h, tq, d), ``k`` (b, h, tk, d) and
+    ``v`` (b, h, tk, dv): ``dv`` need not be ``d``. ``mask`` (tq, tk), if
+    given, is ONE mask for every batch and head (nonzero: the query sees the
+    key), fetched a (block_q, block_k) tile a grid step and shared by the
+    ``group`` heads that step holds; with it ``causal`` promises that the
+    mask is false above the diagonal and skips those blocks unread.
+    ``scale`` multiplies the float32 scores (default ``d ** -0.5``)."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    scale = 1.0 / (d ** 0.5)  # true head dim, even when lanes are padded
-    d_orig = d
-    if d % _LANES:
-        # lane-pad the head dim to a full 128 tile: zero columns contribute
-        # nothing to q·kᵀ, and the padded v columns come out as zeros in the
-        # output, sliced off below. XLA fuses the pads/slice; cost is the
-        # idle lane fraction of the two block matmuls.
-        q, k, v = _lane_pad(q), _lane_pad(k), _lane_pad(v)
-        d = q.shape[-1]
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)  # true head dim, even when lanes are padded
+    dv_orig = v.shape[-1]
+    # lane-pad the head dims to whole 128 tiles: zero columns contribute
+    # nothing to q·kᵀ, and the padded v columns come out as zeros in the
+    # output, sliced off below. XLA fuses the pads/slice; cost is the
+    # idle lane fraction of the two block matmuls.
+    q, k, v = _lane_pad(q), _lane_pad(k), _lane_pad(v)
+    d, dv = q.shape[-1], v.shape[-1]
     bq = _pick_block(tq) if block_q is None else min(block_q, tq)
     bk = _pick_block(tk) if block_k is None else min(block_k, tk)
-    qr = q.reshape(b * h, tq, d)
-    kr = k.reshape(b * h, tk, d)
-    vr = v.reshape(b * h, tk, d)
-    grid = (b * h, tq // bq, tk // bk)
+    n, off = b * h, tk - tq
+    if n % group:
+        raise ValueError(f"{n} heads are not whole groups of {group}")
+    qr = q.reshape(n, tq, d)
+    kr = k.reshape(n, tk, d)
+    vr = v.reshape(n, tk, dv)
+    grid = (n // group, tq // bq, tk // bk)
     kernel = functools.partial(_fwd_kernel, causal=causal, bq=bq, bk=bk,
-                               scale=scale, off=tk - tq,
-                               emit_lse=return_lse)
+                               scale=scale, off=off, emit_lse=return_lse,
+                               masked=mask is not None)
     scratch = [
-        pltpu.VMEM((bq, _LANES), jnp.float32),
-        pltpu.VMEM((bq, _LANES), jnp.float32),
-        pltpu.VMEM((bq, d), jnp.float32),
+        pltpu.VMEM((group, bq, _LANES), jnp.float32),
+        pltpu.VMEM((group, bq, _LANES), jnp.float32),
+        pltpu.VMEM((group, bq, dv), jnp.float32),
     ]
+
+    def key_block(qi, ki):
+        # a block above the diagonal is skipped: naming the last block the
+        # queries do read keeps the pipeline from fetching it
+        if not causal:
+            return ki
+        return jnp.minimum(ki, jnp.maximum(qi * bq + bq - 1 + off, 0) // bk)
+
+    in_specs = [
+        pl.BlockSpec((group, bq, d), lambda g, qi, ki: (g, qi, 0)),
+        pl.BlockSpec((group, bk, d), lambda g, qi, ki: (g, key_block(qi, ki), 0)),
+        pl.BlockSpec((group, bk, dv), lambda g, qi, ki: (g, key_block(qi, ki), 0)),
+    ]
+    operands = [qr, kr, vr]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec(
+            (bq, bk), lambda g, qi, ki: (qi, key_block(qi, ki))))
+        operands.append(mask.astype(jnp.int8))
     # the lse output exists only on the grad path (return_lse): Pallas can't
     # DCE an unused kernel output, and at padded d=64 it would be as large
     # as the attention output itself
-    out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((n, tq, dv), out_dtype or q.dtype)]
+    out_specs = [pl.BlockSpec((group, bq, dv), lambda g, qi, ki: (g, qi, 0))]
     if return_lse:
-        out_shape.append(
-            jax.ShapeDtypeStruct((b * h, tq, _LANES), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((n, tq, _LANES), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((1, bq, _LANES), lambda bh, qi, ki: (bh, qi, 0)))
+            pl.BlockSpec((group, bq, _LANES), lambda g, qi, ki: (g, qi, 0)))
+    itemsize = jnp.dtype(q.dtype).itemsize
+    need = (2 * group * (bq * d + bk * d + bk * dv) * itemsize   # two buffers
+            + 2 * group * bq * dv * jnp.dtype(out_shape[0].dtype).itemsize
+            + 2 * bq * bk * (mask is not None)
+            + group * bq * (2 * _LANES + dv) * 4                # the scratch
+            + 2 * return_lse * group * bq * _LANES * 4
+            + _SCORE_TEMPS * min(group, 2) * bq * bk * 4)
     res = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
         interpret=interpret,
+        name=name,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(qr, kr, vr)
-    out = res[0].reshape(b, h, tq, d)
-    if d_orig != d:
-        out = out[..., :d_orig]
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(need + (8 << 20), 32 << 20)),
+    )(*operands)
+    out = res[0].reshape(b, h, tq, dv)
+    if dv_orig != dv:
+        out = out[..., :dv_orig]
     return (out, res[1]) if return_lse else out
 
 

@@ -1,0 +1,41 @@
+"""GPT-2's and DeepSeek-V2's serving programs are the parent's: the SHA-256
+of their lowered text at toy widths (``tools/loweredsha.py``, a process of
+its own so that no other test's blocks move a name) against the values
+recorded from the tree before PR 32 (commit eea6ea6). A PR that does not
+mean to touch those models' programs keeps them; one that does records anew
+(``JAX_PLATFORMS=cpu python tools/loweredsha.py``) and says so."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RECORDED = {
+    "gpt2.decode": "76b55c8cc854ebb98588fe1698e09959ca505b9e0b0b1fa67b3bea44f7ff3195",
+    "gpt2.prefill8": "5331b6a2166d716c0af61623ada3f9c264c314b117f836fcdc5f50868c9328e6",
+    "gpt2.prefill16": "11b989eaafe81e7e9d28026261f64714c030e15bc975740b9b85e49cd5a2243b",
+    "deepseek_v2.decode": "d8882c71e5cfe3c884f3f9e60c130aeb077ad90820ddd9ade253c9e11e5ad443",
+    "deepseek_v2.prefill8": "e93b9e0451fabb9396df72339c1ae2ca81b20560056374a063b162322f259d80",
+    "deepseek_v2.prefill16": "8bb85cb5486e5fb526cbfe108a276796656747c720cfff7092c32df9bb461366",
+    "deepseek_v2.prefill32": "312dbc5daaa8dc81db10a4862906aa632c17fd1e4579000008c6ae0dd7b9f86a",
+    "deepseek_v2.prefill64": "53764d7519643636edc56b11c028c5c3ed80e3b8f9d149ae2995dae5c52deee9",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    run = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "loweredsha.py")],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert run.returncode == 0, run.stderr[-2000:]
+    return json.loads(run.stdout[run.stdout.index("{"):])
+
+
+@pytest.mark.parametrize("program", sorted(RECORDED))
+def test_a_program_of_another_model_keeps_the_parents_lowered_text(lowered,
+                                                                   program):
+    assert set(lowered) == set(RECORDED)
+    assert lowered[program] == RECORDED[program]

@@ -1,7 +1,7 @@
 """On-chip check + timing of the Pallas kernels (flash attention, fused
 LayerNorm, paged decode-attention over key/value pools and over a latent
-pool, fused Adam, fused softmax-xent) against
-their XLA compositions.
+pool, a long prefill's masked latent attention, fused Adam, fused
+softmax-xent) against their XLA compositions.
 
 Send it through the chip tool. The parent never imports jax, so it never
 holds the chip: each case runs in a child process of its own, one at a
@@ -49,6 +49,12 @@ PAGED_CASES = [(64, 16, 64, 16, 64, 1, 128), (64, 16, 64, 16, 64, 1, 0),
 LATENT_CASES = [(128, 128, 512, 64, 16, 256, 1, 128),
                 (128, 128, 512, 64, 16, 256, 1, 1024),
                 (128, 128, 512, 64, 16, 256, 1, 0)]
+# masked prefill (dots3-note-prev's full layer, one chunk from position 0
+# under the indexer's selection): (tokens, heads, nope, rope, vd, kl, ql,
+# index heads, index dim, top_k); the A/B is the flash forward kernel under
+# the mask against ``_masked_chunk_attention`` (XLA: keys in stretches)
+MASKED_PREFILL_CASES = [(4096, 128, 128, 64, 128, 512, 1024, 64, 128, 2048),
+                        (16384, 128, 128, 64, 128, 512, 1024, 64, 128, 2048)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -68,6 +74,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     CONV_CASES = [(2, 8, 14, 14, 8, 3)]
     PAGED_CASES = [(2, 2, 32, 8, 4, 1, 0), (2, 2, 64, 16, 8, 3, 40)]
     LATENT_CASES = [(2, 4, 32, 8, 16, 8, 1, 0), (2, 4, 32, 8, 16, 16, 2, 100)]
+    MASKED_PREFILL_CASES = [(256, 2, 128, 64, 128, 64, 64, 2, 32, 64)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -358,6 +365,71 @@ def run_latent_case(b, h, kl, rope, ps, n_pages, tq, held, reps):
     return case
 
 
+def run_masked_prefill_case(t, heads, nope, rope, vd, kl, ql, idx_heads,
+                            idx_dim, top_k, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+
+    # XLA:CPU lacks some bfloat16 products with float32 results
+    dtype = jnp.float32 if _INTERP else jnp.bfloat16
+    rng = np.random.RandomState(0)
+    rand = lambda *shape, std=1.0: jnp.asarray(  # noqa: E731
+        rng.randn(*shape) * std, dtype)
+    c_q, c_kv, k_rope = rand(1, t, ql), rand(1, t, kl), rand(1, t, rope)
+    w_qb = rand(heads * (nope + rope), ql, std=0.02)
+    w_kvb = rand(heads * (nope + vd), kl, std=0.02)
+    gate = jax.nn.sigmoid(rand(1, t, heads))
+    inv_freq = 8e7 ** (-np.arange(0, rope, 2) / rope)
+    scale = (nope + rope) ** -0.5
+    case = {"kind": "masked_prefill", "t": t, "heads": heads, "nope": nope,
+            "rope": rope, "vd": vd, "kl": kl, "ql": ql, "top_k": top_k}
+    # the selection as the program makes it: the indexer's top_k of every
+    # query's row among the keys up to it (all of them while t < top_k)
+    idx = (rand(1, t, idx_heads, idx_dim), rand(1, t, idx_dim),
+           jnp.asarray(rng.rand(1, t, idx_heads), jnp.float32))
+    select = jax.jit(lambda q: att.dsa_selection_mask(q, idx[1], idx[2], top_k))
+    seen = select(idx[0])
+    case["ones_a_row"] = round(float(seen.sum()) / t, 1)
+
+    def xla(c_q):
+        return att._masked_chunk_attention(c_q, w_qb, c_kv, k_rope, w_kvb,
+                                           heads, seen, None, inv_freq, scale,
+                                           gate)
+
+    def kernel(c_q):
+        return att._masked_chunk_kernel(c_q, w_qb, c_kv, k_rope, w_kvb, heads,
+                                        seen, None, inv_freq, scale, gate,
+                                        interpret=_INTERP)
+
+    try:
+        ref, out = jax.jit(xla)(c_q), jax.jit(kernel)(c_q)
+        err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                    - ref.astype(jnp.float32))))
+        case["max_err"] = round(err, 6)
+        # weighted means of values of size about 0.5, rounded to bfloat16
+        # and summed in another order
+        case["correct"] = bool(err < 0.01 and jnp.isfinite(
+            out.astype(jnp.float32)).all())
+        del ref, out
+    except Exception as e:
+        case["kernel_error"] = repr(e)[:600]
+    for label, f, arg in (("kernel", kernel, c_q), ("xla", xla, c_q),
+                          ("indexer", select, idx[0])):
+        try:
+            case[f"{label}_ms"] = round(_timeit(f, (arg,), reps) * 1e3, 3)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:300]
+    if "kernel_ms" in case and "xla_ms" in case:
+        case["kernel_vs_xla"] = round(case["xla_ms"] / case["kernel_ms"], 2)
+    if "kernel_ms" in case:   # the products of the pairs at or under the diagonal
+        flops = heads * t * (t + 1) * (nope + rope + vd)
+        case["kernel_tflops"] = round(flops / case["kernel_ms"] / 1e9, 1)
+    return case
+
+
 def run_adam_case(n, reps):
     import jax.numpy as jnp
     import numpy as np
@@ -464,6 +536,8 @@ def run_one(argv):
             case = run_latent_case(spec["b"], spec["h"], spec["kl"],
                                    spec["rope"], spec["ps"], spec["n_pages"],
                                    spec["tq"], spec["held"], spec["reps"])
+        elif spec["kind"] == "masked_prefill":
+            case = run_masked_prefill_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -484,8 +558,9 @@ def main():
     ap.add_argument("--fwd-only", action="store_true")
     ap.add_argument("--kinds", default="",
                     help="comma-separated case kinds to run (attn, ln, "
-                         "conv_layout, paged_attn, paged_latent, fused_adam, "
-                         "softmax_xent); default all")
+                         "conv_layout, paged_attn, paged_latent, "
+                         "masked_prefill, fused_adam, softmax_xent); "
+                         "default all")
     ap.add_argument("--timeout", type=int, default=600)
     args = ap.parse_args()
 
@@ -507,6 +582,9 @@ def main():
                "ps": ps, "n_pages": np_, "tq": tq, "held": held,
                "reps": args.reps}
               for b, h, kl, rope, ps, np_, tq, held in LATENT_CASES]
+    # a call is 0.1-0.6 s: a chain of three is long enough
+    specs += [{"kind": "masked_prefill", "shape": list(shape),
+               "reps": min(args.reps, 3)} for shape in MASKED_PREFILL_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}

@@ -1,0 +1,77 @@
+"""SHA-256 of the lowered text of GPT-2's and DeepSeek-V2's serving programs
+at toy widths, on the CPU: the paged decode step and the prefill buckets of
+each (DeepSeek-V2's buckets cover both forms of its latent attention). A PR
+that says "their programs are the parent's" shows it with these: the same
+hashes from the parent's tree and from its own
+(``tests/test_lowered_text_guard.py`` holds the parent's). The text is what
+jax lowers before XLA sees it, so the hashes hold for one jax version and
+say nothing about another backend's compile.
+
+    JAX_PLATFORMS=cpu python tools/loweredsha.py        # one JSON object
+"""
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+DEEPSEEK_V2_TOY = dict(
+    hidden_size=64, num_attention_heads=4, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, q_lora_rank=24, kv_lora_rank=32,
+    rms_norm_eps=1e-6, rope_theta=10000, n_layer=3, first_k_dense_replace=1,
+    intermediate_size=96, moe_intermediate_size=24, n_shared_experts=2,
+    n_routed_experts=16, n_group=4, topk_group=2, num_experts_per_tok=3,
+    norm_topk_prob=False, routed_scaling_factor=16, n_vocab=200,
+    initializer_range=0.02, max_position_embeddings=256,
+    rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                  "mscale": 0.707, "mscale_all_dim": 0.707,
+                  "original_max_position_embeddings": 4096, "type": "yarn"},
+    held_experts=[0, 1, 5, 9], precision={"weights": "float32"},
+    engine={"batch_size": 4, "paged": True, "page_size": 8, "num_pages": 64,
+            "max_length": 128, "cache_dtype": "float32",
+            "prefill_buckets": [8, 16, 32, 64]})
+GPT2_TOY = dict(n_layer=2, n_embd=32, n_head=2, n_ctx=64, n_vocab=64,
+                engine={"batch_size": 2, "paged": True, "page_size": 8,
+                        "max_length": 64, "prefill_buckets": [8, 16]})
+
+
+def engines():
+    """{model: its toy paged engine}, seeded weights."""
+    import numpy as np
+
+    from benchmark.reference import deepseek_v2 as ref_v2
+    from benchmark.systems import deepseek_v2 as adaptor_v2
+    from benchmark.weights import make_weights
+    from mxnet_tpu import nd
+    from mxnet_tpu.inference import GenerationEngine
+    from mxnet_tpu.models import gpt2
+
+    c = GPT2_TOY
+    net = gpt2.get_gpt2("gpt2_tiny", dropout=0.0, num_layers=c["n_layer"],
+                        units=c["n_embd"], num_heads=c["n_head"],
+                        max_length=c["n_ctx"], vocab_size=c["n_vocab"])
+    net.initialize()
+    net(nd.array(np.zeros((1, 4), np.int32)))   # shapes, then parameters
+    weights = make_weights(ref_v2.param_specs(DEEPSEEK_V2_TOY), 7)
+    return {"gpt2": GenerationEngine(net, **c["engine"]),
+            "deepseek_v2": adaptor_v2.build_serve(DEEPSEEK_V2_TOY, weights)[0]}
+
+
+def lowered_sha():
+    """{"<model>.decode" | "<model>.prefill<bucket>": SHA-256 of the text}."""
+    sha = lambda lowered: hashlib.sha256(  # noqa: E731
+        lowered.as_text().encode()).hexdigest()
+    out = {}
+    for model, engine in engines().items():
+        out[f"{model}.decode"] = sha(engine.lower_decode())
+        for bucket in engine.prefill_buckets:
+            out[f"{model}.prefill{bucket}"] = sha(engine.lower_prefill(bucket))
+    return out
+
+
+if __name__ == "__main__":
+    print(json.dumps(lowered_sha(), indent=1))

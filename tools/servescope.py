@@ -19,6 +19,11 @@ scope, ``layerN/attn`` or ``layerN/mla/core``).
 
 ``--depth 4`` tells ``layerN/mla/dsa/index`` from ``layerN/mla/dsa/select``
 (a full layer's scoring of the held index keys and its selection).
+``--prefill-lens 16384,8192`` traces one prefill of each length besides and
+prints ``prefill_programs``: the program's device time and each scope's as
+the UNION of its operations' intervals (``benchmark/trace/reduce.py``: an
+asynchronous copy's whole span is then counted once, where it covers nothing
+else, and not added to every operation it overlaps).
 
 Ends in one JSON line, also appended to ``chiprun_out/servescope.jsonl``.
 """
@@ -72,6 +77,34 @@ def by_scope(report, table, per, depth=3):
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
+def program_by_scope(report, table, per, depth=3):
+    """One program's traced calls as ``benchmark/trace/reduce.py`` reads a
+    trace: {"busy_ms": the union of every operation's interval, "scope_ms":
+    {scope path: the union of the intervals of the scope's operations that
+    cover no other}, "op_ms": the ten kinds of operation with most own
+    time}, per ``per`` calls."""
+    from benchmark.trace import reduce as red
+    from mxnet_tpu.observability.scopes import instruction_name
+
+    rows = [r for r in report.op_rows if r.lane.startswith("XLA Ops")]
+    events = [(r.hlo_op or r.name, r.start_ns * 1e-9, r.dur_ns * 1e-9)
+              for r in rows or report.op_rows]   # the CPU has no such line
+    lo = min(a for _, a, _ in events)
+    hi = max(a + d for _, a, d in events)
+    spans = {}
+    for name, a, b in red.leaves(events, lo, hi):
+        path = table.get(instruction_name(name), "unscoped")
+        path = re.sub(r"layer\d+", "layerN", path.split("/", 1)[-1])
+        spans.setdefault("/".join(path.split("/")[:depth]), []).append((a, b))
+    ms = lambda s: round(1e3 * s / per, 3)  # noqa: E731
+    scope_ms = {k: ms(red.measure(v)) for k, v in spans.items()}
+    return {"busy_ms": ms(red.measure([(a, b) for _, a, b
+                                       in red.clip(events, lo, hi)])),
+            "scope_ms": dict(sorted(scope_ms.items(), key=lambda kv: -kv[1])),
+            "op_ms": {k: ms(v) for k, v in
+                      red.top(red.per_op_seconds(events, lo, hi))}}
+
+
 def scopes_and_pool_ops(engine, report, lowered, per, depth=3):
     """(ms by scope, {opcode: ms} of the traced operations whose result has
     the shape of one of the engine's page pools, {kernel: ms} of the paged
@@ -107,6 +140,7 @@ def scopes_and_pool_ops(engine, report, lowered, per, depth=3):
 def main(argv):
     from benchmark import traffic
     from mxnet_tpu.observability import profiling
+    from mxnet_tpu.observability.scopes import op_scopes_from_hlo, scoped_text
 
     ap = argparse.ArgumentParser(prog="servescope")
     ap.add_argument("--workload", default="deepseek_v2_serve_reason")
@@ -115,6 +149,9 @@ def main(argv):
     ap.add_argument("--prefills", type=int, default=4)
     ap.add_argument("--ahead", type=int, default=300)
     ap.add_argument("--depth", type=int, default=3)
+    ap.add_argument("--prefill-lens", default="",
+                    help="prompt lengths whose prefill programs are traced "
+                         "besides, each by scope as the union of intervals")
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args(argv)
     engine, mix, config = build(args.workload, args.tiny, args.seed)
@@ -141,6 +178,20 @@ def main(argv):
      out["paged_kernel_ms"]["prefill"]) = scopes_and_pool_ops(
         engine, cap.report, engine.lower_prefill(median), args.prefills,
         args.depth)
+    out["prefill_programs"] = {}
+    for n in [int(x) for x in args.prefill_lens.split(",") if x]:
+        long_prompt = rng.integers(1, config["n_vocab"], n).tolist()
+        cap = profiling.capture(lambda: engine.prefill(long_prompt, 0),
+                                steps=2, warmup=1)
+        table = op_scopes_from_hlo(
+            scoped_text(engine.lower_prefill(n)),
+            scopes=(engine.net._scope_label(None),))
+        found = program_by_scope(cap.report, table, 2, args.depth)
+        out["prefill_programs"][str(engine.bucket_for(n))] = found
+        print(f"[servescope] prefill of {n} tokens: busy "
+              f"{found['busy_ms']:.1f} ms; by operation {found['op_ms']}")
+        for path, ms in found["scope_ms"].items():
+            print(f"[servescope]   {ms:9.3f}  {path}")
     for key in ("decode_ms_by_scope", "prefill_ms_by_scope"):
         print(f"[servescope] {key} (sum {sum(out[key].values()):.3f} ms):")
         for path, ms in out[key].items():
