@@ -560,6 +560,63 @@ def check_index_scores(rows=8, j=64, d=128, ps=16, n_pages=128,
             "tpu_custom_calls": calls, "rel_err": round(err, 6)}
 
 
+def check_gqa(rows=8, h=28, hkv=4, ch=128, ps=16, window=4096,
+              interpret=None):
+    """SmallThinker's geometry, which the kernel's own gate admits: 28 query
+    heads over 4 key-value heads of 128, bfloat16 pools, one query a row,
+    rows short and past the window, through a full layer's table in order
+    and a window layer's ring; the pages no row holds are NaN and count for
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rs = np.random.RandomState(SEED)
+    position = np.concatenate([rs.randint(0, 600, rows // 2),
+                               rs.randint(window, 2 * window, rows - rows // 2)])
+    out = {}
+    for kind, win in (("full_layer", None), ("window_layer", window)):
+        cols = (2 * window) // ps if win is None else win // ps + 3
+        table, pages = np.zeros((rows, cols), np.int32), 0
+        for r, p in enumerate(position):
+            first = 0 if win is None else max(0, p - win + 1) // ps
+            for s in range(first, p // ps + 1):
+                pages += 1
+                table[r, s % cols if win else s] = pages
+        k_pool = rs.randn(2 * pages + 1, ps, hkv * ch).astype("f4")
+        v_pool = rs.randn(2 * pages + 1, ps, hkv * ch).astype("f4")
+        k_pool[pages + 1:] = v_pool[pages + 1:] = np.nan   # held by no row
+        a = (jnp.asarray(rs.randn(rows, h, 1, ch), jnp.bfloat16),
+             jnp.asarray(k_pool, jnp.bfloat16), jnp.asarray(v_pool, jnp.bfloat16),
+             jnp.asarray(table), jnp.asarray(position, jnp.int32))
+        why = ppa.paged_gqa_refusal(a[0], a[1], a[3], win)
+        if why is not None:
+            raise AssertionError(f"gqa {kind}: the kernel's gate refuses: {why}")
+
+        def kernel(*a):
+            return ppa.paged_gqa_read(*a, win, interpret=interpret)
+
+        calls = _custom_calls(kernel, *a)
+        if calls < 1:
+            raise AssertionError(f"gqa {kind}: lowered without its Mosaic kernel")
+        got = jax.jit(kernel)(*a)
+        want = att._paged_gqa_gather_read(*a, win)
+        if not bool(jnp.isfinite(got).all()):
+            raise AssertionError(f"gqa {kind}: what a row does not hold "
+                                 "reached its output")
+        err = _rel_err(got, want)
+        if not err < 2e-2:
+            raise AssertionError(f"gqa {kind}: relative error {err} against "
+                                 "the XLA gather path")
+        out[kind] = {"columns": cols, "tpu_custom_calls": calls,
+                     "rel_err": round(err, 6)}
+    return {"rows": rows, "heads": [h, hkv], "head": ch, "page": ps,
+            "window": window, "pool": "bfloat16", **out}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -625,6 +682,7 @@ def phase_kernels():
                                check_flash(causal=True)],
            "paged_attention": check_paged(),
            "paged_index_scores": check_index_scores(),
+           "paged_gqa_decode": check_gqa(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

@@ -204,26 +204,26 @@ class LatentSublayer(HybridBlock):
         return out if cache is None else (out, tuple(pools), counts)
 
 
-def _in_token_blocks(ffn, x, expert):
-    """``ffn(x)`` with ``x`` (B, T, d) walked in blocks of ``_FFN_TOKENS``
-    tokens where it is longer: (output, pairs, largest load) of an expert
-    layer, the counts added up and the loads' largest; a dense layer's
-    output alone."""
-    b, t, d = x.shape
+def _in_token_blocks(ffn, *xs):
+    """``ffn(*xs)`` with the tokens of every ``x`` (B, T, d) walked in blocks
+    of ``_FFN_TOKENS`` where there are more: a dense layer's output alone; of
+    an expert layer's (output, pairs, largest load, ...) the pairs added up
+    and every later count's largest."""
+    t = xs[0].shape[1]
     if t <= _FFN_TOKENS or t % _FFN_TOKENS:
-        return ffn(x)
+        return ffn(*xs)
 
     # one copy of the sublayer a block, in order (unrolled: inside a
     # ``lax.map`` body XLA:TPU's scatter emitter aborts on the expert
     # layer's scatter-add); each block's rows go where the last one's were
-    outs = [ffn(NDArray(x._data[:, at:at + _FFN_TOKENS]))
+    outs = [ffn(*(NDArray(x._data[:, at:at + _FFN_TOKENS]) for x in xs))
             for at in range(0, t, _FFN_TOKENS)]
-    if not expert:
-        return NDArray(jnp.concatenate([o._data for o in outs], axis=1))
-    ys, pairs, loads = zip(*outs)
-    return (NDArray(jnp.concatenate([y._data for y in ys], axis=1)),
-            NDArray(sum(p._data for p in pairs)),
-            NDArray(jnp.stack([m._data for m in loads]).max()))
+    join = lambda ys: NDArray(jnp.concatenate([y._data for y in ys], axis=1))  # noqa: E731
+    if isinstance(outs[0], NDArray):
+        return join(outs)
+    ys, pairs, *counts = zip(*outs)
+    return (join(ys), NDArray(sum(p._data for p in pairs)),
+            *(NDArray(jnp.stack([m._data for m in ms]).max()) for ms in counts))
 
 
 class Dots3NoteBlock(HybridBlock):
@@ -258,8 +258,7 @@ class Dots3NoteBlock(HybridBlock):
                 self.attn_norm(x), cache=cache, start_pos=start_pos,
                 page_table=page_table, last_pos=last_pos)
             x = x + att
-        y, loads = _in_token_blocks(self.ffn, self.ffn_norm(x),
-                                    not self._dense), None
+        y, loads = _in_token_blocks(self.ffn, self.ffn_norm(x)), None
         if not self._dense:
             y, *loads = y
         x = x + y

@@ -1066,6 +1066,195 @@ def windowed_latent_attention(c_q, w_qb, c_kv, k_rope, w_kvb, heads=1,
 
 
 # --------------------------------------------------------------------------
+# grouped key-value heads over paged K/V pools, under a window or none
+# --------------------------------------------------------------------------
+def _ring_pages(table, pos, ps):
+    """Page ids of positions ``pos`` (B, T) by a RING table: logical page
+    ``s`` lives in column ``s % columns``."""
+    return jnp.take_along_axis(table, (pos // ps) % table.shape[1], axis=1)
+
+
+def _gqa_products_dtype(q, pool_dtype):
+    return (pool_dtype if pool_dtype == jnp.bfloat16
+            else jnp.promote_types(q.dtype, pool_dtype))
+
+
+def _paged_gqa_gather_read(q, k_pool, v_pool, page_table, position,
+                           window=None):
+    """The XLA read path of grouped heads: every row's columns gathered by
+    its page table, the table's whole width, and attended under the frontier
+    mask and, with ``window``, the window's by POSITION (the table is then a
+    ring: column ``c`` holds the logical page nearest below the frontier
+    with ``s % columns == c``). What a freed, stale or never-written column
+    names counts for nothing, whatever it is. ``q`` (B, H, Tq, Ch); returns
+    (B, H, Tq, Ch) float32: :func:`_frontier_masked_attention`'s precision."""
+    b, h, tq, ch = q.shape
+    ps, cols = k_pool.shape[1], page_table.shape[1]
+    hkv = k_pool.shape[2] // ch
+    cap = cols * ps
+    mm = _gqa_products_dtype(q, k_pool.dtype)
+    if window is None:
+        kpos = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (b, cap))
+    else:
+        page = (position + tq - 1) // ps
+        col = jnp.arange(cols, dtype=jnp.int32)[None, :]
+        logical = page[:, None] - (page[:, None] - col) % cols    # (B, cols)
+        kpos = (logical[:, :, None] * ps
+                + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(b, cap)
+    q_pos = position[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    seen = (kpos[:, None, :] <= q_pos[:, :, None]) & (kpos[:, None, :] >= 0)
+    if window is not None:
+        seen &= kpos[:, None, :] > q_pos[:, :, None] - int(window)
+    any_seen = seen.any(axis=1)                                   # (B, cap)
+
+    def history(pool):   # (B, cols, ps, Hkv*Ch) -> (B, cap, Hkv, Ch)
+        hist = pool[page_table].reshape(b, cap, hkv, ch).astype(mm)
+        return jnp.where(any_seen[:, :, None, None], hist, 0)
+
+    scale = 1.0 / jnp.sqrt(jnp.asarray(ch, jnp.float32))
+    q5 = q.astype(mm).reshape(b, hkv, h // hkv, tq, ch)
+    scores = jnp.einsum("bkgqc,bskc->bkgqs", q5, history(k_pool), **_F32) * scale
+    scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
+    att = jax.nn.softmax(scores, axis=-1).astype(mm)
+    out = jnp.einsum("bkgqs,bskc->bkgqc", att, history(v_pool), **_F32)
+    return out.reshape(b, h, tq, ch)
+
+
+def _gqa_chunk_attention(q, k, v, window=None, query_block=512):
+    """Causal attention of one whole chunk from position 0 with grouped
+    heads, ``q`` (B, H, T, Ch) over ``k``/``v`` (B, Hkv, T, Ch), under a
+    window or none: queries in blocks, each against the ``span`` keys that
+    end with its last (a window's worth and a block; every key where there
+    is no window), so no score tensor is T x T. Operands as they come,
+    float32 sums and softmax; returns float32."""
+    b, h, t, ch = q.shape
+    hkv = k.shape[1]
+    qb = _block_of(t, query_block)
+    span = t if window is None else min(t, qb + -(-int(window) // qb) * qb)
+    q5 = q.reshape(b, hkv, h // hkv, t, ch)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(ch, jnp.float32))
+
+    def queries_of(first_q):
+        first_k = jnp.clip(first_q + qb - span, 0, t - span)
+        cut = lambda z, at, n, ax: jax.lax.dynamic_slice_in_dim(z, at, n, ax)  # noqa: E731
+        scores = jnp.einsum("bkgqc,bksc->bkgqs", cut(q5, first_q, qb, 3),
+                            cut(k, first_k, span, 2), **_F32) * scale
+        tq = (first_q + jnp.arange(qb, dtype=jnp.int32))[:, None]
+        ks = (first_k + jnp.arange(span, dtype=jnp.int32))[None, :]
+        seen = ks <= tq
+        if window is not None:
+            seen &= ks > tq - int(window)
+        att = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bkgqs,bksc->bkgqc", att.astype(v.dtype),
+                          cut(v, first_k, span, 2), **_F32)
+
+    out = jax.lax.map(queries_of, jnp.arange(0, t, qb, dtype=jnp.int32))
+    return jnp.moveaxis(out, 0, 3).reshape(b, h, t, ch)   # (B,Hkv,G,T/qb,qb,Ch)
+
+
+def _gqa_chunk_kernel(q, k, v, window=None, interpret=None, block=None):
+    """:func:`_gqa_chunk_attention` through the flash forward kernel
+    (``gqa_prefill`` in a trace): key blocks under a running maximum, the
+    scores in VMEM only, blocks above the diagonal skipped; a chunk longer
+    than the window hands it the band as one mask for every head. A
+    key-value head is repeated for its query heads (the kernel pairs heads
+    one to one). One row (B == 1)."""
+    from . import flash_attention as fa
+
+    h, t = q.shape[1:3]
+    rep = h // k.shape[1]
+    band = None
+    if window is not None and t > int(window):
+        at = jnp.arange(t, dtype=jnp.int32)
+        band = ((at[None, :] <= at[:, None])
+                & (at[None, :] > at[:, None] - int(window))).astype(jnp.int8)
+    block = block or fa._pick_block(t, _KERNEL_BLOCK)
+    return fa._flash_fwd(
+        q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1), True,
+        block_q=block, block_k=block, interpret=fa._resolve_interpret(interpret),
+        mask=band, group=math.gcd(h, _KERNEL_HEADS), out_dtype=jnp.float32,
+        name="gqa_prefill")
+
+
+def _paged_gqa_mha(q, k_new, v_new, k_pool, v_pool, page_table, position,
+                   window=None, last_pos=None):
+    """:func:`_paged_cached_mha` for grouped key-value heads and, with
+    ``window``, a causal window over a RING table (an engine's ``window``
+    pool group: a row keeps the pages its window reaches and no other).
+
+    q: (B, H, Tq, Ch); k_new/v_new: (B, Hkv, Tq, Ch), ``H`` a multiple of
+    ``Hkv`` (query head ``i`` reads key-value head ``i // (H // Hkv)``);
+    pools (P+1, ps, Hkv*Ch); page_table (B, columns): in order from position
+    0 without a window, the ring with one (``columns >= window // ps + 3``).
+
+    One token a row (decode) writes its key and value at ``[page, offset]``
+    and reads by one of two paths, chosen from what the operands and the
+    process show (:func:`~mxnet_tpu.ops.pallas_paged_attention.paged_gqa_refusal`):
+    the Pallas kernel ``paged_gqa_decode`` walks the pages the row holds AND
+    reads in blocks under a running maximum; the XLA path gathers the
+    table's whole width (:func:`_paged_gqa_gather_read`, the CPU's path and
+    the kernel's oracle). A chunk of more than one token opens its rows at
+    position 0 (a prefill without an adopted prefix), attends itself (the
+    flash forward kernel as ``gqa_prefill`` where
+    :func:`~mxnet_tpu.ops.flash_attention.masked_prefill_refusal` lets it,
+    else XLA's query blocks) and, through a ring, writes only the pages the
+    row keeps once ``last_pos`` ((1,) int32, its last real position: the
+    chunk may be padded) is the frontier. ``paged_read_path_total{path,
+    reason}`` says at trace time what was built: ``gqa_kernel`` or
+    ``xla_gather`` for a token, ``gqa_chunk_kernel`` or ``gqa_chunk_xla``
+    for a chunk."""
+    from .. import observability as obs
+    from . import flash_attention as fa
+    from . import pallas_paged_attention as ppa
+
+    b, _, tq, ch = q.shape
+    ps, cols = k_pool.shape[1], page_table.shape[1]
+    count = obs.counter("paged_read_path_total")
+    mm = _gqa_products_dtype(q, k_pool.dtype)
+    # a token's heads side by side, as a pool holds them
+    k_rows, v_rows = _merge_heads(k_new), _merge_heads(v_new)
+    pos = position[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    if window is None:
+        pid = _linear_pages(page_table, pos, ps)
+    elif tq == 1:
+        pid = _ring_pages(page_table, pos, ps)
+    else:
+        if last_pos is None:
+            raise ValueError("a chunk written through a ring table needs "
+                             "last_pos=: the row's last real position")
+        # the pages the row keeps once last_pos is its frontier, and of the
+        # chunk only the stretch that can reach them
+        last = jnp.asarray(_unwrap(last_pos), jnp.int32).reshape(-1)[0]
+        top_page = last // ps
+        low_page = jnp.maximum(last - int(window) + 2, 0) // ps
+        n = min(tq, cols * ps)
+        first = jnp.clip((top_page + 1) * ps - n, 0, tq - n)
+        pos, k_rows, v_rows = (jax.lax.dynamic_slice_in_dim(z, first, n, 1)
+                               for z in (pos, k_rows, v_rows))
+        page = pos // ps
+        pid = jnp.where((page >= low_page) & (page <= top_page),
+                        _ring_pages(page_table, pos, ps), 0)
+    k_pool = _rows_write(k_pool, k_rows, pid, pos % ps)
+    v_pool = _rows_write(v_pool, v_rows, pid, pos % ps)
+    if tq == 1:
+        why = ppa.paged_gqa_refusal(q, k_pool, page_table, window)
+        count.inc(path="xla_gather" if why else "gqa_kernel", reason=why or "")
+        read = _paged_gqa_gather_read if why else ppa.paged_gqa_read
+        return (read(q, k_pool, v_pool, page_table, position, window), k_pool,
+                v_pool)
+    shape = lambda x: jax.ShapeDtypeStruct((b, tq, x.shape[1], ch), mm)  # noqa: E731
+    why = fa.masked_prefill_refusal(
+        shape(q), shape(k_new), shape(v_new),
+        jax.ShapeDtypeStruct((b, tq, tq), jnp.bool_))
+    count.inc(path="gqa_chunk_xla" if why else "gqa_chunk_kernel",
+              reason=why or "")
+    core = _gqa_chunk_attention if why else _gqa_chunk_kernel
+    # the chunk as the pool now holds it (rounded to the pool's dtype)
+    held = lambda x: x.astype(k_pool.dtype).astype(mm)  # noqa: E731
+    return core(q.astype(mm), held(k_new), held(v_new), window), k_pool, v_pool
+
+
+# --------------------------------------------------------------------------
 # blessed fused attention entry point
 # --------------------------------------------------------------------------
 def _reference_mha(q, k, v, mask=None, causal=False):
@@ -1084,7 +1273,8 @@ def _reference_mha(q, k, v, mask=None, causal=False):
 
 @register("multi_head_attention", aliases=("_contrib_multi_head_attention",))
 def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
-                         cache=None, position=None, page_table=None):
+                         cache=None, position=None, page_table=None,
+                         window=None, last_pos=None):
     """Fused scaled-dot-product attention over (B, H, T, Ch) tensors.
 
     ``use_flash='auto'`` picks the Pallas flash kernel on TPU backends when
@@ -1109,12 +1299,30 @@ def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
     buffers — the paged variant (docs/INFERENCE.md "Paged cache"): same
     frontier mask, same return convention, storage indirected through the
     per-row page table.
+
+    **Grouped key-value heads** (``k``/``v`` (B, Hkv, T, Ch) with ``H`` a
+    multiple of ``Hkv``: query head ``i`` reads head ``i // (H // Hkv)``)
+    and a causal ``window`` (query ``t`` attends ``t - window < s <= t``: the
+    window counts the token itself) are causal attention, cached through
+    page pools or not at all: :func:`_paged_gqa_mha` (pools ``(P+1, page,
+    Hkv*Ch)``; with ``window`` the table is an engine's ring and a chunk of
+    more than one token needs ``last_pos=``, its last real position), or
+    without a cache one whole chunk by :func:`_gqa_chunk_attention`. Equal
+    head counts without a window take the paths above, unchanged.
     """
     from . import flash_attention as fa
     from ..contrib.amp import cast_inputs
 
     orig_dtype = q.dtype
     q, k, v = cast_inputs(q, k, v)  # AMP: score/context matmuls on the MXU
+    grouped = k.shape[1] != q.shape[1] or window is not None
+    if grouped and (cache is None or page_table is None):
+        if cache is not None or mask is not None:
+            raise ValueError("grouped key-value heads and a window are causal "
+                             "attention over page pools (cache= with "
+                             "page_table=) or over one whole chunk: no dense "
+                             "cache, no mask")
+        return _gqa_chunk_attention(q, k, v, window).astype(orig_dtype)
     if cache is not None:
         if position is None:
             raise ValueError("multi_head_attention(cache=...) needs position=")
@@ -1124,8 +1332,10 @@ def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
             position = jnp.broadcast_to(position, (q.shape[0],))
         if page_table is not None:
             table = jnp.asarray(_unwrap(page_table), jnp.int32)
-            out, k_buf, v_buf = _paged_cached_mha(q, k, v, k_buf, v_buf,
-                                                  table, position)
+            paged = functools.partial(_paged_gqa_mha, window=window,
+                                      last_pos=last_pos) \
+                if grouped else _paged_cached_mha
+            out, k_buf, v_buf = paged(q, k, v, k_buf, v_buf, table, position)
         else:
             out, k_buf, v_buf = _cached_mha(q, k, v, k_buf, v_buf, position)
         return out.astype(orig_dtype), k_buf, v_buf

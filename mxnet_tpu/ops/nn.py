@@ -452,24 +452,45 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
     return out.astype(data.dtype)
 
 
+def _held_expert_ffn(data, weights, router_data, count_hit, **how):
+    from ..parallel.moe import held_expert_ffn as ffn
+
+    router_h = None if router_data is None else \
+        router_data.reshape(-1, router_data.shape[-1])
+    out, stats = ffn(data.reshape(-1, data.shape[-1]), *weights,
+                     router_h=router_h, count_hit=count_hit, **how)
+    return (out.reshape(data.shape), *stats)
+
+
 @register("held_expert_ffn", nout=3)
 def held_expert_ffn(data, router_weight, gate_weight, up_weight, down_weight,
                     held_experts=(), n_group=1, topk_group=1, top_k=1,
                     scale=1.0, norm_topk_prob=False, scoring="softmax",
-                    router_bias=None):
+                    router_bias=None, router_data=None, activation="silu"):
     """The held experts' part of a top-k expert layer over ``data`` (..., d)
-    (group-limited softmax, or sigmoid scores with a selection bias):
+    (group-limited softmax, or sigmoid scores with a selection bias; the
+    router reads ``router_data`` where given, else ``data``; the gate's
+    ``activation`` ``silu`` or ``relu``):
     :func:`mxnet_tpu.parallel.moe.held_expert_ffn`.
     Returns (the part, pairs routed to held experts, largest load of one)."""
-    from ..parallel.moe import held_expert_ffn as ffn
-
-    out, (pairs, load) = ffn(
-        data.reshape(-1, data.shape[-1]), router_weight, gate_weight,
-        up_weight, down_weight, held_experts=held_experts, n_group=n_group,
+    return _held_expert_ffn(
+        data, (router_weight, gate_weight, up_weight, down_weight),
+        router_data, False, held_experts=held_experts, n_group=n_group,
         topk_group=topk_group, top_k=top_k, scale=scale,
         norm_topk_prob=norm_topk_prob, scoring=scoring,
-        router_bias=router_bias)
-    return out.reshape(data.shape), pairs, load
+        router_bias=router_bias, activation=activation)
+
+
+@register("held_expert_ffn_hit", nout=4)
+def held_expert_ffn_hit(data, router_weight, gate_weight, up_weight,
+                        down_weight, **how):
+    """:func:`held_expert_ffn` with a fourth output: the held experts that
+    drew a pair at all (how near the layer stands to every expert's weights
+    being read)."""
+    router_data = how.pop("router_data", None)
+    return _held_expert_ffn(
+        data, (router_weight, gate_weight, up_weight, down_weight),
+        router_data, True, **how)
 
 
 # --------------------------------------------------------------------------

@@ -608,3 +608,235 @@ def paged_index_scores(idx_q, idx_w, pool, page_table, position,
                        idx_q.astype(pool.dtype),
                        idx_w.astype(jnp.float32).reshape(b, -1, 1), pool,
                        _resolve_interpret(interpret))
+
+
+# --------------------------------------------------------------------------
+# grouped key-value heads, a window or none (one token a row)
+# --------------------------------------------------------------------------
+_GQA_BLOCK = 512   # positions fetched and attended at once: a block of pages
+_NEG = -1e30       # a masked score; finite, so that exp(_NEG - m) is 0 and
+# never NaN (every row's first block holds a position it sees)
+
+
+def paged_gqa_refusal(q, k_pool, page_table, window=None):
+    """Why the grouped-heads kernel does NOT read the pools for these
+    operands (anything with ``.shape``/``.dtype``), or None when it does:
+    ``q`` ``(B, H, Tq, Ch)``, ``k_pool`` ``(P+1, page_size, Hkv*Ch)`` with
+    ``H`` a multiple of ``Hkv``, ``page_table`` ``(B, columns)``, ``window``
+    the layer's causal window or None. The first condition that fails is
+    the one named; callers take the XLA gather then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    _, h, tq, ch = q.shape
+    ps, hc = k_pool.shape[1], k_pool.shape[2]
+    if tq != 1:
+        return f"{tq} queries a row: the kernel reads for one"
+    if k_pool.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"pool dtype {jnp.dtype(k_pool.dtype).name} is not float32 or bfloat16"
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"query dtype {jnp.dtype(q.dtype).name} is not float32 or bfloat16"
+    if ch % _LANES or hc % ch or h % (hc // ch):
+        return (f"{h} query heads of {ch} over the pool's {hc} columns are "
+                f"not whole groups of whole {_LANES}-lane heads")
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    sub = 8 * (4 // itemsize)
+    if ps % sub or _GQA_BLOCK % ps:
+        return (f"page size {ps} is not a multiple of {sub} sublanes that "
+                f"divides a block of {_GQA_BLOCK} positions")
+    if window is not None and window > (page_table.shape[1] - 2) * ps:
+        return (f"a window of {window} positions does not fit a ring of "
+                f"{page_table.shape[1]} pages of {ps}")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _gqa_pages(position, lower, ps, columns, ring):
+    """(first, last) logical page a row reads: from its lower bound's page
+    to its frontier's; a row past a table in order reads the table's width
+    (a released or overflowing row reads what its table names, like the XLA
+    path). Scalars in the kernel, vectors beside it: one formula."""
+    last = position // ps
+    if not ring:
+        last = jnp.minimum(last, columns - 1)
+    return jnp.clip(lower // ps, 0, last), last
+
+
+def _gqa_kernel(table_ref, pos_ref, low_ref, slot_ref, q_ref, kp_ref, vp_ref,
+                o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, ps, columns,
+                nb, ring, scale):
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    hkv, r, ch = q_ref.shape[1:]
+    blk = nb * ps
+
+    def pages(row):
+        return _gqa_pages(pos_ref[row], low_ref[row], ps, columns, ring)
+
+    def for_each_copy(row, j, into, act):
+        """``act`` on the copies of block ``j`` of ``row``: its pages, by
+        the ids the table's columns hold; returns how many there are."""
+        first, last = pages(row)
+        start = first + j * nb
+        n = jnp.clip(last - start + 1, 0, nb)
+
+        def page(p, carry):
+            s = start + p
+            pid = table_ref[row * columns + (s % columns if ring else s)]
+            at = pl.ds(pl.multiple_of(p * ps, ps), ps)
+            for pool, buf in ((kp_ref, kbuf), (vp_ref, vbuf)):
+                act(pltpu.make_async_copy(pool.at[pid], buf.at[into, at, :],
+                                          sem.at[into]))
+            return carry
+
+        lax.fori_loop(0, n, page, 0)
+        return start, n
+
+    first, last = pages(b)
+    n_blocks = (last - first) // nb + 1
+    slot0 = slot_ref[b]
+
+    @pl.when(b == 0)
+    def _():
+        for_each_copy(0, 0, 0, lambda copy: copy.start())
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def block(j, carry):
+        slot = (slot0 + j) % 2
+
+        # the next block's copies, this row's or the next row's first, are
+        # in flight while this block's products run
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for_each_copy(b, j + 1, 1 - slot, lambda copy: copy.start())
+
+        @pl.when((j + 1 == n_blocks) & (b + 1 < rows))
+        def _():
+            for_each_copy(b + 1, 0, 1 - slot, lambda copy: copy.start())
+
+        start, n = for_each_copy(b, j, slot, lambda copy: copy.wait())
+
+        def clear(p, c):
+            # what was not fetched counts for nothing: a weight of 0 does
+            # not clear a NaN that VMEM holds there
+            at = pl.ds(pl.multiple_of(p * ps, ps), ps)
+            vbuf[slot, at, :] = jnp.zeros((ps, vbuf.shape[2]), vbuf.dtype)
+            return c
+
+        lax.fori_loop(n, nb, clear, 0)
+
+        @pl.when(j + 1 == n_blocks)
+        def _():
+            # nor does what the frontier's page holds past the frontier
+            # (a page's last owner's values, or none)
+            at = pl.ds(pl.multiple_of((n - 1) * ps, ps), ps)
+            past = ((start + n - 1) * ps + lax.broadcasted_iota(
+                jnp.int32, (ps, vbuf.shape[2]), 0)) > pos_ref[b]
+            vbuf[slot, at, :] = jnp.where(past, jnp.zeros((), vbuf.dtype),
+                                          vbuf[slot, at, :])
+
+        at = start * ps + lax.broadcasted_iota(jnp.int32, (r, blk), 1)
+        visible = (at >= low_ref[b]) & (at <= pos_ref[b])
+        for g in range(hkv):   # a key-value head's query heads are the rows
+            lanes = pl.ds(g * ch, ch)
+            s = lax.dot_general(q_ref[0, g], kbuf[slot, :, lanes], _NT,
+                                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(visible, s, _NEG)
+            m_prev = m_ref[g, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+            keep = jnp.exp(m_prev - m_new)
+            l_ref[g] = jnp.broadcast_to(
+                keep * l_ref[g, :, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape[1:])
+            acc_ref[g] = acc_ref[g] * keep + lax.dot_general(
+                p.astype(vbuf.dtype), vbuf[slot, :, lanes], _NN,
+                preferred_element_type=jnp.float32)
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        return carry
+
+    lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[0] = acc_ref[...] / l_ref[:, :, :1]
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, interpret):
+    b, hkv, r, ch = q2.shape
+    ps, hc = k_pool.shape[1:]
+    columns = table.shape[0] // b
+    first, last = _gqa_pages(position, lower, ps, columns, ring)
+    n_blocks = (last - first) // nb + 1
+    # the buffer slot each row's first block lands in: rows take turns
+    slot0 = (jnp.cumsum(n_blocks) - n_blocks) % 2
+    row = lambda i, *_: (i, 0, 0, 0)  # noqa: E731
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    need = (4 * nb * ps * hc * itemsize + 2 * hkv * r * ch * (itemsize + 4)
+            + hkv * r * (2 * _LANES + ch) * 4 + _SCORE_TEMPS * r * nb * ps * 4)
+    return pl.pallas_call(
+        functools.partial(
+            _gqa_kernel, ps=ps, columns=columns, nb=nb, ring=ring,
+            scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, ch), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, hkv, r, ch), row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, hkv, r, ch), row),
+            scratch_shapes=[pltpu.VMEM((2, nb * ps, hc), k_pool.dtype),
+                            pltpu.VMEM((2, nb * ps, hc), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((hkv, r, _LANES), jnp.float32),
+                            pltpu.VMEM((hkv, r, _LANES), jnp.float32),
+                            pltpu.VMEM((hkv, r, ch), jnp.float32)]),
+        name="paged_gqa_decode",
+        interpret=interpret,
+        # rows run in order: each starts the next one's copies
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + 16 * 1024 * 1024),
+    )(table, position, lower, slot0.astype(jnp.int32), q2, k_pool, v_pool)
+
+
+def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
+                   block_pages=None, interpret=None):
+    """Attention of one query a row, ``q`` ``(B, H, 1, Ch)``, over the row's
+    paged history: ``H`` query heads over the pools' ``Hkv`` key-value heads
+    (``(P+1, page_size, Hkv*Ch)``; query head ``i`` reads head ``i // (H //
+    Hkv)``), positions ``s <= position[b]`` and, with ``window``, ``s >
+    position[b] - window``. Without a window ``page_table`` ``(B, columns)``
+    holds the row's pages in order from position 0; with one it is a RING:
+    logical page ``s`` lives in column ``s % columns``.
+
+    The kernel fetches the pages a row holds AND reads, a block of
+    ``block_pages`` pages at a time (``_GQA_BLOCK`` positions; copies by page
+    id from the scalar-prefetched table, the next block's in flight while
+    this one's products run, across rows too), under a running maximum and
+    sum in float32: nothing in VMEM has the history's size, so any length
+    is served alike. A key-value head's ``H // Hkv`` query heads are the rows
+    of one left operand. Operands in the pools' dtype, float32 scores and
+    softmax. Returns ``(B, H, 1, Ch)`` float32. Callers gate via
+    :func:`paged_gqa_refusal`."""
+    b, h, tq, ch = q.shape
+    ps, hc = k_pool.shape[1:]
+    hkv = hc // ch
+    g = h // hkv
+    nb = int(block_pages or max(1, _GQA_BLOCK // ps))
+    r = _rows(g, tq, jnp.dtype(k_pool.dtype).itemsize)
+    q2 = q.astype(k_pool.dtype).reshape(b, hkv, g * tq, ch)
+    q2 = jnp.pad(q2, ((0, 0), (0, 0), (0, r - g * tq), (0, 0)))
+    position = jnp.asarray(position, jnp.int32)
+    lower = jnp.zeros_like(position) if window is None else \
+        jnp.maximum(position - (int(window) - 1), 0)
+    o2 = _gqa_call(jnp.asarray(page_table, jnp.int32).reshape(-1), position,
+                   lower, q2, k_pool, v_pool, window is not None, nb,
+                   _resolve_interpret(interpret))
+    return o2[:, :, :g * tq].reshape(b, h, tq, ch)

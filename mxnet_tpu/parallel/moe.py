@@ -137,11 +137,16 @@ def group_limited_topk(probs, n_group: int, topk_group: int, top_k: int):
 def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
                     n_group: int = 1, topk_group: int = 1, top_k: int,
                     scale: float = 1.0, norm_topk_prob: bool = False,
-                    scoring: str = "softmax", router_bias=None):
+                    scoring: str = "softmax", router_bias=None,
+                    router_h=None, activation: str = "silu",
+                    count_hit: bool = False):
     """What the experts held here add to an expert layer's output.
 
     ``h`` [n, d] are the (normed) tokens; ``router_w`` [E, d] routes over
-    ALL ``E`` experts in float32. ``scoring`` ``softmax`` chooses by
+    ALL ``E`` experts in float32, reading ``router_h`` [n, d] where that is
+    given (a router placed elsewhere in the block than its experts: before
+    attention, say) and ``h`` where not. ``activation`` is the gate's:
+    ``silu`` (SwiGLU) or ``relu`` (ReGLU). ``scoring`` ``softmax`` chooses by
     :func:`group_limited_topk` over the probabilities; ``sigmoid`` scores
     each expert alone and chooses the ``top_k`` largest of score +
     ``router_bias`` [E] (a learned selection bias, ``noaux_tc``; no groups),
@@ -156,7 +161,8 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
     nothing here: their chips add them.
 
     Returns ``(y [n, d], stats)``; ``stats`` are two int32 scalars, the
-    pairs routed to held experts and the largest load of one. The
+    pairs routed to held experts and the largest load of one, and with
+    ``count_hit`` a third: the held experts that drew a pair at all. The
     ``moe_path_total{path}`` counter says at trace time what was built.
     """
     from .. import observability as obs
@@ -165,8 +171,14 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
     held = tuple(int(e) for e in held_experts)
     n_held, n_experts = len(held), router_w.shape[0]
     obs.counter("moe_path_total").inc(path="sorted_ragged_dot")  # trace time
+    try:
+        act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
+    except KeyError:
+        raise ValueError(f"unknown activation {activation!r}: silu or relu") \
+            from None
     with jax.named_scope("router"):
-        logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
+        logits = jnp.einsum("nd,ed->ne",
+                            (h if router_h is None else router_h).astype(jnp.float32),
                             router_w.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         if scoring == "sigmoid":
@@ -197,10 +209,13 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
         x = h[token]                                           # [n * k, d]
         gate = lax.ragged_dot(x, w_gate, sizes, **f32)
         up = lax.ragged_dot(x, w_up, sizes, **f32)
-        y = lax.ragged_dot((jax.nn.silu(gate) * up).astype(h.dtype), w_down,
+        y = lax.ragged_dot((act(gate) * up).astype(h.dtype), w_down,
                            sizes, **f32)
         # rows past the held pairs belong to no group: a backend may leave
         # them unwritten (the TPU's does), so they are masked, not weighted 0
         out = jnp.zeros((n, d), jnp.float32).at[token].add(
             jnp.where(is_held[:, None], y * pair_w[:, None], 0.0))
-    return out.astype(h.dtype), (sizes.sum(), sizes.max())
+    stats = (sizes.sum(), sizes.max())
+    if count_hit:
+        stats += ((sizes > 0).sum().astype(jnp.int32),)
+    return out.astype(h.dtype), stats

@@ -99,6 +99,21 @@ def test_the_model_agrees_with_the_reference_on_a_whole_sequence(model):
                   - want).max() > 1e-2
 
 
+def test_a_long_chunks_feed_forward_in_token_blocks_is_the_whole_chunks(
+        model, monkeypatch):
+    """The dense and the expert layers walked in blocks of tokens (what a
+    prefill of more than 4,096 tokens does) give the whole chunk's logits."""
+    from mxnet_tpu.models import dots3_note
+
+    cfg, weights = model
+    tokens = mx.nd.array(np.random.default_rng(3).integers(1, 200, (1, 32)),
+                         dtype="int32")
+    whole = adaptor.build_net(cfg, weights)(tokens)._data
+    monkeypatch.setattr(dots3_note, "_FFN_TOKENS", 8)
+    blocks = adaptor.build_net(cfg, weights)(tokens)._data
+    assert float(jnp.abs(blocks - whole).max()) < 2e-5
+
+
 @pytest.mark.parametrize("poison", [False, True])
 def test_prefill_then_decode_through_both_pool_groups(model, poison):
     """Logits of the prefill and of every paged decode step against the

@@ -1,7 +1,9 @@
-"""GPT-2's and DeepSeek-V2's serving programs are the parent's: the SHA-256
-of their lowered text at toy widths (``tools/loweredsha.py``, a process of
-its own so that no other test's blocks move a name) against the values
-recorded from the tree before PR 32 (commit eea6ea6). A PR that does not
+"""GPT-2's, DeepSeek-V2's and dots3-note-prev's serving programs are the
+parent's: the SHA-256 of their lowered text at toy widths
+(``tools/loweredsha.py``, a process of its own so that no other test's
+blocks move a name) against the values recorded from the tree before PR 32
+(commit eea6ea6) and, for dots3-note-prev's decode step and one prefill
+program, from the tree before PR 35 (commit 28ca294). A PR that does not
 mean to touch those models' programs keeps them; one that does records anew
 (``JAX_PLATFORMS=cpu python tools/loweredsha.py``) and says so."""
 import json
@@ -21,6 +23,8 @@ RECORDED = {
     "deepseek_v2.prefill16": "8bb85cb5486e5fb526cbfe108a276796656747c720cfff7092c32df9bb461366",
     "deepseek_v2.prefill32": "312dbc5daaa8dc81db10a4862906aa632c17fd1e4579000008c6ae0dd7b9f86a",
     "deepseek_v2.prefill64": "53764d7519643636edc56b11c028c5c3ed80e3b8f9d149ae2995dae5c52deee9",
+    "dots3_note.decode": "adf8fc0ee4eb9d46e7bbd4d6fdc842dfecd02404eb5d7b262ae6fce55cfdfab6",
+    "dots3_note.prefill16": "5ad8ede32b118409f13d5179c7e3ef89744aa992c977fea0822f24d15a709a40",
 }
 
 

@@ -49,6 +49,18 @@ PAGED_CASES = [(64, 16, 64, 16, 64, 1, 128), (64, 16, 64, 16, 64, 1, 0),
 LATENT_CASES = [(128, 128, 512, 64, 16, 256, 1, 128),
                 (128, 128, 512, 64, 16, 256, 1, 1024),
                 (128, 128, 512, 64, 16, 256, 1, 0)]
+# grouped key-value heads over K/V pools (SmallThinker's decode, one layer):
+# (b, query heads, key-value heads, ch, page_size, columns, window, lo, hi):
+# b rows whose frontiers lie in lo .. hi; window 0 is a full layer (the table
+# in order from position 0), else a window layer's ring; the A/B is the kernel
+# ``paged_gqa_decode``, which walks the pages a row holds and reads in blocks,
+# against the XLA gather of the table's whole width
+GQA_CASES = [(48, 28, 4, 128, 16, 640, 0, 512, 1024),
+             (48, 28, 4, 128, 16, 640, 0, 4096, 10240),
+             (48, 28, 4, 128, 16, 640, 0, 0, 10240),
+             (48, 28, 4, 128, 16, 259, 4096, 512, 1024),
+             (48, 28, 4, 128, 16, 259, 4096, 4096, 10240),
+             (48, 28, 4, 128, 16, 259, 4096, 0, 10240)]
 # masked prefill (dots3-note-prev's full layer, one chunk from position 0
 # under the indexer's selection): (tokens, heads, nope, rope, vd, kl, ql,
 # index heads, index dim, top_k); the A/B is the flash forward kernel under
@@ -75,6 +87,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     PAGED_CASES = [(2, 2, 32, 8, 4, 1, 0), (2, 2, 64, 16, 8, 3, 40)]
     LATENT_CASES = [(2, 4, 32, 8, 16, 8, 1, 0), (2, 4, 32, 8, 16, 16, 2, 100)]
     MASKED_PREFILL_CASES = [(256, 2, 128, 64, 128, 64, 64, 2, 32, 64)]
+    GQA_CASES = [(3, 6, 2, 16, 4, 16, 0, 0, 60), (3, 6, 2, 16, 4, 4, 5, 0, 60)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -365,6 +378,67 @@ def run_latent_case(b, h, kl, rope, ps, n_pages, tq, held, reps):
     return case
 
 
+def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rng = np.random.RandomState(0)
+    window = window or None
+    dtype = jnp.float32 if _INTERP else jnp.bfloat16
+    position = rng.randint(lo, hi, (b,)).astype(np.int32)
+    # each row's pages: in order from 0, or the ring's columns of the pages
+    # its window reaches; every other entry names the trash page
+    table, pages = np.zeros((b, cols), np.int32), 0
+    for r, p in enumerate(position):
+        first = 0 if window is None else max(0, p - window + 1) // ps
+        for s in range(first, p // ps + 1):
+            pages += 1
+            table[r, s % cols if window else s] = pages
+    k_pool, v_pool = (jnp.asarray(rng.randn(pages + 1, ps, hkv * ch), dtype)
+                      for _ in range(2))
+    table, position = jnp.asarray(table), jnp.asarray(position)
+    q = jnp.asarray(rng.randn(b, h, 1, ch) * 0.3, dtype)
+    read = int(np.minimum(np.asarray(position) + 1,
+                          window or 1 << 30).sum())
+    case = {"kind": "paged_gqa", "b": b, "h": h, "hkv": hkv, "ch": ch,
+            "ps": ps, "columns": cols, "window": window or 0, "lo": lo,
+            "hi": hi, "positions_read": read}
+    if not _INTERP:
+        case["gate"] = ppa.paged_gqa_refusal(q, k_pool, table, window) \
+            or "kernel"
+
+    def gather_ref(q):
+        return att._paged_gqa_gather_read(q, k_pool, v_pool, table, position,
+                                          window)
+
+    def kernel(q):
+        return ppa.paged_gqa_read(q, k_pool, v_pool, table, position, window,
+                                  interpret=_INTERP)
+
+    ref, out = gather_ref(q), kernel(q)
+    err = float(jnp.max(jnp.abs(out - ref)))
+    case["max_err"] = round(err, 6)
+    # both return float32 sums of bfloat16 products; the weights are rounded
+    # to bfloat16 before the second product on both paths
+    case["correct"] = bool(err < 0.02 and jnp.isfinite(out).all())
+    del ref, out
+    for label, f in (("kernel", kernel), ("gather", gather_ref)):
+        try:
+            case[f"{label}_ms"] = round(_timeit(f, (q,), reps) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:120]
+    if "kernel_ms" in case and "gather_ms" in case:
+        case["kernel_vs_gather"] = round(case["gather_ms"] / case["kernel_ms"], 2)
+    gb = read * 2 * hkv * ch * jnp.dtype(dtype).itemsize / 1e9
+    for label in ("kernel", "gather"):   # bytes of the positions READ
+        if f"{label}_ms" in case:
+            case[f"{label}_gb_per_s"] = round(gb / (case[f"{label}_ms"] / 1e3), 1)
+    return case
+
+
 def run_masked_prefill_case(t, heads, nope, rope, vd, kl, ql, idx_heads,
                             idx_dim, top_k, reps):
     import jax
@@ -538,6 +612,8 @@ def run_one(argv):
                                    spec["tq"], spec["held"], spec["reps"])
         elif spec["kind"] == "masked_prefill":
             case = run_masked_prefill_case(*spec["shape"], spec["reps"])
+        elif spec["kind"] == "paged_gqa":
+            case = run_gqa_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -559,7 +635,8 @@ def main():
     ap.add_argument("--kinds", default="",
                     help="comma-separated case kinds to run (attn, ln, "
                          "conv_layout, paged_attn, paged_latent, "
-                         "masked_prefill, fused_adam, softmax_xent); "
+                         "masked_prefill, paged_gqa, fused_adam, "
+                         "softmax_xent); "
                          "default all")
     ap.add_argument("--timeout", type=int, default=600)
     args = ap.parse_args()
@@ -585,6 +662,8 @@ def main():
     # a call is 0.1-0.6 s: a chain of three is long enough
     specs += [{"kind": "masked_prefill", "shape": list(shape),
                "reps": min(args.reps, 3)} for shape in MASKED_PREFILL_CASES]
+    specs += [{"kind": "paged_gqa", "shape": list(shape), "reps": args.reps}
+              for shape in GQA_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}

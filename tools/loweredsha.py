@@ -1,6 +1,8 @@
-"""SHA-256 of the lowered text of GPT-2's and DeepSeek-V2's serving programs
-at toy widths, on the CPU: the paged decode step and the prefill buckets of
-each (DeepSeek-V2's buckets cover both forms of its latent attention). A PR
+"""SHA-256 of the lowered text of GPT-2's, DeepSeek-V2's and dots3-note-prev's
+serving programs at toy widths, on the CPU: the paged decode step and the
+prefill buckets of each (DeepSeek-V2's buckets cover both forms of its latent
+attention; dots3-note-prev's decode step and one prefill program run both
+page groups, the selection and the sigmoid router). A PR
 that says "their programs are the parent's" shows it with these: the same
 hashes from the parent's tree and from its own
 (``tests/test_lowered_text_guard.py`` holds the parent's). The text is what
@@ -34,6 +36,25 @@ DEEPSEEK_V2_TOY = dict(
     engine={"batch_size": 4, "paged": True, "page_size": 8, "num_pages": 64,
             "max_length": 128, "cache_dtype": "float32",
             "prefill_buckets": [8, 16, 32, 64]})
+DOTS3_NOTE_TOY = dict(
+    hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
+    num_attention_heads=4, q_lora_rank=24, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rope_theta=80000000, attention_gate_type="headwise",
+    swa_num_attention_heads=2, swa_q_lora_rank=24, swa_kv_lora_rank=40,
+    swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8, swa_v_head_dim=16,
+    swa_rope_theta=50000, swa_attention_gate_type="headwise",
+    sliding_window_size=5, index_n_heads=4, index_head_dim=16, index_topk=8,
+    apply_mla_qkv_lora_rescale=True, rms_norm_eps=1e-5,
+    layer_types=["full_attention", "full_attention", "sliding_attention",
+                 "sliding_attention"], n_layer=4, first_k_dense_replace=1,
+    n_shared_experts=1, n_routed_experts=16, num_experts_per_tok=3,
+    norm_topk_prob=True, routed_scaling_factor=1, n_vocab=200,
+    initializer_range=0.02, max_position_embeddings=256,
+    held_experts=[0, 1, 2, 5, 9, 14], precision={"weights": "float32"},
+    engine={"batch_size": 3, "paged": True, "page_size": 2,
+            "num_pages": {"all": 90, "window": 20}, "max_length": 64,
+            "cache_dtype": "float32", "prefill_buckets": [16]})
 GPT2_TOY = dict(n_layer=2, n_embd=32, n_head=2, n_ctx=64, n_vocab=64,
                 engine={"batch_size": 2, "paged": True, "page_size": 8,
                         "max_length": 64, "prefill_buckets": [8, 16]})
@@ -44,7 +65,9 @@ def engines():
     import numpy as np
 
     from benchmark.reference import deepseek_v2 as ref_v2
+    from benchmark.reference import dots3_note as ref_dots3
     from benchmark.systems import deepseek_v2 as adaptor_v2
+    from benchmark.systems import dots3_note as adaptor_dots3
     from benchmark.weights import make_weights
     from mxnet_tpu import nd
     from mxnet_tpu.inference import GenerationEngine
@@ -57,8 +80,10 @@ def engines():
     net.initialize()
     net(nd.array(np.zeros((1, 4), np.int32)))   # shapes, then parameters
     weights = make_weights(ref_v2.param_specs(DEEPSEEK_V2_TOY), 7)
+    dots3 = make_weights(ref_dots3.param_specs(DOTS3_NOTE_TOY), 7)
     return {"gpt2": GenerationEngine(net, **c["engine"]),
-            "deepseek_v2": adaptor_v2.build_serve(DEEPSEEK_V2_TOY, weights)[0]}
+            "deepseek_v2": adaptor_v2.build_serve(DEEPSEEK_V2_TOY, weights)[0],
+            "dots3_note": adaptor_dots3.build_serve(DOTS3_NOTE_TOY, dots3)[0]}
 
 
 def lowered_sha():

@@ -15,6 +15,7 @@ scope, ``layerN/attn`` or ``layerN/mla/core``).
 
     python tools/servescope.py --workload deepseek_v2_serve_reason --seed 7
     python tools/servescope.py --workload dots3_note_serve_longctx --depth 4
+    python tools/servescope.py --workload smallthinker_21b_serve_mixed --prefill-lens 8192
     JAX_PLATFORMS=cpu python tools/servescope.py --tiny     # rehearsal
 
 ``--depth 4`` tells ``layerN/mla/dsa/index`` from ``layerN/mla/dsa/select``
@@ -51,8 +52,13 @@ def build(workload, tiny, seed):
 
         # the toy copy lives with the CPU tests of the cell's configuration
         sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
-        name = harness.find_cell(harness.load_benchmark(ROOT), workload)["config"]
-        toy = importlib.import_module(f"test_benchmark_{name}")
+        bench = harness.load_benchmark(ROOT)
+        cell = harness.find_cell(bench, workload)
+        try:
+            toy = importlib.import_module(f"test_benchmark_{cell['config']}")
+        except ModuleNotFoundError:   # named for the model, not its size
+            model = harness.load_config(bench, cell, ROOT)["model"]
+            toy = importlib.import_module(f"test_benchmark_{model}")
         root = toy.make_root(tempfile.mkdtemp(prefix="servescope-"))
         workload, platform = toy.TINY, "cpu"
     bench = harness.load_benchmark(root)
@@ -130,7 +136,8 @@ def scopes_and_pool_ops(engine, report, lowered, per, depth=3):
         op = opcode.get(name)
         if op:
             ms[op] = ms.get(op, 0.0) + own / 1e6 / per
-        kernel = re.match(r"paged_\w*(?:attention|scores)\w*?(?=[.\d]*$)", name)
+        kernel = re.match(r"paged_\w*(?:attention|scores|gqa)\w*?(?=[.\d]*$)",
+                          name)
         if kernel:
             kernels[kernel[0]] = kernels.get(kernel[0], 0.0) + own / 1e6 / per
     rounded = lambda d: {k: round(v, 4) for k, v in d.items()}  # noqa: E731
