@@ -22,7 +22,8 @@
 #   kernelcheck - Pallas kernel correctness gate: CPU interpret-mode
 #             parity/bit-identity suites for every custom kernel (flash
 #             attention, fused layernorm, paged decode attention, fused
-#             Adam, fused softmax-xent), docs/PERFORMANCE.md
+#             Adam, fused softmax-xent, the experts' grouped matmul),
+#             docs/PERFORMANCE.md
 #   profcheck - measured-profiling gate (tools/profcheck.py): traces two
 #             shared golden families for real, asserts non-empty device
 #             op timelines, measured overlap, and step-time agreement
@@ -102,7 +103,8 @@ kernelcheck:
 	$(PY) -m pytest tests/test_flash_attention.py tests/test_pallas_layernorm.py \
 	    tests/test_pallas_paged_attention.py \
 	    tests/test_pallas_paged_latent_attention.py tests/test_pallas_optimizer.py \
-	    tests/test_pallas_softmax_xent.py tests/test_packed_attention.py -q
+	    tests/test_pallas_softmax_xent.py tests/test_packed_attention.py \
+	    tests/test_pallas_grouped_matmul.py -q
 
 native:
 	$(MAKE) -C native

@@ -617,6 +617,64 @@ def check_gqa(rows=8, h=28, hkv=4, ch=128, ps=16, window=4096,
             "window": window, "pool": "bfloat16", **out}
 
 
+def check_grouped(tokens=48, top_k=6, d=512, w=256, experts=64, interpret=None):
+    """The expert layer as the serving cells run it, at widths the grouped
+    kernel's gate admits: ``held_expert_ffn`` with every expert held (a
+    decode step's handful of rows a group) and with an eighth held (most
+    sorted pairs behind the held ones, their row tiles never visited and
+    their rows unwritten), against the same layer by ``lax.ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu import observability as obs
+    from mxnet_tpu.ops import pallas_grouped_matmul as gmm
+    from mxnet_tpu.parallel import moe
+
+    rs = np.random.RandomState(SEED)
+    h = jnp.asarray(rs.randn(tokens, d), jnp.bfloat16)
+    router = jnp.asarray(rs.randn(experts, d) * 0.3, jnp.float32)
+    count, out = obs.counter("moe_path_total"), {}
+    for kind, held in (("all_held", experts), ("an_eighth_held", experts // 8)):
+        mats = [jnp.asarray(rs.randn(*shape) * shape[1] ** -0.5, jnp.bfloat16)
+                for shape in ((held, d, w), (held, d, w), (held, w, d))]
+        how = dict(held_experts=range(held), top_k=top_k, norm_topk_prob=True,
+                   activation="relu")
+        if interpret is None:
+            why = gmm.grouped_matmul_refusal(tokens * top_k, d, w, h.dtype,
+                                             mats[0].dtype)
+            if why is not None:
+                raise AssertionError(f"grouped {kind}: the gate refuses: {why}")
+
+        def layer(h, *mats):
+            return moe.held_expert_ffn(h, router, *mats, **how)[0]
+
+        before = count.value(path="pallas_grouped", reason="")
+        calls = _custom_calls(layer, h, *mats)
+        if (not interpret and calls < 2) or \
+                count.value(path="pallas_grouped", reason="") != before + 1:
+            raise AssertionError(f"grouped {kind}: lowered without its Mosaic "
+                                 f"kernels ({calls} custom calls)")
+        got = jax.jit(layer)(h, *mats)
+        # the same layer traced anew where the gate refuses: three
+        # lax.ragged_dot
+        was, gmm._on_tpu = gmm._on_tpu, lambda: False
+        try:
+            want = jax.jit(lambda *a: layer(*a))(h, *mats)
+        finally:
+            gmm._on_tpu = was
+        if not bool(jnp.isfinite(got).all()):
+            raise AssertionError(f"grouped {kind}: a row of no group reached "
+                                 "the output")
+        err = _rel_err(got, want)
+        if not err < 2e-2:
+            raise AssertionError(f"grouped {kind}: relative error {err} "
+                                 "against lax.ragged_dot")
+        out[kind] = {"held": held, "tpu_custom_calls": calls,
+                     "rel_err": round(err, 6)}
+    return {"pairs": tokens * top_k, "widths": [d, w], "experts": experts, **out}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -683,6 +741,7 @@ def phase_kernels():
            "paged_attention": check_paged(),
            "paged_index_scores": check_index_scores(),
            "paged_gqa_decode": check_gqa(),
+           "grouped_matmul": check_grouped(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

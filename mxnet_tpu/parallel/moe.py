@@ -16,6 +16,7 @@ TPU-native shape, after Switch-Transformer / mesh-tensorflow:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
+
+from ..ops import pallas_grouped_matmul as _grouped
 
 
 def shard_map(f, mesh, in_specs, out_specs):
@@ -134,6 +137,39 @@ def group_limited_topk(probs, n_group: int, topk_group: int, top_k: int):
     return lax.top_k(masked, top_k)
 
 
+def _ragged_products(x, w_gate, w_up, w_down, sizes, activation):
+    """The three grouped products of the sorted pairs' rows ``x`` by
+    ``lax.ragged_dot``: ``[pairs, d]`` float32, the rows of no group as the
+    backend leaves them."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    gate = lax.ragged_dot(x, w_gate, sizes, **f32)
+    up = lax.ragged_dot(x, w_up, sizes, **f32)
+    return lax.ragged_dot((_grouped.ACTIVATIONS[activation](gate) * up).astype(x.dtype),
+                          w_down, sizes, **f32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernel_products(x, w_gate, w_up, w_down, sizes, activation):
+    """:func:`_ragged_products` by the Pallas grouped-matmul kernel
+    (``ops/pallas_grouped_matmul.py``); its gradient is the ragged path's."""
+    return _grouped.grouped_glu_ffn(x, w_gate, w_up, w_down, sizes, activation)
+
+
+def _kernel_products_fwd(x, w_gate, w_up, w_down, sizes, activation):
+    return (_kernel_products(x, w_gate, w_up, w_down, sizes, activation),
+            (x, w_gate, w_up, w_down, sizes))
+
+
+def _kernel_products_bwd(activation, saved, ct):
+    *operands, sizes = saved
+    _, pull = jax.vjp(
+        lambda *a: _ragged_products(*a, sizes, activation), *operands)
+    return (*pull(ct), None)
+
+
+_kernel_products.defvjp(_kernel_products_fwd, _kernel_products_bwd)
+
+
 def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
                     n_group: int = 1, topk_group: int = 1, top_k: int,
                     scale: float = 1.0, norm_topk_prob: bool = False,
@@ -156,26 +192,32 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
     ``w_down`` [held, w, d]. Every (token, expert) pair whose expert is
     held is computed, whatever the load (no capacity, no dropped token):
     the pairs are sorted by expert, the held ones first, and each
-    projection is one grouped product (``lax.ragged_dot``) whose rows past
-    the held pairs belong to no group. Pairs routed to absent experts add
-    nothing here: their chips add them.
+    projection is one grouped product whose rows past the held pairs belong
+    to no group: the Pallas kernel of ``ops/pallas_grouped_matmul.py``
+    (gate and up in one call, row tiles that follow the groups) where its
+    gate ``grouped_matmul_refusal`` lets it run (one TPU chip, widths of
+    whole lane tiles), ``lax.ragged_dot`` elsewhere. Pairs routed to absent
+    experts add nothing here: their chips add them.
 
     Returns ``(y [n, d], stats)``; ``stats`` are two int32 scalars, the
     pairs routed to held experts and the largest load of one, and with
     ``count_hit`` a third: the held experts that drew a pair at all. The
-    ``moe_path_total{path}`` counter says at trace time what was built.
+    ``moe_path_total{path, reason}`` counter says at trace time what was
+    built: ``pallas_grouped``, or ``sorted_ragged_dot`` and the gate's
+    first failed rule.
     """
     from .. import observability as obs
 
     n, d = h.shape
     held = tuple(int(e) for e in held_experts)
     n_held, n_experts = len(held), router_w.shape[0]
-    obs.counter("moe_path_total").inc(path="sorted_ragged_dot")  # trace time
-    try:
-        act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
-    except KeyError:
-        raise ValueError(f"unknown activation {activation!r}: silu or relu") \
-            from None
+    if activation not in _grouped.ACTIVATIONS:
+        raise ValueError(f"unknown activation {activation!r}: silu or relu")
+    why = _grouped.grouped_matmul_refusal(n * top_k, d, w_gate.shape[2],
+                                          h.dtype, w_gate.dtype)
+    obs.counter("moe_path_total").inc(                      # trace time
+        path="sorted_ragged_dot" if why else "pallas_grouped",
+        reason=why or "")
     with jax.named_scope("router"):
         logits = jnp.einsum("nd,ed->ne",
                             (h if router_h is None else router_h).astype(jnp.float32),
@@ -205,12 +247,8 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
         is_held = slot[order] < n_held
         pair_w = weights.reshape(-1)[order]
     with jax.named_scope("experts"):
-        f32 = dict(preferred_element_type=jnp.float32)
-        x = h[token]                                           # [n * k, d]
-        gate = lax.ragged_dot(x, w_gate, sizes, **f32)
-        up = lax.ragged_dot(x, w_up, sizes, **f32)
-        y = lax.ragged_dot((act(gate) * up).astype(h.dtype), w_down,
-                           sizes, **f32)
+        products = _ragged_products if why else _kernel_products
+        y = products(h[token], w_gate, w_up, w_down, sizes, activation)
         # rows past the held pairs belong to no group: a backend may leave
         # them unwritten (the TPU's does), so they are masked, not weighted 0
         out = jnp.zeros((n, d), jnp.float32).at[token].add(

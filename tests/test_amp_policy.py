@@ -23,7 +23,8 @@ IN, OUT = 6, 4
 def _mlp(seed=0):
     mx.random.seed(seed)
     net = nn.HybridSequential()
-    net.add(nn.Dense(16, activation="relu"), nn.Dense(OUT))
+    with net.name_scope():   # names that pair across fresh nets on restore
+        net.add(nn.Dense(16, activation="relu"), nn.Dense(OUT))
     net.initialize()
     _ = net(nd.ones((2, IN)))
     return net

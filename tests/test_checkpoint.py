@@ -10,7 +10,10 @@ from mxnet_tpu.parallel import TrainStep
 def _net():
     mx.random.seed(11)
     net = nn.HybridSequential()
-    net.add(nn.Dense(8, activation="relu"), nn.Dense(2))
+    # named inside the net's scope: a restore pairs two fresh nets by sorted
+    # name, and the process-wide counter's dense9 sorts after its dense10
+    with net.name_scope():
+        net.add(nn.Dense(8, activation="relu"), nn.Dense(2))
     net.initialize()
     _ = net(nd.ones((4, 3)))
     return net

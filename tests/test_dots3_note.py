@@ -389,7 +389,8 @@ def test_few_tokens_alone_read_as_they_do_among_many(scoring):
     """A decode step of a chip that holds a small share: six tokens, some
     held experts without a pair. Routing is a token's own, so the same
     tokens among many give the same rows, by the one grouped path
-    (``moe_path_total{path=sorted_ragged_dot}``) both times."""
+    (``moe_path_total{path=sorted_ragged_dot}``: toy widths on the CPU take
+    the kernel's refusal) both times."""
     rng = np.random.default_rng(8)
     n, d, w, experts, held, k = 6, 16, 8, 32, [3, 11, 30], 4
     many = jnp.asarray(rng.standard_normal((64, d)), jnp.float32)
@@ -400,10 +401,11 @@ def test_few_tokens_alone_read_as_they_do_among_many(scoring):
     how = dict(held_experts=held, top_k=k, scoring=scoring, norm_topk_prob=True,
                router_bias=bias if scoring == "sigmoid" else None)
     count = obs.counter("moe_path_total")
-    before = count.value(path="sorted_ragged_dot")
+    ragged = dict(path="sorted_ragged_dot", reason="the backend is not a TPU")
+    before = count.value(**ragged)
     few, (pairs, load) = moe.held_expert_ffn(many[:n], router, *mats, **how)
     all_, _ = moe.held_expert_ffn(many, router, *mats, **how)
-    assert count.value(path="sorted_ragged_dot") == before + 2
+    assert count.value(**ragged) == before + 2
     assert float(jnp.abs(few - all_[:n]).max()) < 1e-5
     assert float(jnp.abs(few).max()) > 1e-3 and 0 < int(load) <= int(pairs)
 

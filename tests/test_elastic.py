@@ -323,8 +323,12 @@ def _fsdp_ts(mesh, seed=7):
     t, the loss-scale carry)."""
     mx.random.seed(seed)
     net = nn.HybridSequential()
-    net.add(nn.Dense(16, in_units=8, activation="relu"),
-            nn.Dense(4, in_units=16))
+    # layers named inside the net's scope (dense0, dense1 under its prefix):
+    # a restore pairs two fresh nets' parameters by sorted name, and the
+    # process-wide counter's dense9 sorts AFTER its dense10
+    with net.name_scope():
+        net.add(nn.Dense(16, in_units=8, activation="relu"),
+                nn.Dense(4, in_units=16))
     net.initialize()
     _ = net(nd.ones((8, 8)))
     rules = ShardingRules(fsdp_axis="fsdp", min_fsdp_size=1)

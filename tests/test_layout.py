@@ -125,7 +125,8 @@ def test_from_mesh_bridge():
 def _tiny_net():
     mx.random.seed(0)
     net = nn.HybridSequential()
-    net.add(nn.Dense(32, activation="relu"), nn.Dense(8))
+    with net.name_scope():   # names that pair across fresh nets on restore
+        net.add(nn.Dense(32, activation="relu"), nn.Dense(8))
     net.initialize()
     x = nd.ones((8, 16))
     _ = net(x)

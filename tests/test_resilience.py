@@ -50,7 +50,10 @@ def _fast_retry():
 def _net():
     mx.random.seed(11)
     net = nn.HybridSequential()
-    net.add(nn.Dense(8, activation="relu"), nn.Dense(2))
+    # named inside the net's scope: a restore pairs two fresh nets by sorted
+    # name, and the process-wide counter's dense9 sorts after its dense10
+    with net.name_scope():
+        net.add(nn.Dense(8, activation="relu"), nn.Dense(2))
     net.initialize()
     _ = net(nd.ones((4, 3)))
     return net
@@ -393,7 +396,8 @@ def test_sigterm_subprocess_checkpoints_and_exits_zero(tmp_path):
         from mxnet_tpu.parallel import TrainStep
 
         net = nn.HybridSequential()
-        net.add(nn.Dense(4, activation="relu"), nn.Dense(2))
+        with net.name_scope():   # as in _net: names that pair across nets
+            net.add(nn.Dense(4, activation="relu"), nn.Dense(2))
         net.initialize()
         x = nd.ones((2, 3)); _ = net(x)
         loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()

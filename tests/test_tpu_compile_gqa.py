@@ -1,14 +1,17 @@
-"""The grouped-heads decode kernel compiled by Mosaic for a DESCRIBED v5e
-at SmallThinker's real widths, here, without a chip: what interpret mode
-cannot refuse (a slice off the tiling, too much VMEM) fails this at no chip
-time. Nothing runs, so it says nothing of results or times. The topology is
-described inside a fixture (only the worker that is given this file loads
-the TPU's library) and the tests skip where it cannot be."""
+"""The grouped-heads decode kernel and the experts' grouped-matmul kernel
+compiled by Mosaic for a DESCRIBED v5e at the cells' real widths, here,
+without a chip: what interpret mode cannot refuse (a slice off the tiling,
+too much VMEM) fails this at no chip time. Nothing runs, so it says nothing
+of results or times. The topology is described inside a fixture (only the
+worker that is given this file loads the TPU's library: both kernels' cases
+are in this ONE file for that reason) and the tests skip where it cannot
+be."""
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from mxnet_tpu.ops import pallas_grouped_matmul as gmm
 from mxnet_tpu.ops import pallas_paged_attention as ppa
 
 
@@ -24,28 +27,63 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled(one_chip, fn, *shapes):
+    """``fn`` compiled for the described chip at ``shapes`` ((shape, dtype)
+    pairs), the persistent cache off: an entry could not be read back here."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*(
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes)
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("window,pages,columns", [(None, 24576, 640),
                                                   (4096, 12416, 259)])
 def test_the_kernel_compiles_for_a_v5e_at_the_cells_widths(one_chip, window,
                                                            pages, columns):
-    from jax.experimental.compilation_cache import compilation_cache
-
-    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()   # an entry could not be read back here
-    try:
-        compiled = jax.jit(
-            lambda q, k, v, t, p: ppa.paged_gqa_read(q, k, v, t, p, window,
-                                                     interpret=False)
-        ).lower(shape((48, 28, 1, 128), jnp.bfloat16),
-                shape((pages + 1, 16, 512), jnp.bfloat16),
-                shape((pages + 1, 16, 512), jnp.bfloat16),
-                shape((48, columns), jnp.int32),
-                shape((48,), jnp.int32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled(
+        one_chip,
+        lambda q, k, v, t, p: ppa.paged_gqa_read(q, k, v, t, p, window,
+                                                 interpret=False),
+        ((48, 28, 1, 128), jnp.bfloat16),
+        ((pages + 1, 16, 512), jnp.bfloat16),
+        ((pages + 1, 16, 512), jnp.bfloat16),
+        ((48, columns), jnp.int32), ((48,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
     # nothing history-sized beside the pools: the kernel's scratch is VMEM
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# the three expert cells' decode steps and prefill programs: (pairs, d, w,
+# experts held): SmallThinker (48 rows of six; its 512-token bucket; a
+# 4,096-token block), DeepSeek-V2 (128 rows of six; its 512 bucket),
+# dots3-note-prev (48 rows of eight; its 1,024 bucket; a 4,096-token block)
+# and, in float32 (the gate's other dtype: blocks twice as large), a decode
+# step and a prefill block at the widest widths
+@pytest.mark.parametrize("pairs,d,w,held,dtype", [
+    (288, 2560, 768, 64, jnp.bfloat16), (3072, 2560, 768, 64, jnp.bfloat16),
+    (24576, 2560, 768, 64, jnp.bfloat16), (768, 5120, 1536, 8, jnp.bfloat16),
+    (3072, 5120, 1536, 8, jnp.bfloat16), (384, 5120, 1536, 8, jnp.bfloat16),
+    (8192, 5120, 1536, 8, jnp.bfloat16), (32768, 5120, 1536, 8, jnp.bfloat16),
+    (384, 5120, 1536, 8, jnp.float32), (32768, 5120, 1536, 8, jnp.float32)])
+def test_the_grouped_matmul_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, pairs, d, w, held, dtype):
+    compiled = _compiled(
+        one_chip,
+        lambda x, w_gate, w_up, w_down, sizes: gmm.grouped_glu_ffn(
+            x, w_gate, w_up, w_down, sizes, "silu", interpret=False),
+        ((pairs, d), dtype), ((held, d, w), dtype), ((held, d, w), dtype),
+        ((held, w, d), dtype), ((held,), jnp.int32))
+    text = compiled.as_text()
+    assert "grouped_matmul_gate_up" in text and "grouped_matmul_down" in text
+    # nothing beside the rows between the two calls: no float32 pair, no
+    # copy of a layer's weights
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= pairs * w * jnp.dtype(dtype).itemsize + (1 << 20)

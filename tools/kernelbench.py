@@ -1,7 +1,7 @@
 """On-chip check + timing of the Pallas kernels (flash attention, fused
 LayerNorm, paged decode-attention over key/value pools and over a latent
-pool, a long prefill's masked latent attention, fused Adam, fused
-softmax-xent) against their XLA compositions.
+pool, a long prefill's masked latent attention, the held experts' grouped
+products, fused Adam, fused softmax-xent) against their XLA compositions.
 
 Send it through the chip tool. The parent never imports jax, so it never
 holds the chip: each case runs in a child process of its own, one at a
@@ -67,6 +67,16 @@ GQA_CASES = [(48, 28, 4, 128, 16, 640, 0, 512, 1024),
 # the mask against ``_masked_chunk_attention`` (XLA: keys in stretches)
 MASKED_PREFILL_CASES = [(4096, 128, 128, 64, 128, 512, 1024, 64, 128, 2048),
                         (16384, 128, 128, 64, 128, 512, 1024, 64, 128, 2048)]
+# the held experts' three grouped products (one expert layer's, bfloat16):
+# (tokens, top_k, d, w, held, experts): every token picks top_k distinct
+# experts of ``experts`` and the first ``held`` are here, so tokens * top_k
+# sorted pairs of which held / experts belong to a group. SmallThinker's
+# decode step and 4,096-token prefill block (all 64 held), DeepSeek-V2's
+# decode step (8 of 160), dots3-note-prev's decode step and prefill block
+# (8 of 256). The A/B is ``grouped_glu_ffn`` against three ``lax.ragged_dot``
+GROUPED_MM_CASES = [(48, 6, 2560, 768, 64, 64), (4096, 6, 2560, 768, 64, 64),
+                    (128, 6, 5120, 1536, 8, 160), (48, 8, 5120, 1536, 8, 256),
+                    (4096, 8, 5120, 1536, 8, 256)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -88,6 +98,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     LATENT_CASES = [(2, 4, 32, 8, 16, 8, 1, 0), (2, 4, 32, 8, 16, 16, 2, 100)]
     MASKED_PREFILL_CASES = [(256, 2, 128, 64, 128, 64, 64, 2, 32, 64)]
     GQA_CASES = [(3, 6, 2, 16, 4, 16, 0, 0, 60), (3, 6, 2, 16, 4, 4, 5, 0, 60)]
+    GROUPED_MM_CASES = [(8, 2, 128, 128, 4, 4), (64, 2, 128, 256, 2, 8)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -439,6 +450,76 @@ def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps):
     return case
 
 
+def run_grouped_mm_case(tokens, top_k, d, w, held, experts, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from mxnet_tpu.ops import pallas_grouped_matmul as gmm
+
+    rng = np.random.RandomState(0)
+    dtype = jnp.float32 if _INTERP else jnp.bfloat16
+    pairs = tokens * top_k
+    # a router without favourites: top_k distinct experts a token
+    ids = np.argsort(rng.rand(tokens, experts), axis=1)[:, :top_k].reshape(-1)
+    sizes = np.bincount(ids, minlength=experts)[:held].astype(np.int32)
+    rows, hit = int(sizes.sum()), int((sizes > 0).sum())
+    x = jnp.asarray(rng.randn(pairs, d) * 0.5, dtype)
+    w_gate, w_up = (jnp.asarray(rng.randn(held, d, w) * d ** -0.5, dtype)
+                    for _ in range(2))
+    w_down = jnp.asarray(rng.randn(held, w, d) * w ** -0.5, dtype)
+    # the sizes ride first and in float32: the timing chain adds its carry
+    # to the first argument, and a pass over the rows (335 MB at 32,768 x
+    # 5,120) would be timed with either path
+    sizes = jnp.asarray(sizes, jnp.float32)
+    case = {"kind": "grouped_mm", "pairs": pairs, "d": d, "w": w,
+            "held": held, "experts": experts, "held_pairs": rows,
+            "experts_hit": hit, "largest_group": int(sizes.max())}
+    if not _INTERP:
+        case["gate"] = gmm.grouped_matmul_refusal(pairs, d, w, dtype, dtype) \
+            or "kernel"
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def ragged(sizes, x, w_gate, w_up, w_down):
+        sizes = sizes.astype(jnp.int32)
+        mid = jax.nn.relu(lax.ragged_dot(x, w_gate, sizes, **f32)) \
+            * lax.ragged_dot(x, w_up, sizes, **f32)
+        return lax.ragged_dot(mid.astype(x.dtype), w_down, sizes, **f32)
+
+    def kernel(sizes, x, w_gate, w_up, w_down):
+        return gmm.grouped_glu_ffn(x, w_gate, w_up, w_down,
+                                   sizes.astype(jnp.int32), "relu",
+                                   interpret=_INTERP)
+
+    args = (sizes, x, w_gate, w_up, w_down)
+    # the rows of no group are unwritten on both paths: compare the groups'
+    ref, out = (np.asarray(f(*args))[:rows] for f in (ragged, kernel))
+    err = float(np.abs(out - ref).max() / (np.abs(ref).max() + 1e-9)) \
+        if rows else 0.0
+    case["rel_err"] = round(err, 6)
+    case["correct"] = bool(err < 0.02 and np.isfinite(out).all())
+    del ref, out
+    for label, f in (("kernel", kernel), ("ragged", ragged)):
+        try:
+            case[f"{label}_ms"] = round(_timeit(f, args, reps) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:300]
+    if "kernel_ms" in case and "ragged_ms" in case:
+        case["kernel_vs_ragged"] = round(case["ragged_ms"] / case["kernel_ms"], 2)
+    # what the products need: the matrices of the experts that drew a pair
+    # once, and two operations a weight and held pair
+    gb = hit * 3 * d * w * jnp.dtype(dtype).itemsize / 1e9
+    tflop = rows * 3 * 2 * d * w / 1e12
+    case["weights_gb"], case["tflop"] = round(gb, 4), round(tflop, 4)
+    for label in ("kernel", "ragged"):
+        if f"{label}_ms" in case:
+            sec = case[f"{label}_ms"] / 1e3
+            case[f"{label}_gb_per_s"] = round(gb / sec, 1)
+            case[f"{label}_tflop_per_s"] = round(tflop / sec, 2)
+    return case
+
+
 def run_masked_prefill_case(t, heads, nope, rope, vd, kl, ql, idx_heads,
                             idx_dim, top_k, reps):
     import jax
@@ -614,6 +695,8 @@ def run_one(argv):
             case = run_masked_prefill_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "paged_gqa":
             case = run_gqa_case(*spec["shape"], spec["reps"])
+        elif spec["kind"] == "grouped_mm":
+            case = run_grouped_mm_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -635,8 +718,8 @@ def main():
     ap.add_argument("--kinds", default="",
                     help="comma-separated case kinds to run (attn, ln, "
                          "conv_layout, paged_attn, paged_latent, "
-                         "masked_prefill, paged_gqa, fused_adam, "
-                         "softmax_xent); "
+                         "masked_prefill, paged_gqa, grouped_mm, "
+                         "fused_adam, softmax_xent); "
                          "default all")
     ap.add_argument("--timeout", type=int, default=600)
     args = ap.parse_args()
@@ -664,6 +747,8 @@ def main():
                "reps": min(args.reps, 3)} for shape in MASKED_PREFILL_CASES]
     specs += [{"kind": "paged_gqa", "shape": list(shape), "reps": args.reps}
               for shape in GQA_CASES]
+    specs += [{"kind": "grouped_mm", "shape": list(shape), "reps": args.reps}
+              for shape in GROUPED_MM_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}
