@@ -225,6 +225,10 @@ class ContinuousBatcher:
                 cooldown=int(spec_cooldown if spec_cooldown is not None
                              else config.get("serve_spec_cooldown")))
         self._step_id = 0
+        #: rows admitted (prefilled or forked) and rows finished so far: a
+        #: step's record (``serve_step``) counts each by difference
+        self._admissions = 0
+        self._finishes = 0
         self._head_id: Optional[int] = None
         self._head_deferrals = 0
         #: per-request span emitter (observability.tracing.Tracer) —
@@ -512,6 +516,7 @@ class ContinuousBatcher:
         self._trace_queue_exit(req, now, reason, terminal=True)
 
     def _finish(self, slot: int, reason: str):
+        self._finishes += 1
         req = self._slots[slot]
         self._slots[slot] = None
         if (reason in ("eos", "length", "cache_full")
@@ -574,6 +579,7 @@ class ContinuousBatcher:
         """One bucketed batch-1 prefill under the retry policy + watchdog
         (fault site ``gen.prefill`` fires inside the engine, before any
         allocator mutation)."""
+        self._admissions += 1
         req.slot = slot
         self._slots[slot] = req
         req.admit_t = now
@@ -592,10 +598,11 @@ class ContinuousBatcher:
                                       if self._watchdog.enabled else None):
                 return self.engine.prefill(req.prompt, slot)
 
-        svc0 = time.perf_counter()
         tok = _retry.retry_call(_dispatch, site="gen.prefill",
                                 policy=self._retry_policy)
-        svc = time.perf_counter() - svc0
+        # the engine's own record of the prefill that served it, the newest
+        # of the loop ``prefill``: fault site to the prefix cache's insert
+        svc = 1e-9 * _obs.step_records("prefill")[-1].duration_ns
         req.first_token_t = self._clock()
         _obs.histogram("ttft_seconds", "submit -> first sampled token",
                        unit="s").observe(req.first_token_t - req.submit_t)
@@ -633,6 +640,7 @@ class ContinuousBatcher:
             if slot is None:
                 break
             self._queue.remove(sib)
+            self._admissions += 1
             sib.slot = slot
             sib.forked = True
             self._slots[slot] = sib
@@ -759,19 +767,49 @@ class ContinuousBatcher:
         decode step (or one speculative draft+verify round, or — in
         governor fallback — one plain step on the speculative engine).
         Returns True while any work (active rows or queued requests)
-        remains."""
+        remains.
+
+        Every call leaves a record in ``obs.step_records("serve_step")``
+        (root span ``mx.gen.step``; telemetry on or off; host clock marks
+        and host integers, nothing of the device): the phases
+        ``mx.gen.step.sweep``, ``.admit`` (the prefills it runs leave
+        ``prefill`` records of their own inside it), ``.books``, ``.decode``
+        (the engine's ``decode_step`` record nests inside) and ``.tokens``,
+        and the counts ``active`` and ``queued`` as the step found them,
+        ``admitted``, ``finished`` and ``steady``. A step that finds no
+        active row closes its record with the phases it ran."""
         now = self._clock()
         self._step_id += 1
-        self._sweep(now)
-        self._admit(now)
-        self._gauges()
-        if self.active == 0:
-            return bool(self._queue)
-        was_active = [s for s, r in enumerate(self._slots) if r is not None]
-        speculative = getattr(self.engine, "speculative", False)
-        use_spec = speculative and (self.governor is None
-                                    or self.governor.speculating)
-        tr = self.tracer
+        with _obs.step_record("serve_step", self._step_id,
+                              name="mx.gen.step") as rec:
+            rec.counts = tally = {
+                "active": self.active, "queued": len(self._queue),
+                "admitted": 0, "finished": 0, "steady": 0}
+            admissions, finishes = self._admissions, self._finishes
+            try:
+                return self._step_phases(now, tally)
+            finally:
+                tally["admitted"] = self._admissions - admissions
+                tally["finished"] = self._finishes - finishes
+
+    def _step_phases(self, now: float, tally: dict) -> bool:
+        """What :meth:`step` does, statement for statement in the order it
+        always had, each phase under its host span (a phase that the
+        branches below split has its span in each)."""
+        with _obs.span("mx.gen.step.sweep"):
+            self._sweep(now)
+        with _obs.span("mx.gen.step.admit"):
+            self._admit(now)
+        with _obs.span("mx.gen.step.books"):
+            self._gauges()
+            if self.active == 0:
+                return bool(self._queue)
+            was_active = [s for s, r in enumerate(self._slots)
+                          if r is not None]
+            speculative = getattr(self.engine, "speculative", False)
+            use_spec = speculative and (self.governor is None
+                                        or self.governor.speculating)
+            tr = self.tracer
         if use_spec:
             r0 = self._clock() if tr is not None else now
 
@@ -782,84 +820,96 @@ class ContinuousBatcher:
                                           else None):
                     return self.engine.spec_step()
 
-            toks, counts, done = _retry.retry_call(
-                _round, site="gen.decode", policy=self._retry_policy)
-            r1 = self._clock() if tr is not None else now
-            if self.governor is not None and self.engine.last_round_drafted:
-                self.governor.observe_round(self.engine.last_round_accepted,
-                                            self.engine.last_round_drafted)
-            for slot in was_active:
-                req = self._slots[slot]
-                req.rounds += 1
-                n = int(counts[slot])
-                appended = 0
-                for j in range(n):
-                    req.output.append(int(toks[slot, j]))
-                    appended += 1
-                    if len(req.output) >= req.max_new_tokens:
-                        break
-                if tr is not None and req.trace_id is not None:
-                    tr.span(req.trace_id, "decode.round", r0, r1,
-                            step=self._step_id, mode="spec", slot=slot,
-                            accepted=int(self.engine.last_round_accepted),
-                            drafted=int(self.engine.last_round_drafted),
-                            tokens=appended)
-                if appended < n:  # budget hit inside the window
-                    self._finish(slot, "length")
-                elif done[slot]:
-                    self._finish(slot, self._done_reason(
-                        slot, req.output[-1] if req.output else None))
-                elif len(req.output) >= req.max_new_tokens:
-                    self._finish(slot, "length")
+            with _obs.span("mx.gen.step.decode"):
+                toks, counts, done = _retry.retry_call(
+                    _round, site="gen.decode", policy=self._retry_policy)
+            with _obs.span("mx.gen.step.tokens"):
+                r1 = self._clock() if tr is not None else now
+                if (self.governor is not None
+                        and self.engine.last_round_drafted):
+                    self.governor.observe_round(
+                        self.engine.last_round_accepted,
+                        self.engine.last_round_drafted)
+                for slot in was_active:
+                    req = self._slots[slot]
+                    req.rounds += 1
+                    n = int(counts[slot])
+                    appended = 0
+                    for j in range(n):
+                        req.output.append(int(toks[slot, j]))
+                        appended += 1
+                        if len(req.output) >= req.max_new_tokens:
+                            break
+                    if tr is not None and req.trace_id is not None:
+                        tr.span(req.trace_id, "decode.round", r0, r1,
+                                step=self._step_id, mode="spec", slot=slot,
+                                accepted=int(
+                                    self.engine.last_round_accepted),
+                                drafted=int(self.engine.last_round_drafted),
+                                tokens=appended)
+                    if appended < n:  # budget hit inside the window
+                        self._finish(slot, "length")
+                    elif done[slot]:
+                        self._finish(slot, self._done_reason(
+                            slot, req.output[-1] if req.output else None))
+                    elif len(req.output) >= req.max_new_tokens:
+                        self._finish(slot, "length")
         else:
-            if speculative:
-                step_fn = self.engine.plain_step
-            else:
-                # every slot holds a request with two or more tokens to go:
-                # none ends on this step and none can be admitted, so the
-                # rows stay as they are until the next step (a cancellation
-                # or a deadline aside) and the engine may dispatch it ahead
-                steady = all(r is not None
-                             and r.max_new_tokens - len(r.output) >= 2
-                             for r in self._slots)
-                step_fn = (functools.partial(self.engine.decode_step,
-                                             ahead=True)
-                           if steady else self.engine.decode_step)
+            with _obs.span("mx.gen.step.books"):
+                if speculative:
+                    step_fn = self.engine.plain_step
+                else:
+                    # every slot holds a request with two or more tokens to
+                    # go: none ends on this step and none can be admitted,
+                    # so the rows stay as they are until the next step (a
+                    # cancellation or a deadline aside) and the engine may
+                    # dispatch it ahead
+                    steady = all(r is not None
+                                 and r.max_new_tokens - len(r.output) >= 2
+                                 for r in self._slots)
+                    tally["steady"] = int(steady)
+                    step_fn = (functools.partial(self.engine.decode_step,
+                                                 ahead=True)
+                               if steady else self.engine.decode_step)
 
-            def _step():
-                with self._watchdog.guard("decode", self._step_id,
-                                          victims=self._victims()
-                                          if self._watchdog.enabled
-                                          else None):
-                    return step_fn()
+                def _step():
+                    with self._watchdog.guard("decode", self._step_id,
+                                              victims=self._victims()
+                                              if self._watchdog.enabled
+                                              else None):
+                        return step_fn()
 
-            r0 = self._clock() if tr is not None else now
-            tok, done, _ = _retry.retry_call(
-                _step, site="gen.decode", policy=self._retry_policy)
-            r1 = self._clock() if tr is not None else now
-            if self.governor is not None:
-                self.governor.observe_plain_step()
-            for slot in was_active:
-                req = self._slots[slot]
-                req.rounds += 1
-                if tr is not None and req.trace_id is not None:
-                    tr.span(req.trace_id, "decode.round", r0, r1,
-                            step=self._step_id,
-                            mode="plain" if speculative else "decode",
-                            slot=slot, tokens=1)
-                if (self.engine.paged and done[slot]
-                        and bool(self.engine.page_exhausted[slot])):
-                    # evicted BEFORE the dispatch: the row emitted pad this
-                    # step, not a token — finish without appending it
-                    self._finish(slot, "page_exhausted")
-                    continue
-                req.output.append(int(tok[slot]))
-                if done[slot]:
-                    self._finish(slot,
-                                 self._done_reason(slot, req.output[-1]))
-                elif len(req.output) >= req.max_new_tokens:
-                    self._finish(slot, "length")
-        self._gauges()
+                r0 = self._clock() if tr is not None else now
+            with _obs.span("mx.gen.step.decode"):
+                tok, done, _ = _retry.retry_call(
+                    _step, site="gen.decode", policy=self._retry_policy)
+            with _obs.span("mx.gen.step.tokens"):
+                r1 = self._clock() if tr is not None else now
+                if self.governor is not None:
+                    self.governor.observe_plain_step()
+                for slot in was_active:
+                    req = self._slots[slot]
+                    req.rounds += 1
+                    if tr is not None and req.trace_id is not None:
+                        tr.span(req.trace_id, "decode.round", r0, r1,
+                                step=self._step_id,
+                                mode="plain" if speculative else "decode",
+                                slot=slot, tokens=1)
+                    if (self.engine.paged and done[slot]
+                            and bool(self.engine.page_exhausted[slot])):
+                        # evicted BEFORE the dispatch: the row emitted pad
+                        # this step, not a token — finish without appending
+                        # it
+                        self._finish(slot, "page_exhausted")
+                        continue
+                    req.output.append(int(tok[slot]))
+                    if done[slot]:
+                        self._finish(slot,
+                                     self._done_reason(slot, req.output[-1]))
+                    elif len(req.output) >= req.max_new_tokens:
+                        self._finish(slot, "length")
+        with _obs.span("mx.gen.step.tokens"):
+            self._gauges()
         return bool(self._queue) or self.active > 0
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> None:

@@ -469,6 +469,7 @@ class GenerationEngine:
         self.last_round_accepted = 0
         self._plain_decode_jit = None  # lazy spec-engine fallback program
         self._decode_calls = 0  # the decode step records' step id
+        self._prefill_calls = 0  # the prefill records' step id
         #: a decode step dispatched ahead of its call, and the row state it
         #: was built from (decode_step(ahead=True)); bumped by whatever
         #: gives a row another occupant
@@ -538,12 +539,14 @@ class GenerationEngine:
     def speculative(self) -> bool:
         return self.speculate_k > 0
 
-    def _note_program(self, sig, reason):
+    def _note_program(self, sig, reason) -> bool:
+        """Count the program ``sig`` if it is new to this engine; True
+        where it was (the call at hand lowers and compiles it)."""
         from ..analysis import Fingerprint
 
-        self._recompile_guard.observe(Fingerprint.of((), sig=sig),
-                                      reason=reason, group=reason,
-                                      sig=list(map(str, sig)))
+        return self._recompile_guard.observe(
+            Fingerprint.of((), sig=sig), reason=reason, group=reason,
+            sig=list(map(str, sig))) is not None
 
     # -- page accounting (paged mode) ----------------------------------------
     @property
@@ -1338,141 +1341,182 @@ class GenerationEngine:
         # (ContinuousBatcher wraps prefill in retry_call) must replay
         # against untouched page/clear state
         _faults.fire("gen.prefill")
-        t0 = time.perf_counter()
-        if self.paged:
-            if length >= self.max_length:
-                raise ValueError(f"prompt length {length} >= max_length="
-                                 f"{self.max_length}")
-            ps = self.page_size
-            total = self.pages_for(length)
-            # prefix adoption: walk the radix cache for the longest cached
-            # page run, keeping >= 1 suffix token so this prefill still
-            # produces the last-prompt-position logits (the TTFT sample)
-            adopt: List[int] = []
-            tail_src = 0
-            start = 0
-            if self.prefix_cache is not None:
-                cpages, mtok = self.prefix_cache.lookup(prompt.tolist())
-                start = min(mtok, length - 1)
-                adopt = cpages[:start // ps]
-                if start % ps:
-                    # adoption ends inside a cached page: CoW-copy it into
-                    # a private page — stale positions past `start` stay
-                    # frontier-masked until the suffix overwrites them
-                    tail_src = cpages[start // ps]
-            suffix = length - start
-            bucket = self.bucket_for(suffix)
-            need = total - len(adopt)
-            # capacity check BEFORE any allocator mutation: a failed
-            # admission must leave the slot's pending table-clear (and its
-            # reclaimable pages) untouched, or a released row's stale
-            # device table could keep pointing at pages later handed to
-            # someone else (its masked writes would corrupt them). Pages
-            # being adopted are off-limits to the eviction headroom.
-            protect = set(adopt)
-            if tail_src:
-                protect.add(tail_src)
-            own = sum(1 for pid in self._row_pages[slot]
-                      if self._page_rc[pid] == 1 and pid not in protect)
-            headroom = len(self._free_pages) + own
-            if headroom < need and self.prefix_cache is not None:
-                headroom += self.prefix_cache.collectable(
-                    lambda pid: self._page_rc[pid] == 1, protect=protect)
-            if headroom < need:
-                raise RuntimeError(
-                    f"insufficient free pages for a {length}-token prompt "
-                    f"({need} needed, {len(self._free_pages)} free); release "
-                    "slots or raise num_pages")
-            w = self._window
-            if w is not None and (len(w.free) + len(w.rows[slot])
-                                  < w.needed(length)):
-                raise RuntimeError(
-                    f"insufficient free pages in the window group for a "
-                    f"{length}-token prompt ({w.needed(length)} needed, "
-                    f"{len(w.free)} free); release slots or raise its "
-                    "num_pages")
-            self._reclaim_row(slot)  # previous occupant's pages, if any
-            self._pending_clear.discard(slot)  # the new row replaces it
-            self.page_exhausted[slot] = False
-            short = need - len(self._free_pages)
-            if short > 0:
-                self._evict_prefix(short, protect=protect)
-            for pid in adopt:  # adopted prefix: refcount bump, no compute
-                self._page_rc[pid] += 1
-            fresh = []
-            for _ in range(need):
-                pid = self._free_pages.popleft()
-                self._page_rc[pid] = 1
-                fresh.append(pid)
-            pages = adopt + fresh
-            self._row_pages[slot] = list(pages)
-            if need:
-                _obs.counter("gen_page_allocs_total",
-                             "pages taken from the free pool").inc(
-                                 need, site="prefill")
-            if start:
-                _obs.counter("gen_prefix_hits_total",
-                             "prefills that adopted a cached prefix").inc()
-                _obs.counter("gen_prefix_hit_tokens",
-                             "prompt tokens served from the prefix "
-                             "cache").inc(int(start))
-            self._page_gauges()
-            if tail_src:
-                # the copy must land before the prefill dispatch writes
-                # the suffix into the same page
-                self._dispatch_cow([(slot, len(adopt), tail_src, fresh[0])])
-            padded = np.full((1, bucket), self.pad_id, np.int32)
-            padded[0, :suffix] = prompt[start:]
-            new_row = np.zeros(self._n_row_pages, np.int32)
-            new_row[:total] = pages
-            if w is not None:  # one row a group
-                new_row = self._by_group(new_row, w.admit(slot, length))
-                self._page_gauges()
-            self._note_program(("prefill", bucket), "prefill_bucket")
-            start_v = jnp.full((1,), start, jnp.int32)
-            if self.speculative:
-                carry = (self.page_table, self.pools, self.draft_pools)
-                carry, tok, last = self._prefill_jit(
-                    self._params(), self._draft_params(), carry,
-                    jnp.asarray(padded), jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(suffix, jnp.int32), jnp.asarray(new_row),
-                    start_v, self._next_key())
-                self.page_table, self.pools, self.draft_pools = carry
+        self._prefill_calls += 1
+        # the always-on record of this call (obs.step_records("prefill")):
+        # host clock marks around the statements as they stand, and host
+        # integers the code holds anyway; nothing of the device is read
+        with _obs.step_record("prefill", self._prefill_calls,
+                              name="mx.gen.prefill") as rec:
+            if self.paged:
+                with _obs.span("mx.gen.prefill.pages"):
+                    if length >= self.max_length:
+                        raise ValueError(
+                            f"prompt length {length} >= max_length="
+                            f"{self.max_length}")
+                    ps = self.page_size
+                    total = self.pages_for(length)
+                    # prefix adoption: walk the radix cache for the longest
+                    # cached page run, keeping >= 1 suffix token so this
+                    # prefill still produces the last-prompt-position
+                    # logits (the TTFT sample)
+                    adopt: List[int] = []
+                    tail_src = 0
+                    start = 0
+                    if self.prefix_cache is not None:
+                        cpages, mtok = self.prefix_cache.lookup(
+                            prompt.tolist())
+                        start = min(mtok, length - 1)
+                        adopt = cpages[:start // ps]
+                        if start % ps:
+                            # adoption ends inside a cached page: CoW-copy
+                            # it into a private page — stale positions past
+                            # `start` stay frontier-masked until the suffix
+                            # overwrites them
+                            tail_src = cpages[start // ps]
+                    suffix = length - start
+                    bucket = self.bucket_for(suffix)
+                    need = total - len(adopt)
+                    # capacity check BEFORE any allocator mutation: a
+                    # failed admission must leave the slot's pending
+                    # table-clear (and its reclaimable pages) untouched, or
+                    # a released row's stale device table could keep
+                    # pointing at pages later handed to someone else (its
+                    # masked writes would corrupt them). Pages being
+                    # adopted are off-limits to the eviction headroom.
+                    protect = set(adopt)
+                    if tail_src:
+                        protect.add(tail_src)
+                    own = sum(1 for pid in self._row_pages[slot]
+                              if self._page_rc[pid] == 1
+                              and pid not in protect)
+                    headroom = len(self._free_pages) + own
+                    if headroom < need and self.prefix_cache is not None:
+                        headroom += self.prefix_cache.collectable(
+                            lambda pid: self._page_rc[pid] == 1,
+                            protect=protect)
+                    if headroom < need:
+                        raise RuntimeError(
+                            f"insufficient free pages for a {length}-token "
+                            f"prompt ({need} needed, "
+                            f"{len(self._free_pages)} free); release "
+                            "slots or raise num_pages")
+                    w = self._window
+                    if w is not None and (len(w.free) + len(w.rows[slot])
+                                          < w.needed(length)):
+                        raise RuntimeError(
+                            f"insufficient free pages in the window group "
+                            f"for a {length}-token prompt "
+                            f"({w.needed(length)} needed, {len(w.free)} "
+                            "free); release slots or raise its num_pages")
+                    # previous occupant's pages, if any
+                    self._reclaim_row(slot)
+                    # the new row replaces it
+                    self._pending_clear.discard(slot)
+                    self.page_exhausted[slot] = False
+                    short = need - len(self._free_pages)
+                    if short > 0:
+                        self._evict_prefix(short, protect=protect)
+                    # adopted prefix: refcount bump, no compute
+                    for pid in adopt:
+                        self._page_rc[pid] += 1
+                    fresh = []
+                    for _ in range(need):
+                        pid = self._free_pages.popleft()
+                        self._page_rc[pid] = 1
+                        fresh.append(pid)
+                    pages = adopt + fresh
+                    self._row_pages[slot] = list(pages)
+                    if need:
+                        _obs.counter("gen_page_allocs_total",
+                                     "pages taken from the free pool").inc(
+                                         need, site="prefill")
+                    if start:
+                        _obs.counter(
+                            "gen_prefix_hits_total",
+                            "prefills that adopted a cached prefix").inc()
+                        _obs.counter("gen_prefix_hit_tokens",
+                                     "prompt tokens served from the prefix "
+                                     "cache").inc(int(start))
+                    self._page_gauges()
+                    if tail_src:
+                        # the copy must land before the prefill dispatch
+                        # writes the suffix into the same page
+                        self._dispatch_cow(
+                            [(slot, len(adopt), tail_src, fresh[0])])
+                    padded = np.full((1, bucket), self.pad_id, np.int32)
+                    padded[0, :suffix] = prompt[start:]
+                    new_row = np.zeros(self._n_row_pages, np.int32)
+                    new_row[:total] = pages
+                    if w is not None:  # one row a group
+                        new_row = self._by_group(new_row,
+                                                 w.admit(slot, length))
+                        self._page_gauges()
+                    rec.counts = {"bucket": bucket, "suffix": suffix,
+                                  "prompt": length, "pages": need,
+                                  "adopted": len(adopt)}
+                    rec.compiled = self._note_program(
+                        ("prefill", bucket), "prefill_bucket")
+                with _obs.span("mx.gen.prefill.dispatch"):
+                    start_v = jnp.full((1,), start, jnp.int32)
+                    if self.speculative:
+                        carry = (self.page_table, self.pools,
+                                 self.draft_pools)
+                        carry, tok, last = self._prefill_jit(
+                            self._params(), self._draft_params(), carry,
+                            jnp.asarray(padded),
+                            jnp.asarray(slot, jnp.int32),
+                            jnp.asarray(suffix, jnp.int32),
+                            jnp.asarray(new_row), start_v,
+                            self._next_key())
+                        self.page_table, self.pools, self.draft_pools = \
+                            carry
+                    else:
+                        carry, tok, last = self._prefill_jit(
+                            self._params(), (self.page_table, self.pools),
+                            jnp.asarray(padded),
+                            jnp.asarray(slot, jnp.int32),
+                            jnp.asarray(suffix, jnp.int32),
+                            self._vectors(new_row), start_v,
+                            self._next_key())
+                        self.page_table, self.pools = carry
             else:
-                carry, tok, last = self._prefill_jit(
-                    self._params(), (self.page_table, self.pools),
-                    jnp.asarray(padded), jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(suffix, jnp.int32), self._vectors(new_row),
-                    start_v, self._next_key())
-                self.page_table, self.pools = carry
-        else:
-            bucket = self.bucket_for(length)
-            padded = np.full((1, bucket), self.pad_id, np.int32)
-            padded[0, :length] = prompt
-            self._note_program(("prefill", bucket), "prefill_bucket")
-            cache, tok, last = self._prefill_jit(
-                self._params(), self.cache, jnp.asarray(padded),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(length, jnp.int32),
-                self._next_key())
-            self.cache = cache
-        tok = int(tok)  # host sync: the first token is ready here
-        self._row_epoch += 1
-        self.positions[slot] = length
-        self.last_tokens[slot] = tok
-        self.done[slot] = (self.eos_id is not None and tok == self.eos_id)
-        if self.paged:
-            self._prefill_logits[slot] = last
-            if self.prefix_cache is not None:
-                # index this prompt's full pages so later prompts sharing
-                # the prefix adopt them (newly indexed pages gain a cache
-                # reference; already-cached prefixes are kept as-is)
-                for pid in self.prefix_cache.insert(prompt.tolist(),
-                                                    self._row_pages[slot]):
-                    self._page_rc[pid] += 1
-                self._page_gauges()
+                with _obs.span("mx.gen.prefill.pages"):
+                    bucket = self.bucket_for(length)
+                    padded = np.full((1, bucket), self.pad_id, np.int32)
+                    padded[0, :length] = prompt
+                    rec.counts = {"bucket": bucket, "suffix": length,
+                                  "prompt": length, "pages": 0,
+                                  "adopted": 0}
+                    rec.compiled = self._note_program(
+                        ("prefill", bucket), "prefill_bucket")
+                with _obs.span("mx.gen.prefill.dispatch"):
+                    cache, tok, last = self._prefill_jit(
+                        self._params(), self.cache, jnp.asarray(padded),
+                        jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(length, jnp.int32), self._next_key())
+                    self.cache = cache
+            with _obs.span("mx.gen.prefill.read"):
+                tok = int(tok)  # host sync: the first token is ready here
+            with _obs.span("mx.gen.prefill.index"):
+                self._row_epoch += 1
+                self.positions[slot] = length
+                self.last_tokens[slot] = tok
+                self.done[slot] = (self.eos_id is not None
+                                   and tok == self.eos_id)
+                if self.paged:
+                    self._prefill_logits[slot] = last
+                    if self.prefix_cache is not None:
+                        # index this prompt's full pages so later prompts
+                        # sharing the prefix adopt them (newly indexed
+                        # pages gain a cache reference; already-cached
+                        # prefixes are kept as-is)
+                        for pid in self.prefix_cache.insert(
+                                prompt.tolist(), self._row_pages[slot]):
+                            self._page_rc[pid] += 1
+                        self._page_gauges()
         if _obs.enabled():
             _obs.histogram("gen_prefill_seconds", "prompt prefill wall clock",
-                           unit="s").observe(time.perf_counter() - t0,
+                           unit="s").observe(1e-9 * rec.duration_ns,
                                              bucket=bucket)
         self._last_logits = last
         return tok
@@ -1508,7 +1552,6 @@ class GenerationEngine:
 
     def _plain_decode_step(self, ahead=False):
         _faults.fire("gen.decode")
-        t0 = time.perf_counter()
         self._decode_calls += 1
         # the always-on record of this call (obs.step_records("decode_step")):
         # host clock marks, and the model's own counts of the step, which
@@ -1553,10 +1596,9 @@ class GenerationEngine:
             self._ahead[1] = (self._row_epoch, positions.copy(), done.copy(),
                               tok.copy())
         if _obs.enabled():
-            dt = time.perf_counter() - t0
             _obs.histogram("gen_decode_step_seconds",
                            "one compiled decode step wall clock",
-                           unit="s").observe(dt)
+                           unit="s").observe(1e-9 * rec.duration_ns)
             # slot utilization of this step: fraction of the static batch
             # that decoded real tokens (the fleet report's serving rollup)
             _obs.gauge("gen_slot_utilization",
@@ -1612,8 +1654,11 @@ class GenerationEngine:
         if tokens is None:
             tokens = self.last_tokens
         if self.paged:
-            upd_slots, upd_pages = self._grow_pages(0)
-            clear = self._take_clear_mask()
+            # the allocator's part of the step's host time, apart from the
+            # arguments' hand-over and the call (the span below)
+            with _obs.span("mx.gen.decode.pages"):
+                upd_slots, upd_pages = self._grow_pages(0)
+                clear = self._take_clear_mask()
             active_in = ~self.done  # exhaustion may have finished rows
             if self.speculative:
                 # the spec engine compiled draft+verify, not a single-token

@@ -210,7 +210,8 @@ class StepRecord(NamedTuple):
     are contiguous by construction (one's end is the next one's start), so
     their durations sum to the call's."""
 
-    loop: str                            # "train_step" / "run_window" / "decode_step"
+    #: "train_step" / "run_window" / "decode_step" / "serve_step" / "prefill"
+    loop: str
     step: int                            # optimizer.num_update once it ran
     t0_ns: int
     marks: Tuple[Tuple[str, int], ...]   # (span name, end ns)
@@ -232,11 +233,15 @@ class StepRecord(NamedTuple):
         return out
 
 
-#: how many calls the ring remembers (a 50 s benchmark window of BERT-large
-#: is some 400 steps; at 1 ms a step this is still the last four seconds)
+#: how many calls a loop's ring remembers (a 50 s benchmark window of
+#: BERT-large is some 400 steps; at 1 ms a step this is still the last four
+#: seconds)
 STEP_RECORDS_KEPT = 4096
-_records: "collections.deque[StepRecord]" = collections.deque(
-    maxlen=STEP_RECORDS_KEPT)
+#: a ring a loop name: nothing a record of one loop does evicts another's (a
+#: ``serve_step`` and a ``prefill`` record beside every ``decode_step`` one
+#: would else halve what the decode records' readers see of a window)
+_records: "dict[str, collections.deque[StepRecord]]" = {}
+_records_lock = threading.Lock()  # a ring's creation; appends need none
 _open = threading.local()  # .rec: the step_record this thread is inside
 _annotation = None
 
@@ -251,10 +256,15 @@ def _trace_annotation():
 
 
 def step_records(loop: Optional[str] = None) -> list:
-    """The ring's records, oldest first (those of ``loop`` if given). It
-    belongs to the process, not to the object that wrote it: it is read
-    after a ``TrainStep`` is gone."""
-    return [r for r in list(_records) if loop is None or r.loop == loop]
+    """The records of ``loop``'s ring, oldest first; of every loop, merged
+    by ``t0_ns``, where none is named (a record that nests in another, as a
+    ``prefill`` in a ``serve_step``, follows it). The rings belong to the
+    process, not to the object that wrote them: they are read after a
+    ``TrainStep`` is gone."""
+    if loop is not None:
+        return list(_records.get(loop, ()))
+    return sorted((r for ring in list(_records.values()) for r in list(ring)),
+                  key=lambda r: r.t0_ns)
 
 
 class step_record:
@@ -273,6 +283,11 @@ class step_record:
         self.loop, self.step, self.name = loop, int(step), name
         self.marks, self.compiled, self.counts = [], False, None
 
+    @property
+    def duration_ns(self) -> int:
+        """Entry to the end of the last span closed so far."""
+        return (self.marks[-1][1] if self.marks else self.t0) - self.t0
+
     def __enter__(self):
         self._ann = _trace_annotation()(self.name, step=self.step)
         self._ann.__enter__()
@@ -284,9 +299,14 @@ class step_record:
     def __exit__(self, *exc):
         _open.rec = self._outer
         self._ann.__exit__(*exc)
-        _records.append(StepRecord(self.loop, self.step, self.t0,
-                                   tuple(self.marks), self.compiled,
-                                   self.counts))
+        ring = _records.get(self.loop)
+        if ring is None:  # a loop's first record
+            with _records_lock:
+                ring = _records.setdefault(
+                    self.loop, collections.deque(maxlen=STEP_RECORDS_KEPT))
+        ring.append(StepRecord(self.loop, self.step, self.t0,
+                               tuple(self.marks), self.compiled,
+                               self.counts))
         return False
 
 

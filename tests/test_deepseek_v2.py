@@ -122,7 +122,8 @@ def test_prefill_then_decode_through_the_latent_cache_matches_the_reference(mode
     record = obs.step_records("decode_step")[-1]
     assert set(record.counts) == {"moe_pairs_held", "moe_max_load"}
     assert len(record.counts["moe_pairs_held"]) == 2
-    assert [m for m, _ in record.marks] == ["mx.gen.decode.dispatch",
+    assert [m for m, _ in record.marks] == ["mx.gen.decode.pages",
+                                            "mx.gen.decode.dispatch",
                                             "mx.gen.decode.read"]
 
 
@@ -398,7 +399,8 @@ def test_a_step_dispatched_ahead_is_dropped_when_a_row_changes_hands(model):
     assert count.value(outcome="dropped") == before[0] + 1
     assert count.value(outcome="used") == before[1] + 3 + 4
     marks = [m for m, _ in obs.step_records("decode_step")[-2].marks]
-    assert marks == ["mx.gen.decode.ahead", "mx.gen.decode.read"]
+    assert marks == ["mx.gen.decode.pages", "mx.gen.decode.ahead",
+                     "mx.gen.decode.read"]
 
 
 def test_an_engine_that_may_stop_a_row_itself_never_decodes_ahead(model):

@@ -2,7 +2,6 @@
 record", "Named scopes", "The lag of telemetry-on readings"): the always-on
 record of ``TrainStep.__call__``'s host phases, the named scopes on its
 device operations, and telemetry that reads step n some dispatches later."""
-import collections
 import contextlib
 import gc
 import re
@@ -94,8 +93,10 @@ def test_the_ring_is_bounded_and_survives_the_step_object(monkeypatch):
     del ts
     gc.collect()
     assert len(_mine(before)) == 1  # read after the TrainStep is gone
-    assert obs._records.maxlen == obs.STEP_RECORDS_KEPT >= 1024
-    monkeypatch.setattr(obs, "_records", collections.deque(maxlen=8))
+    # a ring a loop name (PR 38), each of STEP_RECORDS_KEPT
+    assert obs._records["train_step"].maxlen == obs.STEP_RECORDS_KEPT >= 1024
+    monkeypatch.setattr(obs, "_records", {})
+    monkeypatch.setattr(obs, "STEP_RECORDS_KEPT", 8)
     for i in range(13):
         with obs.step_record("ring_test", i):
             pass
