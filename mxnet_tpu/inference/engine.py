@@ -70,7 +70,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from collections import deque
+from collections import OrderedDict
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -81,6 +81,7 @@ from .. import observability as _obs
 from ..gluon.block import _HybridTrace
 from ..ndarray import NDArray
 from ..ops import random_ops as _rops
+from ..ops.pallas_paged_attention import RUN_PAGES
 from ..resilience import faults as _faults
 from ..resilience import retry as _retry
 from .prefix_cache import RadixPrefixCache
@@ -118,6 +119,126 @@ def _default_buckets(max_length: int) -> Tuple[int, ...]:
     return tuple(out) or (max_length - 1,)
 
 
+class _FreePages:
+    """The free pages of one pool, ids ``1 .. num_pages`` (0 is the trash
+    page), kept as aligned CHUNKS of ``RUN_PAGES`` ids so that a row's pages
+    stay side by side in a served pool: the decode kernel fetches
+    ``RUN_PAGES`` logically consecutive pages whose ids are consecutive as
+    one copy (``ops/pallas_paged_attention.py``). Every free page can be
+    taken and none is held back; the one rule is a PREFERENCE, told by the
+    taker: ``take(after, head)`` gives logical page ``s`` the id next to
+    page ``s - 1``'s (``after``) where that id is free, and where ``s``
+    starts a group of ``RUN_PAGES`` (``head``) the first id of a wholly
+    free chunk (the neighbour chunk's before any other), so the group can
+    fill that chunk id by id. A taker that finds neither takes from the
+    partly free chunks, and from a whole one last: fragments are used up
+    before a whole chunk is broken, and a chunk is whole again when its
+    last page comes back. O(1) a page: a count a chunk and two ordered
+    sets of chunks."""
+
+    def __init__(self, num_pages: int):
+        self.chunk, self.num_pages = RUN_PAGES, int(num_pages)
+        self._is_free = bytearray([0]) + bytearray([1]) * self.num_pages \
+            + bytearray([0])  # by id; the trash page and an end stop
+        whole, rest = divmod(self.num_pages, self.chunk)
+        #: free ids a chunk (chunk c holds ids c * chunk + 1 ...)
+        self._count = [self.chunk] * whole + [rest] * bool(rest)
+        #: chunks wholly free, and chunks partly free (a short last chunk
+        #: is never whole), oldest first
+        self._whole = OrderedDict.fromkeys(range(whole))
+        self._partial = OrderedDict.fromkeys(range(whole, whole + bool(rest)))
+        self._len = self.num_pages
+
+    def __len__(self) -> int:
+        return self._len
+
+    def __iter__(self):
+        return (pid for pid in range(1, self.num_pages + 1)
+                if self._is_free[pid])
+
+    def _take_id(self, pid: int) -> int:
+        c = (pid - 1) // self.chunk
+        self._is_free[pid] = 0
+        self._count[c] -= 1
+        self._len -= 1
+        was_whole = self._whole.pop(c, 0) is None
+        if not self._count[c]:
+            self._partial.pop(c, None)
+        elif was_whole:
+            self._partial[c] = None
+        return pid
+
+    def give(self, pid: int) -> None:
+        """``pid`` comes back (its last reference is gone)."""
+        c = (pid - 1) // self.chunk
+        self._is_free[pid] = 1
+        self._count[c] += 1
+        self._len += 1
+        if self._count[c] == self.chunk:
+            self._partial.pop(c, None)
+            self._whole[c] = None
+        else:
+            self._partial[c] = None
+
+    def take(self, after: int = 0, head: bool = True) -> int:
+        """One free page for the logical page behind the one that holds id
+        ``after`` (0: the row has none there); ``head``: the page starts a
+        group of ``chunk`` logical pages. The caller has seen ``len(self)
+        > 0``."""
+        nxt = after + 1 if after else 0  # id 0 is never free
+        if self._is_free[nxt]:
+            if not head or (after % self.chunk == 0
+                            and (nxt - 1) // self.chunk in self._whole):
+                return self._take_id(nxt)
+        if head and self._whole:
+            return self._take_id(next(iter(self._whole)) * self.chunk + 1)
+        if self._is_free[nxt]:
+            return self._take_id(nxt)
+        c = next(iter(self._partial or self._whole))
+        return self._take_id(self._is_free.index(1, c * self.chunk + 1))
+
+    def take_row(self, n: int, first: int = 0, after: int = 0) -> List[int]:
+        """``n`` pages for a row's logical pages ``first .. first + n - 1``
+        behind the page that holds ``after`` (a prefill's): what ``take``
+        gives page by page, a whole group's chunk taken at once."""
+        out, s, end, g = [], first, first + n, self.chunk
+        while s < end:
+            if s % g or end - s < g or not self._whole:
+                after = self.take(after, s % g == 0)
+                out.append(after)
+                s += 1
+                continue
+            c = after // g   # the neighbour chunk, if ``after`` ends its own
+            if not after or after % g or c not in self._whole:
+                c = next(iter(self._whole))
+            del self._whole[c]
+            self._count[c] = 0
+            self._len -= g
+            self._is_free[c * g + 1:c * g + g + 1] = bytes(g)
+            out.extend(range(c * g + 1, c * g + g + 1))
+            after = c * g + g
+            s += g
+        return out
+
+
+def _is_run(ids) -> bool:
+    """Whether a whole group's page ids, in logical order (None: a page the
+    row does not hold), are consecutive: what the decode kernel fetches as
+    one copy."""
+    return bool(ids[0]) and ids == list(range(ids[0], ids[0] + len(ids)))
+
+
+def _tally_run(runs: set, k: int, ids) -> int:
+    """Keep group ``k`` in a row's ``runs`` exactly while ``ids`` (the
+    group's pages as the row holds them now) are a whole run; returns the
+    change of the count of runs (``gen_page_run_share``)."""
+    is_run = len(ids) == RUN_PAGES and _is_run(ids)
+    if is_run == (k in runs):
+        return 0
+    runs.symmetric_difference_update((k,))
+    return 1 if is_run else -1
+
+
 class _WindowPages:
     """The host allocator of a ``window`` page group: the pools of layers
     that attend only the last ``window`` positions. A row holds the pages
@@ -133,9 +254,13 @@ class _WindowPages:
         self.num_pages, self.page_size = int(num_pages), int(page_size)
         self.window = int(window)
         self.columns = self.window // self.page_size + 3
-        self.free: deque = deque(range(1, self.num_pages + 1))
+        self.free = _FreePages(self.num_pages)
         #: per row {logical page: page id}
         self.rows: List[dict] = [{} for _ in range(batch_size)]
+        #: per row, the groups of RUN_PAGES logical pages it holds whole
+        #: on consecutive ids (``gen_page_run_share``); their count
+        self.runs: List[set] = [set() for _ in range(batch_size)]
+        self.n_runs = 0
         self.reserved = 0  # free pages held back for a parked queue head
         self.freed_total = 0
 
@@ -155,18 +280,28 @@ class _WindowPages:
     def release(self, slot: int) -> int:
         pages = self.rows[slot]
         self.rows[slot] = {}
-        self.free.extend(pages.values())
+        self.n_runs -= len(self.runs[slot])
+        self.runs[slot] = set()
+        for pid in pages.values():
+            self.free.give(pid)
         return len(pages)
+
+    def _note_group(self, slot: int, k: int) -> None:
+        """Count group ``k`` of row ``slot`` as a run, or no longer."""
+        held = self.rows[slot]
+        self.n_runs += _tally_run(self.runs[slot], k, [
+            held.get(s) for s in range(k * RUN_PAGES, (k + 1) * RUN_PAGES)])
 
     def admit(self, slot: int, length: int) -> np.ndarray:
         """Give row ``slot`` the pages a ``length``-token prompt keeps (the
         caller has checked that they are there); returns its table row."""
         row = np.zeros(self.columns, np.int32)
-        held = {}
-        for s in range(self.low_page(length),
-                       (length - 1) // self.page_size + 1):
-            held[s] = row[s % self.columns] = self.free.popleft()
-        self.rows[slot] = held
+        first, end = self.low_page(length), (length - 1) // self.page_size + 1
+        ids = self.free.take_row(end - first, first)
+        self.rows[slot] = dict(zip(range(first, end), ids))
+        row[np.arange(first, end) % self.columns] = ids
+        for k in range(first // RUN_PAGES, (end - 1) // RUN_PAGES + 1):
+            self._note_group(slot, k)
         return row
 
     def step(self, slot: int, position: int):
@@ -176,15 +311,19 @@ class _WindowPages:
         column)], or None where the pool (less its reservation) is dry."""
         held, updates = self.rows[slot], []
         for s in [s for s in held if s < self.low_page(position)]:
-            self.free.append(held.pop(s))
+            self.free.give(held.pop(s))
+            self._note_group(slot, s // RUN_PAGES)
             updates.append((s % self.columns, -1))
             self.freed_total += 1
         page = position // self.page_size
         if page not in held:
             if len(self.free) - self.reserved <= 0:
                 return None
-            held[page] = self.free.popleft()
+            held[page] = self.free.take(held.get(page - 1, 0),
+                                        page % RUN_PAGES == 0)
             updates.append((page % self.columns, held[page]))
+            if (page + 1) % RUN_PAGES == 0:  # the group it completes
+                self._note_group(slot, page // RUN_PAGES)
         return updates
 
 
@@ -402,9 +541,13 @@ class GenerationEngine:
             self.cache = None  # dense-only state
             # host allocator (authoritative; the device table mirrors it
             # through compiled update vectors shipped with each program)
-            self._free_pages: deque = deque(range(1, self.num_pages + 1))
+            self._free_pages = _FreePages(self.num_pages)
             self._row_pages: List[List[int]] = \
                 [[] for _ in range(self.batch_size)]
+            #: per row, the whole groups of RUN_PAGES logical pages whose
+            #: ids are consecutive (``gen_page_run_share``); their count
+            self._row_runs: List[set] = [set() for _ in range(self.batch_size)]
+            self._n_runs = 0
             self._pending_clear: set = set()
             #: pages the batcher's aging guard holds back from decode-time
             #: growth for a parked queue head (docs/RESILIENCE.md)
@@ -690,14 +833,25 @@ class GenerationEngine:
                        self._reserved_pages)
 
     def _page_gauges(self):
-        free = len(self._free_pages)
+        free, w = len(self._free_pages), self._window
         _obs.gauge("gen_pages_free",
                    "free pages in the paged KV pool").set(free)
         in_use = _obs.gauge("gen_pages_in_use",
                             "allocated pages in the paged KV pool")
         in_use.set(self.num_pages - free)
-        for g, of in self.page_groups.items():  # and one series a pool group
-            in_use.set(of["in_use"], group=g)
+        share = _obs.gauge("gen_page_run_share",
+                           "share of the pages the rows hold that lie in a "
+                           "whole group of RUN_PAGES logical pages with "
+                           "consecutive ids: what the decode kernel fetches "
+                           "as one copy")
+        # and one series a pool group: (pages in use, whole groups that are
+        # runs, pages the rows hold), from the allocators' own counts
+        for g, (used, runs, held) in zip(self._group_names, self._by_group(
+                (self.num_pages - free, self._n_runs,
+                 sum(map(len, self._row_pages))),
+                w and (w.in_use, w.n_runs, w.in_use))):
+            in_use.set(used, group=g)
+            share.set(RUN_PAGES * runs / held if held else 0.0, group=g)
         _obs.gauge("gen_page_refcount_max",
                    "highest per-page refcount (sharing depth)").set(
                        int(self._page_rc.max()) if self.num_pages else 0)
@@ -711,7 +865,7 @@ class GenerationEngine:
             self._page_rc[pid] -= 1
             if self._page_rc[pid] <= 0:
                 self._page_rc[pid] = 0
-                self._free_pages.append(pid)
+                self._free_pages.give(pid)
                 freed += 1
         return freed
 
@@ -722,6 +876,8 @@ class GenerationEngine:
         if not pages:
             return 0
         self._row_pages[slot] = []
+        self._n_runs -= len(self._row_runs[slot])
+        self._row_runs[slot] = set()
         freed = self._unref_pages(pages)
         if freed:
             _obs.counter("gen_pages_reclaimed_total",
@@ -750,15 +906,25 @@ class GenerationEngine:
             self._page_gauges()
         return len(evicted)
 
-    def _take_page(self) -> int:
-        """One page off the free list (refcount 1), LRU-evicting prefix
-        cache entries under pressure. Returns 0 (the trash page id —
+    def _take_page(self, row: int, s: int) -> int:
+        """One free page (refcount 1) for logical page ``s`` of ``row``,
+        beside page ``s - 1`` where the free pages allow, LRU-evicting
+        prefix cache entries under pressure. Returns 0 (the trash page id —
         never allocated) when nothing can be freed."""
         if self._avail() <= 0 and not self._evict_prefix(1):
             return 0
-        pid = self._free_pages.popleft()
+        pages = self._row_pages[row]
+        pid = self._free_pages.take(pages[s - 1] if s else 0,
+                                    s % RUN_PAGES == 0)
         self._page_rc[pid] = 1
         return pid
+
+    def _note_group(self, row: int, k: int) -> None:
+        """Count group ``k`` of ``row`` (logical pages ``k * RUN_PAGES
+        ...``) as a run, or no longer, by the ids it holds now."""
+        self._n_runs += _tally_run(
+            self._row_runs[row], k,
+            self._row_pages[row][k * RUN_PAGES:(k + 1) * RUN_PAGES])
 
     def _grow_pages(self, window: int):
         """Allocate pages so every active row's table covers positions
@@ -796,7 +962,7 @@ class GenerationEngine:
                 pid = self._row_pages[row][s]
                 if self._page_rc[pid] <= 1:
                     continue
-                new = self._take_page()
+                new = self._take_page(row, s)
                 if not new:
                     short = True
                     break
@@ -804,21 +970,25 @@ class GenerationEngine:
                 copies.append((row, s, pid, new))
                 self._page_rc[pid] -= 1
                 self._row_pages[row][s] = new
+                self._note_group(row, s // RUN_PAGES)
             if short:
                 # a shared page it cannot copy = a write it cannot make
                 _evict_row(row)
                 continue
             u = 0
             while len(self._row_pages[row]) < need:
-                pid = self._take_page()
+                s = len(self._row_pages[row])
+                pid = self._take_page(row, s)
                 if not pid:
-                    if len(self._row_pages[row]) * ps <= p:
+                    if s * ps <= p:
                         # cannot write the next token: evict the row
                         _evict_row(row)
                     break
-                upd_slots[row, u] = len(self._row_pages[row])
+                upd_slots[row, u] = s
                 upd_pages[row, u] = pid
                 self._row_pages[row].append(pid)
+                if s % RUN_PAGES == RUN_PAGES - 1:
+                    self._note_group(row, s // RUN_PAGES)
                 u += 1
                 allocated += 1
         grown = self._grow_window(_evict_row)
@@ -1419,13 +1589,13 @@ class GenerationEngine:
                     # adopted prefix: refcount bump, no compute
                     for pid in adopt:
                         self._page_rc[pid] += 1
-                    fresh = []
-                    for _ in range(need):
-                        pid = self._free_pages.popleft()
-                        self._page_rc[pid] = 1
-                        fresh.append(pid)
+                    fresh = self._free_pages.take_row(
+                        need, len(adopt), adopt[-1] if adopt else 0)
+                    self._page_rc[fresh] = 1
                     pages = adopt + fresh
                     self._row_pages[slot] = list(pages)
+                    for k in range(total // RUN_PAGES):
+                        self._note_group(slot, k)
                     if need:
                         _obs.counter("gen_page_allocs_total",
                                      "pages taken from the free pool").inc(
@@ -2064,6 +2234,8 @@ class GenerationEngine:
         for pid in pages:
             self._page_rc[pid] += 1
         self._row_pages[dst] = pages
+        self._row_runs[dst] = set(self._row_runs[src])
+        self._n_runs += len(self._row_runs[dst])
         row = np.zeros(self._n_row_pages, np.int32)
         row[:len(pages)] = pages
         # eager device-table install: forks happen at admission

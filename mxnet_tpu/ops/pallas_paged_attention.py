@@ -614,6 +614,10 @@ def paged_index_scores(idx_q, idx_w, pool, page_table, position,
 # grouped key-value heads, a window or none (one token a row)
 # --------------------------------------------------------------------------
 _GQA_BLOCK = 512   # positions fetched and attended at once: a block of pages
+#: pages fetched by ONE copy where their pool ids are consecutive (a run);
+#: the page allocator (``inference/engine.py``) hands ids out in aligned
+#: chunks of as many, so that a served pool keeps its rows in runs
+RUN_PAGES = 8
 _NEG = -1e30       # a masked score; finite, so that exp(_NEG - m) is 0 and
 # never NaN (every row's first block holds a position it sees)
 
@@ -667,9 +671,26 @@ def _gqa_pages(position, lower, ps, columns, ring):
     return jnp.clip(lower // ps, 0, last), last
 
 
+def _mark_runs(table, g, ring):
+    """``table`` ``(B, columns)`` with the id NEGATED in every column where a
+    run starts: ``g`` logically consecutive pages whose pool ids are
+    consecutive (``table[c + i] == table[c] + i`` for ``i < g``; in a ring
+    the columns wrap, in a table in order a run ends with the table). The
+    trash page 0 is never part of a run. What a paged kernel reads its
+    larger copies from: the flag rides in the scalars it already loads."""
+    columns = table.shape[1]
+    run = table > 0
+    for i in range(1, g):
+        ok = jnp.roll(table, -i, axis=1) == table + i
+        if not ring:
+            ok &= jnp.arange(columns) + i < columns
+        run &= ok
+    return jnp.where(run, -table, table)
+
+
 def _gqa_kernel(table_ref, pos_ref, low_ref, slot_ref, q_ref, kp_ref, vp_ref,
                 o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, ps, columns,
-                nb, ring, scale):
+                nb, run, ring, scale):
     b, rows = pl.program_id(0), pl.num_programs(0)
     hkv, r, ch = q_ref.shape[1:]
     blk = nb * ps
@@ -677,24 +698,58 @@ def _gqa_kernel(table_ref, pos_ref, low_ref, slot_ref, q_ref, kp_ref, vp_ref,
     def pages(row):
         return _gqa_pages(pos_ref[row], low_ref[row], ps, columns, ring)
 
-    def for_each_copy(row, j, into, act):
-        """``act`` on the copies of block ``j`` of ``row``: its pages, by
-        the ids the table's columns hold; returns how many there are."""
+    def extent(row, j):
+        """Block ``j`` of ``row``: its first logical page and how many."""
         first, last = pages(row)
         start = first + j * nb
-        n = jnp.clip(last - start + 1, 0, nb)
+        return start, jnp.clip(last - start + 1, 0, nb)
+
+    def for_each_copy(row, j, into, act):
+        """``act`` on the copies of block ``j`` of ``row``: its pages, by
+        the ids the table's columns hold. The block's whole groups of
+        ``run`` logical pages (``s % run == 0`` ...: the allocator's chunks)
+        go as ONE copy each where the table marks a run (the pools are seen
+        as rows: a run's pages are neighbours there), every other page as a
+        copy of its own."""
+        start, n = extent(row, j)
+
+        def copies(pid, p, count):
+            at = pl.ds(pl.multiple_of(p * ps, ps), count * ps)
+            rows_ = pl.ds(pl.multiple_of(pid * ps, ps), count * ps)
+            for pool, buf in ((kp_ref, kbuf), (vp_ref, vbuf)):
+                act(pltpu.make_async_copy(pool.at[rows_], buf.at[into, at, :],
+                                          sem.at[into]))
+
+        def column(p):
+            s = start + p
+            return table_ref[row * columns + (s % columns if ring else s)]
 
         def page(p, carry):
-            s = start + p
-            pid = table_ref[row * columns + (s % columns if ring else s)]
-            at = pl.ds(pl.multiple_of(p * ps, ps), ps)
-            for pool, buf in ((kp_ref, kbuf), (vp_ref, vbuf)):
-                act(pltpu.make_async_copy(pool.at[pid], buf.at[into, at, :],
-                                          sem.at[into]))
+            copies(jnp.abs(column(p)), p, 1)
             return carry
 
-        lax.fori_loop(0, n, page, 0)
-        return start, n
+        # pages before the block's first whole group
+        head = jnp.minimum((run - start % run) % run, n)
+        groups = (n - head) // run
+
+        def group(g, carry):
+            p = head + g * run
+            pid = column(p)
+
+            @pl.when(pid < 0)
+            def _():
+                copies(-pid, p, run)
+
+            @pl.when(pid >= 0)
+            def _():
+                for i in range(run):
+                    page(p + i, 0)
+
+            return carry
+
+        lax.fori_loop(0, head, page, 0)
+        lax.fori_loop(0, groups, group, 0)
+        lax.fori_loop(head + groups * run, n, page, 0)
 
     first, last = pages(b)
     n_blocks = (last - first) // nb + 1
@@ -721,7 +776,20 @@ def _gqa_kernel(table_ref, pos_ref, low_ref, slot_ref, q_ref, kp_ref, vp_ref,
         def _():
             for_each_copy(b + 1, 0, 1 - slot, lambda copy: copy.start())
 
-        start, n = for_each_copy(b, j, slot, lambda copy: copy.wait())
+        start, n = extent(b, j)
+
+        # a whole block's copies are waited for at once, a pool: the
+        # semaphore counts bytes, and the slot's are the block's (the wait
+        # reads its descriptor's size alone)
+        @pl.when(n == nb)
+        def _():
+            for buf in (kbuf, vbuf):
+                pltpu.make_async_copy(buf.at[1 - slot], buf.at[slot],
+                                      sem.at[slot]).wait()
+
+        @pl.when(n < nb)
+        def _():
+            for_each_copy(b, j, slot, lambda copy: copy.wait())
 
         def clear(p, c):
             # what was not fetched counts for nothing: a weight of 0 does
@@ -766,11 +834,12 @@ def _gqa_kernel(table_ref, pos_ref, low_ref, slot_ref, q_ref, kp_ref, vp_ref,
     o_ref[0] = acc_ref[...] / l_ref[:, :, :1]
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, run,
+              interpret):
     b, hkv, r, ch = q2.shape
     ps, hc = k_pool.shape[1:]
-    columns = table.shape[0] // b
+    columns = table.shape[1]
     first, last = _gqa_pages(position, lower, ps, columns, ring)
     n_blocks = (last - first) // nb + 1
     # the buffer slot each row's first block lands in: rows take turns
@@ -781,7 +850,7 @@ def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, interpret):
             + hkv * r * (2 * _LANES + ch) * 4 + _SCORE_TEMPS * r * nb * ps * 4)
     return pl.pallas_call(
         functools.partial(
-            _gqa_kernel, ps=ps, columns=columns, nb=nb, ring=ring,
+            _gqa_kernel, ps=ps, columns=columns, nb=nb, run=run, ring=ring,
             scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
         out_shape=jax.ShapeDtypeStruct((b, hkv, r, ch), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -803,11 +872,15 @@ def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, interpret):
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=need + 16 * 1024 * 1024),
-    )(table, position, lower, slot0.astype(jnp.int32), q2, k_pool, v_pool)
+    )(_mark_runs(table, run, ring).reshape(-1), position, lower,
+      slot0.astype(jnp.int32), q2,
+      # the pools as rows: a page is page_size of them, a run's pages are
+      # neighbours (whole sublane tiles a page: no byte moves)
+      k_pool.reshape(-1, hc), v_pool.reshape(-1, hc))
 
 
 def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
-                   block_pages=None, interpret=None):
+                   block_pages=None, run_pages=None, interpret=None):
     """Attention of one query a row, ``q`` ``(B, H, 1, Ch)``, over the row's
     paged history: ``H`` query heads over the pools' ``Hkv`` key-value heads
     (``(P+1, page_size, Hkv*Ch)``; query head ``i`` reads head ``i // (H //
@@ -821,22 +894,27 @@ def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
     id from the scalar-prefetched table, the next block's in flight while
     this one's products run, across rows too), under a running maximum and
     sum in float32: nothing in VMEM has the history's size, so any length
-    is served alike. A key-value head's ``H // Hkv`` query heads are the rows
-    of one left operand. Operands in the pools' dtype, float32 scores and
-    softmax. Returns ``(B, H, 1, Ch)`` float32. Callers gate via
+    is served alike. A whole group of ``run_pages`` logical pages
+    (``RUN_PAGES``; ``s % run_pages == 0`` ...) whose pool ids are
+    consecutive is ONE copy, every other page a copy of its own: the table
+    says which (:func:`_mark_runs`), so any valid table gives the
+    page-by-page result (``run_pages=1``) bit for bit. A key-value head's
+    ``H // Hkv`` query heads are the rows of one left operand. Operands in
+    the pools' dtype, float32 scores and softmax. Returns ``(B, H, 1, Ch)`` float32. Callers gate via
     :func:`paged_gqa_refusal`."""
     b, h, tq, ch = q.shape
     ps, hc = k_pool.shape[1:]
     hkv = hc // ch
     g = h // hkv
     nb = int(block_pages or max(1, _GQA_BLOCK // ps))
+    run = int(run_pages or min(RUN_PAGES, nb, k_pool.shape[0]))
     r = _rows(g, tq, jnp.dtype(k_pool.dtype).itemsize)
     q2 = q.astype(k_pool.dtype).reshape(b, hkv, g * tq, ch)
     q2 = jnp.pad(q2, ((0, 0), (0, 0), (0, r - g * tq), (0, 0)))
     position = jnp.asarray(position, jnp.int32)
     lower = jnp.zeros_like(position) if window is None else \
         jnp.maximum(position - (int(window) - 1), 0)
-    o2 = _gqa_call(jnp.asarray(page_table, jnp.int32).reshape(-1), position,
-                   lower, q2, k_pool, v_pool, window is not None, nb,
+    o2 = _gqa_call(jnp.asarray(page_table, jnp.int32), position, lower, q2,
+                   k_pool, v_pool, window is not None, nb, run,
                    _resolve_interpret(interpret))
     return o2[:, :, :g * tq].reshape(b, h, tq, ch)

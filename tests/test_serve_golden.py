@@ -9,7 +9,12 @@ groups. The fixture was recorded at the parent of PR 38 (commit 5d3b4cc),
 before ``batcher.step()`` and ``engine.prefill`` got their records: a span
 wrapped round a statement moves none of this, a statement moved or added
 does (PR 37 was refused for one request reading wrong pages, which no test
-saw). To record it anew: ``PYTHONPATH=. JAX_PLATFORMS=cpu python
+saw). PR 39 recorded the page IDS anew (its allocator hands the same
+number of pages out from aligned chunks, not from a FIFO list: other ids by
+design) after a run that matched the parent's fixture in everything else:
+tokens, finishes, free pages, pages in use, epochs, positions, queue, and
+every row's number of pages in both groups after every step. To record it
+anew: ``PYTHONPATH=. JAX_PLATFORMS=cpu python
 tests/test_serve_golden.py``, on a tree whose history is known good."""
 import json
 import os

@@ -44,20 +44,27 @@ def _compiled(one_chip, fn, *shapes):
         compilation_cache.reset_cache()
 
 
+# run_pages None: the kernel as the cell runs it (a run of RUN_PAGES
+# neighbouring pages is one copy of 128 KB from the pool seen as rows); 1: its
+# page-by-page walk alone
+@pytest.mark.parametrize("run_pages", [None, 1])
 @pytest.mark.parametrize("window,pages,columns", [(None, 24576, 640),
                                                   (4096, 12416, 259)])
 def test_the_kernel_compiles_for_a_v5e_at_the_cells_widths(one_chip, window,
-                                                           pages, columns):
+                                                           pages, columns,
+                                                           run_pages):
     compiled = _compiled(
         one_chip,
         lambda q, k, v, t, p: ppa.paged_gqa_read(q, k, v, t, p, window,
+                                                 run_pages=run_pages,
                                                  interpret=False),
         ((48, 28, 1, 128), jnp.bfloat16),
         ((pages + 1, 16, 512), jnp.bfloat16),
         ((pages + 1, 16, 512), jnp.bfloat16),
         ((48, columns), jnp.int32), ((48,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
-    # nothing history-sized beside the pools: the kernel's scratch is VMEM
+    # nothing history-sized beside the pools: the kernel's scratch is VMEM,
+    # and the pools' view as rows moves no byte
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 

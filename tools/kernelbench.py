@@ -54,7 +54,10 @@ LATENT_CASES = [(128, 128, 512, 64, 16, 256, 1, 128),
 # b rows whose frontiers lie in lo .. hi; window 0 is a full layer (the table
 # in order from position 0), else a window layer's ring; the A/B is the kernel
 # ``paged_gqa_decode``, which walks the pages a row holds and reads in blocks,
-# against the XLA gather of the table's whole width
+# against the XLA gather of the table's whole width. ``--runs`` lays a share
+# of every row's pages side by side in the pool (whole groups of the kernel's
+# ``RUN_PAGES`` logical pages; the other groups' ids are shuffled): 1.0 is a
+# fresh pool's table, 0.0 a shuffled one; the kernel alone is timed then
 GQA_CASES = [(48, 28, 4, 128, 16, 640, 0, 512, 1024),
              (48, 28, 4, 128, 16, 640, 0, 4096, 10240),
              (48, 28, 4, 128, 16, 640, 0, 0, 10240),
@@ -389,7 +392,24 @@ def run_latent_case(b, h, kl, rope, ps, n_pages, tq, held, reps):
     return case
 
 
-def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps):
+def _shuffled_groups(table, where, runs, group, rng):
+    """``table`` with a share ``1 - runs`` of the rows' whole groups of
+    ``group`` logical pages taken apart: ``where`` is every held page's
+    (row, column, logical page), the loose groups' ids are permuted among
+    themselves, so those pages lie anywhere in the pool and the others side
+    by side as before."""
+    import numpy as np
+
+    loose = {key for key in {(r, s // group) for r, _, s in where}
+             if rng.rand() >= runs}
+    at = [(r, c) for r, c, s in where if (r, s // group) in loose]
+    if at:
+        rows, cols = map(np.array, zip(*at))
+        table[rows, cols] = rng.permutation(table[rows, cols])
+    return table
+
+
+def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps, runs=None):
     import jax.numpy as jnp
     import numpy as np
 
@@ -402,14 +422,17 @@ def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps):
     position = rng.randint(lo, hi, (b,)).astype(np.int32)
     # each row's pages: in order from 0, or the ring's columns of the pages
     # its window reaches; every other entry names the trash page
-    table, pages = np.zeros((b, cols), np.int32), 0
+    table, pages, where = np.zeros((b, cols), np.int32), 0, []
     for r, p in enumerate(position):
         first = 0 if window is None else max(0, p - window + 1) // ps
         for s in range(first, p // ps + 1):
             pages += 1
             table[r, s % cols if window else s] = pages
+            where.append((r, s % cols if window else s, s))
     k_pool, v_pool = (jnp.asarray(rng.randn(pages + 1, ps, hkv * ch), dtype)
                       for _ in range(2))
+    if runs is not None:
+        table = _shuffled_groups(table, where, runs, ppa.RUN_PAGES, rng)
     table, position = jnp.asarray(table), jnp.asarray(position)
     q = jnp.asarray(rng.randn(b, h, 1, ch) * 0.3, dtype)
     read = int(np.minimum(np.asarray(position) + 1,
@@ -417,28 +440,35 @@ def run_gqa_case(b, h, hkv, ch, ps, cols, window, lo, hi, reps):
     case = {"kind": "paged_gqa", "b": b, "h": h, "hkv": hkv, "ch": ch,
             "ps": ps, "columns": cols, "window": window or 0, "lo": lo,
             "hi": hi, "positions_read": read}
+    if runs is not None:
+        case["runs"] = runs
     if not _INTERP:
         case["gate"] = ppa.paged_gqa_refusal(q, k_pool, table, window) \
             or "kernel"
 
-    def gather_ref(q):
+    # the pools ride in as arguments: closed over, they are constants of the
+    # timed program and its compile takes minutes at these sizes
+    def gather_ref(q, k_pool, v_pool, table, position):
         return att._paged_gqa_gather_read(q, k_pool, v_pool, table, position,
                                           window)
 
-    def kernel(q):
+    def kernel(q, k_pool, v_pool, table, position):
         return ppa.paged_gqa_read(q, k_pool, v_pool, table, position, window,
                                   interpret=_INTERP)
 
-    ref, out = gather_ref(q), kernel(q)
+    args = (q, k_pool, v_pool, table, position)
+    ref, out = gather_ref(*args), kernel(*args)
     err = float(jnp.max(jnp.abs(out - ref)))
     case["max_err"] = round(err, 6)
     # both return float32 sums of bfloat16 products; the weights are rounded
     # to bfloat16 before the second product on both paths
     case["correct"] = bool(err < 0.02 and jnp.isfinite(out).all())
     del ref, out
-    for label, f in (("kernel", kernel), ("gather", gather_ref)):
+    # where the pages lie moves the kernel's time and not the gather's
+    for label, f in (("kernel", kernel), ("gather", gather_ref))[
+            :1 if runs is not None else 2]:
         try:
-            case[f"{label}_ms"] = round(_timeit(f, (q,), reps) * 1e3, 4)
+            case[f"{label}_ms"] = round(_timeit(f, args, reps) * 1e3, 4)
         except Exception as e:
             case[f"{label}_error"] = repr(e)[:120]
     if "kernel_ms" in case and "gather_ms" in case:
@@ -694,7 +724,8 @@ def run_one(argv):
         elif spec["kind"] == "masked_prefill":
             case = run_masked_prefill_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "paged_gqa":
-            case = run_gqa_case(*spec["shape"], spec["reps"])
+            case = run_gqa_case(*spec["shape"], spec["reps"],
+                                spec.get("runs"))
         elif spec["kind"] == "grouped_mm":
             case = run_grouped_mm_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
@@ -721,6 +752,11 @@ def main():
                          "masked_prefill, paged_gqa, grouped_mm, "
                          "fused_adam, softmax_xent); "
                          "default all")
+    ap.add_argument("--runs", default="",
+                    help="paged_gqa: comma-separated shares of a row's pages "
+                         "laid side by side in the pool (1.0 a fresh pool's "
+                         "table, 0.0 a shuffled one); every case at every "
+                         "share, the kernel alone timed")
     ap.add_argument("--timeout", type=int, default=600)
     args = ap.parse_args()
 
@@ -745,8 +781,11 @@ def main():
     # a call is 0.1-0.6 s: a chain of three is long enough
     specs += [{"kind": "masked_prefill", "shape": list(shape),
                "reps": min(args.reps, 3)} for shape in MASKED_PREFILL_CASES]
-    specs += [{"kind": "paged_gqa", "shape": list(shape), "reps": args.reps}
-              for shape in GQA_CASES]
+    specs += [{"kind": "paged_gqa", "shape": list(shape), "reps": args.reps,
+               **({} if runs is None else {"runs": runs})}
+              for shape in GQA_CASES
+              for runs in ([float(r) for r in args.runs.split(",") if r]
+                           or [None])]
     specs += [{"kind": "grouped_mm", "shape": list(shape), "reps": args.reps}
               for shape in GROUPED_MM_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
