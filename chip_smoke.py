@@ -17,7 +17,8 @@ configuration can select:
            Mosaic, its logits checked against a plain re-forward
   kernels  flash attention fwd+bwd, paged attention and packed attention
            fwd+bwd (BERT-large's: batch 64 x seq 128 x 3 x 1024, key mask),
-           compiled by Mosaic and compared with their XLA references
+           the experts' grouped products and the gated delta rule's decode
+           step, compiled by Mosaic and compared with their XLA references
 
 Any failure in any phase raises and the process exits non-zero; nothing is
 caught. The last line of stdout is one JSON object,
@@ -675,6 +676,78 @@ def check_grouped(tokens=48, top_k=6, d=512, w=256, experts=64, interpret=None):
     return {"pairs": tokens * top_k, "widths": [d, w], "experts": experts, **out}
 
 
+def check_gdn(rows=8, heads=30, dk=96, dv=192, steps=3, interpret=None):
+    """Olmo-Hybrid's linear layer in decode at its published widths: the
+    kernel ``gdn_decode_step`` over a donated state, a few steps on end with
+    some rows dead, against the XLA form: the live rows' reads and state
+    agree, a dead row's state is the one it started with bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import pallas_gdn as gdn
+
+    rs = np.random.RandomState(SEED)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    state = f32(rs.randn(rows, dk, heads * dv))
+    live = jnp.asarray(np.arange(rows) % 3 != 1)
+    if interpret is None:
+        why = gdn.gdn_decode_refusal(
+            state, jax.ShapeDtypeStruct((rows, heads, dk), jnp.float32),
+            jax.ShapeDtypeStruct((rows, heads, dv), jnp.float32))
+        if why is not None:
+            raise AssertionError(f"gdn: the gate refuses: {why}")
+    kernel = jax.jit(lambda s, *a: gdn.gdn_decode_step(s, *a, live,
+                                                       interpret=interpret),
+                     donate_argnums=(0,))
+    calls = kernel.lower(state, *(f32(np.zeros(z)) for z in (
+        (rows, heads, dk), (rows, heads, dk), (rows, heads, dv),
+        (rows, heads), (rows, heads)))).as_text().count("tpu_custom_call")
+    if not interpret and calls != 1:
+        raise AssertionError(f"gdn: lowered with {calls} Mosaic kernels, not 1")
+    got, want, errs = state + 0.0, state, []
+    for _ in range(steps):
+        args = (f32(unit(rs.randn(rows, heads, dk)) * dk ** -0.5),
+                f32(unit(rs.randn(rows, heads, dk))),
+                f32(rs.randn(rows, heads, dv)),
+                f32(rs.uniform(0.5, 1.0, (rows, heads))),
+                f32(rs.uniform(0.0, 2.0, (rows, heads))))
+        o_got, got = kernel(got, *args)
+        o_want, want = gdn.gdn_decode_xla(want, *args, live)
+        errs.append(max(_rel_err(o_got, o_want), _rel_err(got, want)))
+    if not max(errs) < 1e-5:
+        raise AssertionError(f"gdn: relative error {max(errs)} against XLA")
+    if not bool(jnp.array_equal(got[~live], state[~live])):
+        raise AssertionError("gdn: a dead row's state was moved")
+    # the chunked prefill against the kernel a position at a time: two
+    # blocks of 64 from a zero state (its products must be float32's, not
+    # one bfloat16 pass)
+    t = 128
+    q, k, v = (f32(unit(rs.randn(t, heads, dk)) * dk ** -0.5),
+               f32(unit(rs.randn(t, heads, dk))), f32(rs.randn(t, heads, dv)))
+    alpha, beta = (f32(rs.uniform(0.8, 1.0, (t, heads))),
+                   f32(rs.uniform(0.0, 2.0, (t, heads))))
+    o_chunk, s_chunk = jax.jit(gdn.gdn_chunk_prefill)(q, k, v, jnp.log(alpha),
+                                                      beta)
+    one = jax.jit(lambda s, *a: gdn.gdn_decode_step(
+        s, *a, jnp.ones((1,), bool), interpret=interpret), donate_argnums=(0,))
+    s_step, o_step = jnp.zeros((1, dk, heads * dv), jnp.float32), []
+    for i in range(t):
+        o, s_step = one(s_step, q[i:i + 1], k[i:i + 1], v[i:i + 1],
+                        alpha[i:i + 1], beta[i:i + 1])
+        o_step.append(o[0])
+    chunk_err = max(_rel_err(o_chunk, jnp.stack(o_step)),
+                    _rel_err(gdn.state_rows(s_chunk), s_step[0]))
+    if not chunk_err < 1e-4:
+        raise AssertionError(f"gdn: the chunked prefill lies {chunk_err} "
+                             "from the recurrence")
+    return {"rows": rows, "live": int(live.sum()), "heads": heads,
+            "state": [dk, dv], "steps": steps, "tpu_custom_calls": calls,
+            "rel_err": round(max(errs), 8),
+            "chunk_prefill_rel_err": round(chunk_err, 8)}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -742,6 +815,7 @@ def phase_kernels():
            "paged_index_scores": check_index_scores(),
            "paged_gqa_decode": check_gqa(),
            "grouped_matmul": check_grouped(),
+           "gdn_decode_step": check_gdn(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

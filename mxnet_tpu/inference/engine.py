@@ -364,7 +364,18 @@ class GenerationEngine:
             refused for a model with a ``window`` group;
           - optionally ``takes_last_pos = True``: the paged prefill passes
             ``last_pos=`` (the prompt's last real position, (1,) int32) and
-            gets that position's logits alone, (1, 1, V).
+            gets that position's logits alone, (1, 1, V);
+          - optionally **slot state** (docs/INFERENCE.md "Slot state"):
+            ``paged_slot_state = True`` says that some layers keep state by
+            SLOT, not by position (a recurrent state, whatever the row's
+            length). ``init_paged_cache`` is then given ``slots=`` and
+            names the group ``slot`` for such a layer, whose arrays have the
+            slot as axis 0; they ride in the donated carry with the pools.
+            The forward is told ``slot=`` ((1,) int32) by a prefill, which
+            writes that row's state from zero, and ``live=`` ((B,) bool) by
+            a decode step, which advances the live rows and no other. The
+            prefix cache, forks and speculation are refused: each would
+            need a copy of a row's state.
 
         Dropout should be 0 for exact equivalence (evaluation mode disables
         it regardless).
@@ -497,6 +508,15 @@ class GenerationEngine:
                                  else per_group["all"])
             if self.num_pages < 1:
                 raise ValueError("num_pages must be >= 1")
+            #: whether some layers keep state by slot (axis 0 = the slot)
+            self._slot_state = bool(getattr(net, "paged_slot_state", False))
+            if self._slot_state and (prefix_cache or draft_net is not None):
+                raise ValueError(
+                    "the model keeps state by slot (a recurrent state beside "
+                    "the page pools): a prefix adopted or a draft verified "
+                    "there would need a copy of a row's state at an earlier "
+                    "position, which no page holds. prefix_cache= and "
+                    "draft_net= are refused for such a model")
             #: the ``window`` group's allocator (None: the model has none)
             self._window = None
             for g, w in windows.items():
@@ -527,7 +547,8 @@ class GenerationEngine:
                     sizes[next(iter(windows))] = self._window.num_pages
                 pools, self.layer_groups = net.init_paged_cache(
                     {g: sizes[g] for g in groups}, self.page_size,
-                    dtype=cache_dtype)
+                    dtype=cache_dtype, **({"slots": self.batch_size}
+                                          if self._slot_state else {}))
                 self.layer_groups = tuple(self.layer_groups)
             else:
                 pools = net.init_paged_cache(
@@ -577,6 +598,10 @@ class GenerationEngine:
             per_token.set(self.cache_bytes_per_token)
             for g in self._group_names:  # and one series a pool group
                 per_token.set(self._group_bytes_per_token(g), group=g)
+            if self._slot_state:
+                _obs.gauge("gen_slot_state_bytes",
+                           "bytes of the state the model keeps by slot, all "
+                           "layers and slots").set(self.slot_state_bytes)
             #: read path of the paged decode program, as the model says it
             #: (the choice is made per shape at trace time, in the operator)
             describe = getattr(net, "paged_read_path", None)
@@ -591,6 +616,7 @@ class GenerationEngine:
                                         dtype=cache_dtype)
             self.prefix_cache = None
             self._window = None
+            self._slot_state = False
 
         if draft_net is not None:
             self._draft_plist = [p for _, p in
@@ -718,7 +744,17 @@ class GenerationEngine:
         if not self.paged:
             return 0.0
         return sum(self._group_bytes_per_token(g)
-                   for g in dict.fromkeys(self.layer_groups))
+                   for g in dict.fromkeys(self.layer_groups) if g != "slot")
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """Bytes of the state the model keeps by slot (its layers of the
+        group ``slot``), over all slots; 0 for a model that keeps none."""
+        if not self.paged:
+            return 0
+        return sum(b.size * b.dtype.itemsize
+                   for layer, g in zip(self.pools, self.layer_groups)
+                   if g == "slot" for b in layer)
 
     @property
     def page_groups(self) -> dict:
@@ -1239,6 +1275,8 @@ class GenerationEngine:
         only_last = getattr(self.net, "takes_last_pos", False)
         told = {"last_pos": NDArray((length - 1).reshape(1))} \
             if only_last else {}
+        if self._slot_state:  # the row whose state this prompt writes
+            told["slot"] = NDArray(slot.reshape(1))
         with _HybridTrace(self._plist, list(params), False, key):
             logits, new_pools, _ = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
@@ -1283,11 +1321,13 @@ class GenerationEngine:
         of the step, if it returns any, leave with the tokens."""
         table, pools = carry
         table = self._apply_table_updates(table, upd_slots, upd_pages, clear)
+        # slot state is advanced for the rows that decode and no other
+        told = {"live": NDArray(~done)} if self._slot_state else {}
         with _HybridTrace(self._plist, list(params), False, key):
             logits, new_pools, stats = self._cached(self.net(
                 NDArray(tokens.reshape(self.batch_size, 1)),
                 cache=self._cache_nd(pools), start_pos=NDArray(positions),
-                page_table=self._table_nd(table)))
+                page_table=self._table_nd(table), **told))
         logits = logits._data[:, 0]
         sampled = self._sample(logits, key)
         next_tok = jnp.where(done, jnp.int32(self.pad_id), sampled)
@@ -2221,6 +2261,11 @@ class GenerationEngine:
                 "rows cannot be forked")
         if not self.paged:
             raise RuntimeError("fork_slot needs a paged engine")
+        if self._slot_state:
+            raise RuntimeError(
+                "fork_slot shares pages between rows; a model that keeps "
+                "state by slot holds a row's recurrent state in no page, so "
+                "its rows cannot be forked")
         if src == dst or not (0 <= src < self.batch_size
                               and 0 <= dst < self.batch_size):
             raise ValueError(f"bad fork {src} -> {dst}")

@@ -1274,7 +1274,7 @@ def _reference_mha(q, k, v, mask=None, causal=False):
 @register("multi_head_attention", aliases=("_contrib_multi_head_attention",))
 def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
                          cache=None, position=None, page_table=None,
-                         window=None, last_pos=None):
+                         window=None, last_pos=None, grouped=False):
     """Fused scaled-dot-product attention over (B, H, T, Ch) tensors.
 
     ``use_flash='auto'`` picks the Pallas flash kernel on TPU backends when
@@ -1308,14 +1308,19 @@ def multi_head_attention(q, k, v, mask=None, causal=False, use_flash="auto",
     Hkv*Ch)``; with ``window`` the table is an engine's ring and a chunk of
     more than one token needs ``last_pos=``, its last real position), or
     without a cache one whole chunk by :func:`_gqa_chunk_attention`. Equal
-    head counts without a window take the paths above, unchanged.
+    head counts without a window take the paths above, unchanged, unless
+    ``grouped=True`` asks for this path at a group of ONE: its decode kernel
+    walks a row's pages in blocks and holds nothing of a history's size in
+    VMEM, so rows of thousands of positions under 30 heads of 128 are served
+    (the equal-headed kernel copies a row's whole history into VMEM and
+    refuses them; its XLA fallback gathers the table's whole width).
     """
     from . import flash_attention as fa
     from ..contrib.amp import cast_inputs
 
     orig_dtype = q.dtype
     q, k, v = cast_inputs(q, k, v)  # AMP: score/context matmuls on the MXU
-    grouped = k.shape[1] != q.shape[1] or window is not None
+    grouped = grouped or k.shape[1] != q.shape[1] or window is not None
     if grouped and (cache is None or page_table is None):
         if cache is not None or mask is not None:
             raise ValueError("grouped key-value heads and a window are causal "

@@ -3,7 +3,9 @@ parent's: the SHA-256 of their lowered text at toy widths
 (``tools/loweredsha.py``, a process of its own so that no other test's
 blocks move a name) against the values recorded from the tree before PR 32
 (commit eea6ea6) and, for dots3-note-prev's decode step and one prefill
-program, from the tree before PR 35 (commit 28ca294). A PR that does not
+program, from the tree before PR 35 (commit 28ca294); Olmo-Hybrid's decode
+step and one prefill program (slot state in the carry) from PR 40's own
+tree, the first that has them. A PR that does not
 mean to touch those models' programs keeps them; one that does records anew
 (``JAX_PLATFORMS=cpu python tools/loweredsha.py``) and says so."""
 import json
@@ -25,6 +27,8 @@ RECORDED = {
     "deepseek_v2.prefill64": "53764d7519643636edc56b11c028c5c3ed80e3b8f9d149ae2995dae5c52deee9",
     "dots3_note.decode": "adf8fc0ee4eb9d46e7bbd4d6fdc842dfecd02404eb5d7b262ae6fce55cfdfab6",
     "dots3_note.prefill16": "5ad8ede32b118409f13d5179c7e3ef89744aa992c977fea0822f24d15a709a40",
+    "olmo_hybrid.decode": "97083b6f120153213f4f5ec83166d2b72e761d52db92f572cf02f49156c22e23",
+    "olmo_hybrid.prefill16": "3cb9459a0d0052474c4bbb03ac8c54d5c71d087cb4b560653cf1725cc16e526a",
 }
 
 

@@ -1,9 +1,10 @@
-"""The grouped-heads decode kernel and the experts' grouped-matmul kernel
+"""The grouped-heads decode kernel, the experts' grouped-matmul kernel and
+the gated delta rule's decode kernel
 compiled by Mosaic for a DESCRIBED v5e at the cells' real widths, here,
 without a chip: what interpret mode cannot refuse (a slice off the tiling,
 too much VMEM) fails this at no chip time. Nothing runs, so it says nothing
 of results or times. The topology is described inside a fixture (only the
-worker that is given this file loads the TPU's library: both kernels' cases
+worker that is given this file loads the TPU's library: every kernel's cases
 are in this ONE file for that reason) and the tests skip where it cannot
 be."""
 import jax
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from mxnet_tpu.ops import pallas_gdn as gdn
 from mxnet_tpu.ops import pallas_grouped_matmul as gmm
 from mxnet_tpu.ops import pallas_paged_attention as ppa
 
@@ -27,16 +29,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled(one_chip, fn, *shapes):
+def _compiled(one_chip, fn, *shapes, donate=()):
     """``fn`` compiled for the described chip at ``shapes`` ((shape, dtype)
-    pairs), the persistent cache off: an entry could not be read back here."""
+    pairs; ``donate``: the arguments given away), the persistent cache off:
+    an entry could not be read back here."""
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return jax.jit(fn).lower(*(
+        return jax.jit(fn, donate_argnums=donate).lower(*(
             jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes)
         ).compile()
     finally:
@@ -94,3 +97,36 @@ def test_the_grouped_matmul_compiles_for_a_v5e_at_the_cells_shapes(
     # copy of a layer's weights
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= pairs * w * jnp.dtype(dtype).itemsize + (1 << 20)
+
+
+def test_the_grouped_heads_kernel_compiles_at_a_group_of_one(one_chip):
+    """Olmo-Hybrid's full layers: 30 query heads over 30 key-value heads of
+    128, pools 3,840 wide, rows of up to 4,096 positions."""
+    compiled = _compiled(
+        one_chip,
+        lambda q, k, v, t, p: ppa.paged_gqa_read(q, k, v, t, p,
+                                                 interpret=False),
+        ((48, 30, 1, 128), jnp.bfloat16), ((3585, 16, 3840), jnp.bfloat16),
+        ((3585, 16, 3840), jnp.bfloat16), ((48, 256), jnp.int32),
+        ((48,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_gdn_decode_kernel_compiles_for_a_v5e_at_30_x_96_x_192(one_chip):
+    """Olmo-Hybrid's linear layers: 48 slots of 30 heads of 96 x 192 float32
+    (96 x 5,760 a row: whole (8, 128) tiles, two heads a lane group), the
+    state donated: aliased to the output, no copy of it beside the call."""
+    f32 = jnp.float32
+    state = (48, 96, 30 * 192)
+    shapes = [(state, f32), ((48, 30, 96), f32), ((48, 30, 96), f32),
+              ((48, 30, 192), f32), ((48, 30), f32), ((48, 30), f32),
+              ((48,), jnp.bool_)]
+    compiled = _compiled(
+        one_chip, lambda s, q, k, v, a, b, live: gdn.gdn_decode_step(
+            s, q, k, v, a, b, live, interpret=False), *shapes, donate=(0,))
+    assert "gdn_decode_step" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    state_bytes = 48 * 96 * 5760 * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < 1 << 20

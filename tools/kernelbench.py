@@ -80,6 +80,15 @@ MASKED_PREFILL_CASES = [(4096, 128, 128, 64, 128, 512, 1024, 64, 128, 2048),
 GROUPED_MM_CASES = [(48, 6, 2560, 768, 64, 64), (4096, 6, 2560, 768, 64, 64),
                     (128, 6, 5120, 1536, 8, 160), (48, 8, 5120, 1536, 8, 256),
                     (4096, 8, 5120, 1536, 8, 256)]
+# the gated delta rule's decode step (Olmo-Hybrid's linear layer, one layer):
+# (rows, live rows, heads, dk, dv): the state of ``rows`` slots, of which
+# the first ``live`` decode; the A/B is the kernel ``gdn_decode_step`` (a
+# live row's state read once and written once in place, a dead row's not
+# moved) against the XLA form; the state rides the timing chain's carry, so
+# no copy of it is timed
+GDN_CASES = [(16, 16, 30, 96, 192), (32, 32, 30, 96, 192),
+             (48, 48, 30, 96, 192), (64, 64, 30, 96, 192),
+             (48, 24, 30, 96, 192)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -102,6 +111,7 @@ if os.environ.get("KERNELBENCH_TINY") == "1":
     MASKED_PREFILL_CASES = [(256, 2, 128, 64, 128, 64, 64, 2, 32, 64)]
     GQA_CASES = [(3, 6, 2, 16, 4, 16, 0, 0, 60), (3, 6, 2, 16, 4, 4, 5, 0, 60)]
     GROUPED_MM_CASES = [(8, 2, 128, 128, 4, 4), (64, 2, 128, 256, 2, 8)]
+    GDN_CASES = [(3, 3, 4, 8, 64), (4, 2, 4, 8, 64)]
     ADAM_CASES = [(1 << 12,)]
     XENT_CASES = [(64, 256)]
 
@@ -550,6 +560,69 @@ def run_grouped_mm_case(tokens, top_k, d, w, held, experts, reps):
     return case
 
 
+def run_gdn_case(rows, n_live, heads, dk, dv, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import pallas_gdn as gdn
+
+    rng = np.random.RandomState(0)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    state = f32(rng.randn(rows, dk, heads * dv))
+    q = f32(unit(rng.randn(rows, heads, dk)) * dk ** -0.5)
+    k = f32(unit(rng.randn(rows, heads, dk)))
+    v = f32(rng.randn(rows, heads, dv))
+    alpha = f32(rng.uniform(0.9, 1.0, (rows, heads)))
+    beta = f32(rng.uniform(0.0, 2.0, (rows, heads)))
+    live = jnp.arange(rows) < n_live
+    case = {"kind": "gdn_decode", "rows": rows, "live": n_live,
+            "heads": heads, "dk": dk, "dv": dv}
+    if not _INTERP:
+        case["gate"] = gdn.gdn_decode_refusal(state, q, v) or "kernel"
+    forms = {"kernel": lambda s: gdn.gdn_decode_step(
+                 s, q, k, v, alpha, beta, live, interpret=_INTERP),
+             "xla": lambda s: gdn.gdn_decode_xla(s, q, k, v, alpha, beta, live)}
+    (o_k, s_k), (o_x, s_x) = (jax.jit(f)(state) for f in forms.values())
+    err = max(float(jnp.abs(o_k - o_x).max()), float(jnp.abs(s_k - s_x).max()))
+    dead = bool((np.asarray(s_k)[n_live:] == np.asarray(state)[n_live:]).all())
+    case["max_err"] = round(err, 8)
+    case["correct"] = bool(err < 1e-4 and dead
+                           and np.isfinite(np.asarray(o_k)).all())
+    del o_k, s_k, o_x, s_x
+
+    def timed(step):
+        # the state is the chain's carry, donated: every call advances it
+        # in place, as an engine's decode program does
+        chain = jax.jit(lambda s: jax.lax.scan(
+            lambda c, _: (step(c)[1], ()), s, None, length=reps)[0],
+            donate_argnums=(0,))
+        s = chain(state + 0.0)
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            s = chain(s)
+            np.asarray(jax.device_get(s[0, 0, :1]))
+            times.append((time.perf_counter() - t0) / reps)
+        return sorted(times)[1]
+
+    for label, step in forms.items():
+        try:
+            case[f"{label}_ms"] = round(timed(step) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:300]
+    # what a step has to move: the live rows' state, read and written
+    gb = n_live * 2 * heads * dk * dv * 4 / 1e9
+    case["state_gb"] = round(gb, 4)
+    for label in forms:
+        if f"{label}_ms" in case:
+            case[f"{label}_gb_per_s"] = round(gb / case[f"{label}_ms"] * 1e3, 1)
+    if "kernel_ms" in case and "xla_ms" in case:
+        case["kernel_vs_xla"] = round(case["xla_ms"] / case["kernel_ms"], 2)
+    return case
+
+
 def run_masked_prefill_case(t, heads, nope, rope, vd, kl, ql, idx_heads,
                             idx_dim, top_k, reps):
     import jax
@@ -728,6 +801,8 @@ def run_one(argv):
                                 spec.get("runs"))
         elif spec["kind"] == "grouped_mm":
             case = run_grouped_mm_case(*spec["shape"], spec["reps"])
+        elif spec["kind"] == "gdn_decode":
+            case = run_gdn_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -750,7 +825,7 @@ def main():
                     help="comma-separated case kinds to run (attn, ln, "
                          "conv_layout, paged_attn, paged_latent, "
                          "masked_prefill, paged_gqa, grouped_mm, "
-                         "fused_adam, softmax_xent); "
+                         "gdn_decode, fused_adam, softmax_xent); "
                          "default all")
     ap.add_argument("--runs", default="",
                     help="paged_gqa: comma-separated shares of a row's pages "
@@ -788,6 +863,8 @@ def main():
                            or [None])]
     specs += [{"kind": "grouped_mm", "shape": list(shape), "reps": args.reps}
               for shape in GROUPED_MM_CASES]
+    specs += [{"kind": "gdn_decode", "shape": list(shape), "reps": args.reps}
+              for shape in GDN_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}
