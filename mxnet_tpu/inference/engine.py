@@ -239,6 +239,19 @@ def _tally_run(runs: set, k: int, ids) -> int:
     return 1 if is_run else -1
 
 
+def _count_routes(whole) -> int:
+    """A program's expert-layer calls into ``moe_route_total{path}``:
+    ``whole`` is the model's count ``moe_whole_path``, an entry a call, 1
+    where the call walked every sorted pair and 0 where their prefix
+    (``parallel/moe.py:held_expert_ffn``). Returns the whole-length calls."""
+    n = int(np.sum(whole))
+    route = _obs.counter("moe_route_total",
+                         "expert-layer calls by the sorted pairs they walked")
+    route.inc(n, path="whole")
+    route.inc(np.size(whole) - n, path="prefix")
+    return n
+
+
 class _WindowPages:
     """The host allocator of a ``window`` page group: the pools of layers
     that attend only the last ``window`` positions. A row holds the pages
@@ -1278,7 +1291,7 @@ class GenerationEngine:
         if self._slot_state:  # the row whose state this prompt writes
             told["slot"] = NDArray(slot.reshape(1))
         with _HybridTrace(self._plist, list(params), False, key):
-            logits, new_pools, _ = self._cached(self.net(
+            logits, new_pools, stats = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
                 start_pos=NDArray(start), page_table=self._table_nd(row_table),
                 **told))
@@ -1287,6 +1300,12 @@ class GenerationEngine:
         last = logits[0, 0] if only_last else jax.lax.dynamic_index_in_dim(
             logits, length - 1, axis=1, keepdims=False)[0]
         tok = self._sample(last[None, :], key)[0].astype(jnp.int32)
+        if "moe_whole_path" in stats:
+            # the expert layers' route, an entry a call, rides behind the
+            # token: one array, so still one blocking read (a model without
+            # the count keeps its scalar and its program)
+            tok = jnp.concatenate(
+                [tok[None], stats["moe_whole_path"].reshape(-1)])
         return (table, new_pools), tok, last
 
     def _spec_prefill_fn(self, params, dparams, carry, tokens, slot, length,
@@ -1706,7 +1725,11 @@ class GenerationEngine:
                         jnp.asarray(length, jnp.int32), self._next_key())
                     self.cache = cache
             with _obs.span("mx.gen.prefill.read"):
-                tok = int(tok)  # host sync: the first token is ready here
+                tok = np.asarray(tok)  # host sync: the first token is ready
+                if tok.ndim:  # with the expert layers' route behind it
+                    rec.counts["moe_whole_path"] = _count_routes(tok[1:])
+                    tok = tok[0]
+                tok = int(tok)
             with _obs.span("mx.gen.prefill.index"):
                 self._row_epoch += 1
                 self.positions[slot] = length
@@ -1789,6 +1812,8 @@ class GenerationEngine:
                 tok, done = np.array(tok), np.array(done)
                 if stats:
                     rec.counts = {k: v.tolist() for k, v in stats.items()}
+                    if "moe_whole_path" in stats:
+                        _count_routes(stats["moe_whole_path"])
                 if self.paged and self._window is not None:
                     # the engine's own count beside the model's: the window
                     # group's pages in use as this step left them

@@ -156,11 +156,16 @@ class LatentAttention(HybridBlock):
         return out if cache is None else (out, (pool,))
 
 
+#: the names of an ExpertLayer's counts, in the order it returns them
+EXPERT_COUNTS = ("moe_pairs_held", "moe_max_load", "moe_whole_path")
+
+
 class ExpertLayer(HybridBlock):
     """Top-k routed experts (those held here) + shared ones: group-limited
     over softmax probabilities, or (``cfg["scoring"] == "sigmoid"``) over
     sigmoid scores plus a learned selection bias, without groups.
-    Returns (output, pairs routed to held experts, largest load of one)."""
+    Returns (output, pairs routed to held experts, largest load of one,
+    [1]: 1 where the call walked every sorted pair, 0 where their prefix)."""
 
     def __init__(self, cfg, held_experts, dtype="float32", **kwargs):
         super().__init__(**kwargs)
@@ -195,17 +200,18 @@ class ExpertLayer(HybridBlock):
         how = {} if router_bias is None else dict(
             scoring="sigmoid", router_bias=router_bias,
             norm_topk_prob=c["norm_topk_prob"])
-        routed, pairs, load = F.held_expert_ffn(
+        routed, *counts = F.held_expert_ffn(
             x, router_weight, gate_weight, up_weight, down_weight,
             held_experts=self._held, n_group=c.get("n_group", 1),
             topk_group=c.get("topk_group", 1), top_k=c["experts_per_token"],
             scale=c["routed_scaling_factor"], **how)
-        return routed + self.shared(x), pairs, load
+        return (routed + self.shared(x), *counts)
 
 
 class DeepseekV2Block(HybridBlock):
     """Returns ``x``; with ``cache=``, ``(x, layer's cache, counts)``, the
-    counts an expert layer's (pairs, largest load) and a dense layer's None."""
+    counts an expert layer's (pairs, largest load, whole-length calls) and a
+    dense layer's None."""
 
     def __init__(self, cfg, dense, held_experts, dtype="float32", **kwargs):
         super().__init__(**kwargs)
@@ -311,7 +317,9 @@ class DeepseekV2Model(HybridBlock):
                        page_table=None):
         """Logits; with ``cache=``, ``(logits, new_cache, counts)``:
         ``counts`` is {name: (expert layers,) int32} of this forward, the
-        pairs routed to held experts and the largest load of one."""
+        pairs routed to held experts and the largest load of one, and
+        ``moe_whole_path`` (expert layers, calls a layer): 1 where a call's
+        held pairs passed the prefix of ``held_expert_ffn``."""
         x = self.word_embed(token_ids)
         new_cache, counts = [], []
         for i, blk in enumerate(self.blocks):
@@ -331,7 +339,7 @@ class DeepseekV2Model(HybridBlock):
             return logits
         return logits, new_cache, {
             name: jnp.stack(of_layers) for name, of_layers
-            in zip(("moe_pairs_held", "moe_max_load"), zip(*counts))}
+            in zip(EXPERT_COUNTS, zip(*counts))}
 
 
 def get_deepseek_v2(model_name="deepseek_v2", **overrides):

@@ -35,7 +35,7 @@ from .. import initializer as init
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray import NDArray
-from .deepseek_v2 import ExpertLayer, RMSNorm, SwiGLU, _dense
+from .deepseek_v2 import EXPERT_COUNTS, ExpertLayer, RMSNorm, SwiGLU, _dense
 
 __all__ = ["Dots3NoteModel", "get_dots3_note", "dots3_note_configs"]
 
@@ -207,8 +207,9 @@ class LatentSublayer(HybridBlock):
 def _in_token_blocks(ffn, *xs):
     """``ffn(*xs)`` with the tokens of every ``x`` (B, T, d) walked in blocks
     of ``_FFN_TOKENS`` where there are more: a dense layer's output alone; of
-    an expert layer's (output, pairs, largest load, ...) the pairs added up
-    and every later count's largest."""
+    an expert layer's (output, pairs, largest load, ...) the pairs added up,
+    every later count's largest, and a count with an entry a call (the
+    route's, a vector) an entry a block."""
     t = xs[0].shape[1]
     if t <= _FFN_TOKENS or t % _FFN_TOKENS:
         return ffn(*xs)
@@ -223,7 +224,9 @@ def _in_token_blocks(ffn, *xs):
         return join(outs)
     ys, pairs, *counts = zip(*outs)
     return (join(ys), NDArray(sum(p._data for p in pairs)),
-            *(NDArray(jnp.stack([m._data for m in ms]).max()) for ms in counts))
+            *(NDArray(jnp.concatenate([m._data for m in ms])) if ms[0].ndim
+              else NDArray(jnp.stack([m._data for m in ms]).max())
+              for ms in counts))
 
 
 class Dots3NoteBlock(HybridBlock):
@@ -384,8 +387,9 @@ class Dots3NoteModel(HybridBlock):
         ``page_table`` is one table a group in ``paged_pool_groups``' order
         (or the one table where there is one group); ``counts`` is {name:
         (layers that count it,) int32} of this forward: ``dsa_read`` and
-        ``dsa_held`` of the full layers, ``moe_pairs_held`` and
-        ``moe_max_load`` of the expert layers. With ``last_pos=`` the
+        ``dsa_held`` of the full layers, ``moe_pairs_held``,
+        ``moe_max_load`` and ``moe_whole_path`` (an entry a call of the
+        layer: a token block) of the expert layers. With ``last_pos=`` the
         logits are those of that position alone."""
         x = self.word_embed(token_ids)
         tables = {}
@@ -417,7 +421,7 @@ class Dots3NoteModel(HybridBlock):
             return logits
         counts = {}
         for names, rows in ((("dsa_read", "dsa_held"), reads),
-                            (("moe_pairs_held", "moe_max_load"), loads)):
+                            (EXPERT_COUNTS, loads)):
             for name, of_layers in zip(names, zip(*rows)):
                 counts[name] = jnp.stack(of_layers).astype(jnp.int32)
         return logits, new_cache, counts

@@ -452,17 +452,17 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
     return out.astype(data.dtype)
 
 
-def _held_expert_ffn(data, weights, router_data, count_hit, **how):
+def _held_expert_ffn(data, weights, router_data, **how):
     from ..parallel.moe import held_expert_ffn as ffn
 
     router_h = None if router_data is None else \
         router_data.reshape(-1, router_data.shape[-1])
     out, stats = ffn(data.reshape(-1, data.shape[-1]), *weights,
-                     router_h=router_h, count_hit=count_hit, **how)
+                     router_h=router_h, **how)
     return (out.reshape(data.shape), *stats)
 
 
-@register("held_expert_ffn", nout=3)
+@register("held_expert_ffn", nout=4)
 def held_expert_ffn(data, router_weight, gate_weight, up_weight, down_weight,
                     held_experts=(), n_group=1, topk_group=1, top_k=1,
                     scale=1.0, norm_topk_prob=False, scoring="softmax",
@@ -472,10 +472,11 @@ def held_expert_ffn(data, router_weight, gate_weight, up_weight, down_weight,
     router reads ``router_data`` where given, else ``data``; the gate's
     ``activation`` ``silu`` or ``relu``):
     :func:`mxnet_tpu.parallel.moe.held_expert_ffn`.
-    Returns (the part, pairs routed to held experts, largest load of one)."""
+    Returns (the part, pairs routed to held experts, largest load of one,
+    [1]: 1 where the call walked every sorted pair and not their prefix)."""
     return _held_expert_ffn(
         data, (router_weight, gate_weight, up_weight, down_weight),
-        router_data, False, held_experts=held_experts, n_group=n_group,
+        router_data, count_route=True, held_experts=held_experts, n_group=n_group,
         topk_group=topk_group, top_k=top_k, scale=scale,
         norm_topk_prob=norm_topk_prob, scoring=scoring,
         router_bias=router_bias, activation=activation)
@@ -486,11 +487,12 @@ def held_expert_ffn_hit(data, router_weight, gate_weight, up_weight,
                         down_weight, **how):
     """:func:`held_expert_ffn` with a fourth output: the held experts that
     drew a pair at all (how near the layer stands to every expert's weights
-    being read)."""
+    being read), and no count of the route: a layer that holds every expert
+    has one."""
     router_data = how.pop("router_data", None)
     return _held_expert_ffn(
         data, (router_weight, gate_weight, up_weight, down_weight),
-        router_data, True, **how)
+        router_data, count_hit=True, **how)
 
 
 # --------------------------------------------------------------------------

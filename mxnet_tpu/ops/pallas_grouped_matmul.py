@@ -52,7 +52,7 @@ from .pallas_common import on_tpu as _on_tpu
 from .pallas_common import resolve_interpret as _resolve_interpret
 
 __all__ = ["ACTIVATIONS", "grouped_matmul", "grouped_glu_ffn",
-           "grouped_matmul_refusal"]
+           "grouped_matmul_refusal", "whole_row_tiles"]
 
 # What a call's double-buffered blocks (rows, weights, output) and float32
 # products may take of a v5e core's 128 MiB of VMEM; the call asks for what
@@ -77,6 +77,12 @@ def _row_tiles(m):
     a group (PERF.md, PR 36: one chip call, bfloat16, v5e). No count was
     found at which the TPU's ``ragged_dot`` wins."""
     return (32, 32) if m <= 1024 else (256, 128)
+
+
+def whole_row_tiles(m):
+    """``m`` rows rounded up to whole row tiles of a call of that many."""
+    tm, _ = _row_tiles(m)
+    return -(-m // tm) * tm
 
 
 def _block_bytes(tm, k, tn, n_rhs, itemsize, out_itemsize):

@@ -35,7 +35,7 @@ def shard_map(f, mesh, in_specs, out_specs):
                          check_vma=False)
 
 __all__ = ["moe_ffn", "init_moe_params", "moe_param_specs",
-           "group_limited_topk", "held_expert_ffn"]
+           "group_limited_topk", "held_expert_ffn", "held_prefix_rows"]
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, num_experts: int,
@@ -170,12 +170,23 @@ def _kernel_products_bwd(activation, saved, ct):
 _kernel_products.defvjp(_kernel_products_fwd, _kernel_products_bwd)
 
 
+def held_prefix_rows(pairs: int, n_held: int, n_experts: int):
+    """The static count of sorted pairs that :func:`held_expert_ffn`'s round
+    about the grouped products walks when a call's held pairs fit it, or
+    None where it always walks all ``pairs``: four times the even share of
+    ``n_held`` of ``n_experts`` experts, rounded up to whole row tiles of
+    the grouped kernel; None where that is not under ``pairs`` (every
+    expert held, a quarter of them or more)."""
+    rows = _grouped.whole_row_tiles(-(-4 * pairs * n_held // n_experts))
+    return rows if rows < pairs else None
+
+
 def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
                     n_group: int = 1, topk_group: int = 1, top_k: int,
                     scale: float = 1.0, norm_topk_prob: bool = False,
                     scoring: str = "softmax", router_bias=None,
                     router_h=None, activation: str = "silu",
-                    count_hit: bool = False):
+                    count_hit: bool = False, count_route: bool = False):
     """What the experts held here add to an expert layer's output.
 
     ``h`` [n, d] are the (normed) tokens; ``router_w`` [E, d] routes over
@@ -199,12 +210,29 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
     whole lane tiles), ``lax.ragged_dot`` elsewhere. Pairs routed to absent
     experts add nothing here: their chips add them.
 
+    The gather before the products, the products, the mask and the
+    scatter-add after them walk the held pairs, not every pair: the sort
+    puts the held pairs first, so they are a PREFIX of the sorted pairs, and
+    where few of the experts are held that round runs over a prefix of
+    static length (:func:`held_prefix_rows`: four times the held experts'
+    even share of the pairs, in whole row tiles: an eighth of the pairs with
+    8 of 256 held, a fifth with 8 of 160). A call whose held pairs pass that
+    length takes the whole length inside the same program (``lax.cond``), so
+    nothing is dropped whatever the load; the rows the prefix leaves out are
+    those the mask zeroes, and the held rows keep their order in the
+    scatter-add, so both lengths give the same float32 sums bit for bit.
+    Where the prefix is not shorter than the pairs (every expert held, a toy
+    model) no branch is built and the program is the whole-length one.
+
     Returns ``(y [n, d], stats)``; ``stats`` are two int32 scalars, the
-    pairs routed to held experts and the largest load of one, and with
-    ``count_hit`` a third: the held experts that drew a pair at all. The
-    ``moe_path_total{path, reason}`` counter says at trace time what was
-    built: ``pallas_grouped``, or ``sorted_ragged_dot`` and the gate's
-    first failed rule.
+    pairs routed to held experts and the largest load of one, with
+    ``count_hit`` a third: the held experts that drew a pair at all, and
+    with ``count_route`` one more, ``[1]`` int32 (one entry a call): 1 where
+    this call walked the whole length (always, where no branch was built),
+    0 where the prefix. The ``moe_path_total{path, reason, route}`` counter
+    says at trace time what was built: ``pallas_grouped``, or
+    ``sorted_ragged_dot`` and the gate's first failed rule; ``route``
+    ``prefix_or_whole`` where the branch is, ``whole`` where not.
     """
     from .. import observability as obs
 
@@ -215,9 +243,10 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
         raise ValueError(f"unknown activation {activation!r}: silu or relu")
     why = _grouped.grouped_matmul_refusal(n * top_k, d, w_gate.shape[2],
                                           h.dtype, w_gate.dtype)
+    prefix = held_prefix_rows(n * top_k, n_held, n_experts)
     obs.counter("moe_path_total").inc(                      # trace time
         path="sorted_ragged_dot" if why else "pallas_grouped",
-        reason=why or "")
+        reason=why or "", route="prefix_or_whole" if prefix else "whole")
     with jax.named_scope("router"):
         logits = jnp.einsum("nd,ed->ne",
                             (h if router_h is None else router_h).astype(jnp.float32),
@@ -246,14 +275,30 @@ def held_expert_ffn(h, router_w, w_gate, w_up, w_down, *, held_experts,
         token = order // top_k
         is_held = slot[order] < n_held
         pair_w = weights.reshape(-1)[order]
-    with jax.named_scope("experts"):
-        products = _ragged_products if why else _kernel_products
-        y = products(h[token], w_gate, w_up, w_down, sizes, activation)
+    products = _ragged_products if why else _kernel_products
+
+    def held_part(rows=None):
+        """What the first ``rows`` sorted pairs add (default: all of them),
+        ``[n, d]`` float32."""
+        at, mask, w = (token, is_held, pair_w) if rows is None else \
+            (token[:rows], is_held[:rows], pair_w[:rows])
+        y = products(h[at], w_gate, w_up, w_down, sizes, activation)
         # rows past the held pairs belong to no group: a backend may leave
         # them unwritten (the TPU's does), so they are masked, not weighted 0
-        out = jnp.zeros((n, d), jnp.float32).at[token].add(
-            jnp.where(is_held[:, None], y * pair_w[:, None], 0.0))
+        return jnp.zeros((n, d), jnp.float32).at[at].add(
+            jnp.where(mask[:, None], y * w[:, None], 0.0))
+
+    with jax.named_scope("experts"):
+        if prefix:
+            # (the held pairs are summed again for the stats below, where the
+            # whole-length program has always summed them)
+            whole = sizes.sum() > prefix
+            out = lax.cond(whole, held_part, lambda: held_part(prefix))
+        else:   # the whole length is the one there is
+            whole, out = True, held_part()
     stats = (sizes.sum(), sizes.max())
     if count_hit:
         stats += ((sizes > 0).sum().astype(jnp.int32),)
+    if count_route:
+        stats += (jnp.asarray(whole, jnp.int32).reshape(1),)
     return out.astype(h.dtype), stats

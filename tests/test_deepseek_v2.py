@@ -75,9 +75,12 @@ def test_a_cached_forward_returns_the_expert_layers_counts_third(model):
         cache=[tuple(mx.nd.NDArray(b) for b in layer) for layer in pool],
         start_pos=mx.nd.array([0], dtype="int32"),
         page_table=mx.nd.array([[1, 2, 3, 4]], dtype="int32"))
-    assert len(new_cache) == 3 and set(counts) == {"moe_pairs_held",
-                                                   "moe_max_load"}
+    assert len(new_cache) == 3 and set(counts) == {
+        "moe_pairs_held", "moe_max_load", "moe_whole_path"}
     assert counts["moe_pairs_held"].shape == (2,)  # an entry per expert layer
+    # 4 of 16 held: four times their share is every pair, so no prefix of
+    # the sorted pairs is built and each layer's one call walks them whole
+    assert counts["moe_whole_path"].tolist() == [[1], [1]]
     # 12 tokens x 3 experts each, 4 of 16 held: some pairs, never all of them
     assert 0 < int(counts["moe_pairs_held"].max()) < 36
     assert int(counts["moe_max_load"].max()) <= int(counts["moe_pairs_held"].max())
@@ -120,7 +123,8 @@ def test_prefill_then_decode_through_the_latent_cache_matches_the_reference(mode
     assert obs.gauge("gen_cache_bytes_per_token").value() == 1536.0
     assert "latent" in engine.read_path
     record = obs.step_records("decode_step")[-1]
-    assert set(record.counts) == {"moe_pairs_held", "moe_max_load"}
+    assert set(record.counts) == {"moe_pairs_held", "moe_max_load",
+                                  "moe_whole_path"}
     assert len(record.counts["moe_pairs_held"]) == 2
     assert [m for m, _ in record.marks] == ["mx.gen.decode.pages",
                                             "mx.gen.decode.dispatch",

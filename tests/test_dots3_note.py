@@ -390,7 +390,8 @@ def test_few_tokens_alone_read_as_they_do_among_many(scoring):
     held experts without a pair. Routing is a token's own, so the same
     tokens among many give the same rows, by the one grouped path
     (``moe_path_total{path=sorted_ragged_dot}``: toy widths on the CPU take
-    the kernel's refusal) both times."""
+    the kernel's refusal) both times; the few walk their 24 pairs whole (no
+    prefix of whole row tiles is shorter), the many a prefix of 96 of 256."""
     rng = np.random.default_rng(8)
     n, d, w, experts, held, k = 6, 16, 8, 32, [3, 11, 30], 4
     many = jnp.asarray(rng.standard_normal((64, d)), jnp.float32)
@@ -402,10 +403,11 @@ def test_few_tokens_alone_read_as_they_do_among_many(scoring):
                router_bias=bias if scoring == "sigmoid" else None)
     count = obs.counter("moe_path_total")
     ragged = dict(path="sorted_ragged_dot", reason="the backend is not a TPU")
-    before = count.value(**ragged)
+    built = [dict(ragged, route="whole"), dict(ragged, route="prefix_or_whole")]
+    before = [count.value(**b) for b in built]
     few, (pairs, load) = moe.held_expert_ffn(many[:n], router, *mats, **how)
     all_, _ = moe.held_expert_ffn(many, router, *mats, **how)
-    assert count.value(**ragged) == before + 2
+    assert [count.value(**b) for b in built] == [b + 1 for b in before]
     assert float(jnp.abs(few - all_[:n]).max()) < 1e-5
     assert float(jnp.abs(few).max()) > 1e-3 and 0 < int(load) <= int(pairs)
 
@@ -439,6 +441,110 @@ def test_a_long_prefills_held_pairs_are_the_references_plain_loop(skewed):
         router_bias=jnp.asarray(bias), norm_topk_prob=True)
     assert (int(pairs) > n * 8 // 8) is skewed
     assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+def _routed_by_hand(held_pairs, n=256, experts=32, held=(3, 11), k=4):
+    """An expert layer whose tokens' choices are set by hand: the router is
+    a scaled identity, so a token chooses the ``k`` experts whose
+    coordinates of ``h`` stand out; ``held_pairs`` (token, expert) pairs go
+    to the two held experts, two a token and one for an odd count, every
+    other choice to absent ones. (h, router, bias, mats, reference's cfg and
+    params.)"""
+    rng = np.random.default_rng(12)
+    absent = [e for e in range(experts) if e not in held]
+    h = 0.3 * rng.uniform(-1, 1, (n, experts)).astype("f4")
+    for i in range(n):
+        mine = list(held[:max(0, min(2, held_pairs - 2 * i))])
+        mine += list(rng.choice(absent, k - len(mine), replace=False))
+        h[i, mine] = 2.0 + rng.uniform(0, 1, k)
+    router = 4.0 * np.eye(experts, dtype="f4")
+    bias = np.zeros(experts, "f4")
+    d, w = experts, 8
+    mats = [jnp.asarray(rng.standard_normal(s), jnp.float32) * 0.2
+            for s in ((2, w, d), (2, w, d), (2, d, w))]
+    cfg = dict(n_routed_experts=experts, num_experts_per_tok=k,
+               norm_topk_prob=True, routed_scaling_factor=1,
+               held_experts=list(held))
+    params = {"l.router.w": jnp.asarray(router), "l.router.bias": jnp.asarray(bias),
+              "l.experts.gate.w": mats[0], "l.experts.up.w": mats[1],
+              "l.experts.down.w": mats[2]}
+    return jnp.asarray(h), jnp.asarray(router), jnp.asarray(bias), mats, cfg, params
+
+
+# 256 tokens x 4 experts a token, 2 of 32 held: the prefix is 4 x 1,024 x
+# 2 / 32 = 256 sorted pairs, whole row tiles of 32 as it stands
+@pytest.mark.parametrize("held_pairs,whole", [(100, 0), (256, 0), (257, 1),
+                                              (512, 1)],
+                         ids=["under", "at_the_bound", "one_over",
+                              "every_token_holds"])
+def test_the_held_pairs_prefix_is_the_whole_length_bit_for_bit(
+        held_pairs, whole, monkeypatch):
+    """``held_expert_ffn`` walks a prefix of the sorted pairs while a call's
+    held pairs fit it and every pair when they do not, inside one program:
+    either way the whole-length program's sums bit for bit (no branch built:
+    ``held_prefix_rows`` answering None), the reference's plain loop to
+    1e-5, the same gradient, and ``moe_whole_path`` saying which ran."""
+    h, router, bias, mats, cfg, params = _routed_by_hand(held_pairs)
+    assert moe.held_prefix_rows(256 * 4, 2, 32) == 256
+
+    def layer(h, *mats, route=True):
+        return moe.held_expert_ffn(
+            h, router, *(jnp.swapaxes(m, 1, 2) for m in mats),
+            held_experts=cfg["held_experts"], top_k=4, scoring="sigmoid",
+            router_bias=bias, norm_topk_prob=True, count_route=route)
+
+    def loss(h, *mats):
+        return (layer(h, *mats, route=False)[0] ** 2).sum()
+
+    branch = jax.make_jaxpr(layer)(h, *mats)
+    assert str(branch).count(" cond[") == 1
+    got, (pairs, _, route) = jax.jit(layer)(h, *mats)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(h, *mats)
+    assert int(pairs) == held_pairs and route.tolist() == [whole]
+    want = ref.routed_part(params, "l.", cfg, h, "float32")
+    assert float(jnp.abs(want).max()) > 1e-3
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+    # traced anew (other function objects: jax keeps a function's trace)
+    monkeypatch.setattr(moe, "held_prefix_rows", lambda *a: None)
+    assert " cond[" not in str(jax.make_jaxpr(lambda *a: layer(*a))(h, *mats))
+    plain, (_, _, always) = jax.jit(lambda *a: layer(*a))(h, *mats)
+    assert always.tolist() == [1]
+    assert np.array_equal(np.asarray(got), np.asarray(plain))
+    whole_grads = jax.jit(jax.grad(lambda *a: loss(*a), argnums=(0, 1, 2, 3)))
+    for g, p in zip(grads, whole_grads(h, *mats)):
+        assert float(jnp.abs(p).max()) > 0
+        assert np.allclose(np.asarray(g), np.asarray(p), rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("held,pairs_of", [
+    (list(range(32)), 1024),        # every expert held (SmallThinker)
+    ([3, 11, 20, 21, 22, 23, 30, 31], 1024),   # a quarter: 4 x the share is all
+    ([3, 11], 32)],                 # few pairs: no prefix of whole tiles is shorter
+    ids=["all_held", "a_quarter_held", "one_row_tile"])
+def test_where_no_prefix_is_shorter_no_branch_is_built(held, pairs_of):
+    """The program is then the whole-length one, operation for operation:
+    no ``cond`` in what is traced, and the same text as with the rule
+    answering None (SmallThinker's serving programs against the parent's
+    tree: ``tests/test_lowered_text_guard.py``)."""
+    rng = np.random.default_rng(2)
+    n, d, w, experts, k = pairs_of // 4, 16, 8, 32, 4
+    h = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((experts, d)).astype("f4") * 0.3)
+    mats = [jnp.asarray(rng.standard_normal(s), jnp.float32) * 0.2
+            for s in ((len(held), d, w), (len(held), d, w), (len(held), w, d))]
+    assert moe.held_prefix_rows(pairs_of, len(held), experts) is None
+    count = obs.counter("moe_path_total")
+    built = dict(path="sorted_ragged_dot", reason="the backend is not a TPU",
+                 route="whole")
+    before = count.value(**built)
+    traced = jax.make_jaxpr(lambda h: moe.held_expert_ffn(
+        h, router, *mats, held_experts=held, top_k=k))(h)
+    assert count.value(**built) == before + 1
+    assert " cond[" not in str(traced)
+    y, (pairs, load) = moe.held_expert_ffn(h, router, *mats,
+                                           held_experts=held, top_k=k)
+    assert 0 < int(load) <= int(pairs) <= pairs_of and bool(jnp.isfinite(y).all())
 
 
 def test_a_long_chunks_queries_in_quarters_are_the_plain_masked_attention():

@@ -170,8 +170,9 @@ def test_the_layer_by_the_kernel_is_the_layer_by_ragged_dot(
     how = dict(held_experts=held, top_k=TOP_K, scoring=scoring,
                activation=activation, norm_topk_prob=True, count_hit=True)
     count = obs.counter("moe_path_total")
-    ragged = dict(path="sorted_ragged_dot", reason="the backend is not a TPU")
-    kernel = dict(path="pallas_grouped", reason="")
+    ragged = dict(path="sorted_ragged_dot", reason="the backend is not a TPU",
+                  route="whole")     # 48 pairs: no prefix is shorter
+    kernel = dict(path="pallas_grouped", reason="", route="whole")
     before = count.value(**ragged), count.value(**kernel)
     want, want_stats = moe.held_expert_ffn(h, router, *mats, **how)
     kernel_path()

@@ -82,7 +82,12 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cells_widths(one_chip, window,
     (24576, 2560, 768, 64, jnp.bfloat16), (768, 5120, 1536, 8, jnp.bfloat16),
     (3072, 5120, 1536, 8, jnp.bfloat16), (384, 5120, 1536, 8, jnp.bfloat16),
     (8192, 5120, 1536, 8, jnp.bfloat16), (32768, 5120, 1536, 8, jnp.bfloat16),
-    (384, 5120, 1536, 8, jnp.float32), (32768, 5120, 1536, 8, jnp.float32)])
+    (384, 5120, 1536, 8, jnp.float32), (32768, 5120, 1536, 8, jnp.float32),
+    # PR 41: the prefix of the sorted pairs that the round about the products
+    # walks: a 4,096-token block of dots3-note-prev (an eighth of 32,768),
+    # its decode step (64 of 384), DeepSeek-V2's 1,024 bucket (1,280 of 6,144)
+    (4096, 5120, 1536, 8, jnp.bfloat16), (64, 5120, 1536, 8, jnp.bfloat16),
+    (1280, 5120, 1536, 8, jnp.bfloat16)])
 def test_the_grouped_matmul_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, pairs, d, w, held, dtype):
     compiled = _compiled(
@@ -97,6 +102,71 @@ def test_the_grouped_matmul_compiles_for_a_v5e_at_the_cells_shapes(
     # copy of a layer's weights
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= pairs * w * jnp.dtype(dtype).itemsize + (1 << 20)
+
+
+@pytest.mark.parametrize("tokens,top_k,experts,prefix", [
+    (4096, 8, 256, 4096), (1024, 6, 160, 1280)],
+    ids=["dots3_note_block", "deepseek_v2_bucket_1024"])
+def test_the_expert_layer_with_its_branch_compiles_for_a_v5e(
+        one_chip, monkeypatch, tokens, top_k, experts, prefix):
+    """``held_expert_ffn`` with 8 experts held at the cells' widths: the
+    gather, the kernels, the mask and the scatter-add inside BOTH branches
+    of a ``conditional`` (XLA:TPU's scatter emitter aborts on this
+    scatter-add inside a loop body: PERF.md, PR 31), the kernels at the
+    prefix's rows and at the whole length, and no more temporaries than the
+    whole length's own (the gathered rows, the float32 products, the masked
+    products)."""
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(gmm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gmm, "_resolve_interpret", lambda i: False)
+    assert moe.held_prefix_rows(tokens * top_k, 8, experts) == prefix
+    d, w, bf16 = 5120, 1536, jnp.bfloat16
+    compiled = _compiled(
+        one_chip,
+        lambda h, router, bias, *mats: moe.held_expert_ffn(
+            h, router, *mats, held_experts=range(8), top_k=top_k,
+            scoring="sigmoid", router_bias=bias, norm_topk_prob=True,
+            count_route=True),
+        ((tokens, d), bf16), ((experts, d), jnp.float32),
+        ((experts,), jnp.float32), ((8, d, w), bf16), ((8, d, w), bf16),
+        ((8, w, d), bf16))
+    text = compiled.as_text()
+    assert " conditional(" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    pairs = tokens * top_k
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= pairs * d * (2 + 4 + 4) + (64 << 20)
+
+
+def test_a_programs_expert_layers_share_one_lowering_a_row_count(
+        one_chip, monkeypatch):
+    """Three expert layers with the branch in one program: three
+    ``case`` operations and FOUR Mosaic kernels in the lowered text (gate-up
+    and down, at the prefix's rows and at the whole length), not twelve: the
+    prefix's products go through the same jitted function, and a lowering
+    to Mosaic comes before any compile cache is asked (PERF.md, PR 36)."""
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(gmm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gmm, "_resolve_interpret", lambda i: False)
+
+    def layers(h, router, bias, *mats):
+        for _ in range(3):
+            y, _ = moe.held_expert_ffn(
+                h, router, *mats, held_experts=range(8), top_k=8,
+                scoring="sigmoid", router_bias=bias, norm_topk_prob=True)
+            h = h + y
+        return h
+
+    bf16 = jnp.bfloat16
+    text = jax.jit(layers).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((512, 256), bf16), ((256, 256), jnp.float32),
+            ((256,), jnp.float32), ((8, 256, 128), bf16),
+            ((8, 256, 128), bf16), ((8, 128, 256), bf16)))).as_text()
+    assert text.count("stablehlo.case") == 3
+    assert text.count("tpu_custom_call") == 4
 
 
 def test_the_grouped_heads_kernel_compiles_at_a_group_of_one(one_chip):

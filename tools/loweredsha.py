@@ -1,9 +1,10 @@
-"""SHA-256 of the lowered text of GPT-2's, DeepSeek-V2's, dots3-note-prev's
-and Olmo-Hybrid's serving programs at toy widths, on the CPU: the paged
-decode step and the prefill buckets of each (DeepSeek-V2's buckets cover both
-forms of its latent attention; dots3-note-prev's decode step and one prefill
-program run both page groups, the selection and the sigmoid router;
-Olmo-Hybrid's carry slot state beside the pools). A PR
+"""SHA-256 of the lowered text of GPT-2's, DeepSeek-V2's, dots3-note-prev's,
+Olmo-Hybrid's and SmallThinker's serving programs at toy widths, on the CPU:
+the paged decode step and the prefill buckets of each (DeepSeek-V2's buckets
+cover both forms of its latent attention; dots3-note-prev's decode step and
+one prefill program run both page groups, the selection and the sigmoid
+router; Olmo-Hybrid's carry slot state beside the pools; SmallThinker's hold
+every expert, so its expert layers build no branch over the sorted pairs). A PR
 that says "their programs are the parent's" shows it with these: the same
 hashes from the parent's tree and from its own
 (``tests/test_lowered_text_guard.py`` holds the parent's). The text is what
@@ -69,6 +70,19 @@ OLMO_HYBRID_TOY = dict(
     engine={"batch_size": 3, "paged": True, "page_size": 4,
             "num_pages": {"all": 64}, "max_length": 64,
             "cache_dtype": "float32", "prefill_buckets": [16]})
+SMALLTHINKER_TOY = dict(
+    model="smallthinker", hidden_size=64, num_attention_heads=6,
+    num_key_value_heads=2, head_dim=16, rope_theta=1500000,
+    sliding_window_size=5, rms_norm_eps=1e-6, n_layer=4,
+    moe_ffn_hidden_size=24, moe_num_primary_experts=8,
+    moe_num_active_primary_experts=3, moe_primary_router_apply_softmax=True,
+    norm_topk_prob=True, rope_layout=[0, 1, 1, 1],
+    sliding_window_layout=[0, 1, 1, 1], n_vocab=200, initializer_range=0.1,
+    max_position_embeddings=256, held_experts=list(range(8)),
+    precision={"weights": "float32"},
+    engine={"batch_size": 3, "paged": True, "page_size": 4,
+            "num_pages": {"all": 64, "window": 12}, "max_length": 64,
+            "cache_dtype": "float32", "prefill_buckets": [16]})
 GPT2_TOY = dict(n_layer=2, n_embd=32, n_head=2, n_ctx=64, n_vocab=64,
                 engine={"batch_size": 2, "paged": True, "page_size": 8,
                         "max_length": 64, "prefill_buckets": [8, 16]})
@@ -81,9 +95,11 @@ def engines():
     from benchmark.reference import deepseek_v2 as ref_v2
     from benchmark.reference import dots3_note as ref_dots3
     from benchmark.reference import olmo_hybrid as ref_olmo
+    from benchmark.reference import smallthinker as ref_small
     from benchmark.systems import deepseek_v2 as adaptor_v2
     from benchmark.systems import dots3_note as adaptor_dots3
     from benchmark.systems import olmo_hybrid as adaptor_olmo
+    from benchmark.systems import smallthinker as adaptor_small
     from benchmark.weights import make_weights
     from mxnet_tpu import nd
     from mxnet_tpu.inference import GenerationEngine
@@ -98,11 +114,13 @@ def engines():
     weights = make_weights(ref_v2.param_specs(DEEPSEEK_V2_TOY), 7)
     dots3 = make_weights(ref_dots3.param_specs(DOTS3_NOTE_TOY), 7)
     olmo = make_weights(ref_olmo.param_specs(OLMO_HYBRID_TOY), 7)
+    small = make_weights(ref_small.param_specs(SMALLTHINKER_TOY), 7)
     # a later model is built LAST: the blocks' names count up as they are made
     return {"gpt2": GenerationEngine(net, **c["engine"]),
             "deepseek_v2": adaptor_v2.build_serve(DEEPSEEK_V2_TOY, weights)[0],
             "dots3_note": adaptor_dots3.build_serve(DOTS3_NOTE_TOY, dots3)[0],
-            "olmo_hybrid": adaptor_olmo.build_serve(OLMO_HYBRID_TOY, olmo)[0]}
+            "olmo_hybrid": adaptor_olmo.build_serve(OLMO_HYBRID_TOY, olmo)[0],
+            "smallthinker": adaptor_small.build_serve(SMALLTHINKER_TOY, small)[0]}
 
 
 def lowered_sha():
