@@ -753,6 +753,118 @@ def check_gdn(rows=8, heads=30, dk=96, dv=192, steps=3, interpret=None):
             "chunk_prefill_rel_err": round(chunk_err, 8)}
 
 
+def check_lightning(rows=8, heads=32, d=128, steps=3, interpret=None):
+    """MiniCPM-SALA's lightning layer in decode at its published widths: the
+    gated-delta kernel without its delta term (``lightning_decode_step``)
+    over a donated state, some rows dead, against the XLA form; then the
+    chunked prefill, two stretches that hand the state on with padding
+    behind the prompt, against the kernel a position at a time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import pallas_gdn as gdn
+
+    rs = np.random.RandomState(SEED)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    state = f32(rs.randn(rows, d, heads * d))
+    live = jnp.asarray(np.arange(rows) % 3 != 1)
+    shape = jax.ShapeDtypeStruct((rows, heads, d), jnp.float32)
+    if interpret is None:
+        why = gdn.gdn_decode_refusal(state, shape, shape)
+        if why is not None:
+            raise AssertionError(f"lightning: the gate refuses: {why}")
+    decay = f32(np.exp(-2.0 ** (-8.0 * np.arange(1, heads + 1) / heads)))
+    ones = jnp.ones((rows, heads), jnp.float32)
+    kernel = jax.jit(lambda s, q, k, v: gdn.gdn_decode_step(
+        s, q, k, v, ones * decay, ones, live, interpret=interpret,
+        delta=False), donate_argnums=(0,))
+    got, want, errs = state + 0.0, state, []
+    for _ in range(steps):
+        args = tuple(f32(rs.randn(rows, heads, d)) for _ in range(3))
+        o_got, got = kernel(got, *args)
+        o_want, want = gdn.gdn_decode_xla(want, *args, ones * decay, ones,
+                                          live, delta=False)
+        errs.append(max(_rel_err(o_got, o_want), _rel_err(got, want)))
+    if not max(errs) < 1e-5:
+        raise AssertionError(f"lightning: relative error {max(errs)} against XLA")
+    if not bool(jnp.array_equal(got[~live], state[~live])):
+        raise AssertionError("lightning: a dead row's state was moved")
+    t, length = 256, 200
+    q, k, v = (f32(rs.randn(t, heads, d)) * d ** -0.5 for _ in range(3))
+    chunk = jax.jit(gdn.lightning_chunk_prefill, static_argnums=(5,))
+    o1, s1 = chunk(q[:128], k[:128], v[:128], jnp.log(decay), length, 128)
+    o2, s2 = chunk(q[128:], k[128:], v[128:], jnp.log(decay), length - 128,
+                   128, None, s1)
+    one = jax.jit(lambda s, q, k, v: gdn.gdn_decode_step(
+        s, q, k, v, decay[None], jnp.ones((1, heads)), jnp.ones((1,), bool),
+        interpret=interpret, delta=False), donate_argnums=(0,))
+    s_step, o_step = jnp.zeros((1, d, heads * d), jnp.float32), []
+    for i in range(length):
+        o, s_step = one(s_step, q[i:i + 1], k[i:i + 1], v[i:i + 1])
+        o_step.append(o[0])
+    chunk_err = max(
+        _rel_err(jnp.concatenate([o1, o2])[:length], jnp.stack(o_step)),
+        _rel_err(gdn.state_rows(s2), s_step[0]))
+    if not chunk_err < 1e-4:
+        raise AssertionError(f"lightning: the chunked prefill lies {chunk_err} "
+                             "from the recurrence")
+    return {"rows": rows, "live": int(live.sum()), "heads": heads,
+            "state": [d, d], "rel_err": round(max(errs), 8),
+            "chunk_prefill_rel_err": round(chunk_err, 8)}
+
+
+def check_block_list(rows=8, heads=32, kv=2, ch=128, ps=64, pages=600,
+                     length=128, dtype="bfloat16", interpret=None):
+    """MiniCPM-SALA's sparse layer in decode at its published widths: the
+    block-list kernel ``paged_gqa_decode_selected`` (a list of up to 128 pages
+    a row and key-value head, of a page that head's 128 lanes) against the
+    XLA gather of the listed pages: rows that list every block they hold,
+    rows that list 64 chosen ones, a list of one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rs = np.random.RandomState(SEED)
+    q = jnp.asarray(rs.randn(rows, heads, 1, ch), dtype)
+    k_pool, v_pool = (jnp.asarray(rs.randn(pages + 1, ps, kv * ch), dtype)
+                      for _ in range(2))
+    counts = np.minimum(np.array(
+        [[1, 1]] + [[64, 64], [length, length], [37, 37]] * rows,
+        np.int32)[:rows], length)
+    blocks = np.stack([np.sort(np.stack([
+        rs.permutation(4 * length)[:length] for _ in range(kv)]), axis=1)
+        for _ in range(rows)]).astype(np.int32)
+    own = np.array([blocks[b, :, counts[b, 0] - 1].max() for b in range(rows)])
+    for b in range(rows):   # both heads' lists end with the query's own block
+        blocks[b, :, counts[b, 0] - 1] = own[b]
+    position = (own * ps + rs.randint(0, ps, rows)).astype(np.int32)
+    page_ids = rs.randint(1, pages + 1, (rows, kv, length)).astype(np.int32)
+    args = (q, k_pool, v_pool, jnp.asarray(page_ids), jnp.asarray(blocks * ps),
+            jnp.asarray(counts), jnp.asarray(position))
+    if interpret is None:
+        why = ppa.paged_gqa_selected_refusal(q, k_pool, page_ids)
+        if why is not None:
+            raise AssertionError(f"block list: the gate refuses: {why}")
+    kernel = jax.jit(lambda *a: ppa.paged_gqa_read(
+        *a[:3], None, a[6], selected=a[3:6], interpret=interpret))
+    calls = kernel.lower(*args).as_text().count("tpu_custom_call")
+    if not interpret and calls != 1:
+        raise AssertionError(f"block list: lowered with {calls} Mosaic kernels")
+    got, want = kernel(*args), att._paged_block_gather_read(*args)
+    if not bool(jnp.isfinite(got).all()):
+        raise AssertionError("block list: the kernel's output is not finite")
+    err = _rel_err(got, want)
+    if not err < (2e-2 if dtype == "bfloat16" else 1e-5):
+        raise AssertionError(f"block list: relative error {err} against XLA")
+    return {"rows": rows, "heads": [heads, kv, ch], "page": ps,
+            "lists": counts[:, 0].tolist(), "tpu_custom_calls": calls,
+            "rel_err": round(err, 6)}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -821,6 +933,8 @@ def phase_kernels():
            "paged_gqa_decode": check_gqa(),
            "grouped_matmul": check_grouped(),
            "gdn_decode_step": check_gdn(),
+           "lightning_decode_step": check_lightning(),
+           "paged_gqa_decode_selected": check_block_list(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

@@ -252,6 +252,26 @@ def _count_routes(whole) -> int:
     return n
 
 
+#: the models' counts that feed a counter beside the records they land in
+_COUNTED = frozenset(("moe_whole_path", "blocks_read", "blocks_held"))
+
+
+def _count_stats(name, values):
+    """A program's count ``name`` (an entry a layer or a call) as it came
+    back with the tokens: into its counter where it has one
+    (``moe_route_total{path}``; ``gen_blocks_read_total`` and
+    ``gen_blocks_held_total``, the blocks a selecting layer's reads visited
+    and the blocks their rows held). Returns what a prefill's record keeps
+    of it: the whole-length calls of the route, else the entries."""
+    if name == "moe_whole_path":
+        return _count_routes(values)
+    if name in _COUNTED:
+        _obs.counter(f"gen_{name}_total",
+                     f"the models' count {name}, summed over layers, decode "
+                     "steps and prefills").inc(int(np.sum(values)))
+    return np.asarray(values).tolist()
+
+
 class _WindowPages:
     """The host allocator of a ``window`` page group: the pools of layers
     that attend only the last ``window`` positions. A row holds the pages
@@ -388,7 +408,11 @@ class GenerationEngine:
             writes that row's state from zero, and ``live=`` ((B,) bool) by
             a decode step, which advances the live rows and no other. The
             prefix cache, forks and speculation are refused: each would
-            need a copy of a row's state.
+            need a copy of a row's state;
+          - optionally ``prefill_counts`` = names of the forward's ``counts``
+            that a paged PREFILL brings back behind its first token (one
+            array, so still one blocking read) into the ``prefill`` record's
+            ``counts``; a decode step's counts all come back with its tokens.
 
         Dropout should be 0 for exact equivalence (evaluation mode disables
         it regardless).
@@ -1300,12 +1324,17 @@ class GenerationEngine:
         last = logits[0, 0] if only_last else jax.lax.dynamic_index_in_dim(
             logits, length - 1, axis=1, keepdims=False)[0]
         tok = self._sample(last[None, :], key)[0].astype(jnp.int32)
-        if "moe_whole_path" in stats:
-            # the expert layers' route, an entry a call, rides behind the
-            # token: one array, so still one blocking read (a model without
-            # the count keeps its scalar and its program)
+        # the counts the model names (``prefill_counts``; the expert layers'
+        # route, an entry a call, where it names none) ride behind the
+        # token: one array, so still one blocking read (a model without
+        # such counts keeps its scalar and its program)
+        names = getattr(self.net, "prefill_counts", None) or tuple(
+            n for n in ("moe_whole_path",) if n in stats)
+        self._prefill_ride = tuple((n, stats[n].size) for n in names)
+        if names:
             tok = jnp.concatenate(
-                [tok[None], stats["moe_whole_path"].reshape(-1)])
+                [tok[None]] + [stats[n].reshape(-1).astype(jnp.int32)
+                               for n in names])
         return (table, new_pools), tok, last
 
     def _spec_prefill_fn(self, params, dparams, carry, tokens, slot, length,
@@ -1726,8 +1755,11 @@ class GenerationEngine:
                     self.cache = cache
             with _obs.span("mx.gen.prefill.read"):
                 tok = np.asarray(tok)  # host sync: the first token is ready
-                if tok.ndim:  # with the expert layers' route behind it
-                    rec.counts["moe_whole_path"] = _count_routes(tok[1:])
+                if tok.ndim:  # with the model's counts behind it
+                    at = 1
+                    for name, n in self._prefill_ride:
+                        rec.counts[name] = _count_stats(name, tok[at:at + n])
+                        at += n
                     tok = tok[0]
                 tok = int(tok)
             with _obs.span("mx.gen.prefill.index"):
@@ -1812,8 +1844,8 @@ class GenerationEngine:
                 tok, done = np.array(tok), np.array(done)
                 if stats:
                     rec.counts = {k: v.tolist() for k, v in stats.items()}
-                    if "moe_whole_path" in stats:
-                        _count_routes(stats["moe_whole_path"])
+                    for name in _COUNTED & set(stats):
+                        _count_stats(name, stats[name])
                 if self.paged and self._window is not None:
                     # the engine's own count beside the model's: the window
                     # group's pages in use as this step left them

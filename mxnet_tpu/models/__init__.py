@@ -16,12 +16,16 @@ models.smallthinker (grouped key-value heads over paged K/V pools, window
 layers with positions beside full layers without, a router that reads the
 block's input, every ReLU-gated expert held), and models.olmo_hybrid (Gated
 DeltaNet layers, whose recurrent state a paged engine keeps by slot, three
-to one with full attention over paged K/V pools).
+to one with full attention over paged K/V pools), and models.minicpm_sala
+(block-sparse attention whose decode read walks a list of chosen blocks by
+page, with a pool of compressed keys for the selector, one to three with
+Lightning Attention layers whose decayed state is kept by slot).
 """
 from . import deepseek_v2  # noqa: F401
 from . import dots3_note  # noqa: F401
 from . import smallthinker  # noqa: F401
 from . import olmo_hybrid  # noqa: F401
+from . import minicpm_sala  # noqa: F401
 from . import bert  # noqa: F401
 from . import gpt2  # noqa: F401
 from . import ssd  # noqa: F401
@@ -32,5 +36,6 @@ from .dots3_note import Dots3NoteModel, get_dots3_note  # noqa: F401
 from .gpt2 import GPT2Model, get_gpt2  # noqa: F401
 from .smallthinker import SmallThinkerModel, get_smallthinker  # noqa: F401
 from .olmo_hybrid import OlmoHybridModel, get_olmo_hybrid  # noqa: F401
+from .minicpm_sala import MiniCPMSALAModel, get_minicpm_sala  # noqa: F401
 from .ssd import SSD, get_ssd  # noqa: F401
 from .transformer import Transformer, get_transformer  # noqa: F401

@@ -1120,6 +1120,64 @@ def _paged_gqa_gather_read(q, k_pool, v_pool, page_table, position,
     return out.reshape(b, h, tq, ch)
 
 
+def _paged_block_gather_read(q, k_pool, v_pool, page_ids, starts, counts,
+                             position):
+    """The XLA read of a LIST of blocks a row and key-value head (a block is
+    one page): the listed pages gathered, a head's own lanes of each, and
+    attended where a block counts (``counts``) and a position is at or
+    before the query's. ``q`` (B, H, 1, Ch); ``page_ids``, ``starts`` (B,
+    Hkv, L); ``counts`` (B, Hkv); returns (B, H, 1, Ch) float32. The oracle
+    of ``pallas_paged_attention.paged_gqa_read(selected=)`` and the CPU's
+    path."""
+    b, h, _, ch = q.shape
+    ps, hkv = k_pool.shape[1], k_pool.shape[2] // ch
+    length = page_ids.shape[2]
+    mm = _gqa_products_dtype(q, k_pool.dtype)
+    kpos = (starts[..., None]
+            + jnp.arange(ps, dtype=jnp.int32)).reshape(b, hkv, length * ps)
+    listed = jnp.repeat(jnp.arange(length)[None, None, :]
+                        < jnp.maximum(counts, 1)[..., None], ps, axis=2)
+    seen = listed & (kpos <= position[:, None, None])       # (B, Hkv, L*ps)
+
+    def history(pool):   # a head's own lanes: (B, Hkv, L*ps, Ch)
+        hist = jnp.stack([pool[page_ids[:, g], :, g * ch:(g + 1) * ch]
+                          for g in range(hkv)], axis=1)
+        hist = hist.reshape(b, hkv, length * ps, ch).astype(mm)
+        return jnp.where(seen[..., None], hist, 0)
+
+    scale = 1.0 / jnp.sqrt(jnp.asarray(ch, jnp.float32))
+    q4 = q.astype(mm).reshape(b, hkv, h // hkv, ch)
+    scores = jnp.einsum("bkgc,bksc->bkgs", q4, history(k_pool), **_F32) * scale
+    scores = jnp.where(seen[:, :, None, :], scores, -jnp.inf)
+    att = jax.nn.softmax(scores, axis=-1).astype(mm)
+    out = jnp.einsum("bkgs,bksc->bkgc", att, history(v_pool), **_F32)
+    return out.reshape(b, h, 1, ch)
+
+
+def paged_block_attention(q, k_pool, v_pool, page_ids, starts, counts,
+                          position):
+    """One query a row, ``q`` (B, H, 1, Ch), over the pages a table of
+    SELECTED pages names, a list a row and key-value head (block selection's
+    decode read, a block a page): ``paged_gqa_read(selected=)``
+    (``paged_gqa_decode_selected`` in a trace) where
+    :func:`~mxnet_tpu.ops.pallas_paged_attention.paged_gqa_selected_refusal`
+    lets it, else the XLA gather of the listed pages.
+    ``paged_read_path_total{path="selected_pages_kernel"|"selected_pages_xla",
+    reason}`` says at trace time what was built."""
+    from .. import observability as obs
+    from . import pallas_paged_attention as ppa
+
+    why = ppa.paged_gqa_selected_refusal(q, k_pool, page_ids)
+    obs.counter("paged_read_path_total").inc(
+        path="selected_pages_xla" if why else "selected_pages_kernel",
+        reason=why or "")
+    if why:
+        return _paged_block_gather_read(q, k_pool, v_pool, page_ids, starts,
+                                        counts, position)
+    return ppa.paged_gqa_read(q, k_pool, v_pool, None, position,
+                              selected=(page_ids, starts, counts))
+
+
 def _gqa_chunk_attention(q, k, v, window=None, query_block=512):
     """Causal attention of one whole chunk from position 0 with grouped
     heads, ``q`` (B, H, T, Ch) over ``k``/``v`` (B, Hkv, T, Ch), under a

@@ -16,6 +16,14 @@ XLA: within a block of ``chunk`` positions the delta rule's triangular
 system is solved by matrix products, and a ``lax.scan`` over the blocks
 carries the state from one to the next. No scan over positions.
 
+**The same family without the delta term** (Lightning Attention,
+arXiv:2401.04658: ``S_t = lambda S_(t-1) + k_t v_t^T`` with a fixed decay a
+head): ``delta=False`` of :func:`gdn_decode_step` / :func:`gdn_decode_xla`
+is the same kernel with the read ``S^T k`` and the write's correction left
+out (``lightning_decode_step`` in a trace), and
+:func:`lightning_chunk_prefill` its chunked prefill, which stops decaying
+and writing at the prompt's length.
+
 **The state's layout.** ``(slots, dk, H * dv)`` float32: key width on the
 sublanes, the heads' value widths side by side on the lanes (head ``h`` in
 lanes ``h * dv ..``), so a row's state is whole (8, 128) tiles with nothing
@@ -90,7 +98,7 @@ def gdn_decode_refusal(state, q, v):
 
 
 def _decode_kernel(block_ref, live_ref, s_ref, kt_ref, qt_ref, ax_ref, bv_ref,
-                   ab_ref, o_ref, out_ref, *, heads, dv, group):
+                   ab_ref, o_ref, out_ref, *, heads, dv, group, delta=True):
     """One row a grid step. ``block_ref`` names the row whose state the step
     holds: a dead row's step holds a live neighbour's and does nothing, so
     its own state is neither read nor written."""
@@ -119,15 +127,19 @@ def _decode_kernel(block_ref, live_ref, s_ref, kt_ref, qt_ref, ax_ref, bv_ref,
             lanes = pl.ds(g * width, width)
             s = s_ref[0, :, lanes]                          # (dk, width)
             kx, qx = spread(kt, g * group), spread(qt, g * group)
-            read = jnp.sum(s * kx, axis=0, keepdims=True)   # S^T k
-            write = bv_ref[0, :, lanes] - ab_ref[0, :, lanes] * read
+            if delta:
+                read = jnp.sum(s * kx, axis=0, keepdims=True)   # S^T k
+                write = bv_ref[0, :, lanes] - ab_ref[0, :, lanes] * read
+            else:
+                write = bv_ref[0, :, lanes]
             s = ax_ref[0, :, lanes] * s + kx * write
             out_ref[0, :, lanes] = s
             o_ref[0, :, lanes] = jnp.sum(s * qx, axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _decode_call(state, kt, qt, ax, bv, ab, live, heads, interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _decode_call(state, kt, qt, ax, bv, ab, live, heads, interpret,
+                 delta=True):
     b, dk, hv = state.shape
     dv = hv // heads
     group = _heads_a_group(heads, dv)
@@ -143,7 +155,8 @@ def _decode_call(state, kt, qt, ax, bv, ab, live, heads, interpret):
     own = lambda i, *_: (i, 0, 0)  # noqa: E731
     small = lambda w: pl.BlockSpec((1, 1, w), own)  # noqa: E731
     o, new = pl.pallas_call(
-        functools.partial(_decode_kernel, heads=heads, dv=dv, group=group),
+        functools.partial(_decode_kernel, heads=heads, dv=dv, group=group,
+                          delta=delta),
         out_shape=(jax.ShapeDtypeStruct((b, 1, hv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -155,7 +168,7 @@ def _decode_call(state, kt, qt, ax, bv, ab, live, heads, interpret):
                       small(hv), small(hv), small(hv)],
             out_specs=(small(hv), pl.BlockSpec((1, dk, hv), held))),
         input_output_aliases={2: 1},   # the state, behind the two scalars
-        name="gdn_decode_step",
+        name="gdn_decode_step" if delta else "lightning_decode_step",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -177,13 +190,16 @@ def _decode_operands(q, k, v, alpha, beta, live):
             wide(alpha * beta), jnp.asarray(live, bool))
 
 
-def gdn_decode_step(state, q, k, v, alpha, beta, live, interpret=None):
+def gdn_decode_step(state, q, k, v, alpha, beta, live, interpret=None,
+                    delta=True):
     """Advance the live rows' state one position: ``state`` ``(B, dk, H *
     dv)`` float32 (written in place where the caller donates it), ``q``, ``k`` ``(B, H,
     dk)``, ``v`` ``(B, H, dv)``, ``alpha``, ``beta`` ``(B, H)``, ``live``
     ``(B,)`` bool. Returns ``(o (B, H, dv) float32, state')``; a dead row's
     state is untouched bit for bit (its bytes are not moved) and its ``o``
-    is zero. Callers gate via :func:`gdn_decode_refusal`."""
+    is zero. With ``delta=False`` the rule is ``S <- alpha S + beta k v^T``
+    (no delta term: Lightning Attention's state, ``beta`` 1). Callers gate
+    via :func:`gdn_decode_refusal`."""
     kt, qt, ax, bv, ab, live = _decode_operands(q, k, v, alpha, beta, live)
     none = ~jnp.any(live)
     first = jnp.arange(live.shape[0]) == 0
@@ -192,11 +208,11 @@ def gdn_decode_step(state, q, k, v, alpha, beta, live, interpret=None):
     ax, bv, ab = (jnp.where(hold, fill, x)
                   for x, fill in ((ax, 1.0), (bv, 0.0), (ab, 0.0)))
     o, state = _decode_call(state, kt, qt, ax, bv, ab, live | (none & first),
-                            q.shape[1], _resolve_interpret(interpret))
+                            q.shape[1], _resolve_interpret(interpret), delta)
     return jnp.where(live[:, None, None], o, 0.0), state
 
 
-def gdn_decode_xla(state, q, k, v, alpha, beta, live):
+def gdn_decode_xla(state, q, k, v, alpha, beta, live, delta=True):
     """:func:`gdn_decode_step` in XLA: the same arithmetic in the same
     order, every row's state read and written."""
     b, heads, _ = q.shape
@@ -204,8 +220,11 @@ def gdn_decode_xla(state, q, k, v, alpha, beta, live):
     dv = v.shape[2]
     spread = lambda cols: jnp.repeat(cols, dv, axis=2)     # (B, dk, H*dv)  # noqa: E731
     kx, qx = spread(kt), spread(qt)
-    read = jnp.sum(state * kx, axis=1, keepdims=True)
-    new = ax * state + kx * (bv - ab * read)
+    if delta:
+        read = jnp.sum(state * kx, axis=1, keepdims=True)
+        new = ax * state + kx * (bv - ab * read)
+    else:
+        new = ax * state + kx * bv
     o = jnp.sum(new * qx, axis=1)
     keep = live[:, None, None]
     return (jnp.where(keep[:, 0], o, 0.0).reshape(b, heads, dv),
@@ -275,3 +294,62 @@ def gdn_chunk_prefill(q, k, v, g, beta, chunk=64):
     s, o = lax.scan(block, jnp.zeros((heads, dk, v.shape[-1]), jnp.float32),
                     (u, w, qk, q_in, k_out, carry))
     return jnp.moveaxis(o, 1, 2).reshape(t, heads, -1)[:length], s
+
+
+def lightning_chunk_prefill(q, k, v, log_decay, length, chunk=128, emit=None,
+                            state=None):
+    """``S_t = lambda S_(t-1) + k_t v_t^T``, ``o_t = S_t^T q_t`` over one
+    sequence from a zero state (or from ``state`` ``(H, dk, dv)``: a long
+    sequence a stretch at a time), in blocks of ``chunk`` positions: ``q``,
+    ``k`` ``(T, H, dk)``, ``v`` ``(T, H, dv)`` in any float dtype (a block
+    is turned to float32 as the scan reaches it), ``log_decay`` ``(H,)`` =
+    log lambda, ``length`` the positions that are real (a traced scalar,
+    negative or zero for none: the rest is a prefill's padding, which
+    neither decays nor writes the state).
+    Returns ``(o (T, H, dv) float32, S (H, dk, dv))``: every real
+    position's read and the state behind position ``length - 1``. With
+    ``emit`` a block's reads ``(chunk, H, dv)`` leave the scan as
+    ``emit(reads)`` ``(chunk, ...)``: what follows a read position by
+    position (a norm, a cast) then holds no float32 array of the sequence's
+    length.
+
+    Within a block ``(Q K^T * D) V`` with ``D_ij = lambda^(i-j)`` for ``i >=
+    j``, plus ``diag(lambda^(i+1)) Q S_in``, and ``S_out = lambda^C S_in +
+    sum_j lambda^(C-1-j) k_j v_j^T``; every exponent is a sum of log-decays
+    over REAL positions, none is positive, and every product is at
+    ``highest``."""
+    t, heads, dk = q.shape
+    c = min(chunk, t)
+    whole = lambda x: jnp.pad(  # noqa: E731
+        x, ((0, -t % c),) + ((0, 0),) * (x.ndim - 1))
+    q, k, v = whole(q), whole(k), whole(v)
+    n = q.shape[0] // c
+    real = jnp.arange(n * c) < length
+    blocks = lambda x: x.reshape(n, c, *x.shape[1:])  # noqa: E731
+    upto = jnp.tril(jnp.ones((c, c), bool))
+    log_decay = jnp.asarray(log_decay, jnp.float32)
+    f32 = lambda x: jnp.moveaxis(x.astype(jnp.float32), 0, 1)  # noqa: E731
+
+    def block(s, xs):
+        q_b, k_b, v_b, real_b = xs                          # (c, H, d), (c,)
+        q_b, v_b = f32(q_b), f32(v_b)                       # (H, c, d)
+        k_b = jnp.where(real_b[None, :, None], f32(k_b), 0.0)
+        run = jnp.cumsum(real_b.astype(jnp.float32))[None, :] \
+            * log_decay[:, None]                            # (H, c)
+        decay = jnp.exp(jnp.where(upto, run[:, :, None] - run[:, None, :],
+                                  -jnp.inf))
+        qk = jnp.einsum("hik,hjk->hij", q_b, k_b, precision=_HI) * decay
+        o = jnp.einsum("hij,hjv->hiv", qk, v_b, precision=_HI) \
+            + jnp.einsum("hik,hkv->hiv", q_b * jnp.exp(run)[..., None], s,
+                         precision=_HI)
+        k_out = k_b * jnp.exp(run[:, -1:] - run)[..., None]
+        s = s * jnp.exp(run[:, -1])[:, None, None] \
+            + jnp.einsum("hik,hiv->hkv", k_out, v_b, precision=_HI)
+        o = jnp.moveaxis(o, 0, 1)
+        return s, o if emit is None else emit(o)
+
+    if state is None:
+        state = jnp.zeros((heads, dk, v.shape[-1]), jnp.float32)
+    s, o = lax.scan(block, state,
+                    (blocks(q), blocks(k), blocks(v), blocks(real)))
+    return o.reshape(n * c, *o.shape[2:])[:t], s

@@ -880,7 +880,8 @@ def _gqa_call(table, position, lower, q2, k_pool, v_pool, ring, nb, run,
 
 
 def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
-                   block_pages=None, run_pages=None, interpret=None):
+                   block_pages=None, run_pages=None, interpret=None,
+                   selected=None):
     """Attention of one query a row, ``q`` ``(B, H, 1, Ch)``, over the row's
     paged history: ``H`` query heads over the pools' ``Hkv`` key-value heads
     (``(P+1, page_size, Hkv*Ch)``; query head ``i`` reads head ``i // (H //
@@ -901,7 +902,16 @@ def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
     page-by-page result (``run_pages=1``) bit for bit. A key-value head's
     ``H // Hkv`` query heads are the rows of one left operand. Operands in
     the pools' dtype, float32 scores and softmax. Returns ``(B, H, 1, Ch)`` float32. Callers gate via
-    :func:`paged_gqa_refusal`."""
+    :func:`paged_gqa_refusal`.
+
+    With ``selected=(page_ids, starts, counts)`` the read walks THAT table
+    and not every page the row holds (block selection, a block a page:
+    :func:`_selected_read`; ``page_table`` is not read; callers gate via
+    :func:`paged_gqa_selected_refusal`). A call without it builds the
+    program it built before there was one."""
+    if selected is not None:
+        return _selected_read(q, k_pool, v_pool, *selected, position,
+                              block_pages, interpret)
     b, h, tq, ch = q.shape
     ps, hc = k_pool.shape[1:]
     hkv = hc // ch
@@ -918,3 +928,313 @@ def paged_gqa_read(q, k_pool, v_pool, page_table, position, window=None,
                    k_pool, v_pool, window is not None, nb, run,
                    _resolve_interpret(interpret))
     return o2[:, :, :g * tq].reshape(b, h, tq, ch)
+
+
+# --------------------------------------------------------------------------
+# grouped key-value heads over a TABLE of SELECTED pages a row and key-value
+# head (one token a row): block selection's decode read
+# --------------------------------------------------------------------------
+_LIST_CHUNK = 8    # blocks fetched and attended at once
+
+
+def paged_gqa_selected_refusal(q, k_pool, page_ids):
+    """Why the selected-pages kernel does NOT read the pools for these operands
+    (anything with ``.shape``/``.dtype``), or None when it does: ``q`` ``(B,
+    H, 1, Ch)``, ``k_pool`` ``(P+1, page_size, Hkv*Ch)``, ``page_ids`` ``(B,
+    Hkv, L)``: a block of the list is one page. The first condition that
+    fails is the one named; callers take the XLA gather then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    _, h, tq, ch = q.shape
+    ps, hc = k_pool.shape[1], k_pool.shape[2]
+    if tq != 1:
+        return f"{tq} queries a row: the kernel reads for one"
+    if k_pool.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"pool dtype {jnp.dtype(k_pool.dtype).name} is not float32 or bfloat16"
+    if ch % _LANES or hc % ch or h % (hc // ch) or page_ids.shape[1] != hc // ch:
+        return (f"{h} query heads of {ch} over the pool's {hc} columns are "
+                f"not whole groups of whole {_LANES}-lane heads with a list "
+                "each")
+    sub = 8 * (4 // jnp.dtype(k_pool.dtype).itemsize)
+    if ps % sub:
+        return f"page size {ps} is not a multiple of {sub} sublanes"
+    if page_ids.shape[2] % _LIST_CHUNK:
+        return (f"lists of {page_ids.shape[2]} blocks are not whole chunks "
+                f"of {_LIST_CHUNK}")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _block_list_kernel(pid_ref, start_ref, cnt_ref, pos_ref, slot_ref, q_ref,
+                       kp_ref, vp_ref, o_ref, kbuf, vbuf, sem, m_ref, l_ref,
+                       acc_ref, *, ps, length, nb, scale):
+    """One row a grid step; its key-value heads in turn, each list in chunks
+    of ``nb`` blocks under a running maximum. The chunks of a row's heads and
+    of the rows follow one another through two buffer slots: the next chunk's
+    copies (this head's, the next head's first, the next row's first) are in
+    flight while this one's products run."""
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    hkv, r, ch = q_ref.shape[1:]
+    blk = nb * ps
+
+    def count(row, g):
+        return cnt_ref[row * hkv + g]
+
+    def for_each_copy(row, g, c, into, act):
+        """``act`` on the copies of chunk ``c`` of ``row``'s head ``g`` (a
+        Python int: its lanes of a page are a static slice)."""
+        base = (row * hkv + g) * length + c * nb
+        n = jnp.clip(count(row, g) - c * nb, 0, nb)
+
+        def page(i, carry):
+            pid = pid_ref[base + i]
+            at = pl.ds(pl.multiple_of(i * ps, ps), ps)
+            for pool, buf in ((kp_ref, kbuf), (vp_ref, vbuf)):
+                act(pltpu.make_async_copy(
+                    pool.at[pid, :, pl.ds(g * ch, ch)], buf.at[into, at, :],
+                    sem.at[into]))
+            return carry
+
+        lax.fori_loop(0, n, page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        for_each_copy(0, 0, 0, 0, lambda copy: copy.start())
+
+    before = slot_ref[b]
+    for g in range(hkv):
+        n_chunks = (count(b, g) + nb - 1) // nb
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def chunk(c, carry, g=g, n_chunks=n_chunks, before=before):
+            slot = (before + c) % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                for_each_copy(b, g, c + 1, 1 - slot, lambda copy: copy.start())
+
+            if g + 1 < hkv:
+                @pl.when(c + 1 == n_chunks)
+                def _():
+                    for_each_copy(b, g + 1, 0, 1 - slot,
+                                  lambda copy: copy.start())
+            else:
+                @pl.when((c + 1 == n_chunks) & (b + 1 < rows))
+                def _():
+                    for_each_copy(b + 1, 0, 0, 1 - slot,
+                                  lambda copy: copy.start())
+
+            for_each_copy(b, g, c, slot, lambda copy: copy.wait())
+            n = jnp.clip(count(b, g) - c * nb, 0, nb)
+
+            def clear(i, carry_):
+                # what was not fetched counts for nothing: a weight of 0
+                # does not clear a NaN that VMEM holds there
+                at = pl.ds(pl.multiple_of(i * ps, ps), ps)
+                vbuf[slot, at, :] = jnp.zeros((ps, ch), vbuf.dtype)
+                return carry_
+
+            lax.fori_loop(n, nb, clear, 0)
+            base = (b * hkv + g) * length + c * nb
+            lane = lax.broadcasted_iota(jnp.int32, (r, blk), 1)
+            entry = lane // ps
+            first = jnp.zeros((r, blk), jnp.int32)
+            for i in range(nb):   # each block's first position, by its lanes
+                first = jnp.where(entry == i, start_ref[base + i], first)
+            visible = (entry < n) & (first + lane % ps <= pos_ref[b])
+            s = lax.dot_general(q_ref[0, g], kbuf[slot], _NT,
+                                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(visible, s, _NEG)
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+            keep = jnp.exp(m_prev - m_new)
+            l_ref[...] = jnp.broadcast_to(
+                keep * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape)
+            acc_ref[...] = acc_ref[...] * keep + lax.dot_general(
+                p.astype(vbuf.dtype), vbuf[slot], _NN,
+                preferred_element_type=jnp.float32)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            return carry
+
+        lax.fori_loop(0, n_chunks, chunk, 0)
+        # a list with no visible position (never a served row's) reads zero
+        o_ref[0, g] = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        before = before + n_chunks
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _block_list_call(page_ids, starts, counts, position, q2, k_pool, v_pool,
+                     nb, interpret):
+    b, hkv, r, ch = q2.shape
+    ps = k_pool.shape[1]
+    length = page_ids.shape[2]
+    chunks = jnp.sum((counts + nb - 1) // nb, axis=1)
+    # the buffer slot each row's first chunk lands in: rows take turns
+    slot0 = (jnp.cumsum(chunks) - chunks) % 2
+    row = lambda i, *_: (i, 0, 0, 0)  # noqa: E731
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    need = (4 * nb * ps * ch * itemsize + 2 * hkv * r * ch * (itemsize + 4)
+            + r * (2 * _LANES + ch) * 4 + _SCORE_TEMPS * r * nb * ps * 4)
+    return pl.pallas_call(
+        functools.partial(
+            _block_list_kernel, ps=ps, length=length, nb=nb,
+            scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, ch), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, hkv, r, ch), row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, hkv, r, ch), row),
+            scratch_shapes=[pltpu.VMEM((2, nb * ps, ch), k_pool.dtype),
+                            pltpu.VMEM((2, nb * ps, ch), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((r, _LANES), jnp.float32),
+                            pltpu.VMEM((r, _LANES), jnp.float32),
+                            pltpu.VMEM((r, ch), jnp.float32)]),
+        name="paged_gqa_decode_selected",
+        interpret=interpret,
+        # rows run in order: each starts the next one's copies
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + 16 * 1024 * 1024),
+    )(page_ids.reshape(-1), starts.reshape(-1), counts.reshape(-1), position,
+      slot0.astype(jnp.int32), q2, k_pool, v_pool)
+
+
+def _selected_read(q, k_pool, v_pool, page_ids, starts, counts, position,
+                   chunk_blocks=None, interpret=None):
+    """Attention of one query a row, ``q`` ``(B, H, 1, Ch)``, over the blocks
+    a LIST names, a list a row and key-value head: ``page_ids`` ``(B, Hkv,
+    L)`` the pool ids of the blocks (a block is one page of the pools ``(P+1,
+    page_size, Hkv*Ch)``), ``starts`` ``(B, Hkv, L)`` each block's first
+    position, ``counts`` ``(B, Hkv)`` how many entries of a list count (at
+    least one), ``position`` ``(B,)`` the query's: of a listed block the
+    positions ``<= position[b]`` are seen. Query head ``i`` reads the list of
+    key-value head ``i // (H // Hkv)``, and of a page that head's lanes
+    alone. Block-sparse attention's decode read (a row under the dense
+    length lists every block it holds, so one kernel serves both regimes).
+
+    The kernel fetches ``chunk_blocks`` blocks at a time (``_LIST_CHUNK``;
+    copies by id from the scalar-prefetched lists, the next chunk's in flight
+    while this one's products run, across heads and rows too) under a running
+    maximum and sum in float32 (``paged_gqa_decode_selected`` in a trace).
+    Operands in the pools' dtype, float32 scores and softmax. Returns ``(B,
+    H, 1, Ch)`` float32. Reached through ``paged_gqa_read(selected=)``;
+    callers gate via :func:`paged_gqa_selected_refusal`."""
+    b, h, tq, ch = q.shape
+    hkv = k_pool.shape[2] // ch
+    g = h // hkv
+    nb = int(chunk_blocks or _LIST_CHUNK)
+    r = _rows(g, tq, jnp.dtype(k_pool.dtype).itemsize)
+    q2 = q.astype(k_pool.dtype).reshape(b, hkv, g * tq, ch)
+    q2 = jnp.pad(q2, ((0, 0), (0, 0), (0, r - g * tq), (0, 0)))
+    as_i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    o2 = _block_list_call(as_i32(page_ids), as_i32(starts),
+                          jnp.maximum(as_i32(counts), 1), as_i32(position),
+                          q2, k_pool, v_pool, nb, _resolve_interpret(interpret))
+    return o2[:, :, :g * tq].reshape(b, h, tq, ch)
+
+
+# --------------------------------------------------------------------------
+# block selection's scoring: a group's summed softmax weights over a row's
+# compressed keys (one token a row)
+# --------------------------------------------------------------------------
+def paged_block_scores_refusal(q, keys):
+    """Why the scoring kernel does NOT weigh these operands (anything with
+    ``.shape``/``.dtype``), or None when it does: ``q`` ``(B, Hkv, G, Ch)``,
+    ``keys`` ``(B, J, Hkv*Ch)`` a row's compressed keys as gathered by its
+    page table. The first condition that fails is the one named; callers
+    take the XLA einsum then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    _, hkv, _, ch = q.shape
+    if keys.dtype not in (jnp.float32, jnp.bfloat16) or q.dtype != keys.dtype:
+        return (f"queries {jnp.dtype(q.dtype).name} and keys "
+                f"{jnp.dtype(keys.dtype).name} are not both float32 or both "
+                "bfloat16")
+    if ch % _LANES or keys.shape[2] != hkv * ch or keys.shape[1] % _LANES:
+        return (f"{keys.shape[1]} keys of {keys.shape[2]} columns under {hkv} "
+                f"heads of {ch} are not whole {_LANES}-lane tiles")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _block_scores_kernel(pos_ref, q_ref, k_ref, o_ref, *, group, size, stride,
+                         scale):
+    b = pl.program_id(0)
+    hkv, r, ch = q_ref.shape[1:]
+    n = k_ref.shape[1]
+    at = lax.broadcasted_iota(jnp.int32, (r, n), 1)
+    valid = at * stride + (size - 1) <= pos_ref[b]
+    counts = lax.broadcasted_iota(jnp.int32, (r, n), 0) < group
+    for g in range(hkv):
+        dots = lax.dot_general(q_ref[0, g], k_ref[0, :, pl.ds(g * ch, ch)],
+                               _NT, preferred_element_type=jnp.float32) * scale
+        dots = jnp.where(valid, dots, _NEG)
+        e = jnp.where(valid, jnp.exp(dots - jnp.max(dots, axis=1,
+                                                    keepdims=True)), 0.0)
+        p = e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True), 1e-30)
+        o_ref[0, pl.ds(g, 1), :] = jnp.sum(jnp.where(counts, p, 0.0), axis=0,
+                                           keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _block_scores_call(position, q2, keys, group, size, stride, interpret):
+    b, hkv, r, ch = q2.shape
+    n = keys.shape[1]
+    itemsize = jnp.dtype(keys.dtype).itemsize
+    need = 2 * n * hkv * ch * itemsize + 2 * hkv * n * 4 \
+        + (_SCORE_TEMPS + 1) * r * n * 4
+    return pl.pallas_call(
+        functools.partial(
+            _block_scores_kernel, group=group, size=size, stride=stride,
+            scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, hkv, r, ch), lambda i, *_: (i, 0, 0, 0)),
+                      pl.BlockSpec((1, n, hkv * ch), lambda i, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, hkv, n), lambda i, *_: (i, 0, 0))),
+        name="paged_block_scores",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=need + 16 * 1024 * 1024),
+    )(position, q2, keys)
+
+
+def paged_block_scores(q, keys, position, kernel_size, kernel_stride,
+                       interpret=None):
+    """``s`` ``(B, Hkv, J)`` float32: for every key-value head the sum, over
+    its ``G`` query heads, of ``softmax_j(q_h . c_j / sqrt(Ch))`` over the
+    compressed keys that lie wholly at or before the query
+    (``kernel_stride * j + kernel_size - 1 <= position[b]``), zero for the
+    others. ``q`` ``(B, Hkv, G, Ch)``, ``keys`` ``(B, J, Hkv*Ch)`` the row's
+    compressed keys in the table's order, both in the cache's dtype. One
+    row a grid step, its keys read once (``paged_block_scores`` in a trace).
+    Callers gate via :func:`paged_block_scores_refusal`."""
+    b, hkv, g, ch = q.shape
+    r = _rows(g, 1, jnp.dtype(keys.dtype).itemsize)
+    q2 = jnp.pad(q, ((0, 0), (0, 0), (0, r - g), (0, 0)))
+    return _block_scores_call(jnp.asarray(position, jnp.int32), q2, keys, g,
+                              int(kernel_size), int(kernel_stride),
+                              _resolve_interpret(interpret))

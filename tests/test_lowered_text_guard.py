@@ -7,7 +7,10 @@ first that has them; SmallThinker's decode step and one prefill program from
 the tree before PR 41 (commit a35b2af: every expert held, so PR 41's branch
 over the sorted pairs is not built and the programs are that tree's);
 DeepSeek-V2's and dots3-note-prev's from PR 41's own tree, whose expert
-layers return one more count (``moe_whole_path``). A PR that does not mean
+layers return one more count (``moe_whole_path``); MiniCPM-SALA's decode step
+and one prefill program (a read over a table of selected pages, a pool of
+compressed keys, a second kind of slot state) from PR 43's own tree, the
+first that has them. A PR that does not mean
 to touch those models' programs keeps them; one that does records anew
 (``JAX_PLATFORMS=cpu python tools/loweredsha.py``) and says so."""
 import json
@@ -33,6 +36,8 @@ RECORDED = {
     "olmo_hybrid.prefill16": "3cb9459a0d0052474c4bbb03ac8c54d5c71d087cb4b560653cf1725cc16e526a",
     "smallthinker.decode": "05fe721bb8ff83fee9e490b4919ccd80dd3792736efc587c68e2486c1aae8e88",
     "smallthinker.prefill16": "513b547450397901779089169e03127b038c1e1f6fe760843328cb103dd25fc5",
+    "minicpm_sala.decode": "f1ade0fc065414fd12957de6fe4879444d3f3d118baef4cd61ef1f49858f2a57",
+    "minicpm_sala.prefill64": "f2ff9f05135d2645e2123ac5416e73169675709a3d0bb8716428aad7fdffffb0",
 }
 
 

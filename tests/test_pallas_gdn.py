@@ -151,3 +151,99 @@ def test_the_chip_smokes_check_rehearsed_at_a_toy_size():
 
     found = chip_smoke.check_gdn(rows=4, heads=4, dk=8, dv=64, interpret=True)
     assert found["live"] == 3 and found["rel_err"] < 1e-5
+
+
+# -- the same family without the delta term (Lightning Attention) -----------
+def lightning_reference(q, k, v, lam):
+    """``S_t = lam S_(t-1) + k_t v_t^T``, ``o_t = S_t^T q_t`` position by
+    position in float64; at equal key and value widths also the plain
+    reference's own scan."""
+    s, out = np.zeros((q.shape[1], q.shape[2], v.shape[2])), []
+    for q_t, k_t, v_t in zip(q, k, v):
+        s = s * lam[:, None, None] + np.einsum("hk,hv->hkv", k_t, v_t)
+        out.append(np.einsum("hkv,hk->hv", s, q_t))
+    return np.stack(out)
+
+
+def test_the_plain_references_scan_is_that_recurrence():
+    from benchmark.reference.minicpm_sala import decayed_state
+
+    rng = np.random.default_rng(0)
+    q, k, v, _, _ = operands(rng, 50, 3, 8, 8)
+    lam = np.array([0.3, 0.9, 0.999], np.float32)
+    np.testing.assert_allclose(
+        decayed_state(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                      jnp.asarray(lam)),
+        lightning_reference(q, k, v, lam), atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,dk,dv", SHAPES)
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_a_step_without_the_delta_term_is_the_decayed_outer_product(
+        heads, dk, dv, form):
+    rng = np.random.default_rng(heads + dv)
+    b = 6
+    state = rng.normal(size=(b, heads, dk, dv)).astype(np.float32)
+    q, k, v, alpha, _ = operands(rng, b, heads, dk, dv)
+    live = np.array([1, 0, 1, 1, 0, 1], bool)
+    ones = np.ones_like(alpha)
+    rows = gdn.state_rows(jnp.asarray(state))
+    step = gdn.gdn_decode_xla if form == "xla" else \
+        lambda *a, **kw: gdn.gdn_decode_step(*a, interpret=True, **kw)
+    o, new = step(rows, q, k, v, alpha, ones, live, delta=False)
+    want_s = state * alpha[:, :, None, None] \
+        + np.einsum("bhk,bhv->bhkv", k, v)
+    want_o = np.einsum("bhkv,bhk->bhv", want_s, q)
+    got_s = np.asarray(gdn.state_heads(new, heads))
+    np.testing.assert_allclose(np.asarray(o)[live], want_o[live], atol=3e-6)
+    np.testing.assert_allclose(got_s[live], want_s[live], atol=3e-6)
+    np.testing.assert_array_equal(got_s[~live], state[~live])
+    assert not np.asarray(o)[~live].any()
+    # held at decay one and write zero (a step run twice): read and left
+    o, new = step(rows, q, k, v, ones, 0 * ones, live, delta=False)
+    np.testing.assert_array_equal(new, rows)
+
+
+@pytest.mark.parametrize("length,chunk", [(128, 64), (40, 64), (150, 64),
+                                          (13, 4), (3, 4)])
+def test_the_lightning_prefill_is_the_recurrence_and_stops_at_the_length(
+        length, chunk):
+    """A prompt of ``length`` in a bucket half as long again: the padding
+    neither decays nor writes the state; a block's reads leave the scan
+    through ``emit``; two stretches hand the state on."""
+    rng = np.random.default_rng(length)
+    heads, dk, dv = 3, 8, 16
+    t = length + length // 2 + 1
+    q, k, v, _, _ = operands(rng, t, heads, dk, dv)
+    lam = np.array([0.3, 0.9, 0.999], np.float32)
+    want = lightning_reference(q[:length], k[:length], v[:length], lam)
+    o, state = gdn.lightning_chunk_prefill(q, k, v, np.log(lam), length, chunk)
+    np.testing.assert_allclose(o[:length], want, atol=3e-5)
+    # the state behind the last REAL position: one more position read from it
+    more = lightning_reference(
+        np.concatenate([q[:length], q[-1:]]), np.concatenate([k[:length], k[-1:]]),
+        np.concatenate([v[:length], v[-1:]]), lam)[-1]
+    got, _ = gdn.gdn_decode_xla(
+        gdn.state_rows(state)[None], q[-1:], k[-1:], v[-1:], lam[None],
+        np.ones((1, heads), np.float32), np.ones(1, bool), delta=False)
+    np.testing.assert_allclose(got[0], more, atol=3e-5)
+    # in two stretches, the second from the first's state, reads flattened
+    cut = (t // 2 // chunk + 1) * chunk
+    if cut >= t:
+        return
+    flat = lambda x: x.reshape(x.shape[0], -1) * 2.0  # noqa: E731
+    o1, s1 = gdn.lightning_chunk_prefill(q[:cut], k[:cut], v[:cut],
+                                         np.log(lam), length, chunk, flat)
+    o2, s2 = gdn.lightning_chunk_prefill(q[cut:], k[cut:], v[cut:], np.log(lam),
+                                         length - cut, chunk, flat, s1)
+    both = np.concatenate([o1, o2])[:length]
+    np.testing.assert_allclose(both, 2.0 * want.reshape(length, -1), atol=6e-5)
+    np.testing.assert_allclose(s2, state, atol=3e-5)
+
+
+def test_the_chip_smokes_lightning_check_rehearsed_at_a_toy_size():
+    import chip_smoke
+
+    found = chip_smoke.check_lightning(rows=4, heads=4, d=64, interpret=True)
+    assert found["live"] == 3 and found["rel_err"] < 1e-5
+    assert found["chunk_prefill_rel_err"] < 1e-4

@@ -1,5 +1,6 @@
-"""The grouped-heads decode kernel, the experts' grouped-matmul kernel and
-the gated delta rule's decode kernel
+"""The grouped-heads decode kernel, the experts' grouped-matmul kernel, the
+gated delta rule's decode kernel (with and without its delta term), the
+block-list decode read and the block selection's scoring kernel
 compiled by Mosaic for a DESCRIBED v5e at the cells' real widths, here,
 without a chip: what interpret mode cannot refuse (a slice off the tiling,
 too much VMEM) fails this at no chip time. Nothing runs, so it says nothing
@@ -27,6 +28,12 @@ def one_chip():
     except Exception as e:  # no TPU compiler here, or another holds it
         pytest.skip(f"no v5e topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _read_selected(q, k_pool, v_pool, pages, starts, counts, position, **kw):
+    """``paged_gqa_read`` over a table of selected pages."""
+    return ppa.paged_gqa_read(q, k_pool, v_pool, None, position,
+                              selected=(pages, starts, counts), **kw)
 
 
 def _compiled(one_chip, fn, *shapes, donate=()):
@@ -200,3 +207,56 @@ def test_the_gdn_decode_kernel_compiles_for_a_v5e_at_30_x_96_x_192(one_chip):
     state_bytes = 48 * 96 * 5760 * 4
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+def test_the_lightning_decode_kernel_compiles_for_a_v5e_at_32_x_128_x_128(
+        one_chip):
+    """MiniCPM-SALA's lightning layers: 48 slots of 32 heads of 128 x 128
+    float32 (128 x 4,096 a row, one head a lane group), the kernel without
+    its delta term, the state donated: aliased, no copy beside the call."""
+    f32 = jnp.float32
+    heads = (48, 32, 128)
+    shapes = [((48, 128, 32 * 128), f32), (heads, f32), (heads, f32),
+              (heads, f32), ((48, 32), f32), ((48, 32), f32),
+              ((48,), jnp.bool_)]
+    compiled = _compiled(
+        one_chip, lambda s, q, k, v, a, b, live: gdn.gdn_decode_step(
+            s, q, k, v, a, b, live, interpret=False, delta=False), *shapes,
+        donate=(0,))
+    assert "lightning_decode_step" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 48 * 128 * 4096 * 4
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def test_the_selected_pages_read_compiles_for_a_v5e_at_the_cells_widths(
+        one_chip):
+    """MiniCPM-SALA's sparse layer: 48 rows, 2 x 16 query heads over 2
+    key-value heads of 128, pools of 24,576 pages of 64, tables of 128 pages
+    (a row under the dense length lists all it holds): a page's 128 lanes of
+    one head are one strided copy; nothing beside the pools."""
+    i32 = jnp.int32
+    compiled = _compiled(
+        one_chip,
+        lambda q, k, v, p, s, c, at: _read_selected(
+            q, k, v, p, s, c, at, interpret=False),
+        ((48, 32, 1, 128), jnp.bfloat16), ((24577, 64, 256), jnp.bfloat16),
+        ((24577, 64, 256), jnp.bfloat16), ((48, 2, 128), i32),
+        ((48, 2, 128), i32), ((48, 2), i32), ((48,), i32))
+    assert "paged_gqa_decode_selected" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_block_scores_kernel_compiles_for_a_v5e_at_the_cells_widths(
+        one_chip):
+    """The selection's scoring: 48 rows' 4,352 compressed keys of 256
+    columns as the page tables of 1,088 pages gathered them (69,632
+    positions), a group of 16 query heads a key-value head."""
+    compiled = _compiled(
+        one_chip,
+        lambda q, keys, at: ppa.paged_block_scores(q, keys, at, 32, 16,
+                                                   interpret=False),
+        ((48, 2, 16, 128), jnp.bfloat16), ((48, 4352, 256), jnp.bfloat16),
+        ((48,), jnp.int32))
+    assert "paged_block_scores" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -89,6 +89,18 @@ GROUPED_MM_CASES = [(48, 6, 2560, 768, 64, 64), (4096, 6, 2560, 768, 64, 64),
 GDN_CASES = [(16, 16, 30, 96, 192), (32, 32, 30, 96, 192),
              (48, 48, 30, 96, 192), (64, 64, 30, 96, 192),
              (48, 24, 30, 96, 192)]
+# the same family without the delta term (MiniCPM-SALA's lightning layer, one
+# layer: 32 heads of 128 x 128): (rows, live rows, heads, dk, dv)
+LIGHTNING_CASES = [(32, 32, 32, 128, 128), (64, 64, 32, 128, 128),
+                   (64, 40, 32, 128, 128)]
+# the block-list decode read (MiniCPM-SALA's sparse layer): (rows, query
+# heads, key-value heads, head size, page = block, pool pages, blocks a
+# list): every row lists that many blocks a key-value head, scattered over
+# the pool; the A/B is the kernel ``paged_gqa_decode_selected`` against the XLA
+# gather of the listed pages
+BLOCK_LIST_CASES = [(64, 32, 2, 128, 64, 24576, 64),
+                    (64, 32, 2, 128, 64, 24576, 128),
+                    (16, 32, 2, 128, 64, 24576, 64)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -560,7 +572,70 @@ def run_grouped_mm_case(tokens, top_k, d, w, held, experts, reps):
     return case
 
 
-def run_gdn_case(rows, n_live, heads, dk, dv, reps):
+def run_block_list_case(rows, heads, kv, ch, ps, pages, listed, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    rng = np.random.RandomState(0)
+    length = -(-listed // 128) * 128
+    q = jnp.asarray(rng.randn(rows, heads, 1, ch), jnp.bfloat16)
+    k_pool, v_pool = (jnp.asarray(rng.randn(pages + 1, ps, kv * ch),
+                                  jnp.bfloat16) for _ in range(2))
+    blocks = np.sort(np.stack([np.stack([
+        rng.permutation(4 * length)[:length] for _ in range(kv)])
+        for _ in range(rows)]), axis=2).astype(np.int32)
+    counts = np.full((rows, kv), listed, np.int32)
+    position = (blocks[:, :, listed - 1].max(axis=1) * ps + ps // 2).astype(np.int32)
+    lists = tuple(jnp.asarray(x) for x in (
+        rng.randint(1, pages + 1, (rows, kv, length)).astype(np.int32),
+        blocks * ps, counts, position))
+    case = {"kind": "block_list", "rows": rows, "heads": [heads, kv, ch],
+            "page": ps, "pages": pages, "listed": listed}
+    if not _INTERP:
+        case["gate"] = ppa.paged_gqa_selected_refusal(q, k_pool, lists[0]) \
+            or "kernel"
+    # the pools are ARGUMENTS: closed over, they would be constants of the
+    # timed program
+    forms = {"kernel": lambda q, k, v, *ls: ppa.paged_gqa_read(
+                 q, k, v, None, ls[3], selected=ls[:3], interpret=_INTERP),
+             "xla": att._paged_block_gather_read}
+    got, want = (jax.jit(f)(q, k_pool, v_pool, *lists) for f in forms.values())
+    err = float(jnp.abs(got - want).max())
+    case["max_err"] = round(err, 6)
+    case["correct"] = bool(err < 3e-2 and np.isfinite(np.asarray(got)).all())
+
+    def timed(read):
+        chain = jax.jit(lambda q, k, v, *ls: jax.lax.scan(
+            lambda c, _: (read(c, k, v, *ls).astype(c.dtype), ()), q, None,
+            length=reps)[0])
+        chain(q, k_pool, v_pool, *lists)
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            out = chain(q, k_pool, v_pool, *lists)
+            np.asarray(jax.device_get(out[0, 0, 0, :1]))
+            times.append((time.perf_counter() - t0) / reps)
+        return sorted(times)[1]
+
+    for label, read in forms.items():
+        try:
+            case[f"{label}_ms"] = round(timed(read) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:300]
+    # what a read has to move: the listed blocks' key and value, a head's
+    gb = rows * kv * listed * ps * 2 * ch * 2 / 1e9
+    case["read_gb"] = round(gb, 4)
+    for label in forms:
+        if f"{label}_ms" in case:
+            case[f"{label}_gb_per_s"] = round(gb / case[f"{label}_ms"] * 1e3, 1)
+    return case
+
+
+def run_gdn_case(rows, n_live, heads, dk, dv, reps, delta=True):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -577,13 +652,15 @@ def run_gdn_case(rows, n_live, heads, dk, dv, reps):
     alpha = f32(rng.uniform(0.9, 1.0, (rows, heads)))
     beta = f32(rng.uniform(0.0, 2.0, (rows, heads)))
     live = jnp.arange(rows) < n_live
-    case = {"kind": "gdn_decode", "rows": rows, "live": n_live,
-            "heads": heads, "dk": dk, "dv": dv}
+    case = {"kind": "gdn_decode" if delta else "lightning_decode",
+            "rows": rows, "live": n_live, "heads": heads, "dk": dk, "dv": dv}
     if not _INTERP:
         case["gate"] = gdn.gdn_decode_refusal(state, q, v) or "kernel"
     forms = {"kernel": lambda s: gdn.gdn_decode_step(
-                 s, q, k, v, alpha, beta, live, interpret=_INTERP),
-             "xla": lambda s: gdn.gdn_decode_xla(s, q, k, v, alpha, beta, live)}
+                 s, q, k, v, alpha, beta, live, interpret=_INTERP,
+                 delta=delta),
+             "xla": lambda s: gdn.gdn_decode_xla(s, q, k, v, alpha, beta, live,
+                                                 delta=delta)}
     (o_k, s_k), (o_x, s_x) = (jax.jit(f)(state) for f in forms.values())
     err = max(float(jnp.abs(o_k - o_x).max()), float(jnp.abs(s_k - s_x).max()))
     dead = bool((np.asarray(s_k)[n_live:] == np.asarray(state)[n_live:]).all())
@@ -803,6 +880,10 @@ def run_one(argv):
             case = run_grouped_mm_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "gdn_decode":
             case = run_gdn_case(*spec["shape"], spec["reps"])
+        elif spec["kind"] == "lightning_decode":
+            case = run_gdn_case(*spec["shape"], spec["reps"], delta=False)
+        elif spec["kind"] == "block_list":
+            case = run_block_list_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -825,7 +906,8 @@ def main():
                     help="comma-separated case kinds to run (attn, ln, "
                          "conv_layout, paged_attn, paged_latent, "
                          "masked_prefill, paged_gqa, grouped_mm, "
-                         "gdn_decode, fused_adam, softmax_xent); "
+                         "gdn_decode, lightning_decode, block_list, "
+                         "fused_adam, softmax_xent); "
                          "default all")
     ap.add_argument("--runs", default="",
                     help="paged_gqa: comma-separated shares of a row's pages "
@@ -865,6 +947,10 @@ def main():
               for shape in GROUPED_MM_CASES]
     specs += [{"kind": "gdn_decode", "shape": list(shape), "reps": args.reps}
               for shape in GDN_CASES]
+    specs += [{"kind": "lightning_decode", "shape": list(shape),
+               "reps": args.reps} for shape in LIGHTNING_CASES]
+    specs += [{"kind": "block_list", "shape": list(shape), "reps": args.reps}
+              for shape in BLOCK_LIST_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}

@@ -1,10 +1,13 @@
 """SHA-256 of the lowered text of GPT-2's, DeepSeek-V2's, dots3-note-prev's,
-Olmo-Hybrid's and SmallThinker's serving programs at toy widths, on the CPU:
+Olmo-Hybrid's, SmallThinker's and MiniCPM-SALA's serving programs at toy
+widths, on the CPU:
 the paged decode step and the prefill buckets of each (DeepSeek-V2's buckets
 cover both forms of its latent attention; dots3-note-prev's decode step and
 one prefill program run both page groups, the selection and the sigmoid
 router; Olmo-Hybrid's carry slot state beside the pools; SmallThinker's hold
-every expert, so its expert layers build no branch over the sorted pairs). A PR
+every expert, so its expert layers build no branch over the sorted pairs;
+MiniCPM-SALA's decode step scores, selects and reads a table of pages, and
+its one prefill program passes the dense length). A PR
 that says "their programs are the parent's" shows it with these: the same
 hashes from the parent's tree and from its own
 (``tests/test_lowered_text_guard.py`` holds the parent's). The text is what
@@ -83,6 +86,20 @@ SMALLTHINKER_TOY = dict(
     engine={"batch_size": 3, "paged": True, "page_size": 4,
             "num_pages": {"all": 64, "window": 12}, "max_length": 64,
             "cache_dtype": "float32", "prefill_buckets": [16]})
+MINICPM_SALA_TOY = dict(
+    model="minicpm_sala", hidden_size=32, intermediate_size=48,
+    num_attention_heads=4, num_key_value_heads=1, head_dim=8,
+    rms_norm_eps=1e-6, n_layer=4, num_hidden_layers=32,
+    mixer_types=["minicpm4", "lightning-attn", "lightning-attn", "minicpm4"],
+    lightning_nh=2, lightning_nkv=2, lightning_head_dim=8, n_vocab=200,
+    max_position_embeddings=128, rope_theta=10000, scale_emb=1,
+    scale_depth=1.4, dim_model_base=32,
+    sparse_config=dict(kernel_size=2, kernel_stride=1, block_size=4, topk=5,
+                       init_blocks=1, window_size=4, dense_len=8),
+    initializer_range=0.1, precision={"weights": "float32"},
+    engine={"batch_size": 3, "paged": True, "page_size": 4,
+            "num_pages": {"all": 120}, "max_length": 128,
+            "cache_dtype": "float32", "prefill_buckets": [64]})
 GPT2_TOY = dict(n_layer=2, n_embd=32, n_head=2, n_ctx=64, n_vocab=64,
                 engine={"batch_size": 2, "paged": True, "page_size": 8,
                         "max_length": 64, "prefill_buckets": [8, 16]})
@@ -93,10 +110,12 @@ def engines():
     import numpy as np
 
     from benchmark.reference import deepseek_v2 as ref_v2
+    from benchmark.reference import minicpm_sala as ref_sala
     from benchmark.reference import dots3_note as ref_dots3
     from benchmark.reference import olmo_hybrid as ref_olmo
     from benchmark.reference import smallthinker as ref_small
     from benchmark.systems import deepseek_v2 as adaptor_v2
+    from benchmark.systems import minicpm_sala as adaptor_sala
     from benchmark.systems import dots3_note as adaptor_dots3
     from benchmark.systems import olmo_hybrid as adaptor_olmo
     from benchmark.systems import smallthinker as adaptor_small
@@ -115,12 +134,14 @@ def engines():
     dots3 = make_weights(ref_dots3.param_specs(DOTS3_NOTE_TOY), 7)
     olmo = make_weights(ref_olmo.param_specs(OLMO_HYBRID_TOY), 7)
     small = make_weights(ref_small.param_specs(SMALLTHINKER_TOY), 7)
+    sala = make_weights(ref_sala.param_specs(MINICPM_SALA_TOY), 7)
     # a later model is built LAST: the blocks' names count up as they are made
     return {"gpt2": GenerationEngine(net, **c["engine"]),
             "deepseek_v2": adaptor_v2.build_serve(DEEPSEEK_V2_TOY, weights)[0],
             "dots3_note": adaptor_dots3.build_serve(DOTS3_NOTE_TOY, dots3)[0],
             "olmo_hybrid": adaptor_olmo.build_serve(OLMO_HYBRID_TOY, olmo)[0],
-            "smallthinker": adaptor_small.build_serve(SMALLTHINKER_TOY, small)[0]}
+            "smallthinker": adaptor_small.build_serve(SMALLTHINKER_TOY, small)[0],
+            "minicpm_sala": adaptor_sala.build_serve(MINICPM_SALA_TOY, sala)[0]}
 
 
 def lowered_sha():
