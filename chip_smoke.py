@@ -865,6 +865,52 @@ def check_block_list(rows=8, heads=32, kv=2, ch=128, ps=64, pages=600,
             "rel_err": round(err, 6)}
 
 
+def check_chunk_scores(queries=512, heads=32, kv=2, ch=128, tokens=16384,
+                       first=12288, dtype="bfloat16", interpret=None):
+    """MiniCPM-SALA's sparse layer in a prefill at its published widths: the
+    scoring kernel ``sparse_chunk_scores`` (a stretch's queries against the
+    bucket's compressed keys, 32 positions every 16, pooled to blocks of 64
+    inside the kernel) against the model's XLA form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.models import minicpm_sala as sala
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    cfg = dict(kernel_size=32, kernel_stride=16, block_size=64)
+    sizes = cfg["block_size"], cfg["kernel_size"], cfg["kernel_stride"]
+    rs = np.random.RandomState(SEED)
+    q = jnp.asarray(rs.randn(queries, kv, heads // kv, ch), dtype)
+    ck = jnp.asarray(rs.randn(tokens // 16 - 1, kv, ch), dtype)
+    if interpret is None:
+        why = ppa.sparse_chunk_scores_refusal(q, ck, *sizes)
+        if why is not None:
+            raise AssertionError(f"chunk scores: the gate refuses: {why}")
+    kernel = jax.jit(lambda q, ck, at: ppa.sparse_chunk_scores(
+        q, ppa.sparse_chunk_keys(ck, sizes[0], sizes[2]), at, *sizes,
+        interpret=interpret)[:, :, :tokens // 64])
+    at = jnp.asarray(first, jnp.int32)
+    calls = kernel.lower(q, ck, at).as_text().count("tpu_custom_call")
+    if not interpret and calls != 1:
+        raise AssertionError(f"chunk scores: lowered with {calls} Mosaic "
+                             "kernels")
+    got = np.asarray(kernel(q, ck, at))
+    pos = first + jnp.arange(queries, dtype=jnp.int32)
+    want = np.moveaxis(np.asarray(sala.pooled_weights(
+        sala.key_weights(q, ck, pos, cfg), pos, cfg, tokens // 64)), 1, 0)
+    seen = np.isfinite(want)
+    if not (np.isneginf(got) == ~seen).all():
+        raise AssertionError("chunk scores: other blocks are scored than "
+                             "XLA's")
+    err = float(np.abs(got[seen] - want[seen]).max())
+    if not err < 1e-5:
+        raise AssertionError(f"chunk scores: {err} from XLA's weights")
+    return {"queries": queries, "heads": [heads, kv, ch],
+            "keys": int(ck.shape[0]), "first": first,
+            "tpu_custom_calls": calls, "max_err": round(err, 8)}
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -935,6 +981,7 @@ def phase_kernels():
            "gdn_decode_step": check_gdn(),
            "lightning_decode_step": check_lightning(),
            "paged_gqa_decode_selected": check_block_list(),
+           "sparse_chunk_scores": check_chunk_scores(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

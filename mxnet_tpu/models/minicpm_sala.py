@@ -144,14 +144,27 @@ def block_ranks(s, at, cfg, n_blocks):
     ``init_blocks``, the query's own and the ``window_size / block_size``
     before it), its score ``b_m`` for another block the row holds, ``-inf``
     past the query's own; beside it the forced blocks' mask."""
-    block = cfg["block_size"]
-    valid = _whole_keys(s.shape[-1], at, cfg)[..., None, :]
-    b = block_scores(jnp.where(valid, s, -jnp.inf), cfg, n_blocks)
+    b = pooled_weights(s, at, cfg, n_blocks)
     m = jnp.arange(n_blocks)
-    own = (at // block)[..., None, None]
+    return _forced_ranks(b, m, (at // cfg["block_size"])[..., None, None], cfg)
+
+
+def pooled_weights(s, at, cfg, n_blocks):
+    """b_m (..., Hkv, M) of the weights ``s`` (..., Hkv, J)
+    (:func:`key_weights`) of the queries at ``at`` (...,): :func:`block_scores`
+    over the compressed keys that lie wholly at or before the query, ``-inf``
+    where none touches a block (what ``sparse_chunk_scores`` computes in one
+    kernel)."""
+    valid = _whole_keys(s.shape[-1], at, cfg)[..., None, :]
+    return block_scores(jnp.where(valid, s, -jnp.inf), cfg, n_blocks)
+
+
+def _forced_ranks(b, m, own, cfg):
+    """:func:`block_ranks` of the pooled scores ``b`` (..., M) of blocks
+    ``m`` (M,), ``own`` the queries' own blocks (broadcast against ``b``)."""
     held = m <= own
     forced = (m < cfg["init_blocks"]) \
-        | ((m >= own - cfg["window_size"] // block) & held)
+        | ((m >= own - cfg["window_size"] // cfg["block_size"]) & held)
     forced = jnp.broadcast_to(forced, b.shape)
     return jnp.where(forced, jnp.inf, jnp.where(held, b, -jnp.inf)), forced
 
@@ -234,10 +247,26 @@ class BlockSparseAttention(HybridBlock):
         return sum(sums[..., i:i + j, :] for i in range(n)) / size
 
     # -- one whole chunk from position 0 -------------------------------------
-    def _taken(self, q, ck, at, n_blocks):
+    def _taken(self, q, ck, at, n_blocks, laid=None):
         """(Hkv, Q, M) bool: the blocks the queries ``q`` (Q, Hkv, G, Ch) at
-        positions ``at`` read, ``_SELECT_QUERIES`` at a time."""
+        positions ``at`` read. With ``laid`` (the compressed keys as
+        ``sparse_chunk_keys`` lays them out) the queries are one stretch from
+        ``at[0]`` on, their block scores come from the kernel
+        ``sparse_chunk_scores`` and the choice is made for all of them at
+        once; else XLA weighs every compressed key, ``_SELECT_QUERIES``
+        queries at a time."""
         c = self._cfg
+        if laid is not None:
+            from ..ops import pallas_paged_attention as ppa
+
+            b = ppa.sparse_chunk_scores(
+                q, laid, at[0], c["block_size"], c["kernel_size"],
+                c["kernel_stride"])[:, :, :n_blocks]         # (Hkv, Q, M)
+            rank, _ = _forced_ranks(b, jnp.arange(n_blocks),
+                                    (at // c["block_size"])[:, None], c)
+            held = rank > -jnp.inf
+            taken = att.top_k_mask(rank, min(c["topk"], n_blocks)) & held
+            return jnp.where((at + 1 <= c["dense_len"])[:, None], held, taken)
         qb = math.gcd(q.shape[0], _SELECT_QUERIES)
 
         def of(start):
@@ -317,7 +346,7 @@ class BlockSparseAttention(HybridBlock):
                                     **att._F32))
         return jnp.moveaxis(jnp.stack(heads), 2, 0)
 
-    def _chunk(self, x, norm, tail, k, v, ck, kernel, length):
+    def _chunk(self, x, norm, tail, k, v, ck, kernel, length, laid=None):
         """The BLOCK over one row's whole chunk from position 0: ``x`` (1,
         T, units) the block's input, ``length`` of its positions real,
         ``k``, ``v`` (T, Hkv, Ch) and ``ck`` (J, Hkv, Ch) as the cache holds
@@ -327,7 +356,9 @@ class BlockSparseAttention(HybridBlock):
         projected back and taken through the rest of the block (``tail``)
         before the next, so nothing of a stretch but the block's output
         outlives it. ``kernel``: the flash forward kernel (``sparse_prefill``
-        in a trace) or XLA's masked softmax. Returns (the block's output,
+        in a trace) or XLA's masked softmax; ``laid``: the compressed keys
+        for the selection's scoring kernel (:meth:`_taken`) or None. Returns
+        (the block's output,
         raw (1, T, units), (blocks the first key-value head's real queries
         read, blocks they hold))."""
         c, t = self._cfg, x.shape[1]
@@ -346,7 +377,7 @@ class BlockSparseAttention(HybridBlock):
                     q = self._queries(u_s)[0].astype(k.dtype)  # (s, Hkv, G, Ch)
                 if tk > c["dense_len"]:
                     with jax.named_scope("select"):
-                        taken = self._taken(q, ck, at, n_blocks)
+                        taken = self._taken(q, ck, at, n_blocks, laid)
                         read += jnp.sum(taken[0] & real[:, None],
                                         dtype=jnp.int32)
                 else:
@@ -361,6 +392,27 @@ class BlockSparseAttention(HybridBlock):
         every = jnp.arange(t, dtype=jnp.int32)
         held = jnp.sum(jnp.where(every < length, every // block + 1, 0))
         return out, (read, held)
+
+    def _chunk_keys(self, ck, stretch):
+        """The compressed keys ``ck`` (J, Hkv, Ch) laid out for the scoring
+        kernel ``sparse_chunk_scores`` where it weighs a prefill's stretches
+        of ``stretch`` queries, else None (XLA's ``key_weights``), counted
+        by path and reason."""
+        from .. import observability as obs
+        from ..ops import pallas_paged_attention as ppa
+
+        c = self._cfg
+        sizes = c["block_size"], c["kernel_size"], c["kernel_stride"]
+        why = ppa.sparse_chunk_scores_refusal(
+            jax.ShapeDtypeStruct((stretch, self._kv, self._heads // self._kv,
+                                  self._ch), ck.dtype), ck, *sizes)
+        obs.counter("sparse_read_path_total").inc(
+            path="chunk_scores_xla" if why else "chunk_scores_kernel",
+            reason=why or "")
+        if why:
+            return None
+        with jax.named_scope("sparse"), jax.named_scope("select"):
+            return ppa.sparse_chunk_keys(ck, sizes[0], sizes[2])
 
     def _chunk_path(self, q, t):
         """None where a chunk of ``t`` tokens goes through the flash forward
@@ -401,9 +453,12 @@ class BlockSparseAttention(HybridBlock):
         obs.counter("paged_read_path_total").inc(
             path="sparse_chunk_xla" if why else "sparse_chunk_kernel",
             reason=why or "")
-        y, (read, held) = self._chunk(
-            x, norm, tail, k, v, ck.reshape(-1, self._kv, self._ch), not why,
-            last + 1)
+        ck = ck.reshape(-1, self._kv, self._ch)
+        laid = None
+        if t > c["dense_len"]:   # a bucket with stretches that select
+            laid = self._chunk_keys(ck, math.gcd(t, _STRETCH))
+        y, (read, held) = self._chunk(x, norm, tail, k, v, ck, not why,
+                                      last + 1, laid)
         written = jnp.sum(cpid > 0, dtype=jnp.int32)
         return y, (k_pool, v_pool, ck_pool), (read, held, written)
 

@@ -1238,3 +1238,211 @@ def paged_block_scores(q, keys, position, kernel_size, kernel_stride,
     return _block_scores_call(jnp.asarray(position, jnp.int32), q2, keys, g,
                               int(kernel_size), int(kernel_stride),
                               _resolve_interpret(interpret))
+
+
+# --------------------------------------------------------------------------
+# block selection's scoring in a PREFILL: a stretch of queries against the
+# row's compressed keys, pooled to blocks (score-shaped values in VMEM only)
+# --------------------------------------------------------------------------
+_CHUNK_QUERIES = 16   # queries a grid step: with a group's heads its rows
+_CHUNK_BLOCKS = 256   # blocks a key tile (a phase's keys of a product)
+
+
+def _chunk_tiles(n_blocks):
+    """(blocks a key tile, key tiles) that cover ``n_blocks`` in whole lane
+    tiles."""
+    cover = -(-n_blocks // _LANES) * _LANES
+    tm = _CHUNK_BLOCKS if cover % _CHUNK_BLOCKS == 0 else _LANES
+    return tm, cover // tm
+
+
+def _chunk_scores_vmem(rows, tq, per, tm, tiles, ch, itemsize):
+    """Bytes of VMEM a grid step holds: a head's keys and the query and
+    output blocks twice, the rows' scores against every key, the pooled
+    phases, the float32 temporaries of one product."""
+    keys = per * tiles * tm
+    return (2 * keys * ch * itemsize + 2 * rows * ch * itemsize
+            + rows * keys * 4 + (per + 2) * tq * tiles * tm * 4
+            + _SCORE_TEMPS * rows * tm * 4)
+
+
+def sparse_chunk_scores_refusal(q, keys, block_size, kernel_size,
+                                kernel_stride):
+    """Why the prefill's scoring kernel does NOT weigh these operands
+    (anything with ``.shape``/``.dtype``), or None when it does: ``q`` ``(S,
+    Hkv, G, Ch)`` a stretch's queries, ``keys`` ``(J, Hkv, Ch)`` the row's
+    compressed keys. The first condition that fails is the one named;
+    callers take the XLA form (``key_weights`` a few queries at a time)
+    then."""
+    from .. import config as _config
+
+    if not _config.get("paged_attention_kernel"):
+        return "paged_attention_kernel knob is off"
+    if not _on_tpu():
+        return "the backend is not a TPU"
+    s, hkv, g, ch = q.shape
+    if keys.dtype not in (jnp.float32, jnp.bfloat16) or q.dtype != keys.dtype:
+        return (f"queries {jnp.dtype(q.dtype).name} and keys "
+                f"{jnp.dtype(keys.dtype).name} are not both float32 or both "
+                "bfloat16")
+    if ch % _LANES or tuple(keys.shape[1:]) != (hkv, ch) \
+            or s % _CHUNK_QUERIES:
+        return (f"{s} queries against keys {tuple(keys.shape)} under {hkv} "
+                f"heads of {ch} are not whole tiles of {_CHUNK_QUERIES} "
+                f"queries and {_LANES} lanes")
+    per = block_size // kernel_stride
+    if block_size % kernel_stride or kernel_size % kernel_stride \
+            or kernel_size // kernel_stride - 1 > per:
+        return (f"compressed keys of {kernel_size} every {kernel_stride} do "
+                f"not lie in phases of a block of {block_size}")
+    tm, tiles = _chunk_tiles(-(-keys.shape[0] // per))
+    need = _chunk_scores_vmem(g * _CHUNK_QUERIES, _CHUNK_QUERIES, per, tm,
+                              tiles, ch, jnp.dtype(keys.dtype).itemsize)
+    if need > _MAX_VMEM_BYTES:
+        return (f"a head's {keys.shape[0]} compressed keys and their scores "
+                f"need {need} bytes of VMEM (budget {_MAX_VMEM_BYTES})")
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _chunk_scores_kernel(first_ref, q_ref, k_ref, o_ref, d_ref, s_ref, *, tq,
+                         back, size, stride, scale):
+    per, tiles, tm = k_ref.shape[1:4]
+    rows = q_ref.shape[2]
+    q = q_ref[0, 0]
+    start = first_ref[0] + pl.program_id(1) * tq
+    # the key tiles that hold a key the tile's LAST query sees whole
+    reach = start + tq - size
+    nt = jnp.where(reach >= 0,
+                   jnp.minimum(reach // (stride * per * tm) + 1, tiles), 0)
+    at_q = start + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    at = start + lax.rem(lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
+    # the last position of a tile's first phase's keys, less the tile's own
+    ends = lax.broadcasted_iota(jnp.int32, (1, tm), 1) * (stride * per)
+    past = lambda t, r: stride * (per * tm * t + r) + size - 1  # noqa: E731
+
+    def scores(t, top):
+        for r in range(per):
+            d = lax.dot_general(q, k_ref[0, r, t], _NT,
+                                preferred_element_type=jnp.float32) * scale
+            d = jnp.where(ends <= at - past(t, r), d, _NEG)
+            d_ref[r, t] = d
+            top = jnp.maximum(top, jnp.max(d, axis=1, keepdims=True))
+        return top
+
+    top = lax.fori_loop(0, nt, scores, jnp.full((rows, 1), _NEG, jnp.float32))
+
+    def exponents(t, total):
+        # a masked score's is 0 (no row of a tile that counts is all masked
+        # behind the dense length; one that is gives weights masked below)
+        for r in range(per):
+            e = jnp.exp(d_ref[r, t] - top)
+            d_ref[r, t] = e
+            total = total + jnp.sum(e, axis=1, keepdims=True)
+        return total
+
+    total = lax.fori_loop(0, nt, exponents, jnp.zeros((rows, 1), jnp.float32))
+    share = 1.0 / jnp.maximum(total, 1e-30)
+
+    def weights(t, carry):
+        for r in range(per):
+            p = d_ref[r, t] * share
+            group = p[0:tq]
+            for h in range(1, rows // tq):
+                group = group + p[h * tq:(h + 1) * tq]
+            s_ref[r, t] = jnp.where(ends <= at_q - past(t, r), group, -jnp.inf)
+        return carry
+
+    lax.fori_loop(0, nt, weights, 0)
+    # block m's score: the largest weight among the keys that start in it
+    # and the ``back`` that reach into it from the block before (the last
+    # phases, one block to the right)
+    first_lane = lax.broadcasted_iota(jnp.int32, (tq, tm), 1) == 0
+    phase = lambda r, t: jnp.where(t < nt, s_ref[r, t], -jnp.inf)  # noqa: E731
+    for t in range(tiles):
+        best = phase(0, t)
+        for r in range(1, per):
+            best = jnp.maximum(best, phase(r, t))
+        for r in range(per - back, per):
+            edge = phase(r, t - 1)[:, tm - 1:tm] if t else -jnp.inf
+            best = jnp.maximum(best, jnp.where(
+                first_lane, edge, pltpu.roll(phase(r, t), 1, 1)))
+        o_ref[0, :, t * tm:(t + 1) * tm] = best
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _chunk_scores_call(first, q4, keys, tq, back, size, stride, interpret):
+    hkv, steps, rows, ch = q4.shape
+    per, tiles, tm = keys.shape[1:4]
+    need = _chunk_scores_vmem(rows, tq, per, tm, tiles, ch,
+                              jnp.dtype(keys.dtype).itemsize)
+    return pl.pallas_call(
+        functools.partial(
+            _chunk_scores_kernel, tq=tq, back=back, size=size, stride=stride,
+            scale=float(np.float32(1.0) / np.sqrt(np.float32(ch)))),
+        out_shape=jax.ShapeDtypeStruct((hkv, steps * tq, tiles * tm),
+                                       jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(hkv, steps),
+            in_specs=[pl.BlockSpec((1, 1, rows, ch),
+                                   lambda h, i, *_: (h, i, 0, 0)),
+                      pl.BlockSpec((1, per, tiles, tm, ch),
+                                   lambda h, i, *_: (h, 0, 0, 0, 0))],
+            out_specs=pl.BlockSpec((1, tq, tiles * tm),
+                                   lambda h, i, *_: (h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((per, tiles, rows, tm), jnp.float32),
+                            pltpu.VMEM((per, tiles, tq, tm), jnp.float32)]),
+        name="sparse_chunk_scores",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=need + 16 * 1024 * 1024),
+    )(first, q4, keys)
+
+
+def sparse_chunk_keys(keys, block_size, kernel_stride):
+    """A row's compressed keys ``(J, Hkv, Ch)`` as :func:`sparse_chunk_scores`
+    reads them, ``(Hkv, phases, tiles, blocks a tile, Ch)``: key ``j`` lies
+    in phase ``j % phases`` at block ``j // phases`` (``phases = block_size /
+    kernel_stride`` keys start in a block), zeros behind the last. A prefill
+    lays them out once for all its stretches."""
+    j, hkv, ch = keys.shape
+    per = block_size // kernel_stride
+    tm, tiles = _chunk_tiles(-(-j // per))
+    keys = jnp.pad(keys, ((0, per * tiles * tm - j), (0, 0), (0, 0)))
+    return keys.reshape(tiles, tm, per, hkv, ch).transpose(3, 2, 0, 1, 4)
+
+
+def sparse_chunk_scores(q, keys, first, block_size, kernel_size,
+                        kernel_stride, interpret=None):
+    """``b`` ``(Hkv, S, M)`` float32, ``M`` the blocks the laid-out keys
+    cover: for the queries ``q`` ``(S, Hkv, G, Ch)`` at positions ``first +
+    arange(S)`` and every key-value head, block ``m``'s score: the largest,
+    over the compressed keys that touch the block (those that start in it
+    and the ``kernel_size / kernel_stride - 1`` before them) and lie wholly
+    at or before the query (``kernel_stride * j + kernel_size - 1 <=
+    position``), of the sum over the head's ``G`` query heads of
+    ``softmax_j(q_h . c_j / sqrt(Ch))`` over the keys that so lie; ``-inf``
+    where no such key touches the block. ``keys`` from
+    :func:`sparse_chunk_keys`, in the queries' dtype; ``first`` a scalar of
+    the RUNNING program, so every stretch of a bucket is one kernel.
+
+    A grid step weighs ``_CHUNK_QUERIES`` queries of one key-value head, a
+    group's heads stacked over the rows of one left operand, against the
+    head's keys, which stay in VMEM whole: the products go a tile of keys at
+    a time up to the last tile the step's last query sees, the scores stay
+    in VMEM for the exact softmax (float32; the largest first, no running
+    rescale), and the pooling is an element-wise maximum over the phases
+    (``sparse_chunk_scores`` in a trace). Callers gate via
+    :func:`sparse_chunk_scores_refusal`."""
+    s, hkv, g, ch = q.shape
+    tq = _CHUNK_QUERIES
+    q4 = q.reshape(s // tq, tq, hkv, g, ch).transpose(2, 0, 3, 1, 4)
+    return _chunk_scores_call(
+        jnp.asarray(first, jnp.int32).reshape(1),
+        q4.reshape(hkv, s // tq, g * tq, ch), keys, tq,
+        int(kernel_size) // int(kernel_stride) - 1, int(kernel_size),
+        int(kernel_stride), _resolve_interpret(interpret))

@@ -526,7 +526,7 @@ def test_the_kernels_inside_an_engine(monkeypatch):
     reads the selected pages through ``paged_gqa_read(selected=)`` and
     advances the state through ``gdn_decode_step(delta=False)``; a prefill of
     a whole 128 tokens goes through the flash forward kernel under the
-    selection's mask."""
+    selection's mask, which ``sparse_chunk_scores`` scored."""
     from mxnet_tpu.ops import flash_attention
     cfg = wide_config(head_dim=128, lightning_head_dim=64,
                       initializer_range=0.05)
@@ -538,7 +538,7 @@ def test_the_kernels_inside_an_engine(monkeypatch):
     for module in (pallas_gdn, pallas_paged_attention, flash_attention):
         monkeypatch.setattr(module, "_on_tpu", lambda: True)
         monkeypatch.setattr(module, "_resolve_interpret", lambda i: True)
-    traced = {"state": 0, "tables": 0, "scores": 0, "chunk": 0}
+    traced = {"state": 0, "tables": 0, "scores": 0, "chunk": 0, "select": 0}
     forward = flash_attention._flash_fwd
     step, read = pallas_gdn.gdn_decode_step, pallas_paged_attention.paged_gqa_read
     scores = pallas_paged_attention.paged_block_scores
@@ -556,6 +556,9 @@ def test_the_kernels_inside_an_engine(monkeypatch):
                         counted("scores", scores))
     monkeypatch.setattr(flash_attention, "_flash_fwd",
                         counted("chunk", forward))
+    monkeypatch.setattr(
+        pallas_paged_attention, "sparse_chunk_scores",
+        counted("select", pallas_paged_attention.sparse_chunk_scores))
     engine, _ = build(cfg, weights)
     assert engine.read_path == ("sparse layers: selected_pages_kernel; "
                                 "selector: block_scores_kernel; "
@@ -567,7 +570,13 @@ def test_the_kernels_inside_an_engine(monkeypatch):
     # one a layer of its kind; the bucket of 128 a sparse layer's one
     # key-value head (the buckets of 32 and 64 are not whole 128-key tiles:
     # XLA's softmax)
-    assert traced == {"state": 2, "tables": 2, "scores": 2, "chunk": 2}
+    # XLA's softmax); the bucket of 128 is one stretch, which passes the
+    # dense length of 64: its selection weighs the compressed
+    # keys through ``sparse_chunk_scores``
+    assert traced == {"state": 2, "tables": 2, "scores": 2, "chunk": 2,
+                      "select": 2}
+    assert obs.counter("sparse_read_path_total").value(
+        path="chunk_scores_kernel", reason="") >= 2
     paths = obs.counter("paged_read_path_total")
     assert paths.value(path="sparse_chunk_kernel", reason="") >= 1
     assert paths.value(path="selected_pages_kernel", reason="") >= 1

@@ -1,7 +1,7 @@
 """The grouped-heads decode kernel, the experts' grouped-matmul kernel, the
 gated delta rule's decode kernel (with and without its delta term), the
-block-list decode read and the block selection's scoring kernel
-compiled by Mosaic for a DESCRIBED v5e at the cells' real widths, here,
+block-list decode read and the block selection's scoring kernels (a decode
+step's and a prefill stretch's) compiled by Mosaic for a DESCRIBED v5e at the cells' real widths, here,
 without a chip: what interpret mode cannot refuse (a slice off the tiling,
 too much VMEM) fails this at no chip time. Nothing runs, so it says nothing
 of results or times. The topology is described inside a fixture (only the
@@ -260,3 +260,23 @@ def test_the_block_scores_kernel_compiles_for_a_v5e_at_the_cells_widths(
         ((48,), jnp.int32))
     assert "paged_block_scores" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tokens", [16384, 32768, 65536])
+def test_the_prefills_scoring_kernel_compiles_for_a_v5e_at_a_buckets_shapes(
+        one_chip, tokens):
+    """A stretch of 4,096 queries, a group of 16 query heads a key-value
+    head, against a selecting bucket's compressed keys (1,023, 2,047, 4,095
+    of 32 positions every 16) laid out by phase, the stretch's first position
+    a scalar of the running program: a head's keys and a grid step's scores
+    stay in VMEM, out come a stretch's pooled block scores and nothing of the
+    scores' shape."""
+    compiled = _compiled(
+        one_chip,
+        lambda q, ck, first: ppa.sparse_chunk_scores(
+            q, ppa.sparse_chunk_keys(ck, 64, 16), first, 64, 32, 16,
+            interpret=False),
+        ((4096, 2, 16, 128), jnp.bfloat16),
+        ((tokens // 16 - 1, 2, 128), jnp.bfloat16), ((), jnp.int32))
+    assert "sparse_chunk_scores" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

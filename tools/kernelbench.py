@@ -101,6 +101,19 @@ LIGHTNING_CASES = [(32, 32, 32, 128, 128), (64, 64, 32, 128, 128),
 BLOCK_LIST_CASES = [(64, 32, 2, 128, 64, 24576, 64),
                     (64, 32, 2, 128, 64, 24576, 128),
                     (16, 32, 2, 128, 64, 24576, 64)]
+# the prefill's scoring of compressed keys (MiniCPM-SALA's sparse layer): (a
+# stretch's queries, query heads, key-value heads, head size, the bucket's
+# tokens, the stretch's first position): the first selecting and the last
+# stretch of the buckets of 16,384, 32,768 and 65,536 tokens (1,023, 2,047,
+# 4,095 compressed keys); the A/B is the kernel ``sparse_chunk_scores``
+# against the model's XLA form (``key_weights`` and ``block_scores``, 512
+# queries at a time)
+CHUNK_SCORES_CASES = [(4096, 32, 2, 128, 16384, 8192),
+                      (4096, 32, 2, 128, 16384, 12288),
+                      (4096, 32, 2, 128, 32768, 8192),
+                      (4096, 32, 2, 128, 32768, 28672),
+                      (4096, 32, 2, 128, 65536, 8192),
+                      (4096, 32, 2, 128, 65536, 61440)]
 # fused Adam: parameter element counts (one tensor per case; the mp variant
 # also emits the bf16 model copy in the same pass)
 ADAM_CASES = [(1 << 20,), (1 << 24,)]
@@ -635,6 +648,91 @@ def run_block_list_case(rows, heads, kv, ch, ps, pages, listed, reps):
     return case
 
 
+def run_chunk_scores_case(queries, heads, kv, ch, tokens, first, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.models import minicpm_sala as sala
+    from mxnet_tpu.ops import pallas_paged_attention as ppa
+
+    cfg = dict(kernel_size=32, kernel_stride=16, block_size=64)
+    size, stride, block = cfg["kernel_size"], cfg["kernel_stride"], \
+        cfg["block_size"]
+    rng = np.random.RandomState(0)
+    n_keys, n_blocks = tokens // stride - size // stride + 1, tokens // block
+    q = jnp.asarray(rng.randn(queries, kv, heads // kv, ch), jnp.bfloat16)
+    ck = jnp.asarray(rng.randn(n_keys, kv, ch), jnp.bfloat16)
+    case = {"kind": "chunk_scores", "queries": queries,
+            "heads": [heads, kv, ch], "keys": n_keys, "first": first}
+    if not _INTERP:
+        case["gate"] = ppa.sparse_chunk_scores_refusal(
+            q, ck, block, size, stride) or "kernel"
+
+    def kernel(q, ck, first):
+        return ppa.sparse_chunk_scores(
+            q, ppa.sparse_chunk_keys(ck, block, stride), first, block, size,
+            stride, interpret=_INTERP)[:, :, :n_blocks]
+
+    def xla(q, ck, first):
+        few = min(queries, sala._SELECT_QUERIES)
+
+        def of(start):
+            at = first + start + jnp.arange(few, dtype=jnp.int32)
+            return sala.pooled_weights(sala.key_weights(
+                jax.lax.dynamic_slice_in_dim(q, start, few, 0), ck, at, cfg),
+                at, cfg, n_blocks)
+
+        out = jax.lax.map(of, jnp.arange(0, queries, few, dtype=jnp.int32))
+        return jnp.moveaxis(out.reshape(queries, kv, n_blocks), 1, 0)
+
+    forms = {"kernel": kernel, "xla": xla}
+    at = jnp.asarray(first, jnp.int32)
+    got, want = (np.asarray(jax.jit(f)(q, ck, at)) for f in forms.values())
+    seen = np.isfinite(want)
+    err = float(np.abs(got[seen] - want[seen]).max())
+    case["max_err"] = round(err, 8)
+    case["correct"] = bool(err < 1e-5 and (np.isneginf(got) == ~seen).all())
+    del got, want
+
+    def timed(score):
+        # every call's queries follow from the last one's scores, so the
+        # chain's calls run one after another
+        chain = jax.jit(lambda q, ck, at: jax.lax.scan(
+            lambda c, _: (c + (score(c, ck, at)[0, :, :1, None, None] > 1e9
+                               ).astype(c.dtype), ()), q, None,
+            length=reps)[0])
+        chain(q, ck, at)
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            out = chain(q, ck, at)
+            np.asarray(jax.device_get(out[0, 0, 0, :1]))
+            times.append((time.perf_counter() - t0) / reps)
+        return sorted(times)[1]
+
+    for label, score in forms.items():
+        try:
+            case[f"{label}_ms"] = round(timed(score) * 1e3, 4)
+        except Exception as e:
+            case[f"{label}_error"] = repr(e)[:300]
+    # the products of the keys a query sees whole (two operations a key,
+    # head and channel), and what has to cross HBM: queries and keys in,
+    # the block scores out
+    seen_keys = sum(min(max((first + i - size + 1) // stride + 1, 0), n_keys)
+                    for i in range(queries))
+    case["tflop"] = round(2 * seen_keys * heads * ch / 1e12, 4)
+    case["hbm_gb"] = round((q.size * 2 + ck.size * 2
+                            + kv * queries * n_blocks * 4) / 1e9, 4)
+    for label in forms:
+        if f"{label}_ms" in case:
+            case[f"{label}_tflop_per_s"] = round(
+                case["tflop"] / case[f"{label}_ms"] * 1e3, 2)
+    if "kernel_ms" in case and "xla_ms" in case:
+        case["kernel_vs_xla"] = round(case["xla_ms"] / case["kernel_ms"], 2)
+    return case
+
+
 def run_gdn_case(rows, n_live, heads, dk, dv, reps, delta=True):
     import jax
     import jax.numpy as jnp
@@ -884,6 +982,8 @@ def run_one(argv):
             case = run_gdn_case(*spec["shape"], spec["reps"], delta=False)
         elif spec["kind"] == "block_list":
             case = run_block_list_case(*spec["shape"], spec["reps"])
+        elif spec["kind"] == "chunk_scores":
+            case = run_chunk_scores_case(*spec["shape"], spec["reps"])
         elif spec["kind"] == "fused_adam":
             case = run_adam_case(spec["n"], spec["reps"])
         elif spec["kind"] == "softmax_xent":
@@ -907,6 +1007,7 @@ def main():
                          "conv_layout, paged_attn, paged_latent, "
                          "masked_prefill, paged_gqa, grouped_mm, "
                          "gdn_decode, lightning_decode, block_list, "
+                         "chunk_scores, "
                          "fused_adam, softmax_xent); "
                          "default all")
     ap.add_argument("--runs", default="",
@@ -951,6 +1052,9 @@ def main():
                "reps": args.reps} for shape in LIGHTNING_CASES]
     specs += [{"kind": "block_list", "shape": list(shape), "reps": args.reps}
               for shape in BLOCK_LIST_CASES]
+    # a call is 10-80 ms: a chain of five is long enough
+    specs += [{"kind": "chunk_scores", "shape": list(shape),
+               "reps": min(args.reps, 5)} for shape in CHUNK_SCORES_CASES]
     specs += [{"kind": "fused_adam", "n": n, "reps": args.reps}
               for (n,) in ADAM_CASES]
     specs += [{"kind": "softmax_xent", "n": n, "c": c, "reps": args.reps}
