@@ -18,7 +18,9 @@ configuration can select:
   kernels  flash attention fwd+bwd, paged attention and packed attention
            fwd+bwd (BERT-large's: batch 64 x seq 128 x 3 x 1024, key mask),
            the experts' grouped products and the gated delta rule's decode
-           step, compiled by Mosaic and compared with their XLA references
+           step, compiled by Mosaic and compared with their XLA references;
+           MiniCPM-SALA's 16,384 prefill program on a prompt of 12,288: the
+           stretch the prompt does not reach is not run
 
 Any failure in any phase raises and the process exits non-zero; nothing is
 caught. The last line of stdout is one JSON object,
@@ -911,6 +913,62 @@ def check_chunk_scores(queries=512, heads=32, kv=2, ch=128, tokens=16384,
             "tpu_custom_calls": calls, "max_err": round(err, 8)}
 
 
+def check_stretches_reached(bucket=16384, lengths=(12288, 16384)):
+    """MiniCPM-SALA's prefill program at the cell's widths (the benchmark's
+    configuration, its first sparse and first lightning layer, a vocabulary
+    of 2,048): the 16,384 program lowers with its Mosaic kernels
+    (``sparse_prefill``, ``sparse_chunk_scores``); a 12,288-token prompt runs
+    three of its four stretches of 4,096 in every layer (``positions_run``
+    behind the first token) and a 16,384-token prompt all four; and the first
+    token lies within the cell's own limit of the float32 reference's best
+    logit (``benchmark.serve.logit_gaps``' comparison, one position)."""
+    import os
+
+    import numpy as np
+
+    from benchmark.reference import minicpm_sala as ref
+    from benchmark.systems import minicpm_sala as adaptor
+    from benchmark.weights import make_weights
+    from mxnet_tpu import observability as obs
+    from mxnet_tpu.models.minicpm_sala import _STRETCH
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", "minicpm_sala.json")) as f:
+        cfg = json.load(f)
+    cfg.update(n_layer=2, n_vocab=2048)
+    cfg["engine"] = dict(cfg["engine"], batch_size=len(lengths),
+                         num_pages={"all": 640}, max_length=bucket + 64,
+                         prefill_buckets=[bucket])
+    weights = make_weights(ref.param_specs(cfg), SEED)
+    engine, _ = adaptor.build_serve(cfg, weights)
+    text = engine.lower_prefill(bucket).as_text()
+    kernels = {name: text.count(name)
+               for name in ("sparse_prefill", "sparse_chunk_scores")}
+    if not all(kernels.values()):
+        raise AssertionError(f"stretches: the {bucket} program lowered "
+                             f"without a Mosaic kernel: {kernels}")
+    rs = np.random.RandomState(SEED + 3)
+    out = {"bucket": bucket, "kernels_named": kernels, "prompts": []}
+    for slot, n in enumerate(lengths):
+        prompt = rs.randint(1, cfg["n_vocab"], n).tolist()
+        tok = engine.prefill(prompt, slot)
+        ran = obs.step_records("prefill")[-1].counts["positions_run"]
+        if ran != [-(-n // _STRETCH) * _STRETCH] * cfg["n_layer"]:
+            raise AssertionError(f"stretches: a prompt of {n} ran {ran}")
+        want = ref.next_token_logits(weights, cfg, prompt, n - 1, 1,
+                                     pad_to=bucket, out_pad=32)[0]
+        gap = float(want.max() - want[tok])
+        if not gap <= cfg["check"]["widest_gap"]:
+            raise AssertionError(
+                f"stretches: the first token behind {n} tokens lies {gap} "
+                f"under the float32 reference's best logit")
+        out["prompts"].append({"length": n, "positions_run": ran,
+                               "first_token_is_the_references":
+                               bool(tok == int(want.argmax())),
+                               "gap": round(gap, 6)})
+    return out
+
+
 def check_packed(b=64, t=128, heads=16, d=64, interpret=None):
     """The training cell's attention: the packed projection of BERT-large
     with the cell's key-padding mask (valid lengths T/2..T), forward and
@@ -982,6 +1040,7 @@ def phase_kernels():
            "lightning_decode_step": check_lightning(),
            "paged_gqa_decode_selected": check_block_list(),
            "sparse_chunk_scores": check_chunk_scores(),
+           "prefill_stretches_reached": check_stretches_reached(),
            "packed_attention": check_packed()}
     say(f"kernels: {out}")
     return out

@@ -253,7 +253,8 @@ def _count_routes(whole) -> int:
 
 
 #: the models' counts that feed a counter beside the records they land in
-_COUNTED = frozenset(("moe_whole_path", "blocks_read", "blocks_held"))
+_COUNTED = frozenset(("moe_whole_path", "blocks_read", "blocks_held",
+                      "positions_run"))
 
 
 def _count_stats(name, values):
@@ -261,7 +262,8 @@ def _count_stats(name, values):
     back with the tokens: into its counter where it has one
     (``moe_route_total{path}``; ``gen_blocks_read_total`` and
     ``gen_blocks_held_total``, the blocks a selecting layer's reads visited
-    and the blocks their rows held). Returns what a prefill's record keeps
+    and the blocks their rows held; ``gen_positions_run_total``, the
+    positions of the stretches a stretch-major prefill ran). Returns what a prefill's record keeps
     of it: the whole-length calls of the route, else the entries."""
     if name == "moe_whole_path":
         return _count_routes(values)

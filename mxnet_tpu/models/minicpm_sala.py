@@ -40,7 +40,18 @@ of ``_STRETCH`` tokens and take each through the WHOLE block before the
 next (normed, projected, mixed, gated, projected back, added to the residual
 and through the feed-forward, its output written over its input): of the
 prompt's length one array of the model's width is held, and the keys and
-values. A lightning layer keeps SLOT STATE (docs/INFERENCE.md
+values. A prefill whose prompt ends short of its bucket RUNS ONLY THE
+STRETCHES THE PROMPT REACHES: a stretch whose first position is not below
+the prompt's length stands under a predicate (``lax.cond``) and costs that,
+not a block. Nothing reads what it would have computed (the logits are the
+last real position's, attention is causal, a compressed key is written only
+where its last key is real, the state stops at the length, the counts are of
+real queries); what it leaves at its positions is the block's INPUT passed
+through, finite down from the padding's embedding, which a later sparse
+layer projects into keys that land on the trash page or behind the prompt in
+its last page, as the padding's keys always did. The count
+``positions_run`` (an entry a layer) says how many positions a prefill's
+stretches ran. A lightning layer keeps SLOT STATE (docs/INFERENCE.md
 "Slot state"): the state ``(slots, Ch, H*Ch)`` float32 and the positions it
 has taken ``(slots,)``; a prefill writes its row's state from zero and stops
 at the prompt's length, a decode step advances the live rows and no other,
@@ -97,6 +108,16 @@ _CHUNK = 128            # positions a block of the lightning prefill
 _STRETCH = 4096         # tokens a stretch of a prefill's mixers
 _SELECT_QUERIES = 512   # queries whose choice is made at once
 _NEG = -1e30            # a masked score (finite: no row is ever all -inf)
+
+
+def _where_reached(first, length, run, skip, *args):
+    """``run(*args)`` where the stretch that starts at ``first`` holds a real
+    position (``first < length``), else ``skip(*args)``: one ``lax.cond`` on
+    a prefill's traced ``length``; a whole sequence's ``length`` is a Python
+    int, all of it real, and its stretches run as they stand."""
+    if isinstance(length, int):
+        return run(*args)
+    return jax.lax.cond(first < length, run, skip, *args)
 
 
 def block_scores(s, cfg, n_blocks):
@@ -204,13 +225,15 @@ def selected_pages(s, table, position, cfg):
 class BlockSparseAttention(HybridBlock):
     """One ``minicpm4`` sublayer. Returns the output; with ``cache=`` (the
     layer's ``(k_pool, v_pool, compressed-key pool)``), ``(output, new
-    cache, counts)``: ``counts`` int32 scalars of this forward:
+    cache, counts)``: ``counts`` {name: int32 scalar} of this forward:
     ``blocks_read`` (the blocks a key-value head's reads visit, summed over
     the live rows of a decode step or the real queries of a prefill),
-    ``blocks_held`` (the blocks those rows or queries hold) and
-    ``compressed_written`` (compressed keys completed and written)."""
+    ``blocks_held`` (the blocks those rows or queries hold),
+    ``compressed_written`` (compressed keys completed and written) and, of
+    a prefill, ``positions_run`` (the positions of the stretches it ran)."""
 
-    COUNTS = ("blocks_read", "blocks_held", "compressed_written")
+    COUNTS = ("blocks_read", "blocks_held", "compressed_written",
+              "positions_run")
 
     def __init__(self, cfg, dtype="float32", **kwargs):
         super().__init__(**kwargs)
@@ -357,41 +380,51 @@ class BlockSparseAttention(HybridBlock):
         before the next, so nothing of a stretch but the block's output
         outlives it. ``kernel``: the flash forward kernel (``sparse_prefill``
         in a trace) or XLA's masked softmax; ``laid``: the compressed keys
-        for the selection's scoring kernel (:meth:`_taken`) or None. Returns
-        (the block's output,
+        for the selection's scoring kernel (:meth:`_taken`) or None. A
+        stretch past the first whose first position is not below ``length``
+        is not run (:func:`_where_reached`). Returns (the block's output,
         raw (1, T, units), (blocks the first key-value head's real queries
-        read, blocks they hold))."""
+        read, blocks they hold, positions of the stretches that ran))."""
         c, t = self._cfg, x.shape[1]
         block = c["block_size"]
         s = math.gcd(t, _STRETCH)
         n_blocks = -(-t // block)
-        out, read = x._data, jnp.zeros((), jnp.int32)
+        zero = jnp.zeros((), jnp.int32)
+        out, read, ran = x._data, zero, zero
         for first in range(0, t, s):
             tk = first + s
-            at = first + jnp.arange(s, dtype=jnp.int32)
-            x_s = NDArray(out[:, first:tk])
-            taken, real = None, at < length
-            with jax.named_scope("sparse"):
-                with jax.named_scope("qkv"):
-                    u_s = norm(x_s)
-                    q = self._queries(u_s)[0].astype(k.dtype)  # (s, Hkv, G, Ch)
-                if tk > c["dense_len"]:
-                    with jax.named_scope("select"):
-                        taken = self._taken(q, ck, at, n_blocks, laid)
-                        read += jnp.sum(taken[0] & real[:, None],
+
+            def stretch(x_s):   # traced at once, here or by the predicate
+                at = first + jnp.arange(s, dtype=jnp.int32)
+                x_s = NDArray(x_s)
+                taken, real = None, at < length
+                with jax.named_scope("sparse"):
+                    with jax.named_scope("qkv"):
+                        u_s = norm(x_s)
+                        q = self._queries(u_s)[0].astype(k.dtype)  # (s, Hkv, G, Ch)
+                    if tk > c["dense_len"]:
+                        with jax.named_scope("select"):
+                            taken = self._taken(q, ck, at, n_blocks, laid)
+                            n = jnp.sum(taken[0] & real[:, None],
                                         dtype=jnp.int32)
-                else:
-                    read += jnp.sum(jnp.where(real, at // block + 1, 0))
-                with jax.named_scope("read"):
-                    a = self._attend(q, k[:tk], v[:tk], taken, at, kernel)
-                with jax.named_scope("out"):
-                    y = self._gated(a.reshape(1, s, -1), u_s)
-            # the stretch's output takes its input's place
-            out = jax.lax.dynamic_update_slice_in_dim(
-                out, tail(x_s, y), first, axis=1)
+                    else:
+                        n = jnp.sum(jnp.where(real, at // block + 1, 0))
+                    with jax.named_scope("read"):
+                        a = self._attend(q, k[:tk], v[:tk], taken, at, kernel)
+                    with jax.named_scope("out"):
+                        y = self._gated(a.reshape(1, s, -1), u_s)
+                return tail(x_s, y), n, jnp.asarray(s, jnp.int32)
+
+            # a stretch past the first runs where the prompt reaches it; its
+            # output takes its input's place (a stretch not run: its input)
+            x_s = out[:, first:tk]
+            new, n, positions = stretch(x_s) if first == 0 else _where_reached(
+                first, length, stretch, lambda x_s: (x_s, zero, zero), x_s)
+            out = jax.lax.dynamic_update_slice_in_dim(out, new, first, axis=1)
+            read, ran = read + n, ran + positions
         every = jnp.arange(t, dtype=jnp.int32)
         held = jnp.sum(jnp.where(every < length, every // block + 1, 0))
-        return out, (read, held)
+        return out, (read, held, ran)
 
     def _chunk_keys(self, ck, stretch):
         """The compressed keys ``ck`` (J, Hkv, Ch) laid out for the scoring
@@ -457,10 +490,10 @@ class BlockSparseAttention(HybridBlock):
         laid = None
         if t > c["dense_len"]:   # a bucket with stretches that select
             laid = self._chunk_keys(ck, math.gcd(t, _STRETCH))
-        y, (read, held) = self._chunk(x, norm, tail, k, v, ck, not why,
-                                      last + 1, laid)
+        y, (read, held, ran) = self._chunk(x, norm, tail, k, v, ck, not why,
+                                           last + 1, laid)
         written = jnp.sum(cpid > 0, dtype=jnp.int32)
-        return y, (k_pool, v_pool, ck_pool), (read, held, written)
+        return y, (k_pool, v_pool, ck_pool), (read, held, written, ran)
 
     # -- one token a row -----------------------------------------------------
     def _decode(self, q, k, v, pools, table, position, live):
@@ -566,13 +599,16 @@ class BlockSparseAttention(HybridBlock):
             out = tail(x, y)
         if cache is None:
             return NDArray(out)
-        return NDArray(out), tuple(NDArray(p) for p in cache), stats
+        return NDArray(out), tuple(NDArray(p) for p in cache), \
+            dict(zip(self.COUNTS, stats))
 
 
 class LightningAttention(HybridBlock):
     """One ``lightning-attn`` sublayer at published layer ``layer``. Returns
     the output; with ``cache=`` (the layer's slot state), ``(output, new
-    state, rows advanced)``."""
+    state, counts)``: ``counts`` {name: int32 scalar}, ``state_rows`` (the
+    rows whose state advanced) and, of a prefill, ``positions_run`` (the
+    positions of the stretches it ran)."""
 
     def __init__(self, cfg, layer, dtype="float32", **kwargs):
         super().__init__(**kwargs)
@@ -634,14 +670,16 @@ class LightningAttention(HybridBlock):
         last one left, normed a head, gated, projected back and taken through
         the rest of the block (``tail``) before the next. The stretches are
         all alike, so they are ONE ``lax.scan`` body (a sixteenth of a
-        65,536-token program to compile). Returns (the block's output, raw
-        (1, T, units), the state (H, Ch, Ch) behind position ``length -
-        1``)."""
+        65,536-token program to compile), and a stretch whose first position
+        is not below ``length`` is not run (:func:`_where_reached`: state and
+        input pass through). Returns (the block's output, raw (1, T, units),
+        (the state (H, Ch, Ch) behind position ``length - 1``, positions of
+        the stretches that ran))."""
         t = x.shape[1]
         s = math.gcd(t, _STRETCH)
 
-        def stretch(state, at):
-            first, x_s = at                         # (), (1, s, units)
+        def run(carry, first, x_s):
+            state, ran = carry
             x_s = NDArray(x_s)
             with jax.named_scope("lightning"):
                 with jax.named_scope("proj"):
@@ -652,24 +690,30 @@ class LightningAttention(HybridBlock):
                         _CHUNK, lambda o: self._normed(o, gate.dtype), state)
                 with jax.named_scope("out"):
                     y = self.o(NDArray(o[None] * gate))._data
-            return state, tail(x_s, y)
+            return (state, ran + s), tail(x_s, y)
 
-        state = jnp.zeros((self._heads, self._ch, self._ch), jnp.float32)
+        def stretch(carry, at):
+            first, x_s = at                         # (), (1, s, units)
+            return _where_reached(
+                first, length, lambda c, x_s: run(c, first, x_s),
+                lambda c, x_s: (c, x_s), carry, x_s)
+
+        carry = (jnp.zeros((self._heads, self._ch, self._ch), jnp.float32),
+                 jnp.zeros((), jnp.int32))
         if t == s:
-            state, out = stretch(state, (jnp.zeros((), jnp.int32), x._data))
-            return out, state
-        state, out = jax.lax.scan(
-            stretch, state, (jnp.arange(0, t, s, dtype=jnp.int32),
+            carry, out = run(carry, jnp.zeros((), jnp.int32), x._data)
+            return out, carry
+        carry, out = jax.lax.scan(
+            stretch, carry, (jnp.arange(0, t, s, dtype=jnp.int32),
                              x._data.reshape(t // s, 1, s, -1)))
-        return out.reshape(1, t, -1), state
+        return out.reshape(1, t, -1), carry
 
     def mix(self, x, norm, tail, cache=None, start_pos=None, last_pos=None,
             slot=None, live=None):
         """The block around this mixer: ``x`` (B, T, units) the block's
         input, ``norm`` its norm before the mixer, ``tail(x_s, y_s)`` the
         rest of the block on a stretch (raw in, raw out). Returns the
-        block's output; with ``cache=``, ``(output, new state, rows
-        advanced)``."""
+        block's output; with ``cache=``, ``(output, new state, counts)``."""
         b, t, _ = x.shape
         row = lambda i: NDArray(x._data[i:i + 1])  # noqa: E731
         if cache is None:        # one whole chunk a row, no state kept
@@ -677,13 +721,14 @@ class LightningAttention(HybridBlock):
                 [self._chunk(row(i), norm, tail, t)[0] for i in range(b)]))
         if slot is not None:     # a prefill: one row's prompt, from zero
             length = jnp.asarray(last_pos._data, jnp.int32).reshape(()) + 1
-            out, s = self._chunk(x, norm, tail, length)
+            out, (s, ran) = self._chunk(x, norm, tail, length)
             at = jnp.asarray(slot._data, jnp.int32).reshape(())
             new = tuple(
                 jax.lax.dynamic_update_slice_in_dim(
                     full._data, row_[None].astype(full._data.dtype), at, 0)
                 for full, row_ in zip(cache, (gdn.state_rows(s), length)))
-            rows = jnp.asarray(1, jnp.int32)
+            counts = {"state_rows": jnp.asarray(1, jnp.int32),
+                      "positions_run": ran}
         else:                    # a decode step: one token a row
             position = jnp.asarray(start_pos._data, jnp.int32)
             with jax.named_scope("lightning"):
@@ -698,13 +743,13 @@ class LightningAttention(HybridBlock):
                     y = self.o(NDArray(
                         self._normed(o, gate.dtype)[:, None] * gate))._data
             out = tail(x, y)
-            rows = jnp.sum(jnp.asarray(live._data, jnp.int32))
-        return NDArray(out), tuple(NDArray(c) for c in new), rows
+            counts = {"state_rows": jnp.sum(jnp.asarray(live._data, jnp.int32))}
+        return NDArray(out), tuple(NDArray(c) for c in new), counts
 
 
 class MiniCPMSALABlock(HybridBlock):
     """Returns ``x``; with ``cache=``, ``(x, layer's cache, the mixer's
-    counts)``: a sparse layer's tuple of scalars, a lightning layer's rows."""
+    counts)``."""
 
     def __init__(self, cfg, kind, layer, dtype="float32", **kwargs):
         super().__init__(**kwargs)
@@ -762,7 +807,7 @@ class MiniCPMSALAModel(HybridBlock):
     #: first token (one array, so still one blocking read) and writes into
     #: the ``prefill`` record; a decode step's all come back with its tokens
     prefill_counts = ("blocks_read", "blocks_held", "compressed_written",
-                      "state_rows")
+                      "state_rows", "positions_run")
 
     def __init__(self, dtype="float32", **cfg):
         known = minicpm_sala_configs["minicpm_sala"]
@@ -887,14 +932,16 @@ class MiniCPMSALAModel(HybridBlock):
         layer's ``blocks_read`` and ``blocks_held`` (the blocks a key-value
         head's reads visit and the blocks held, over the live rows of a step
         or the real queries of a prompt) and ``compressed_written``; a
-        lightning layer's ``state_rows``. With ``last_pos=`` the logits are
-        those of that position alone."""
+        lightning layer's ``state_rows``; of a prefill, every layer's
+        ``positions_run`` (the positions of the stretches it ran: the
+        prompt's length up to a whole stretch). With ``last_pos=`` the
+        logits are those of that position alone."""
         c = self._cfg
         x = self.word_embed(token_ids) * c["scale_emb"]
         table = page_table[0] if isinstance(page_table, (tuple, list)) \
             else page_table
-        new_cache, sparse, rows = [], [], []
-        for i, (blk, kind) in enumerate(zip(self.blocks, self._kinds)):
+        new_cache, of_layers = [], []
+        for i, blk in enumerate(self.blocks):
             if cache is None:
                 x = blk(x)
                 continue
@@ -902,7 +949,7 @@ class MiniCPMSALAModel(HybridBlock):
                 x, cache=cache[i], start_pos=start_pos, page_table=table,
                 last_pos=last_pos, slot=slot, live=live)
             new_cache.append(layer_cache)
-            (sparse if kind == "minicpm4" else rows).append(counts)
+            of_layers.append(counts)
         if last_pos is not None:
             at = jnp.asarray(last_pos._data, jnp.int32).reshape(-1)[0]
             x = NDArray(jax.lax.dynamic_slice_in_dim(x._data, at, 1, axis=1))
@@ -912,14 +959,11 @@ class MiniCPMSALAModel(HybridBlock):
                            * (c["dim_model_base"] / c["units"]))
         if cache is None:
             return logits
-        stack = lambda zs: jnp.stack(zs).astype(jnp.int32)  # noqa: E731
         counts = {}
-        if sparse:
-            for name, of_layers in zip(BlockSparseAttention.COUNTS,
-                                       zip(*sparse)):
-                counts[name] = stack(of_layers)
-        if rows:
-            counts["state_rows"] = stack(rows)
+        for name in self.prefill_counts:   # an entry a layer that counts it
+            entries = [layer[name] for layer in of_layers if name in layer]
+            if entries:
+                counts[name] = jnp.stack(entries).astype(jnp.int32)
         return logits, new_cache, counts
 
 

@@ -67,3 +67,18 @@ def _seed():
 
     if amp.amp_dtype() is not None:
         amp._reset()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_step_records():
+    """A module starts with empty step-record rings. The rings are the
+    PROCESS's (``obs.step_records``) and a worker runs several modules one
+    after another, in an order that follows their sizes: a check of "every
+    ``prefill`` record of the process" (tests/benchmark/
+    test_benchmark_minicpm_sala.py) must not meet the toys of the module
+    that ran before it."""
+    from mxnet_tpu import observability as obs
+
+    for ring in obs._records.values():
+        ring.clear()
+    yield

@@ -8,9 +8,11 @@ the tree before PR 41 (commit a35b2af: every expert held, so PR 41's branch
 over the sorted pairs is not built and the programs are that tree's);
 DeepSeek-V2's and dots3-note-prev's from PR 41's own tree, whose expert
 layers return one more count (``moe_whole_path``); MiniCPM-SALA's decode step
-and one prefill program (a read over a table of selected pages, a pool of
+(a read over a table of selected pages, a pool of
 compressed keys, a second kind of slot state) from PR 43's own tree, the
-first that has them. A PR that does not mean
+first that has them; its one prefill program (one stretch: no predicate)
+from PR 46's tree, which brings one more count back behind the first token
+(``positions_run``). A PR that does not mean
 to touch those models' programs keeps them; one that does records anew
 (``JAX_PLATFORMS=cpu python tools/loweredsha.py``) and says so."""
 import json
@@ -37,7 +39,7 @@ RECORDED = {
     "smallthinker.decode": "05fe721bb8ff83fee9e490b4919ccd80dd3792736efc587c68e2486c1aae8e88",
     "smallthinker.prefill16": "513b547450397901779089169e03127b038c1e1f6fe760843328cb103dd25fc5",
     "minicpm_sala.decode": "f1ade0fc065414fd12957de6fe4879444d3f3d118baef4cd61ef1f49858f2a57",
-    "minicpm_sala.prefill64": "f2ff9f05135d2645e2123ac5416e73169675709a3d0bb8716428aad7fdffffb0",
+    "minicpm_sala.prefill64": "9816a9e81c000be7a15112cc6c689f45fba6005d5eaa6743cd43563272d90012",
 }
 
 

@@ -10,7 +10,8 @@ dense, rows that start past the dense length and rows that cross it while
 decoding; the tables of selected pages; a compressed key written by the
 decode step that completes it against a prefill's; a prefill in stretches;
 the chunked lightning prefill against the scan; the same prompt in two
-buckets; a slot's next tenant; rows ending while others decode; steps
+buckets; a prompt that ends in each stretch of its bucket (the stretches
+past it are not run); a slot's next tenant; rows ending while others decode; steps
 dispatched ahead, used and dropped; forks, the prefix cache and speculation
 refused by name; every control failing the toy limits ten times over; the
 counts of a decode step and of a prefill; and the three decode kernels
@@ -358,11 +359,24 @@ def test_a_compressed_key_written_by_a_decode_step_is_a_prefills():
     np.testing.assert_allclose(got[5], keys[10:14].mean(axis=0), atol=1e-6)
 
 
-def test_the_same_prompt_in_two_buckets_leaves_the_same_state(model):
+def small_stretches(monkeypatch):
+    """Stretches of 16 tokens (4,096 at the published size): a bucket of 64
+    is four."""
+    monkeypatch.setattr(model_module, "_STRETCH", 16)
+    monkeypatch.setattr(model_module, "_CHUNK", 4)
+    monkeypatch.setattr(model_module, "_SELECT_QUERIES", 8)
+
+
+@pytest.mark.parametrize("stretches", [1, 4])
+def test_the_same_prompt_in_two_buckets_leaves_the_same_state(
+        model, monkeypatch, stretches):
     """Padding writes nothing: the state behind a prompt of 13 in a bucket
-    of 16 is the state in a bucket of 64, and no compressed key of the
-    padding reaches the cache."""
+    of 16 is the state in a bucket of 64, one stretch or four of which the
+    last three are not run, and no compressed key of the padding reaches
+    the cache."""
     cfg, weights = model
+    if stretches > 1:
+        small_stretches(monkeypatch)
     prompt = prompts_of(np.random.default_rng(5), cfg, (13,))[0]
     left = []
     for buckets in ([16], [64]):
@@ -370,6 +384,9 @@ def test_the_same_prompt_in_two_buckets_leaves_the_same_state(model):
         tok = engine.prefill(prompt, slot=1)
         ck = np.asarray(engine.pools[0][2])[np.asarray(engine.page_table)[1][:4]]
         left.append((tok, slot_states(engine), ck.reshape(16, 8)))
+        # the stretches the 13 tokens reach: the first of four, or the one
+        ran = obs.step_records("prefill")[-1].counts["positions_run"]
+        assert ran == [buckets[0] if stretches == 1 else 16] * 4
     assert left[0][0] == left[1][0]
     for (s0, n0), (s1, n1) in zip(left[0][1], left[1][1]):
         # (the projections' products round differently at 16 rows and at 64)
@@ -379,6 +396,93 @@ def test_the_same_prompt_in_two_buckets_leaves_the_same_state(model):
         assert not s0[0].any() and not s0[2].any()   # other slots untouched
     np.testing.assert_allclose(left[0][2], left[1][2], atol=1e-6)
     assert left[0][2][:12].any(axis=1).all() and not left[0][2][12:].any()
+
+
+def left_by_a_prefill(engine, prompt, slot):
+    """What a prefill leaves: the first token, its logits, the lightning
+    layers' state and positions at the slot, a sparse layer's keys and
+    values at the prompt's positions and the compressed keys of every page
+    the row holds, and the program's counts."""
+    tok = engine.prefill(prompt, slot=slot)
+    held = -(-len(prompt) // 4)                             # pages of 4
+    table = np.asarray(engine.page_table)[slot][:held]
+    rows = lambda pool: np.asarray(pool)[table].reshape(4 * held, -1)  # noqa: E731
+    sparse = [tuple(rows(pool) for pool in layer)
+              for layer, g in zip(engine.pools, engine.layer_groups)
+              if g == "all"]
+    return dict(
+        token=tok, logits=np.asarray(engine._last_logits),
+        state=[(s[slot], n[slot]) for s, n in slot_states(engine)],
+        keys=[(k[:len(prompt)], v[:len(prompt)]) for k, v, _ in sparse],
+        compressed=[ck for _, _, ck in sparse],
+        counts=obs.step_records("prefill")[-1].counts)
+
+
+@pytest.mark.parametrize("length", [13, 29, 45, 61, 64])
+def test_a_prefill_runs_only_the_stretches_its_prompt_reaches(
+        model, monkeypatch, length):
+    """A bucket of 64 in four stretches of 16, the prompt ending in the
+    1st, 2nd, 3rd and last of them, and filling the bucket: what the prefill
+    leaves is what the smallest bucket that holds the prompt leaves (every
+    stretch of that one reaches the prompt) and the reference's full
+    forward, and every layer ran the stretches the prompt reaches."""
+    cfg, weights = model
+    small_stretches(monkeypatch)
+    prompt = prompts_of(np.random.default_rng(length), cfg, (length,))[0]
+    total = obs.counter("gen_positions_run_total")
+    before = total.value()
+    wide, _ = build(cfg, weights, prefill_buckets=[64])
+    close, _ = build(cfg, weights, prefill_buckets=[16, 32, 48, 64])
+    got, want = (left_by_a_prefill(e, prompt, 2) for e in (wide, close))
+    reached = -(-length // 16) * 16
+    assert got["counts"]["bucket"] == 64
+    assert want["counts"]["bucket"] == reached
+    assert got["counts"]["positions_run"] == [reached] * 4
+    assert want["counts"]["positions_run"] == [reached] * 4
+    assert total.value() - before == 2 * 4 * reached
+    assert got["token"] == want["token"]
+    np.testing.assert_allclose(got["logits"], want["logits"], atol=1e-5)
+    np.testing.assert_allclose(
+        got["logits"],
+        reference_logits(cfg, weights, prompt, length - 1, 1)[0], atol=3e-5)
+    for (s0, n0), (s1, n1) in zip(got["state"], want["state"]):
+        np.testing.assert_allclose(s0, s1, atol=1e-5)
+        assert n0 == n1 == length and np.abs(s0).max() > 0.01
+    for (k0, v0), (k1, v1) in zip(got["keys"], want["keys"]):
+        np.testing.assert_allclose(k0, k1, atol=1e-5)
+        np.testing.assert_allclose(v0, v1, atol=1e-5)
+        assert k0.any(axis=1).all()
+    # a compressed key of 2 every 1: those whose last key is real
+    for ck0, ck1 in zip(got["compressed"], want["compressed"]):
+        np.testing.assert_allclose(ck0, ck1, atol=1e-5)
+        assert ck0[:length - 1].any(axis=1).all() \
+            and not ck0[length - 1:].any()
+    read, held = zip(*(blocks_read(cfg, q + 1) for q in range(length)))
+    for counts in (got["counts"], want["counts"]):
+        assert counts["blocks_read"] == [sum(read)] * 2
+        assert counts["blocks_held"] == [sum(held)] * 2
+        assert counts["compressed_written"] == [length - 1] * 2
+        assert counts["state_rows"] == [1, 1]
+
+
+def test_a_stretch_past_the_first_stands_under_a_predicate(model, monkeypatch):
+    """The lowered prefill program of a bucket of four stretches: a sparse
+    layer's three stretches past the first are a conditional each (the first
+    always runs), a lightning layer's scanned stretch is one; a bucket of one
+    stretch has none, and the decode program none and no stretch: its text
+    does not move with the stretch's length (its SHA-256 is held to PR 43's
+    tree in ``tests/test_lowered_text_guard.py``)."""
+    cfg, weights = model
+    engine, _ = build(cfg, weights, prefill_buckets=[16, 64])
+    decode = engine.lower_decode().as_text()
+    assert "stablehlo.case" not in engine.lower_prefill(64).as_text()
+    small_stretches(monkeypatch)
+    engine, _ = build(cfg, weights, prefill_buckets=[16, 64])
+    assert engine.lower_prefill(64).as_text().count("stablehlo.case") \
+        == 2 * 3 + 2 * 1
+    assert "stablehlo.case" not in engine.lower_prefill(16).as_text()
+    assert "stablehlo.case" not in decode
+    assert engine.lower_decode().as_text() == decode
 
 
 def test_a_slot_used_again_after_a_longer_tenant(model):

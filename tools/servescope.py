@@ -21,7 +21,10 @@ scope, ``layerN/attn`` or ``layerN/mla/core``).
 ``--depth 4`` tells ``layerN/mla/dsa/index`` from ``layerN/mla/dsa/select``
 (a full layer's scoring of the held index keys and its selection).
 ``--prefill-lens 16384,8192`` traces one prefill of each length besides and
-prints ``prefill_programs``: the program's device time and each scope's as
+prints ``prefill_programs`` (by bucket; a prompt that ends short of its
+bucket, as 36864 in 65,536, under ``"65536:36864"``: a program that runs only
+the stretches its prompt reaches costs what the prompt's length costs): the
+program's device time and each scope's as
 the UNION of its operations' intervals (``benchmark/trace/reduce.py``: an
 asynchronous copy's whole span is then counted once, where it covers nothing
 else, and not added to every operation it overlaps).
@@ -397,7 +400,9 @@ def main(argv):
             scoped_text(engine.lower_prefill(n)),
             scopes=(engine.net._scope_label(None),))
         found = program_by_scope(cap.report, table, 2, args.depth)
-        out["prefill_programs"][str(engine.bucket_for(n))] = found
+        bucket = engine.bucket_for(n)  # a prompt short of it: "<bucket>:<n>"
+        out["prefill_programs"][str(bucket) if n == bucket
+                                else f"{bucket}:{n}"] = found
         print(f"[servescope] prefill of {n} tokens: busy "
               f"{found['busy_ms']:.1f} ms; by operation {found['op_ms']}")
         for path, ms in found["scope_ms"].items():
