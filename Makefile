@@ -50,7 +50,7 @@ PY ?= python
 # 3-attempt retry policy can never see an injected failure twice in a row.
 CHAOS_FAULTS ?= ckpt.save:every=3;ckpt.load:every=3;kv.save_states:every=2;kv.load_states:every=3;kv.dcn_psum:every=4;kv.dcn_psum_batch:every=4;data.batch:every=7;seed=1234
 
-.PHONY: ci sanity lint audit shardcheck memcheck profcheck kernelcheck native fast slow test chaos chaos-elastic chaos-serve chaos-fleet obs obsfleet perfwin genbench ampbench bench clean
+.PHONY: ci sanity lint audit shardcheck memcheck profcheck kernelcheck native fast slow test chaos chaos-elastic chaos-serve chaos-fleet obs obsfleet perfwin ampbench bench clean
 
 ci: sanity lint native fast audit shardcheck memcheck profcheck kernelcheck chaos-elastic chaos-serve chaos-fleet obsfleet
 
@@ -179,24 +179,6 @@ obsfleet: native
 # the single-step path; artifact committed as BENCH_r06.json
 perfwin: native
 	$(PY) tools/benchall.py --window 4 --out BENCH_r06.json
-
-# compiled-generation gates (docs/INFERENCE.md), tiny GPT-2, CPU, median
-# of alternating A/B pairs, identical greedy tokens required everywhere:
-#   cached vs naive  — >= 3x amortized per-token over the eager re-forward
-#                      loop, exactly (prefill buckets used + 1) programs;
-#   paged vs dense   — >= 4x concurrent sequences at equal cache memory
-#                      (page pool == dense token capacity), bytes-of-cache
-#                      per admitted sequence down accordingly, serving
-#                      tokens/sec up at the high slot count;
-#   spec vs paged    — self-drafting speculative decode >= 1.5x amortized
-#                      tokens/sec over the paged non-speculative engine,
-#                      exactly (buckets + 1 decode + 1 verify) programs.
-# artifact committed per measurement round as GENBENCH_$(GENBENCH_ROUND).json
-# (override GENBENCH_ROUND to rebless an old round; the default is the
-# current round so a rerun never silently clobbers an earlier artifact)
-GENBENCH_ROUND ?= r04
-genbench:
-	$(PY) tools/genbench.py --out GENBENCH_$(GENBENCH_ROUND).json
 
 # compiled mixed-precision gate (docs/PERFORMANCE.md "Mixed precision"):
 # HLO dtype assertions (bf16 dots + f32 master update, f16 loss scaling

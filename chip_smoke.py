@@ -238,7 +238,7 @@ def phase_train_four_chips(devices):
 # serve
 # --------------------------------------------------------------------------
 def _reforward_greedy(net, prompt, n_new, length):
-    """The oracle of tools/genbench.py's cached-vs-naive section: greedy
+    """The oracle of the serving phase: greedy
     tokens from a plain full forward of the hybridized net over the
     growing sequence. The sequence sits in a fixed ``length`` buffer
     (causal attention keeps position i blind to the padding behind it), so

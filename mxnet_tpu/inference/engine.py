@@ -70,7 +70,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from collections import OrderedDict
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -81,9 +80,9 @@ from .. import observability as _obs
 from ..gluon.block import _HybridTrace
 from ..ndarray import NDArray
 from ..ops import random_ops as _rops
-from ..ops.pallas_paged_attention import RUN_PAGES
 from ..resilience import faults as _faults
 from ..resilience import retry as _retry
+from . import pages as _pages
 from .prefix_cache import RadixPrefixCache
 
 __all__ = ["GenerationEngine", "SamplingConfig"]
@@ -119,126 +118,6 @@ def _default_buckets(max_length: int) -> Tuple[int, ...]:
     return tuple(out) or (max_length - 1,)
 
 
-class _FreePages:
-    """The free pages of one pool, ids ``1 .. num_pages`` (0 is the trash
-    page), kept as aligned CHUNKS of ``RUN_PAGES`` ids so that a row's pages
-    stay side by side in a served pool: the decode kernel fetches
-    ``RUN_PAGES`` logically consecutive pages whose ids are consecutive as
-    one copy (``ops/pallas_paged_attention.py``). Every free page can be
-    taken and none is held back; the one rule is a PREFERENCE, told by the
-    taker: ``take(after, head)`` gives logical page ``s`` the id next to
-    page ``s - 1``'s (``after``) where that id is free, and where ``s``
-    starts a group of ``RUN_PAGES`` (``head``) the first id of a wholly
-    free chunk (the neighbour chunk's before any other), so the group can
-    fill that chunk id by id. A taker that finds neither takes from the
-    partly free chunks, and from a whole one last: fragments are used up
-    before a whole chunk is broken, and a chunk is whole again when its
-    last page comes back. O(1) a page: a count a chunk and two ordered
-    sets of chunks."""
-
-    def __init__(self, num_pages: int):
-        self.chunk, self.num_pages = RUN_PAGES, int(num_pages)
-        self._is_free = bytearray([0]) + bytearray([1]) * self.num_pages \
-            + bytearray([0])  # by id; the trash page and an end stop
-        whole, rest = divmod(self.num_pages, self.chunk)
-        #: free ids a chunk (chunk c holds ids c * chunk + 1 ...)
-        self._count = [self.chunk] * whole + [rest] * bool(rest)
-        #: chunks wholly free, and chunks partly free (a short last chunk
-        #: is never whole), oldest first
-        self._whole = OrderedDict.fromkeys(range(whole))
-        self._partial = OrderedDict.fromkeys(range(whole, whole + bool(rest)))
-        self._len = self.num_pages
-
-    def __len__(self) -> int:
-        return self._len
-
-    def __iter__(self):
-        return (pid for pid in range(1, self.num_pages + 1)
-                if self._is_free[pid])
-
-    def _take_id(self, pid: int) -> int:
-        c = (pid - 1) // self.chunk
-        self._is_free[pid] = 0
-        self._count[c] -= 1
-        self._len -= 1
-        was_whole = self._whole.pop(c, 0) is None
-        if not self._count[c]:
-            self._partial.pop(c, None)
-        elif was_whole:
-            self._partial[c] = None
-        return pid
-
-    def give(self, pid: int) -> None:
-        """``pid`` comes back (its last reference is gone)."""
-        c = (pid - 1) // self.chunk
-        self._is_free[pid] = 1
-        self._count[c] += 1
-        self._len += 1
-        if self._count[c] == self.chunk:
-            self._partial.pop(c, None)
-            self._whole[c] = None
-        else:
-            self._partial[c] = None
-
-    def take(self, after: int = 0, head: bool = True) -> int:
-        """One free page for the logical page behind the one that holds id
-        ``after`` (0: the row has none there); ``head``: the page starts a
-        group of ``chunk`` logical pages. The caller has seen ``len(self)
-        > 0``."""
-        nxt = after + 1 if after else 0  # id 0 is never free
-        if self._is_free[nxt]:
-            if not head or (after % self.chunk == 0
-                            and (nxt - 1) // self.chunk in self._whole):
-                return self._take_id(nxt)
-        if head and self._whole:
-            return self._take_id(next(iter(self._whole)) * self.chunk + 1)
-        if self._is_free[nxt]:
-            return self._take_id(nxt)
-        c = next(iter(self._partial or self._whole))
-        return self._take_id(self._is_free.index(1, c * self.chunk + 1))
-
-    def take_row(self, n: int, first: int = 0, after: int = 0) -> List[int]:
-        """``n`` pages for a row's logical pages ``first .. first + n - 1``
-        behind the page that holds ``after`` (a prefill's): what ``take``
-        gives page by page, a whole group's chunk taken at once."""
-        out, s, end, g = [], first, first + n, self.chunk
-        while s < end:
-            if s % g or end - s < g or not self._whole:
-                after = self.take(after, s % g == 0)
-                out.append(after)
-                s += 1
-                continue
-            c = after // g   # the neighbour chunk, if ``after`` ends its own
-            if not after or after % g or c not in self._whole:
-                c = next(iter(self._whole))
-            del self._whole[c]
-            self._count[c] = 0
-            self._len -= g
-            self._is_free[c * g + 1:c * g + g + 1] = bytes(g)
-            out.extend(range(c * g + 1, c * g + g + 1))
-            after = c * g + g
-            s += g
-        return out
-
-
-def _is_run(ids) -> bool:
-    """Whether a whole group's page ids, in logical order (None: a page the
-    row does not hold), are consecutive: what the decode kernel fetches as
-    one copy."""
-    return bool(ids[0]) and ids == list(range(ids[0], ids[0] + len(ids)))
-
-
-def _tally_run(runs: set, k: int, ids) -> int:
-    """Keep group ``k`` in a row's ``runs`` exactly while ``ids`` (the
-    group's pages as the row holds them now) are a whole run; returns the
-    change of the count of runs (``gen_page_run_share``)."""
-    is_run = len(ids) == RUN_PAGES and _is_run(ids)
-    if is_run == (k in runs):
-        return 0
-    runs.symmetric_difference_update((k,))
-    return 1 if is_run else -1
-
-
 def _count_routes(whole) -> int:
     """A program's expert-layer calls into ``moe_route_total{path}``:
     ``whole`` is the model's count ``moe_whole_path``, an entry a call, 1
@@ -272,94 +151,6 @@ def _count_stats(name, values):
                      f"the models' count {name}, summed over layers, decode "
                      "steps and prefills").inc(int(np.sum(values)))
     return np.asarray(values).tolist()
-
-
-class _WindowPages:
-    """The host allocator of a ``window`` page group: the pools of layers
-    that attend only the last ``window`` positions. A row holds the pages
-    its window reaches and no other: the pages behind the window go back to
-    the free list while the row lives. The group has a page table of its
-    own, a RING of ``columns = window // page_size + 3`` columns a row:
-    logical page ``s`` (positions ``s * page_size ...``) lives in column
-    ``s % columns``, and a row never holds more pages than ``columns - 1``.
-    Pages are never shared (no prefix cache, no fork), so there are no
-    reference counts."""
-
-    def __init__(self, num_pages, batch_size, page_size, window):
-        self.num_pages, self.page_size = int(num_pages), int(page_size)
-        self.window = int(window)
-        self.columns = self.window // self.page_size + 3
-        self.free = _FreePages(self.num_pages)
-        #: per row {logical page: page id}
-        self.rows: List[dict] = [{} for _ in range(batch_size)]
-        #: per row, the groups of RUN_PAGES logical pages it holds whole
-        #: on consecutive ids (``gen_page_run_share``); their count
-        self.runs: List[set] = [set() for _ in range(batch_size)]
-        self.n_runs = 0
-        self.reserved = 0  # free pages held back for a parked queue head
-        self.freed_total = 0
-
-    @property
-    def in_use(self) -> int:
-        return self.num_pages - len(self.free)
-
-    def low_page(self, position: int) -> int:
-        """The first logical page a row whose next token lies at
-        ``position`` still reads."""
-        return max(0, position - self.window + 1) // self.page_size
-
-    def needed(self, length: int) -> int:
-        """Pages a prefill of ``length`` tokens takes."""
-        return (length - 1) // self.page_size - self.low_page(length) + 1
-
-    def release(self, slot: int) -> int:
-        pages = self.rows[slot]
-        self.rows[slot] = {}
-        self.n_runs -= len(self.runs[slot])
-        self.runs[slot] = set()
-        for pid in pages.values():
-            self.free.give(pid)
-        return len(pages)
-
-    def _note_group(self, slot: int, k: int) -> None:
-        """Count group ``k`` of row ``slot`` as a run, or no longer."""
-        held = self.rows[slot]
-        self.n_runs += _tally_run(self.runs[slot], k, [
-            held.get(s) for s in range(k * RUN_PAGES, (k + 1) * RUN_PAGES)])
-
-    def admit(self, slot: int, length: int) -> np.ndarray:
-        """Give row ``slot`` the pages a ``length``-token prompt keeps (the
-        caller has checked that they are there); returns its table row."""
-        row = np.zeros(self.columns, np.int32)
-        first, end = self.low_page(length), (length - 1) // self.page_size + 1
-        ids = self.free.take_row(end - first, first)
-        self.rows[slot] = dict(zip(range(first, end), ids))
-        row[np.arange(first, end) % self.columns] = ids
-        for k in range(first // RUN_PAGES, (end - 1) // RUN_PAGES + 1):
-            self._note_group(slot, k)
-        return row
-
-    def step(self, slot: int, position: int):
-        """Before row ``slot`` writes at ``position``: the page of that
-        position, taken now if the row lacks it, and the pages now behind
-        the window, freed. Returns [(column, page id, or -1 for a freed
-        column)], or None where the pool (less its reservation) is dry."""
-        held, updates = self.rows[slot], []
-        for s in [s for s in held if s < self.low_page(position)]:
-            self.free.give(held.pop(s))
-            self._note_group(slot, s // RUN_PAGES)
-            updates.append((s % self.columns, -1))
-            self.freed_total += 1
-        page = position // self.page_size
-        if page not in held:
-            if len(self.free) - self.reserved <= 0:
-                return None
-            held[page] = self.free.take(held.get(page - 1, 0),
-                                        page % RUN_PAGES == 0)
-            updates.append((page % self.columns, held[page]))
-            if (page + 1) % RUN_PAGES == 0:  # the group it completes
-                self._note_group(slot, page // RUN_PAGES)
-        return updates
 
 
 class GenerationEngine:
@@ -523,30 +314,20 @@ class GenerationEngine:
         if self.paged:
             if self.page_size < 1:
                 raise ValueError("page_size must be >= 1")
-            #: page-table width: page slots per row (slot s = positions
-            #: s*ps .. (s+1)*ps - 1)
-            self._n_row_pages = -(-self.max_length // self.page_size)
             #: the model's pool groups ({group: rule}); one group ``all``
             #: where it declares none
-            groups = dict(getattr(net, "paged_pool_groups", None) or {})
-            windows = {g: int(r["window"]) for g, r in groups.items()
-                       if r.get("window")}
-            if len(windows) > 1 or set(groups) - set(windows) - {"all"}:
+            rules = dict(getattr(net, "paged_pool_groups", None)
+                         or {"all": {}})
+            windows = [g for g, r in rules.items() if r.get("window")]
+            if len(windows) > 1 or set(rules) - set(windows) != {"all"}:
                 raise ValueError(
-                    f"pool groups {groups}: the engine keeps one group "
+                    f"pool groups {rules}: the engine keeps one group "
                     "'all' and at most one group with a window")
             per_group = dict(num_pages) if isinstance(num_pages, dict) \
                 else {"all": num_pages}
-            if set(per_group) - (set(groups) or {"all"}):
+            if set(per_group) - set(rules):
                 raise ValueError(f"num_pages names groups {sorted(per_group)}"
-                                 f"; the model has {sorted(groups) or ['all']}")
-            # explicit `is None` check: a computed num_pages that underflows
-            # to 0 must hit the error below, not the dense-equivalent default
-            self.num_pages = int(self.batch_size * self._n_row_pages
-                                 if per_group.get("all") is None
-                                 else per_group["all"])
-            if self.num_pages < 1:
-                raise ValueError("num_pages must be >= 1")
+                                 f"; the model has {sorted(rules)}")
             #: whether some layers keep state by slot (axis 0 = the slot)
             self._slot_state = bool(getattr(net, "paged_slot_state", False))
             if self._slot_state and (prefix_cache or draft_net is not None):
@@ -556,86 +337,72 @@ class GenerationEngine:
                     "there would need a copy of a row's state at an earlier "
                     "position, which no page holds. prefix_cache= and "
                     "draft_net= are refused for such a model")
-            #: the ``window`` group's allocator (None: the model has none)
-            self._window = None
-            for g, w in windows.items():
-                if prefix_cache or draft_net is not None:
-                    raise ValueError(
-                        f"the model's pool group {g!r} frees the pages behind "
-                        f"a window of {w} positions: a prefix cached or a "
-                        "draft verified there could need a page that is "
-                        "gone. prefix_cache= and draft_net= are refused for "
-                        "such a model")
-                pages = per_group.get(g)
-                self._window = _WindowPages(
-                    self.batch_size * (w // self.page_size + 3)
-                    if pages is None else pages,
-                    self.batch_size, self.page_size, w)
-                if self._window.num_pages < 1:
+            self.prefix_cache = (RadixPrefixCache(self.page_size)
+                                 if prefix_cache else None)
+            # worst-case NEW pages per row per dispatch (window k spans at
+            # most k//ps + 2 page slots from an arbitrary start offset)
+            self._upd_width = self.speculate_k // self.page_size + 2
+            #: {group: its host allocator} (``inference/pages.py``) in the
+            #: model's order: authoritative; the device tables mirror them
+            #: through compiled update vectors shipped with each program
+            self._groups = {
+                g: _pages.group_for(rule, per_group.get(g), self.batch_size,
+                                    self.page_size, self.max_length,
+                                    self._upd_width, self.prefix_cache)
+                for g, rule in rules.items()}
+            for name, g in self._groups.items():
+                if g.num_pages < 1:
                     raise ValueError("num_pages must be >= 1")
-            #: device carry: per-row page tables (0 = unallocated/trash);
-            #: with a window group a tuple, one table a group
-            self.page_table = jnp.zeros(
-                (self.batch_size, self._n_row_pages), jnp.int32)
+                if not g.shares and (prefix_cache or draft_net is not None):
+                    raise ValueError(
+                        f"the model's pool group {name!r} frees the pages "
+                        f"behind a window of {g.window} positions: a prefix "
+                        "cached or a draft verified there could need a page "
+                        "that is gone. prefix_cache= and draft_net= are "
+                        "refused for such a model")
+            #: the group whose rows may share pages (it keeps every
+            #: position): what the public page counts (``num_pages``,
+            #: ``free_pages``, ...), the prefix cache and forks speak of
+            self._pages = next(g for g in self._groups.values() if g.shares)
+            self.num_pages = self._pages.num_pages
+            #: device carry: per-row page tables (0 = unallocated/trash),
+            #: one table where there is one group, else a tuple in the
+            #: groups' order (the form the models are traced with)
+            self.page_table = self._form([
+                jnp.zeros((self.batch_size, g.columns), jnp.int32)
+                for g in self._groups.values()])
             #: device carry: the model's per-layer state, one tuple of page
             #: pools a layer (axis 0 = pages; GPT-2: (k_pool, v_pool),
             #: DeepSeek-V2: one latent pool)
-            if groups:
-                sizes = {"all": self.num_pages}
-                if self._window is not None:
-                    sizes[next(iter(windows))] = self._window.num_pages
+            if getattr(net, "paged_pool_groups", None):
                 pools, self.layer_groups = net.init_paged_cache(
-                    {g: sizes[g] for g in groups}, self.page_size,
-                    dtype=cache_dtype, **({"slots": self.batch_size}
-                                          if self._slot_state else {}))
+                    {n: g.num_pages for n, g in self._groups.items()},
+                    self.page_size, dtype=cache_dtype,
+                    **({"slots": self.batch_size}
+                       if self._slot_state else {}))
                 self.layer_groups = tuple(self.layer_groups)
             else:
                 pools = net.init_paged_cache(
                     self.num_pages, self.page_size, dtype=cache_dtype)
                 self.layer_groups = ("all",) * len(pools)
             self.pools = [tuple(layer) for layer in pools]
-            self._group_names = tuple(groups) or ("all",)
-            if self._window is not None:
-                self.page_table = self._by_group(self.page_table, jnp.zeros(
-                    (self.batch_size, self._window.columns), jnp.int32))
             self.cache = None  # dense-only state
-            # host allocator (authoritative; the device table mirrors it
-            # through compiled update vectors shipped with each program)
-            self._free_pages = _FreePages(self.num_pages)
-            self._row_pages: List[List[int]] = \
-                [[] for _ in range(self.batch_size)]
-            #: per row, the whole groups of RUN_PAGES logical pages whose
-            #: ids are consecutive (``gen_page_run_share``); their count
-            self._row_runs: List[set] = [set() for _ in range(self.batch_size)]
-            self._n_runs = 0
             self._pending_clear: set = set()
-            #: pages the batcher's aging guard holds back from decode-time
-            #: growth for a parked queue head (docs/RESILIENCE.md)
-            self._reserved_pages = 0
             #: rows force-finished because the pool ran dry (the batcher
             #: reports these as finish_reason="page_exhausted")
             self.page_exhausted = np.zeros(self.batch_size, bool)
-            # worst-case NEW pages per row per dispatch (window k spans at
-            # most k//ps + 2 page slots from an arbitrary start offset)
-            self._upd_width = self.speculate_k // self.page_size + 2
-            #: per-page refcounts (index 0 = trash page, never counted):
-            #: a page may back several rows / the prefix cache at once;
-            #: only refcount-0 pages return to the free list
-            self._page_rc = np.zeros(self.num_pages + 1, np.int32)
             #: copy-on-write copies per compiled dispatch (chunked)
             self._cow_width = self.batch_size
             self._cow_jit = None  # lazily lowered page-copy program
             #: per-slot prefill logits (device (V,) arrays) — fork_slot's
             #: resample_first draws an independent first token from them
             self._prefill_logits = {}
-            self.prefix_cache = (RadixPrefixCache(self.page_size)
-                                 if prefix_cache else None)
             self._page_gauges()
             per_token = _obs.gauge("gen_cache_bytes_per_token",
                                    "bytes the paged cache holds for one "
                                    "token, all layers")
             per_token.set(self.cache_bytes_per_token)
-            for g in self._group_names:  # and one series a pool group
+            for g in self._groups:  # and one series a pool group
                 per_token.set(self._group_bytes_per_token(g), group=g)
             if self._slot_state:
                 _obs.gauge("gen_slot_state_bytes",
@@ -654,8 +421,10 @@ class GenerationEngine:
             self.cache = net.init_cache(self.batch_size, self.max_length,
                                         dtype=cache_dtype)
             self.prefix_cache = None
-            self._window = None
+            self._groups, self._pages = {}, None
             self._slot_state = False
+        #: the groups whose pages in use a decode step's record keeps
+        self._counted = [g for g in self._groups.values() if g.counted_as]
 
         if draft_net is not None:
             self._draft_plist = [p for _, p in
@@ -757,14 +526,24 @@ class GenerationEngine:
             sig=list(map(str, sig))) is not None
 
     # -- page accounting (paged mode) ----------------------------------------
+    def _form(self, per_group):
+        """One value a pool group in the form the programs take it: the
+        value itself where there is one group, else a tuple in the groups'
+        order (the form the models are traced with)."""
+        return per_group[0] if len(self._groups) == 1 else tuple(per_group)
+
+    def _each(self, formed) -> tuple:
+        """:meth:`_form`'s inverse: one value a pool group."""
+        return (formed,) if len(self._groups) == 1 else formed
+
     @property
     def free_pages(self) -> int:
         """Unallocated pages in the pool (paged mode)."""
-        return len(self._free_pages) if self.paged else 0
+        return len(self._pages.free) if self.paged else 0
 
     @property
     def pages_in_use(self) -> int:
-        return self.num_pages - len(self._free_pages) if self.paged else 0
+        return self._pages.in_use if self.paged else 0
 
     def _group_bytes_per_token(self, group: str) -> float:
         """Bytes the layers of ``group`` hold for one token: their pools'
@@ -772,18 +551,15 @@ class GenerationEngine:
         total = sum(b.size * b.dtype.itemsize
                     for layer, g in zip(self.pools, self.layer_groups)
                     if g == group for b in layer)
-        pages = self.num_pages if group == "all" else self._window.num_pages
-        return total / float((pages + 1) * self.page_size)
+        return total / float((self._groups[group].num_pages + 1)
+                             * self.page_size)
 
     @property
     def cache_bytes_per_token(self) -> float:
         """Bytes the paged cache holds for one token over all layers: the
         pools' bytes over the pool's token capacity (with a window group,
         the groups' sum: a token within the window)."""
-        if not self.paged:
-            return 0.0
-        return sum(self._group_bytes_per_token(g)
-                   for g in dict.fromkeys(self.layer_groups) if g != "slot")
+        return sum(map(self._group_bytes_per_token, self._groups), 0.0)
 
     @property
     def slot_state_bytes(self) -> int:
@@ -799,34 +575,20 @@ class GenerationEngine:
     def page_groups(self) -> dict:
         """{group: {"num_pages", "in_use", "window"}} of a paged engine's
         pool groups."""
-        if not self.paged:
-            return {}
-        every = {"num_pages": self.num_pages, "in_use": self.pages_in_use,
-                 "window": None}
-        w = self._window
-        return dict(zip(self._group_names, self._by_group(
-            every, w and {"num_pages": w.num_pages, "in_use": w.in_use,
-                          "window": w.window})))
-
-    def _by_group(self, of_all, of_window) -> tuple:
-        """One value a pool group, in the groups' order: ``of_all`` for the
-        group ``all``, ``of_window`` for the window group."""
-        return tuple(of_all if g == "all" else of_window
-                     for g in self._group_names)
+        return {name: {"num_pages": g.num_pages, "in_use": g.in_use,
+                       "window": g.window}
+                for name, g in self._groups.items()}
 
     def covers(self, prompt, unreserved: bool = False) -> bool:
         """Whether every pool group has the pages that admitting ``prompt``
-        takes: the ``all`` group's free pages plus what the prefix cache
-        would give up (``unreserved``: its free pages less the reservation,
-        what a request that bypasses a parked head may take), and a window
-        group's free pages. The group that runs short decides."""
-        have = (self.free_pages - self.reserved_pages if unreserved
-                else self.available_pages)
-        if have < self.pages_needed(prompt):
-            return False
-        w = self._window if self.paged else None
-        return w is None or (len(w.free) - (w.reserved if unreserved else 0)
-                             >= w.needed(len(prompt)))
+        takes: a group's free pages plus what the prefix cache would give
+        up (``unreserved``: its free pages less the reservation, what a
+        request that bypasses a parked head may take). The group that runs
+        short decides."""
+        n = len(prompt)
+        adopted = (n - self.suffix_for(prompt)) // self.page_size
+        return all(g.spare(unreserved) >= g.needed(n, adopted)
+                   for g in self._groups.values())
 
     def pages_for(self, length: int) -> int:
         """Pages a ``length``-token sequence occupies."""
@@ -874,237 +636,83 @@ class GenerationEngine:
         """Free pages plus prefix-cache pages evictable under pressure —
         the admission headroom (``free_pages`` alone undercounts once the
         cache holds refcount-1 pages the allocator can LRU-reclaim)."""
-        if not self.paged:
-            return 0
-        n = len(self._free_pages)
-        if self.prefix_cache is not None:
-            n += self.prefix_cache.collectable(
-                lambda pid: self._page_rc[pid] == 1)
-        return n
+        return self._pages.spare() if self.paged else 0
 
     @property
     def reserved_pages(self) -> int:
         """Free pages currently held back for a parked queue head."""
-        return self._reserved_pages if self.paged else 0
+        return self._pages.reserved if self.paged else 0
 
     def reserve_pages(self, n: int) -> None:
-        """Hold ``n`` free pages back from decode-time growth (the
-        batcher's aging guard: a queue head deferred too long on
-        ``free_pages`` gets freed pages *reserved* instead of watching
-        running rows' ``_grow_pages`` consume them forever). Reserved
-        pages are still visible to :meth:`prefill` — the head's admission
-        is exactly what they are being saved for. ``n=0`` releases the
-        reservation. Rows that cannot cover their next write because of a
-        reservation are evicted through the ordinary page-exhaustion path
-        (explicit ``page_exhausted`` finish, never a hang)."""
+        """Hold ``n`` free pages of every pool group back from decode-time
+        growth (the batcher's aging guard: a queue head deferred too long
+        on ``free_pages`` gets freed pages *reserved* instead of watching
+        running rows' growth consume them forever). Reserved pages are
+        still visible to :meth:`prefill` — the head's admission is exactly
+        what they are being saved for. ``n=0`` releases the reservation.
+        Rows that cannot cover their next write because of a reservation
+        are evicted through the ordinary page-exhaustion path (explicit
+        ``page_exhausted`` finish, never a hang)."""
         if not self.paged:
             return
-        self._reserved_pages = max(0, int(n))
-        if self._window is not None:  # the head's window pages too
-            self._window.reserved = min(
-                self._window.columns, self._reserved_pages)
+        for g in self._groups.values():
+            g.reserve(n)
         _obs.gauge("gen_pages_reserved",
                    "free pages held back for a parked queue head").set(
-                       self._reserved_pages)
+                       self._pages.reserved)
 
     def _page_gauges(self):
-        free, w = len(self._free_pages), self._window
         _obs.gauge("gen_pages_free",
-                   "free pages in the paged KV pool").set(free)
+                   "free pages in the paged KV pool").set(
+                       len(self._pages.free))
         in_use = _obs.gauge("gen_pages_in_use",
                             "allocated pages in the paged KV pool")
-        in_use.set(self.num_pages - free)
+        in_use.set(self._pages.in_use)
         share = _obs.gauge("gen_page_run_share",
                            "share of the pages the rows hold that lie in a "
                            "whole group of RUN_PAGES logical pages with "
                            "consecutive ids: what the decode kernel fetches "
                            "as one copy")
-        # and one series a pool group: (pages in use, whole groups that are
-        # runs, pages the rows hold), from the allocators' own counts
-        for g, (used, runs, held) in zip(self._group_names, self._by_group(
-                (self.num_pages - free, self._n_runs,
-                 sum(map(len, self._row_pages))),
-                w and (w.in_use, w.n_runs, w.in_use))):
-            in_use.set(used, group=g)
-            share.set(RUN_PAGES * runs / held if held else 0.0, group=g)
+        # and one series a pool group, from the allocators' own counts
+        for name, g in self._groups.items():
+            in_use.set(g.in_use, group=name)
+            share.set(g.run_share, group=name)
         _obs.gauge("gen_page_refcount_max",
                    "highest per-page refcount (sharing depth)").set(
-                       int(self._page_rc.max()) if self.num_pages else 0)
+                       self._pages.refcount_max)
 
-    def _unref_pages(self, pages) -> int:
-        """Drop one reference from each page; refcount-0 pages return to
-        the free list (the trash-page-safe reclaim contract: a page still
-        backing another row or the prefix cache stays allocated)."""
-        freed = 0
-        for pid in pages:
-            self._page_rc[pid] -= 1
-            if self._page_rc[pid] <= 0:
-                self._page_rc[pid] = 0
-                self._free_pages.give(pid)
-                freed += 1
-        return freed
-
-    def _reclaim_row(self, slot: int) -> int:
-        pages = self._row_pages[slot]
-        if self._window is not None:
-            self._window.release(slot)
-        if not pages:
-            return 0
-        self._row_pages[slot] = []
-        self._n_runs -= len(self._row_runs[slot])
-        self._row_runs[slot] = set()
-        freed = self._unref_pages(pages)
-        if freed:
-            _obs.counter("gen_pages_reclaimed_total",
-                         "pages returned to the free pool").inc(freed)
-        self._page_gauges()
-        return freed
-
-    def _avail(self) -> int:
-        # pages past the reservation are off-limits to growth: they are
-        # being accumulated for a parked queue head (reserve_pages)
-        return len(self._free_pages) - self._reserved_pages
-
-    def _evict_prefix(self, n: int, protect=()) -> int:
-        """Free up to ``n`` pages by LRU-evicting cache-only (refcount-1)
-        prefix-cache entries. Pages still shared with a live row are
-        refused by the predicate."""
-        if self.prefix_cache is None:
-            return 0
-        evicted = self.prefix_cache.evict(
-            n, lambda pid: self._page_rc[pid] == 1, protect=protect)
-        if evicted:
-            self._unref_pages(evicted)
-            _obs.counter("gen_prefix_evictions_total",
-                         "prefix-cache pages evicted under free-page "
-                         "pressure").inc(len(evicted))
+    def _reclaim_row(self, slot: int) -> None:
+        """Row ``slot``'s pages go back to their groups."""
+        if sum(g.release(slot) for g in self._groups.values()):
             self._page_gauges()
-        return len(evicted)
 
-    def _take_page(self, row: int, s: int) -> int:
-        """One free page (refcount 1) for logical page ``s`` of ``row``,
-        beside page ``s - 1`` where the free pages allow, LRU-evicting
-        prefix cache entries under pressure. Returns 0 (the trash page id —
-        never allocated) when nothing can be freed."""
-        if self._avail() <= 0 and not self._evict_prefix(1):
-            return 0
-        pages = self._row_pages[row]
-        pid = self._free_pages.take(pages[s - 1] if s else 0,
-                                    s % RUN_PAGES == 0)
-        self._page_rc[pid] = 1
-        return pid
-
-    def _note_group(self, row: int, k: int) -> None:
-        """Count group ``k`` of ``row`` (logical pages ``k * RUN_PAGES
-        ...``) as a run, or no longer, by the ids it holds now."""
-        self._n_runs += _tally_run(
-            self._row_runs[row], k,
-            self._row_pages[row][k * RUN_PAGES:(k + 1) * RUN_PAGES])
-
-    def _grow_pages(self, window: int):
-        """Allocate pages so every active row's table covers positions
-        ``p .. min(p + window, max_length - 1)``; rows that cannot even
-        cover their next write are force-finished (evicted) with
-        ``gen_page_evictions_total``. Shared (refcount > 1) pages inside
-        the write window get a private copy first — the copy-on-write
-        point: the compiled copy program runs before the decode dispatch,
-        so a forked row's writes can never mutate a page another row or
-        the prefix cache still reads. Returns the (B, U) update vectors
-        the compiled program scatters into the page-table carry."""
-        ps = self.page_size
-        upd_slots = np.zeros((self.batch_size, self._upd_width), np.int32)
-        upd_pages = np.zeros((self.batch_size, self._upd_width), np.int32)
-        allocated = 0
-        copies = []  # (row, slot, src, dst) for the compiled copy program
-
-        def _evict_row(row):
-            self.done[row] = True
-            self.page_exhausted[row] = True
-            _obs.counter(
-                "gen_page_evictions_total",
-                "rows force-finished on page exhaustion").inc(
-                    reason="exhausted")
-
-        for row in range(self.batch_size):
-            if self.done[row]:
-                continue
-            p = int(self.positions[row])
-            need = min(p + window, self.max_length - 1) // ps + 1
-            # copy-on-write: every existing page slot the window writes
-            # into must be private before the next program dispatches
-            short = False
-            for s in range(p // ps, min(need, len(self._row_pages[row]))):
-                pid = self._row_pages[row][s]
-                if self._page_rc[pid] <= 1:
-                    continue
-                new = self._take_page(row, s)
-                if not new:
-                    short = True
-                    break
-                allocated += 1
-                copies.append((row, s, pid, new))
-                self._page_rc[pid] -= 1
-                self._row_pages[row][s] = new
-                self._note_group(row, s // RUN_PAGES)
-            if short:
-                # a shared page it cannot copy = a write it cannot make
-                _evict_row(row)
-                continue
-            u = 0
-            while len(self._row_pages[row]) < need:
-                s = len(self._row_pages[row])
-                pid = self._take_page(row, s)
-                if not pid:
-                    if s * ps <= p:
-                        # cannot write the next token: evict the row
-                        _evict_row(row)
-                    break
-                upd_slots[row, u] = s
-                upd_pages[row, u] = pid
-                self._row_pages[row].append(pid)
-                if s % RUN_PAGES == RUN_PAGES - 1:
-                    self._note_group(row, s // RUN_PAGES)
-                u += 1
-                allocated += 1
-        grown = self._grow_window(_evict_row)
-        if allocated:
-            _obs.counter("gen_page_allocs_total",
-                         "pages taken from the free pool").inc(
-                             allocated, site="decode")
-        if allocated or grown:
+    def _grow_pages(self, span: int):
+        """Before a dispatch every pool group grows the active rows' tables
+        to cover positions ``p .. min(p + span, max_length - 1)``
+        (``pages.py:grow``); the page copies it asks for run first, so a
+        forked row's writes can never mutate a page another row or the
+        prefix cache still reads; rows that cannot even cover their next
+        write are force-finished (evicted) with
+        ``gen_page_evictions_total``. Returns the (B, U) update vectors the
+        compiled program scatters into the page-table carry, one a group."""
+        slots, pages, copies, changed = [], [], [], 0
+        for g in self._groups.values():
+            upd_slots, upd_pages, cow, dry, moved = g.grow(
+                self.done, self.positions, span)
+            for row in dry:  # before the next group looks at the row
+                self.done[row] = True
+                self.page_exhausted[row] = True
+                _obs.counter(
+                    "gen_page_evictions_total",
+                    "rows force-finished on page exhaustion").inc(
+                        reason="exhausted")
+            slots.append(upd_slots)
+            pages.append(upd_pages)
+            copies += cow
+            changed += moved
+        if changed:
             self._page_gauges()
         self._dispatch_cow(copies)
-        if grown is not None:  # one vector a group
-            return (self._by_group(upd_slots, grown[0]),
-                    self._by_group(upd_pages, grown[1]))
-        return upd_slots, upd_pages
-
-    def _grow_window(self, evict_row):
-        """The window group's part of :meth:`_grow_pages`: every active row
-        takes the page of its next write and gives back the pages now
-        behind its window; a row that finds the pool dry is force-finished.
-        Returns the group's (B, U) update vectors (page -1 zeroes a freed
-        column), or None where the model has no window group."""
-        w = self._window
-        if w is None:
-            return None
-        slots = np.zeros((self.batch_size, self._upd_width), np.int32)
-        pages = np.zeros((self.batch_size, self._upd_width), np.int32)
-        before = w.freed_total
-        for row in range(self.batch_size):
-            if self.done[row]:
-                continue
-            updates = w.step(row, int(self.positions[row]))
-            if updates is None:
-                evict_row(row)
-                continue
-            for u, (column, pid) in enumerate(updates):
-                slots[row, u], pages[row, u] = column, pid
-        if w.freed_total > before:
-            _obs.counter("gen_window_pages_freed_total",
-                         "pages behind a row's window returned to the "
-                         "free pool while the row lived").inc(
-                             w.freed_total - before)
         return slots, pages
 
     def _dispatch_cow(self, copies) -> None:
@@ -1254,50 +862,28 @@ class GenerationEngine:
 
     # -- pure programs (paged) -----------------------------------------------
     def _apply_table_updates(self, table, upd_slots, upd_pages, clear):
-        """Scatter the host allocator's decisions into the page-table carry:
-        install newly allocated pages ((B, U) slot/page vectors, page 0 =
-        no-op), then zero the rows of released slots."""
-        if isinstance(table, tuple):  # one table a pool group
-            return tuple(
-                self._apply_table_updates(t, s, p, clear) if g == "all"
-                else self._apply_ring_updates(t, s, p, clear)
-                for g, t, s, p in zip(self._group_names, table, upd_slots,
-                                      upd_pages))
-        bidx = jnp.arange(self.batch_size, dtype=jnp.int32)[:, None]
-        cur = table[bidx, upd_slots]
-        table = table.at[bidx, upd_slots].set(
-            jnp.where(upd_pages > 0, upd_pages, cur))
-        return jnp.where(clear[:, None], 0, table)
-
-    def _apply_ring_updates(self, table, upd_slots, upd_pages, clear):
-        """A window group's table: as above, and a page of -1 zeroes its
-        column (a page freed behind the window: the trash page from now
-        on). Frees come first in a row's vector, so a column freed and
-        given again in one step ends up given."""
-        bidx = jnp.arange(self.batch_size, dtype=jnp.int32)[:, None]
-        for u in range(upd_slots.shape[1]):
-            col, page = upd_slots[:, u:u + 1], upd_pages[:, u:u + 1]
-            table = table.at[bidx, col].set(
-                jnp.where(page > 0, page,
-                          jnp.where(page < 0, 0, table[bidx, col])))
-        return jnp.where(clear[:, None], 0, table)
+        """Scatter the host allocators' decisions into the page-table
+        carry, every group's into its own table
+        (``pages.py:apply_updates``), and zero the rows of released slots."""
+        return self._form([
+            g.apply_updates(t, s, p, clear)
+            for g, t, s, p in zip(self._groups.values(), self._each(table),
+                                  self._each(upd_slots),
+                                  self._each(upd_pages))])
 
     def _row_tables(self, table, new_row, slot):
         """(tables with ``new_row`` installed at ``slot``, that row's own
-        (1, columns) tables); a tuple of each where there are groups."""
-        if isinstance(table, tuple):
-            both = [self._row_tables(t, r, slot)
-                    for t, r in zip(table, new_row)]
-            return tuple(b[0] for b in both), tuple(b[1] for b in both)
-        table = jax.lax.dynamic_update_slice(table, new_row[None, :],
-                                             (slot, 0))
-        return table, jax.lax.dynamic_slice(table, (slot, 0),
-                                            (1, table.shape[1]))
+        (1, columns) tables), each one a pool group."""
+        tables, rows = [], []
+        for t, r in zip(self._each(table), self._each(new_row)):
+            t = jax.lax.dynamic_update_slice(t, r[None, :], (slot, 0))
+            tables.append(t)
+            rows.append(jax.lax.dynamic_slice(t, (slot, 0), (1, t.shape[1])))
+        return self._form(tables), self._form(rows)
 
-    @staticmethod
-    def _table_nd(table):
-        return tuple(NDArray(t) for t in table) \
-            if isinstance(table, tuple) else NDArray(table)
+    def _table_nd(self, table):
+        """The page tables as the model takes them."""
+        return self._form([NDArray(t) for t in self._each(table)])
 
     def _paged_prefill_fn(self, params, carry, tokens, slot, length,
                           new_row, start, key):
@@ -1347,7 +933,7 @@ class GenerationEngine:
         table = jax.lax.dynamic_update_slice(table, new_row[None, :],
                                              (slot, 0))
         row_table = jax.lax.dynamic_slice(table, (slot, 0),
-                                          (1, self._n_row_pages))
+                                          (1, table.shape[1]))
         with _HybridTrace(self._plist, list(params), False, key):
             logits, new_pools, _ = self._cached(self.net(
                 NDArray(tokens), cache=self._cache_nd(pools),
@@ -1614,7 +1200,6 @@ class GenerationEngine:
                             f"prompt length {length} >= max_length="
                             f"{self.max_length}")
                     ps = self.page_size
-                    total = self.pages_for(length)
                     # prefix adoption: walk the radix cache for the longest
                     # cached page run, keeping >= 1 suffix token so this
                     # prefill still produces the last-prompt-position
@@ -1635,61 +1220,24 @@ class GenerationEngine:
                             tail_src = cpages[start // ps]
                     suffix = length - start
                     bucket = self.bucket_for(suffix)
-                    need = total - len(adopt)
-                    # capacity check BEFORE any allocator mutation: a
-                    # failed admission must leave the slot's pending
-                    # table-clear (and its reclaimable pages) untouched, or
-                    # a released row's stale device table could keep
-                    # pointing at pages later handed to someone else (its
-                    # masked writes would corrupt them). Pages being
-                    # adopted are off-limits to the eviction headroom.
+                    need = self._pages.needed(length, len(adopt))
+                    # capacity check BEFORE any allocator mutation (every
+                    # group's, ``pages.py:require``). Pages being adopted
+                    # are off-limits to the eviction headroom.
                     protect = set(adopt)
                     if tail_src:
                         protect.add(tail_src)
-                    own = sum(1 for pid in self._row_pages[slot]
-                              if self._page_rc[pid] == 1
-                              and pid not in protect)
-                    headroom = len(self._free_pages) + own
-                    if headroom < need and self.prefix_cache is not None:
-                        headroom += self.prefix_cache.collectable(
-                            lambda pid: self._page_rc[pid] == 1,
-                            protect=protect)
-                    if headroom < need:
-                        raise RuntimeError(
-                            f"insufficient free pages for a {length}-token "
-                            f"prompt ({need} needed, "
-                            f"{len(self._free_pages)} free); release "
-                            "slots or raise num_pages")
-                    w = self._window
-                    if w is not None and (len(w.free) + len(w.rows[slot])
-                                          < w.needed(length)):
-                        raise RuntimeError(
-                            f"insufficient free pages in the window group "
-                            f"for a {length}-token prompt "
-                            f"({w.needed(length)} needed, {len(w.free)} "
-                            "free); release slots or raise its num_pages")
+                    for g in self._groups.values():
+                        g.require(slot, length, adopt, protect)
                     # previous occupant's pages, if any
                     self._reclaim_row(slot)
                     # the new row replaces it
                     self._pending_clear.discard(slot)
                     self.page_exhausted[slot] = False
-                    short = need - len(self._free_pages)
-                    if short > 0:
-                        self._evict_prefix(short, protect=protect)
-                    # adopted prefix: refcount bump, no compute
-                    for pid in adopt:
-                        self._page_rc[pid] += 1
-                    fresh = self._free_pages.take_row(
-                        need, len(adopt), adopt[-1] if adopt else 0)
-                    self._page_rc[fresh] = 1
-                    pages = adopt + fresh
-                    self._row_pages[slot] = list(pages)
-                    for k in range(total // RUN_PAGES):
-                        self._note_group(slot, k)
-                    if need:
-                        _obs.counter("gen_page_allocs_total",
-                                     "pages taken from the free pool").inc(
-                                         need, site="prefill")
+                    # one row a group; an adopted prefix costs a reference
+                    # a page, no compute
+                    new_row = [g.admit(slot, length, adopt, protect)
+                               for g in self._groups.values()]
                     if start:
                         _obs.counter(
                             "gen_prefix_hits_total",
@@ -1701,16 +1249,11 @@ class GenerationEngine:
                     if tail_src:
                         # the copy must land before the prefill dispatch
                         # writes the suffix into the same page
-                        self._dispatch_cow(
-                            [(slot, len(adopt), tail_src, fresh[0])])
+                        self._dispatch_cow([(
+                            slot, len(adopt), tail_src,
+                            self._pages.rows[slot][len(adopt)])])
                     padded = np.full((1, bucket), self.pad_id, np.int32)
                     padded[0, :suffix] = prompt[start:]
-                    new_row = np.zeros(self._n_row_pages, np.int32)
-                    new_row[:total] = pages
-                    if w is not None:  # one row a group
-                        new_row = self._by_group(new_row,
-                                                 w.admit(slot, length))
-                        self._page_gauges()
                     rec.counts = {"bucket": bucket, "suffix": suffix,
                                   "prompt": length, "pages": need,
                                   "adopted": len(adopt)}
@@ -1718,6 +1261,7 @@ class GenerationEngine:
                         ("prefill", bucket), "prefill_bucket")
                 with _obs.span("mx.gen.prefill.dispatch"):
                     start_v = jnp.full((1,), start, jnp.int32)
+                    new_row = self._form([jnp.asarray(r) for r in new_row])
                     if self.speculative:
                         carry = (self.page_table, self.pools,
                                  self.draft_pools)
@@ -1725,9 +1269,8 @@ class GenerationEngine:
                             self._params(), self._draft_params(), carry,
                             jnp.asarray(padded),
                             jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(suffix, jnp.int32),
-                            jnp.asarray(new_row), start_v,
-                            self._next_key())
+                            jnp.asarray(suffix, jnp.int32), new_row,
+                            start_v, self._next_key())
                         self.page_table, self.pools, self.draft_pools = \
                             carry
                     else:
@@ -1735,9 +1278,8 @@ class GenerationEngine:
                             self._params(), (self.page_table, self.pools),
                             jnp.asarray(padded),
                             jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(suffix, jnp.int32),
-                            self._vectors(new_row), start_v,
-                            self._next_key())
+                            jnp.asarray(suffix, jnp.int32), new_row,
+                            start_v, self._next_key())
                         self.page_table, self.pools = carry
             else:
                 with _obs.span("mx.gen.prefill.pages"):
@@ -1777,9 +1319,7 @@ class GenerationEngine:
                         # sharing the prefix adopt them (newly indexed
                         # pages gain a cache reference; already-cached
                         # prefixes are kept as-is)
-                        for pid in self.prefix_cache.insert(
-                                prompt.tolist(), self._row_pages[slot]):
-                            self._page_rc[pid] += 1
+                        self._pages.cache(slot, prompt.tolist())
                         self._page_gauges()
         if _obs.enabled():
             _obs.histogram("gen_prefill_seconds", "prompt prefill wall clock",
@@ -1848,11 +1388,11 @@ class GenerationEngine:
                     rec.counts = {k: v.tolist() for k, v in stats.items()}
                     for name in _COUNTED & set(stats):
                         _count_stats(name, stats[name])
-                if self.paged and self._window is not None:
-                    # the engine's own count beside the model's: the window
+                for g in self._counted:
+                    # the engine's own count beside the model's: the
                     # group's pages in use as this step left them
-                    rec.counts = {**(rec.counts or {}), "window_pages_in_use":
-                                  [self._window.in_use]}
+                    rec.counts = {**(rec.counts or {}),
+                                  g.counted_as: [g.in_use]}
         self.positions = positions
         if full.any():
             done = done | full
@@ -1883,16 +1423,13 @@ class GenerationEngine:
         tables cannot evict)."""
         return (self.paged and not self.speculative and self.eos_id is None
                 and not self.sampling.stochastic
-                and len(self._free_pages) >= self.batch_size
-                and (self._window is None
-                     or len(self._window.free) >= self.batch_size))
+                and all(len(g.free) >= self.batch_size
+                        for g in self._groups.values()))
 
-    @staticmethod
-    def _vectors(upd):
-        """A dispatch's update vectors on the device: one array, or one a
-        pool group."""
-        return tuple(jnp.asarray(u) for u in upd) \
-            if isinstance(upd, tuple) else jnp.asarray(upd)
+    def _vectors(self, upd):
+        """A dispatch's update vectors (:meth:`_grow_pages`) on the device,
+        in the form the programs take them."""
+        return self._form([jnp.asarray(u) for u in upd])
 
     def _take_ahead(self):
         """The step dispatched ahead, if the rows are as it left them;
@@ -1980,9 +1517,10 @@ class GenerationEngine:
         # verify program clamps per-row emission to this window
         room = np.zeros(self.batch_size, np.int32)
         for row in range(self.batch_size):
-            covered = len(self._row_pages[row]) * self.page_size
-            room[row] = min(covered, self.max_length) \
+            room[row] = min(self._pages.covered(row), self.max_length) \
                 - int(self.positions[row])
+        upd_slots = self._vectors(upd_slots)
+        upd_pages = self._vectors(upd_pages)
         key = self._next_key()
         self._note_program(("draft", self.batch_size, k), "decode")
         stochastic = self.sampling.stochastic
@@ -1993,14 +1531,14 @@ class GenerationEngine:
             (table, dpools), drafted, qdist = self._draft_jit(
                 self._draft_params(), (self.page_table, self.draft_pools),
                 jnp.asarray(self.last_tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.done), jnp.asarray(upd_slots),
-                jnp.asarray(upd_pages), jnp.asarray(clear), key)
+                jnp.asarray(self.done), upd_slots, upd_pages,
+                jnp.asarray(clear), key)
         else:
             (table, dpools), drafted = self._draft_jit(
                 self._draft_params(), (self.page_table, self.draft_pools),
                 jnp.asarray(self.last_tokens), jnp.asarray(self.positions),
-                jnp.asarray(self.done), jnp.asarray(upd_slots),
-                jnp.asarray(upd_pages), jnp.asarray(clear), key)
+                jnp.asarray(self.done), upd_slots, upd_pages,
+                jnp.asarray(clear), key)
         # commit the draft half's carry BEFORE the verify dispatch: the
         # old page_table buffer was donated to the draft program, and the
         # gen.verify fault site below must leave the engine re-entrant (a
@@ -2090,9 +1628,8 @@ class GenerationEngine:
         if not self.paged:
             return self._decode_jit.lower(self._params(), self.cache, toks,
                                           pos, done, key)
-        upd = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
-        if self._window is not None:
-            upd = self._by_group(upd, upd)
+        upd = self._form([jnp.zeros((self.batch_size, self._upd_width),
+                                    jnp.int32)] * len(self._groups))
         clear = jnp.zeros((self.batch_size,), bool)
         return self._decode_jit.lower(
             self._params(), (self.page_table, self.pools), toks, pos, done,
@@ -2171,31 +1708,22 @@ class GenerationEngine:
                     params, carry, tokens, jnp.asarray(0, jnp.int32),
                     jnp.asarray(bucket, jnp.int32), key)
         else:
-            upd_s = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
-            upd_p = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
+            upd = jnp.zeros((self.batch_size, self._upd_width), jnp.int32)
             clear = jnp.zeros((self.batch_size,), bool)
-            if bucket is not None:
+            if bucket is not None and self.speculative:
                 bucket = self.bucket_for(bucket)
-                tokens = jnp.full((1, bucket), self.pad_id, jnp.int32)
-                new_row = jax.tree.map(
-                    lambda t: jnp.zeros((t.shape[1],), jnp.int32),
-                    self.page_table)
-                start0 = jnp.zeros((1,), jnp.int32)
-                if self.speculative:
-                    dparams = self._draft_params()
-                    n_pre += len(jax.tree_util.tree_leaves(dparams))
-                    carry = (self.page_table, self.pools, self.draft_pools)
-                    lowered = self._prefill_jit.lower(
-                        params, dparams, carry, tokens,
-                        jnp.asarray(0, jnp.int32),
-                        jnp.asarray(bucket, jnp.int32), new_row, start0,
-                        key)
-                else:
-                    carry = (self.page_table, self.pools)
-                    lowered = self._prefill_jit.lower(
-                        params, carry, tokens, jnp.asarray(0, jnp.int32),
-                        jnp.asarray(bucket, jnp.int32), new_row, start0,
-                        key)
+                dparams = self._draft_params()
+                n_pre += len(jax.tree_util.tree_leaves(dparams))
+                carry = (self.page_table, self.pools, self.draft_pools)
+                lowered = self._prefill_jit.lower(
+                    params, dparams, carry,
+                    jnp.full((1, bucket), self.pad_id, jnp.int32),
+                    jnp.asarray(0, jnp.int32), jnp.asarray(bucket, jnp.int32),
+                    jnp.zeros((self.page_table.shape[1],), jnp.int32),
+                    jnp.zeros((1,), jnp.int32), key)
+            elif bucket is not None:
+                carry = (self.page_table, self.pools)
+                lowered = self.lower_prefill(bucket)
             elif program == "cow":
                 # the copy-on-write page-copy program: no params at all —
                 # the donated carry's leaves lead the flat input order
@@ -2234,8 +1762,7 @@ class GenerationEngine:
                 n_pre = len(jax.tree_util.tree_leaves(dparams))
                 carry = (self.page_table, self.draft_pools)
                 lowered = self._draft_jit.lower(dparams, carry, toks, pos,
-                                                done, upd_s, upd_p, clear,
-                                                key)
+                                                done, upd, upd, clear, key)
             else:
                 carry = (self.page_table, self.pools)
                 lowered = self.lower_decode()
@@ -2252,8 +1779,7 @@ class GenerationEngine:
         comm = _analysis.comm_report(rep)
         # residency estimate with serving categories: the donated cache
         # carry is "kv_pages" (page table + pools) in paged mode and
-        # "kv_cache" (per-layer K/V buffers) in dense mode, so genbench's
-        # "equal cache memory" claim reads auditor-attributed bytes; the
+        # "kv_cache" (per-layer K/V buffers) in dense mode; the
         # draft/verify programs tag their temporaries distinctly
         kv_cat = "kv_pages" if self.paged else "kv_cache"
         mem_cats = {i: "params" for i in range(n_pre)}
@@ -2313,13 +1839,13 @@ class GenerationEngine:
         after :meth:`prefill`, before any decode step — later forks would
         re-sample a stale position). Returns ``dst``'s current last token.
         """
-        if self.paged and self._window is not None:
+        if not self.paged:
+            raise RuntimeError("fork_slot needs a paged engine")
+        if not all(g.shares for g in self._groups.values()):
             raise RuntimeError(
                 "fork_slot shares pages between rows; a model with a window "
                 "pool group frees a row's pages behind its window, so its "
                 "rows cannot be forked")
-        if not self.paged:
-            raise RuntimeError("fork_slot needs a paged engine")
         if self._slot_state:
             raise RuntimeError(
                 "fork_slot shares pages between rows; a model that keeps "
@@ -2328,23 +1854,18 @@ class GenerationEngine:
         if src == dst or not (0 <= src < self.batch_size
                               and 0 <= dst < self.batch_size):
             raise ValueError(f"bad fork {src} -> {dst}")
-        if self.done[src] or not self._row_pages[src]:
+        if self.done[src] or not self._pages.covered(src):
             raise RuntimeError(f"cannot fork finished/empty row {src}")
         self._row_epoch += 1
         self._reclaim_row(dst)  # previous occupant's pages, if any
         self._pending_clear.discard(dst)
         self.page_exhausted[dst] = False
-        pages = list(self._row_pages[src])
-        for pid in pages:
-            self._page_rc[pid] += 1
-        self._row_pages[dst] = pages
-        self._row_runs[dst] = set(self._row_runs[src])
-        self._n_runs += len(self._row_runs[dst])
-        row = np.zeros(self._n_row_pages, np.int32)
-        row[:len(pages)] = pages
         # eager device-table install: forks happen at admission
         # boundaries, not in the per-token hot loop
-        self.page_table = self.page_table.at[dst].set(jnp.asarray(row))
+        self.page_table = self._form([
+            t.at[dst].set(jnp.asarray(g.fork(src, dst)))
+            for g, t in zip(self._groups.values(),
+                            self._each(self.page_table))])
         self.positions[dst] = self.positions[src]
         tok = int(self.last_tokens[src])
         if resample_first:
@@ -2374,9 +1895,7 @@ class GenerationEngine:
         n = min(len(tokens), int(self.positions[slot]))
         if n < self.page_size:
             return 0
-        for pid in self.prefix_cache.insert(list(tokens)[:n],
-                                            self._row_pages[slot]):
-            self._page_rc[pid] += 1
+        self._pages.cache(slot, list(tokens)[:n])
         self._page_gauges()
         return (n // self.page_size) * self.page_size
 

@@ -65,7 +65,7 @@ def served(cfg, weights, lengths=(13, 30, 7), steps=12, poison=False):
     for slot, prompt in enumerate(prompts):
         tok = engine.prefill(prompt, slot)
         rows.append((prompt, [tok], [np.asarray(engine._last_logits)]))
-    w = engine._window
+    w = engine._groups["window"]
     for _ in range(steps):
         if poison:  # what a free page of the window group holds is garbage
             free = jnp.asarray(list(w.free), jnp.int32)
@@ -127,7 +127,7 @@ def test_prefill_then_decode_through_both_pool_groups(model, poison):
                                 len(prompt) - 1, len(out))
         assert np.abs(np.stack(logits) - want).max() < 5e-5
         assert out == want.argmax(-1).tolist()
-    w = engine._window
+    w = engine._groups["window"]
     assert w.freed_total > 10 and engine.page_groups["window"]["in_use"] <= 12
     counts = obs.step_records("decode_step")[-1].counts
     held = int((engine.positions).sum())
@@ -224,11 +224,12 @@ def test_the_window_group_has_its_own_allocator_and_table(model):
     assert "xla_gather_ring" in engine.read_path
     # a row of 30 + 4 positions holds every page of the all group and the
     # window's few of the other
-    assert len(engine._row_pages[1]) == 17 and len(engine._window.rows[1]) <= 4
+    w = engine._groups["window"]
+    assert len(engine._pages.rows[1]) == 17 and len(w.rows[1]) <= 4
     engine.release_slot(1)
-    assert engine._window.rows[1] == {} and not engine._row_pages[1]
+    assert w.rows[1] == {} and not engine._pages.rows[1]
     gauge = obs.gauge("gen_pages_in_use")
-    assert gauge.value(group="window") == engine._window.in_use
+    assert gauge.value(group="window") == w.in_use
     assert gauge.value(group="all") == engine.pages_in_use
 
 
@@ -270,7 +271,7 @@ def test_admission_waits_for_the_group_that_runs_short(model):
         want = reference_logits(cfg, weights, prompt + list(req.output)[:-1],
                                 len(prompt) - 1, 6)
         assert list(req.output) == want.argmax(-1).tolist()
-    assert engine._window.in_use == 0 and engine.pages_in_use == 0
+    assert engine._groups["window"].in_use == 0 and engine.pages_in_use == 0
 
 
 def test_a_row_that_finds_the_window_pool_dry_ends_page_exhausted(model):
@@ -280,7 +281,7 @@ def test_a_row_that_finds_the_window_pool_dry_ends_page_exhausted(model):
     engine, _ = adaptor.build_serve(cfg, weights)
     for slot in range(2):     # 2 pages each: positions 2..5
         engine.prefill(list(range(1, 7)), slot)
-    assert len(engine._window.free) == 0
+    assert len(engine._groups["window"].free) == 0
     _, done, _ = engine.decode_step()   # position 6 opens a page: none is left
     assert engine.page_exhausted.all() and done.all()
     assert obs.counter("gen_page_evictions_total").value(reason="exhausted") >= 2

@@ -178,34 +178,34 @@ class TestRefcountLifecycle:
         eng = _engine(net, prefix_cache=True, eos_id=None)
         p = _prompt(16, 400)
         eng.prefill(p, slot=0)
-        a, b = eng._row_pages[0]
+        a, b = eng._pages.rows[0]
         # both full pages indexed at prefill: row + cache = rc 2
-        assert eng._page_rc[a] == eng._page_rc[b] == 2
+        assert eng._pages.rc[a] == eng._pages.rc[b] == 2
         eng.fork_slot(0, 1)
-        assert eng._page_rc[a] == eng._page_rc[b] == 3
+        assert eng._pages.rc[a] == eng._pages.rc[b] == 3
         assert REGISTRY.get("gen_page_refcount_max").value() == 3
         used = eng.pages_in_use
         eng.release_slot(0)
-        assert eng._page_rc[a] == eng._page_rc[b] == 2
+        assert eng._pages.rc[a] == eng._pages.rc[b] == 2
         assert eng.pages_in_use == used  # nothing hit rc 0 yet
         eng.release_slot(1)
-        assert eng._page_rc[a] == eng._page_rc[b] == 1  # cache-only now
+        assert eng._pages.rc[a] == eng._pages.rc[b] == 1  # cache-only now
         assert eng.pages_in_use == used
         ev0 = _counter_total("gen_prefix_evictions_total")
-        assert eng._evict_prefix(2) == 2
+        assert eng._pages.evict(2) == 2
         assert _counter_total("gen_prefix_evictions_total") == ev0 + 2
-        assert eng._page_rc[a] == eng._page_rc[b] == 0
+        assert eng._pages.rc[a] == eng._pages.rc[b] == 0
         assert eng.free_pages == eng.num_pages
 
     def test_eviction_refuses_row_backed_pages(self, net):
         eng = _engine(net, prefix_cache=True, eos_id=None)
         eng.prefill(_prompt(16, 401), slot=0)  # cached pages still rc 2
         ev0 = _counter_total("gen_prefix_evictions_total")
-        assert eng._evict_prefix(2) == 0  # a live row still reads them
+        assert eng._pages.evict(2) == 0  # a live row still reads them
         assert len(eng.prefix_cache) == 2
         assert _counter_total("gen_prefix_evictions_total") == ev0
         eng.release_slot(0)  # rc 1: cache-only, evictable now
-        assert eng._evict_prefix(2) == 2
+        assert eng._pages.evict(2) == 2
 
     def test_fork_slot_error_paths(self, net):
         dense = _engine(net, paged=False, batch_size=2)
@@ -426,19 +426,19 @@ class TestForkCancel:
         eng = _engine(net, prefix_cache=True, eos_id=None)
         got = [eng.prefill(p, slot=0)]
         eng.fork_slot(0, 1)
-        a = eng._row_pages[0][0]  # first prompt page: shared + cached
+        a = eng._pages.rows[0][0]  # first prompt page: shared + cached
         for i in range(8):
             tok, _, _ = eng.decode_step()
             got.append(int(tok[0]))
             if i == 2:  # cancel the fork mid-decode
                 free0 = eng.free_pages
-                fork_only = [pid for pid in eng._row_pages[1]
-                             if eng._page_rc[pid] == 1]
+                fork_only = [pid for pid in eng._pages.rows[1]
+                             if eng._pages.rc[pid] == 1]
                 eng.release_slot(1)
                 # only the fork's private (rc-0 after release) pages came
                 # back; pages shared with row 0 / the cache survived
                 assert eng.free_pages == free0 + len(fork_only)
-                assert eng._page_rc[a] == 2  # row 0 + prefix cache
+                assert eng._pages.rc[a] == 2  # row 0 + prefix cache
         assert got == want  # the survivor never saw the cancellation
 
 

@@ -64,7 +64,7 @@ def history(model):
     ahead = obs.counter("gen_decode_ahead_total")
     before = {o: ahead.value(outcome=o) for o in ("used", "dropped")}
     reqs, steps, step = [], [], 0
-    w = engine._window
+    w = engine._groups["window"]
     while step < 48 or batcher.pending or batcher.active:
         for i, (_, new, at) in enumerate(ARRIVALS):
             if at == step:
@@ -73,9 +73,9 @@ def history(model):
             reqs[CANCELS[step]].cancel()
         batcher.step()
         steps.append({
-            "free": len(engine._free_pages), "window_in_use": w.in_use,
+            "free": len(engine._pages.free), "window_in_use": w.in_use,
             "row_epoch": engine._row_epoch,
-            "pages": [sorted(p) for p in engine._row_pages],
+            "pages": [sorted(p) for p in engine._pages.rows],
             "window_pages": [sorted(r.values()) for r in w.rows],
             "positions": engine.positions.tolist(),
             "queued": batcher.pending, "active": batcher.active})

@@ -145,8 +145,8 @@ def test_a_cancelled_rows_finish_counts_in_the_step_that_swept_it(toy):
 
 def test_a_prefill_that_the_pool_cannot_cover_leaves_a_short_record(toy):
     engine, _ = toy()
-    while len(engine._free_pages):   # a pool run dry
-        engine._free_pages.take()
+    while len(engine._pages.free):   # a pool run dry
+        engine._pages.free.take()
     before = obs.step_records("prefill")
     with pytest.raises(RuntimeError, match="insufficient free pages"):
         engine.prefill([1, 2, 3, 4, 5], slot=0)

@@ -14,7 +14,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import observability as obs
 from mxnet_tpu.inference import GenerationEngine
-from mxnet_tpu.inference.engine import _WindowPages
+from mxnet_tpu.inference.pages import _WindowPages
 from mxnet_tpu.ops import flash_attention
 from mxnet_tpu.ops import pallas_paged_attention as ppa
 from mxnet_tpu.parallel import moe
@@ -87,7 +87,7 @@ def served(model):
     reqs = [batcher.submit(rng.integers(1, cfg["n_vocab"], n).tolist(),
                            max_new_tokens=m)
             for n, m in ((5, 12), (13, 20), (30, 9), (7, 25), (16, 16), (3, 30))]
-    w = engine._window
+    w = engine._groups["window"]
     while batcher.pending or batcher.active:
         batcher.step()
         assert max(map(len, w.rows)) <= w.columns - 1
@@ -133,7 +133,7 @@ def test_prefill_then_decode_through_both_page_groups(model, served):
     got = gaps(cfg, weights, requests)
     assert all(got[k] <= TOY_LIMITS[k] for k in TOY_LIMITS), got
     assert engine.layer_groups == ("all", "window", "window", "window")
-    assert engine._window.freed_total > 10
+    assert engine._groups["window"].freed_total > 10
     assert "full layers: xla_gather (the backend is not a TPU)" in engine.read_path
     assert "window layers: xla_gather" in engine.read_path
     counts = obs.step_records("decode_step")[-1].counts
@@ -340,7 +340,8 @@ def test_a_window_of_4096_over_pages_of_16_never_holds_more_than_258_pages():
         assert len(w.rows[slot]) == w.needed(length) <= 258
         assert np.count_nonzero(row) == len(w.rows[slot])
         for position in range(length, length + 600):
-            assert w.step(slot, position) is not None
+            done = [row != slot for row in range(2)]
+            assert not w.grow(done, [position] * 2)[3]   # no row left dry
             held = w.rows[slot]
             assert len(held) <= 258
             # every position the row's next softmax reads lies in a held page

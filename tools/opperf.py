@@ -76,7 +76,7 @@ _CASES = {
 
 def _paged_attention_case():
     """Engine-internal surface (no nd registry entry): the paged decode
-    read path at the genbench decode shape — f32 activations, bf16 pool."""
+    read path at a small decode shape — f32 activations, bf16 pool."""
     import jax.numpy as jnp
 
     rng = np.random.RandomState(0)
